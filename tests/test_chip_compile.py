@@ -1,0 +1,155 @@
+"""Ask the chip's compiler about the main-path kernels, without a chip.
+
+The TPU compiler is installed here and compiles for a *described* v5e
+(`jax.experimental.topologies`), so the Pallas kernels that every other
+test only ever runs interpreted are compiled for real: a misaligned
+slice, an index map the Mosaic compiler cannot legalize or a kernel
+that needs more fast memory than it may use fails here, at no chip
+time. Nothing runs and nothing is timed — a compile that passes is not
+a chip run (chip_smoke.py is).
+
+All cases live in this one file and the topology is described inside a
+module-scoped fixture, never at import: only one process may load the
+TPU's library, xdist workers import every test file, and only the
+worker that is handed this file may load it.
+
+Not here, on purpose: the XLA programs the join/aggregate queries mint
+(`ops/join.build_lookup`, `probe_counts`, `ops/groupby.sort_group_reduce`,
+`ops/sort.sort_order`) at the default batch of 2^20 rows. The chip's
+compiler accepts them, but takes 63-290 s for each here, and still 38 s
+for `build_lookup` at 2^16; the seconds are in CHANGES.md (PR 22). One
+of them is kept, at the length that compiles in about 10 s: the join
+build at 2^14 rows.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+import trino_tpu  # noqa: F401  (x64)
+
+BATCH = 1 << 20  # the engine's default batch_rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry written for a described chip cannot be read back without
+    # one: keep these compiles out of the persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "n,value_columns,capacity",
+    [
+        pytest.param(BATCH, 9, 16, id="1M-9cols-cap16"),
+        pytest.param(BATCH, 9, 2048, id="1M-9cols-cap2048"),
+        pytest.param(1 << 22, 9, 16, id="4M-9cols-cap16"),
+        # what chip_smoke's G3 produces at SF1: count(*) and
+        # sum(l_quantity) over a 160-slot key domain
+        pytest.param(BATCH, 3, 160, id="g3-1M-3cols-cap160"),
+    ],
+)
+def test_grouped_sum_mxu_compiles_for_v5e(one_chip, n, value_columns, capacity):
+    from trino_tpu.ops.mxu_groupby import grouped_sum_mxu
+
+    compiled = grouped_sum_mxu.lower(
+        _sds((n,), jnp.int32, one_chip),
+        tuple(_sds((n,), jnp.int64, one_chip) for _ in range(value_columns)),
+        _sds((n,), jnp.bool_, one_chip),
+        capacity=capacity, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mxu_join_probe_page_sums_compiles_for_v5e(one_chip):
+    """The MXU join-project contraction at its largest key domain. It is
+    behind mxu_join_enabled=False today; a refusal here is recorded, not
+    repaired, in this PR."""
+    from trino_tpu.ops import join as J
+    from trino_tpu.ops.mxu_join import MAX_CAPACITY, probe_page_sums
+
+    def s(shape, dtype):
+        return _sds(shape, dtype, one_chip)
+
+    ls = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(
+            J.build_lookup,
+            (s((MAX_CAPACITY,), jnp.int64),),
+            (s((MAX_CAPACITY,), jnp.bool_),),
+            s((MAX_CAPACITY,), jnp.bool_),
+        ),
+    )
+    try:
+        compiled = probe_page_sums.lower(
+            ls,
+            s((MAX_CAPACITY,), jnp.int32),  # kid_by_pos
+            s((MAX_CAPACITY,), jnp.int64),  # distinct_keys
+            s((), jnp.int32),  # n_distinct
+            s((BATCH,), jnp.int64), s((BATCH,), jnp.bool_),
+            s((BATCH,), jnp.bool_),
+            (s((BATCH,), jnp.int64), s((BATCH,), jnp.int64)),
+            (s((BATCH,), jnp.bool_), s((BATCH,), jnp.bool_)),
+            kinds=("sum", "count"), capacity=MAX_CAPACITY,
+            use_mxu=True, interpret=False, hash_path=False,
+        ).compile()
+    except Exception as e:  # the compiler's refusal is the finding
+        pytest.xfail(f"v5e compiler refused probe_page_sums: {str(e)[:300]}")
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_join_build_lookup_compiles_for_v5e(one_chip):
+    """One packed uint64 sort: the program every join's build side runs
+    (2^14 rows; see the module docstring for why not 2^20)."""
+    from trino_tpu.ops.join import build_lookup
+
+    n = 1 << 14
+    compiled = build_lookup.lower(
+        (_sds((n,), jnp.int64, one_chip),), (_sds((n,), jnp.bool_, one_chip),),
+        _sds((n,), jnp.bool_, one_chip),
+    ).compile()
+    assert "sort" in compiled.as_text()
+
+
+def test_distributed_groupby_step_compiles_for_four_v5e(topo):
+    """The partial -> all_to_all -> final aggregation step as one SPMD
+    program over the four described chips."""
+    from trino_tpu.parallel.exchange import distributed_groupby_step
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    rows = NamedSharding(mesh, PartitionSpec("shard"))
+    n = 4 * (1 << 14)
+    step = distributed_groupby_step(mesh, "shard", 1 << 10, 1)
+    compiled = step.lower(
+        [_sds((n,), jnp.int64, rows)], [_sds((n,), jnp.bool_, rows)],
+        _sds((n,), jnp.bool_, rows), [_sds((n,), jnp.int64, rows)],
+    ).compile()
+    assert "all-to-all" in compiled.as_text()

@@ -187,6 +187,84 @@ def test_persistent_cache_prepare_is_idempotent(tmp_path):
     assert cache.scrubbed == 0 and cache.evicted == 0
 
 
+@pytest.mark.parametrize("case", ["env_set", "env_unset", "cpu_process"])
+def test_persistent_cache_location(case, monkeypatch, tmp_path):
+    """Where the cache lands: JAX_COMPILATION_CACHE_DIR when set (and no
+    directory set in code), else one fixed path inside the checkout; a
+    CPU process gets none. jax.config.update is recorded, not applied,
+    and the real cache directory is never created."""
+    import subprocess
+    import sys
+
+    import jax
+
+    from trino_tpu.compile import cache as cc
+
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: updates.append((name, value))
+    )
+    monkeypatch.setattr(cc, "ACTIVE_PERSISTENT_CACHE", None)
+    monkeypatch.setattr(cc, "install_cache_event_listener", lambda: True)
+    made = []
+    monkeypatch.setattr(
+        cc.os, "makedirs", lambda d, exist_ok=False: made.append(d)
+    )
+    monkeypatch.delenv("TRINO_TPU_NO_COMPILE_CACHE", raising=False)
+
+    if case == "cpu_process":
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path))
+        assert cc.configure_persistent_cache() is None
+        assert updates == [] and made == []
+        return
+
+    # a TPU-targeted process: JAX_PLATFORMS unset, or the CPU not first
+    monkeypatch.delenv("JAX_PLATFORMS")
+    if case == "env_set":
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")  # the chip machine's
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path))
+        cache = cc.configure_persistent_cache()
+        assert cache.dir == str(tmp_path) and made == [str(tmp_path)]
+        assert "jax_compilation_cache_dir" not in dict(updates)
+    else:
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        cache = cc.configure_persistent_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cache.dir.startswith(os.path.join(repo, ".cache", "xla") + os.sep)
+        assert dict(updates)["jax_compilation_cache_dir"] == cache.dir
+        # fixed: the same path on a second call and in another process
+        assert cc.PersistentCompileCache().dir == cache.dir
+        env = {k: v for k, v in os.environ.items() if k != cc.CACHE_DIR_ENV}
+        env["JAX_PLATFORMS"] = "cpu"  # the child only computes the path
+        other = subprocess.run(
+            [sys.executable, "-c",
+             "from trino_tpu.compile.cache import PersistentCompileCache as P;"
+             "print(P().dir)"],
+            env=env, cwd=repo, capture_output=True, text=True, check=True,
+        ).stdout.strip().splitlines()[-1]
+        assert other == cache.dir
+    # TPU compiles of about a second are cached too
+    assert dict(updates)["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert cc.ACTIVE_PERSISTENT_CACHE is cache
+
+
+def test_persistent_cache_activation_failure_raises(monkeypatch, tmp_path):
+    """A cache that cannot be prepared is an error on a TPU process, not
+    a silent downgrade to compiling everything again."""
+    from trino_tpu.compile import cache as cc
+
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.setattr(cc, "ACTIVE_PERSISTENT_CACHE", None)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.delenv("TRINO_TPU_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(blocker / "cache"))
+    with pytest.raises(OSError):
+        cc.configure_persistent_cache()
+    assert cc.ACTIVE_PERSISTENT_CACHE is None
+
+
 # ---------------------------------------------------------------------------
 # spill re-read capacity restore (exec/spill.py)
 # ---------------------------------------------------------------------------

@@ -91,11 +91,7 @@ def test_mesh_program_contains_collective():
     from trino_tpu import types as T
     from trino_tpu.block import Column, RelBatch
     from trino_tpu.parallel.mesh_plan import AXIS, _exchange_hash
-    from trino_tpu.jaxcfg import get_shard_map
-
-    shard_map = get_shard_map()
-    if shard_map is None:
-        pytest.skip("shard_map unavailable in this jax")
+    from jax import shard_map
 
     devs = jax.devices()
     mesh = Mesh(np.array(devs), (AXIS,))

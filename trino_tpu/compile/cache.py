@@ -22,9 +22,16 @@ a managed directory: entries live under a versioned salt directory
 schema-rev bump starts a fresh namespace instead of deserializing
 stale executables; startup scrubs zero-byte / orphaned-tmp entries
 (a process killed mid-write must not poison successors); total size is
-LRU-bounded by file mtime; hit/evict/scrub counts feed METRICS. The
-CPU-platform opt-out and the 5 s min-compile-time floor are preserved
-from jaxcfg (XLA:CPU AOT entries can SIGILL on reload).
+LRU-bounded by file mtime; hit/evict/scrub counts feed METRICS. CPU
+processes get no persistent cache (XLA:CPU AOT entries can SIGILL on
+reload).
+
+Placement: `JAX_COMPILATION_CACHE_DIR`, when set, is the cache — JAX
+reads the variable itself, so this module sets no directory in code and
+only keeps its scrub/evict/counters on it. Unset, the cache is one
+fixed path inside the checkout (`<repo>/.cache/xla/<salt>`): the path
+is part of the cache key, so it is never built from a temp name, pid or
+time.
 """
 
 from __future__ import annotations
@@ -41,6 +48,14 @@ from typing import Any, Callable, Dict, Optional, Tuple
 ENGINE_SCHEMA_REV = 1
 
 _MB = 1 << 20
+
+# where JAX itself looks for the cache directory
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the root when that variable is unset: fixed, inside the checkout
+DEFAULT_CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "xla",
+)
 
 
 class ProgramCache:
@@ -159,12 +174,16 @@ class PersistentCompileCache:
                  max_bytes: Optional[int] = None):
         import jax
 
-        self.root = root or os.environ.get(
-            "TRINO_TPU_COMPILE_CACHE",
-            os.path.expanduser("~/.trino_tpu_xla_cache"),
-        )
         self.salt = f"jax{jax.__version__}-schema{ENGINE_SCHEMA_REV}"
-        self.dir = os.path.join(self.root, self.salt)
+        # placed from outside: the directory is exactly what the
+        # variable says (JAX reads it too, so both agree); otherwise a
+        # salted directory under `root` that this class points JAX at
+        self.external = root is None and bool(os.environ.get(CACHE_DIR_ENV))
+        if self.external:
+            self.root = self.dir = os.environ[CACHE_DIR_ENV]
+        else:
+            self.root = root or DEFAULT_CACHE_ROOT
+            self.dir = os.path.join(self.root, self.salt)
         if max_bytes is None:
             max_bytes = int(
                 os.environ.get("TRINO_TPU_COMPILE_CACHE_MAX_MB", "1024")
@@ -239,26 +258,22 @@ class PersistentCompileCache:
 
     # -- activation ------------------------------------------------------
 
-    def activate(self) -> bool:
-        """Point jax's persistent compilation cache at the managed salt
-        directory. Returns False (cache disabled, engine fully
-        functional) on any failure — the cache is an optimization."""
+    def activate(self) -> None:
+        """Make the managed directory JAX's persistent compilation
+        cache. Raises when the directory cannot be prepared or JAX
+        refuses the settings: on a TPU process a cache that cannot be
+        activated costs minutes of compiling per process, so it is an
+        error, not a silent downgrade."""
         import jax
 
-        try:
-            self.prepare()
+        self.prepare()
+        if not self.external:
             jax.config.update("jax_compilation_cache_dir", self.dir)
-            # 5s floor keeps XLA:CPU programs (sub-second compiles) out
-            # of the cache even when JAX silently falls back to CPU —
-            # CPU AOT entries record compile-option pseudo-features the
-            # loader rejects on reload (can SIGILL)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 5.0
-            )
-        except Exception:
-            return False
+        # cache every program: a query mints many that compile in about
+        # a second each (the Pallas group-by among them), and CPU
+        # processes, the old reason for a floor, never get here
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         install_cache_event_listener()
-        return True
 
     # -- observability ---------------------------------------------------
 
@@ -322,18 +337,22 @@ def install_cache_event_listener() -> bool:
 
 def configure_persistent_cache() -> Optional[PersistentCompileCache]:
     """jaxcfg entry point, run once at import. TPU-targeted processes
-    only (see PersistentCompileCache.activate for the CPU rationale);
-    opt out entirely with TRINO_TPU_NO_COMPILE_CACHE=1."""
+    only (XLA:CPU AOT entries record compile-option pseudo-features the
+    loader rejects on reload, and can SIGILL); opt out entirely with
+    TRINO_TPU_NO_COMPILE_CACHE=1. Raises if the cache cannot be
+    activated."""
     global ACTIVE_PERSISTENT_CACHE
     if ACTIVE_PERSISTENT_CACHE is not None:
         return ACTIVE_PERSISTENT_CACHE
+    # a CPU process is one whose JAX_PLATFORMS puts the CPU first: in
+    # "tpu,cpu" the CPU is only where host-side arrays live
+    first_platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
     if (
         os.environ.get("TRINO_TPU_NO_COMPILE_CACHE") == "1"
-        or "cpu" in os.environ.get("JAX_PLATFORMS", "")
+        or first_platform == "cpu"
     ):
         return None
     cache = PersistentCompileCache()
-    if cache.activate():
-        ACTIVE_PERSISTENT_CACHE = cache
-        return cache
-    return None
+    cache.activate()
+    ACTIVE_PERSISTENT_CACHE = cache
+    return cache

@@ -12,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from trino_tpu.analysis.witness import named_condition, named_lock, named_rlock
 from typing import List, Optional
@@ -43,7 +44,17 @@ def _build() -> Optional[str]:
         )
         os.replace(tmp, _LIB)
         return _LIB
-    except Exception:
+    except Exception as e:
+        # get_lib tries once per process, so this is said once
+        compiler_said = (getattr(e, "stderr", None) or b"").decode(
+            errors="replace"
+        )[-500:]
+        print(
+            f"trino_tpu.native: could not build {os.path.basename(_LIB)} "
+            f"({type(e).__name__}: {e}); using the numpy fallbacks\n"
+            f"{compiler_said}",
+            file=sys.stderr,
+        )
         return None
 
 

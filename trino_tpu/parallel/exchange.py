@@ -21,11 +21,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
-
-from trino_tpu.jaxcfg import get_shard_map
-
-shard_map = get_shard_map()
 
 from trino_tpu.ops import groupby as G
 from trino_tpu.ops.gather import take_clip
@@ -100,11 +97,6 @@ def distributed_groupby_step(
     so every group lives on exactly one shard; a nonzero `overflowed`
     means some shard's table filled — the host reruns at 2x capacity.
     """
-    if shard_map is None:
-        raise RuntimeError(
-            "shard_map is unavailable in this jax version; the collective "
-            "exchange requires jax.shard_map or jax.experimental.shard_map"
-        )
     n = mesh.shape[axis]
 
     def local(keys, valids, live, values):

@@ -38,12 +38,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
 from trino_tpu.analysis.witness import named_lock
-from trino_tpu.jaxcfg import get_shard_map
-
-shard_map = get_shard_map()
 
 from trino_tpu import types as T
 from trino_tpu.block import (
@@ -944,8 +942,6 @@ def mesh_eligibility(subplan: SubPlan) -> Dict[str, int]:
     from trino_tpu.parallel.mesh_chunk import static_collective_counts
     from trino_tpu.runtime.stages import topo_order
 
-    if shard_map is None:
-        raise MeshUnsupported("shard_map unavailable in this jax")
     order = topo_order(subplan)
     if len(order) < 2:
         raise MeshUnsupported("single-fragment plan")
@@ -1009,8 +1005,6 @@ class MeshExecutor:
         from trino_tpu.parallel.mesh_chunk import ChunkedMeshRunner
         from trino_tpu.runtime.stages import topo_order
 
-        if shard_map is None:
-            raise MeshUnsupported("shard_map unavailable in this jax")
         order = topo_order(subplan)
         if len(order) < 2:
             raise MeshUnsupported("single-fragment plan")
