@@ -22,6 +22,9 @@ class ClientResult:
     query_id: str
     columns: List[dict]
     rows: List[list]
+    # the last response's `stats` (StatementStats): state and the
+    # server's queuedTimeMillis / elapsedTimeMillis / cpuTimeMillis
+    stats: dict = dataclasses.field(default_factory=dict)
 
     @property
     def column_names(self) -> List[str]:
@@ -87,7 +90,8 @@ class Client:
             rows.extend(out.get("data", ()))
             next_uri = out.get("nextUri")
             if next_uri is None:
-                return ClientResult(query_id, columns, rows)
+                return ClientResult(query_id, columns, rows,
+                                    out.get("stats") or {})
             if time.monotonic() > deadline:
                 raise QueryError(f"query {query_id} timed out client-side")
             if not out.get("data"):

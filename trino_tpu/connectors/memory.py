@@ -19,6 +19,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.block import Column, Dictionary, RelBatch, bucket_capacity
+from trino_tpu.runtime.tracing import host_span
 from trino_tpu.connectors.spi import (
     ColumnMetadata,
     Connector,
@@ -262,7 +263,14 @@ class MemoryPageSource(ConnectorPageSource):
             idx = None
             cache_key = (t.version, tuple(columns), batch_rows, lo, hi, cs,
                          stab_sig)
-        cached = t.device_cache.get(cache_key)
+        # leaf spans of a profiler trace (runtime/tracing.py), around the
+        # work between the yields: `scan.batches` says whether the
+        # split's batches were on the device; where not, the scan pays
+        # `scan.host_filter` once and `scan.to_device` per batch
+        with host_span("scan.batches", cached=0) as span:
+            cached = t.device_cache.get(cache_key)
+            if cached is not None:
+                span.set_metadata(cached=1)
         if cached is not None:
             yield from cached
             return
@@ -273,22 +281,28 @@ class MemoryPageSource(ConnectorPageSource):
             from trino_tpu.connectors.pushdown import constraint_mask
 
             n = t.row_count
-            mask = constraint_mask(
-                cs,
-                lambda name: (
-                    np.asarray(t.data[name].data[:n]),
-                    None if t.data[name].valid is None
-                    else t.data[name].valid[:n],
-                ),
-            )
-            if idx is None:
-                idx = np.nonzero(mask[lo:hi])[0] + lo
-                lo = hi = None
-            else:
-                idx = idx[mask[idx]]
+            with host_span("scan.host_filter", rows=n):
+                mask = constraint_mask(
+                    cs,
+                    lambda name: (
+                        np.asarray(t.data[name].data[:n]),
+                        None if t.data[name].valid is None
+                        else t.data[name].valid[:n],
+                    ),
+                )
+                if idx is None:
+                    idx = np.nonzero(mask[lo:hi])[0] + lo
+                    lo = hi = None
+                else:
+                    idx = idx[mask[idx]]
         out = []
-        for batch in self._materialize(t, columns, batch_rows, lo, hi, idx,
-                                       stabilizer=stabilizer):
+        batches = self._materialize(t, columns, batch_rows, lo, hi, idx,
+                                    stabilizer=stabilizer)
+        while True:
+            with host_span("scan.to_device"):
+                batch = next(batches, None)
+            if batch is None:
+                break
             out.append(batch)
             yield batch
         for k in [k for k in t.device_cache if k[0] != t.version]:

@@ -10,11 +10,13 @@ on top of the same plans.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Sequence
 
 from trino_tpu import types as T
 from trino_tpu.connectors.spi import CatalogManager, Connector
 from trino_tpu.exec import CollectorSink, Driver, Pipeline
+from trino_tpu.runtime.tracing import host_span, phase_span
 from trino_tpu.sql import ast
 from trino_tpu.sql.analyzer import AnalysisError, Analyzer
 from trino_tpu.sql.local_planner import LocalPlanner
@@ -232,6 +234,10 @@ class MaterializedResult:
     # "mesh" (ICI collectives), "http" (page exchange), "fte" (spooled).
     # Surfaces the silent mesh fallback (VERDICT r2 weak #4).
     data_plane: str = "local"
+    # what the runner measured of a tracked query, from the stamps its
+    # spans use: query_id, elapsed_ms, plan_ms, cpu_ms (the protocol's
+    # StatementStats are filled from it)
+    stats: Optional[dict] = None
 
     def only_value(self):
         assert len(self.rows) == 1 and len(self.rows[0]) == 1, self.rows
@@ -336,9 +342,12 @@ class LocalQueryRunner:
     def execute(
         self, sql: str, identity=None, transaction_id: Optional[str] = None,
         prepared: Optional[Dict[str, str]] = None,
+        queued_ns: Optional[int] = None,
     ) -> MaterializedResult:
         """`identity` overrides the session user for this statement (the
-        HTTP front passes the authenticated principal).
+        HTTP front passes the authenticated principal). `queued_ns` is
+        how long the caller held the statement before this call (the
+        server's admission and hand-off); it goes onto the query span.
 
         `transaction_id` selects EXPLICIT transaction threading — the
         protocol model, where each client connection carries its own
@@ -346,7 +355,13 @@ class LocalQueryRunner:
         holds no cross-client state. Pass the sentinel "NONE" for an
         autocommit statement in explicit mode. When None (embedded
         use), the runner's own session transaction applies."""
-        stmt = parse(sql)
+        t_parse = time.perf_counter_ns()
+        with host_span("phase.parse"):
+            stmt = parse(sql)
+        # (parse, queued) nanoseconds before the query span opens
+        self._stmt_txn.before_ns = (
+            time.perf_counter_ns() - t_parse, queued_ns or 0
+        )
         explicit = transaction_id is not None
         active = (
             None if transaction_id in (None, "NONE") else transaction_id
@@ -1222,24 +1237,36 @@ class LocalQueryRunner:
             QueryCreatedEvent(query_id, sql, _time.time())
         )
         status, failure, rows_n = "finished", None, 0
-        try:
-            result = self._execute_query(
-                stmt, sql_key=sql, query_id=query_id,
-                trace=trace, query_span=qspan,
-            )
-            rows_n = len(result.rows)
-            return result
-        except BaseException as e:
-            status, failure = "failed", repr(e)
-            if not qspan.ended:
-                qspan.event("exception", error=repr(e)[:300])
-                qspan.set(error=True)
-            raise
-        finally:
-            self._finalize_query(
-                query_id, sql, trace, qspan, status, failure, rows_n,
-                counters_before,
-            )
+        parse_ns, queued_ns = getattr(self._stmt_txn, "before_ns", (0, 0))
+        qspan.set(queued_ms=queued_ns / 1e6, plan_ms=parse_ns / 1e6)
+        # entered and left on this, the executing thread: in a profiler
+        # trace the statement is one event with everything below inside
+        with qspan:
+            try:
+                result = self._execute_query(
+                    stmt, sql_key=sql, query_id=query_id,
+                    trace=trace, query_span=qspan,
+                )
+                rows_n = len(result.rows)
+                result.stats = {
+                    "query_id": query_id,
+                    "elapsed_ms": qspan.duration_s * 1e3,
+                    "plan_ms": qspan.attributes["plan_ms"],
+                    "cpu_ms": qspan.attributes.get("cpu_ms", 0.0),
+                }
+                return result
+            except BaseException as e:
+                status, failure = "failed", repr(e)
+                if not qspan.ended:
+                    qspan.event("exception", error=repr(e)[:300])
+                    qspan.set(error=True)
+                raise
+            finally:
+                with phase_span(qspan, "finalize"):
+                    self._finalize_query(
+                        query_id, sql, trace, qspan, status, failure,
+                        rows_n, counters_before,
+                    )
 
     def _finalize_query(self, query_id, sql, trace, qspan, status,
                         failure, rows_n, counters_before):
@@ -1287,6 +1314,13 @@ class LocalQueryRunner:
             )
 
     def _plan(self, q: ast.Query, sql_key: Optional[str], query_span=None):
+        """(logical, physical) plan of `q`, from the plan cache where it
+        has one; `phase.plan` in a profiler trace, with `hit`."""
+        with phase_span(query_span, "plan", hit=0) as span:
+            return self._plan_in(span, q, sql_key, query_span)
+
+    def _plan_in(self, plan_span, q: ast.Query, sql_key: Optional[str],
+                 query_span=None):
         import contextlib
 
         self._last_adaptive_report = None  # set again if adaptive runs
@@ -1318,6 +1352,7 @@ class LocalQueryRunner:
         if cached is not None:
             # access control re-checks on every execution, cached or not
             self._check_scans(cached[0])
+            plan_span.set_metadata(hit=1)
             return cached
         from trino_tpu.sql.analyzer import (
             plan_is_volatile,
@@ -1446,13 +1481,18 @@ class LocalQueryRunner:
 
         from trino_tpu.runtime.metrics import set_compile_attribution
 
+        t_plan = time.perf_counter_ns()
         output, physical = self._plan(q, sql_key, query_span=query_span)
-        self._start_warmup(physical)
-        ctx = self._execution_ctx()
-        self._last_pool = ctx.get("memory_pool")
-        pipelines, chain = physical.instantiate(ctx)
-        sink = CollectorSink()
-        chain.append(sink)
+        with phase_span(query_span, "instantiate"):
+            self._start_warmup(physical)
+            ctx = self._execution_ctx()
+            self._last_pool = ctx.get("memory_pool")
+            pipelines, chain = physical.instantiate(ctx)
+            sink = CollectorSink()
+            chain.append(sink)
+        if query_span is not None:
+            query_span.set(plan_ms=query_span.attributes.get("plan_ms", 0.0)
+                           + (time.perf_counter_ns() - t_plan) / 1e6)
         # compile attribution reuses the tracked query id, so the
         # per-query counter retired at finalization is the same one the
         # listener installed compiles under. Internal subqueries
@@ -1470,18 +1510,33 @@ class LocalQueryRunner:
             from trino_tpu.runtime.tracing import KIND_PHASE
 
             exec_span = query_span.child("execute", KIND_PHASE)
+        op_parent = exec_span if query_span is not None else None
+        cpu0 = time.thread_time_ns()
         try:
             with exec_span:
-                for p in pipelines:
-                    Driver(p).run()
-                Driver(Pipeline(chain)).run()
-                checks = ctx.get("deferred_checks", ())
-                rows, flags = sink.rows_with(tuple(f for f, _ in checks))
-                for v, (_, msg) in zip(flags, checks):
-                    if v:
-                        raise RuntimeError(msg)
+                try:
+                    for p in pipelines:
+                        Driver(p, span=op_parent).run()
+                    Driver(Pipeline(chain), span=op_parent).run()
+                    checks = ctx.get("deferred_checks", ())
+                    rows, flags = sink.rows_with(
+                        tuple(f for f, _ in checks))
+                    for v, (_, msg) in zip(flags, checks):
+                        if v:
+                            raise RuntimeError(msg)
+                finally:
+                    if query_span is not None:
+                        # this thread's CPU time inside the phase: wall
+                        # minus it is waiting (the device, the GIL)
+                        cpu_ns = time.thread_time_ns() - cpu0
+                        exec_span.set(cpu_ns=cpu_ns)
+                        query_span.set(cpu_ms=cpu_ns / 1e6)
         finally:
             set_compile_attribution(prev_qid)
+        with phase_span(query_span, "release"):
+            # the operators' state goes here and not as the frame ends:
+            # dropping the last references frees their device buffers
+            del pipelines, chain, sink, ctx
         return MaterializedResult(
             rows,
             list(output.names),

@@ -16,6 +16,7 @@ import dataclasses
 from typing import List, Sequence
 
 from trino_tpu.exec.operators import Operator
+from trino_tpu.runtime import tracing
 
 
 @dataclasses.dataclass
@@ -37,8 +38,12 @@ class TaskAbortedError(RuntimeError):
 class Driver:
     """Runs one pipeline to completion (Driver.processInternal analogue)."""
 
-    def __init__(self, pipeline: Pipeline, should_stop=None, observer=None):
+    def __init__(self, pipeline: Pipeline, should_stop=None, observer=None,
+                 span=None):
         self.ops = pipeline.operators
+        # while a profiler trace runs, every operator call is a leaf
+        # span of it, and `span` gets one operator span per operator
+        self._span = span
         self._finish_signalled = [False] * len(self.ops)
         self._should_stop = should_stop
         # observer(op_name, moved) fires after every batch move (moved=
@@ -49,6 +54,15 @@ class Driver:
         self._observer = observer
 
     def run(self) -> None:
+        if not tracing.profiling():
+            return self._run(None)
+        tallies = [tracing.OpTally(type(o).__name__) for o in self.ops]
+        try:
+            return self._run(tallies)
+        finally:
+            tracing.record_operators(self._span, tallies)
+
+    def _run(self, tallies) -> None:
         ops = self.ops
         n = len(ops)
         while not ops[-1].is_finished():
@@ -67,16 +81,31 @@ class Driver:
                     # a long batch train, not after it
                     if self._should_stop is not None and self._should_stop():
                         raise TaskAbortedError("task aborted")
-                    out = cur.get_output()
-                    if out is None:
-                        break
-                    nxt.add_input(out)
+                    if tallies is None:
+                        out = cur.get_output()
+                        if out is None:
+                            break
+                        nxt.add_input(out)
+                    else:
+                        with tallies[i].call("get_output"):
+                            out = cur.get_output()
+                        if out is None:
+                            break
+                        # batches an operator put out; the sink's: took in
+                        tallies[i].batches += 1
+                        tallies[i + 1].batches += i + 2 == n
+                        with tallies[i + 1].call("add_input"):
+                            nxt.add_input(out)
                     progressed = True
                     if self._observer is not None:
                         self._observer(type(cur).__name__, True)
                 # finish cascade (Driver.java:417)
                 if cur.is_finished() and not self._finish_signalled[i + 1]:
-                    nxt.finish()
+                    if tallies is None:
+                        nxt.finish()
+                    else:
+                        with tallies[i + 1].call("finish"):
+                            nxt.finish()
                     self._finish_signalled[i + 1] = True
                     progressed = True
             if not progressed and not ops[-1].is_finished():

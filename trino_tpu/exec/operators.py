@@ -41,6 +41,7 @@ from trino_tpu.ops import groupby as G
 from trino_tpu.ops.gather import take_clip
 from trino_tpu.ops import join as J
 from trino_tpu.ops.sort import SortKey, sort_order
+from trino_tpu.runtime.tracing import host_span, host_sync, profiling
 
 
 class Operator:
@@ -171,7 +172,8 @@ class TableScanOperator(Operator):
         from trino_tpu.runtime.metrics import METRICS
 
         if nxt.live is not None:
-            n = int(np.asarray(nxt.live).sum())
+            with host_sync("scan.rows_scanned", nxt.live.shape[0]):
+                n = int(np.asarray(nxt.live).sum())
         elif nxt.columns:
             n = int(nxt.columns[0].data.shape[0])
         else:
@@ -1655,7 +1657,7 @@ class HashAggregationOperator(Operator):
         reseeds via _order_seed) until it comes back clean — same
         semantics as the old per-batch retry ladder."""
         idx, ovf, ngroups, batch, cap = self._pending_meta.pop(0)
-        while bool(ovf):
+        while _flag("agg.ingest_overflow", ovf):
             cap = max(cap * 2, bucket_capacity(int(ngroups)))
             self._cap = max(self._cap, cap)
             gk, gv, used, vals, cnts, ngroups, ovf = _agg_ingest(
@@ -1700,7 +1702,7 @@ class HashAggregationOperator(Operator):
             if self._static_bound is not None:
                 self._deferred_ovf.append(ovf)
                 break
-            if not bool(ovf):
+            if not _flag("agg.merge_overflow", ovf):
                 break
             self._cap = max(self._cap * 2, bucket_capacity(int(ngroups)))
         self._acc = merged
@@ -1868,7 +1870,7 @@ class HashAggregationOperator(Operator):
         out = self._partial_state_batch()
         if out.capacity >= _SHRINK_MIN_CAPACITY and self._dense_dims is None \
                 and self._mxu_dims is None:
-            out = _shrink_prefix(out, int(jnp.sum(out.live_mask())))
+            out = _shrink_prefix(out, _count("agg.partial_rows", out.live_mask()))
         self._out = out
 
     # -- holistic (collect) path: min_by/max_by/approx_percentile --
@@ -2311,7 +2313,7 @@ class HashAggregationOperator(Operator):
             if self._checks is not None:
                 # deferred to the end-of-query sync point
                 self._checks.append((flag, msg))
-            elif bool(flag):
+            elif _flag("agg.bound_overflow", flag):
                 raise RuntimeError(msg)
             self._deferred_ovf = []
         if self._step == "partial":
@@ -2376,7 +2378,7 @@ class HashAggregationOperator(Operator):
                 and self._mxu_dims is None:
             # sort-path group rows are prefix-dense: hand downstream
             # operators the live size, not the table capacity
-            out = _shrink_prefix(out, int(jnp.sum(used)))
+            out = _shrink_prefix(out, _count("agg.group_rows", used))
         self._out = out
 
     def get_output(self) -> Optional[RelBatch]:
@@ -2439,6 +2441,19 @@ GRACE_PARTITIONS = 8
 # ops/groupby.py — while sort itself compiles in ~20-60s at any
 # multi-million-row shape. Compaction remains worthwhile for runtime.)
 _SHRINK_MIN_CAPACITY = 1 << 17
+
+
+def _flag(site: str, flag) -> bool:
+    """Read one device flag back: the host waits for whatever computes
+    it (`sync.<site>` in a profiler trace)."""
+    with host_sync(site, 1):
+        return bool(flag)
+
+
+def _count(site: str, mask) -> int:
+    """Live rows of a device mask, read back (`sync.<site>`)."""
+    with host_sync(site, 8):
+        return int(jnp.sum(mask))
 
 
 def _shrink_prefix(batch: RelBatch, live_count: int) -> RelBatch:
@@ -2562,9 +2577,10 @@ class HashBuildSink(Operator):
             # sparse build side (e.g. a HAVING-filtered aggregate):
             # host-compact so the lookup build and every probe compile
             # at the live size, not the upstream capacity
-            counts = jax.device_get(
-                [jnp.sum(b.live_mask().astype(jnp.int32)) for b in parts]
-            )
+            with host_sync("join.build_rows", 4 * len(parts)):
+                counts = jax.device_get(
+                    [jnp.sum(b.live_mask().astype(jnp.int32)) for b in parts]
+                )
             n_live = int(sum(int(c) for c in counts))
             target = max(bucket_capacity(n_live), 16)
             if target * 4 <= total_cap:
@@ -2640,7 +2656,7 @@ class MxuJoinAggOperator(Operator):
         # decides the probe lookup path for the whole query
         self._analysis = (
             kid, kid_by_pos, distinct, n_distinct,
-            bool(jax.device_get(hash_pure)),
+            _flag("join.hash_pure", hash_pure),
         )
 
     def add_input(self, probe: RelBatch) -> None:
@@ -2983,9 +2999,10 @@ class LookupJoinOperator(Operator):
         if not rec.get("remapped") and self._bridge.build_key_channels:
             pkc = tuple(self._keys)
             bkc = tuple(self._bridge.build_key_channels)
-        total = int(rec["total"])
+        with host_sync("join.match_total", 8):
+            total = int(rec["total"])
         dense = total * 4 >= rec["probe"].capacity
-        if dense and "fan1" in rec and bool(rec["fan1"]):
+        if dense and "fan1" in rec and _flag("join.fanout_one", rec["fan1"]):
             # fanout<=1 (PK-side FK join) AND most probe rows match:
             # pairs = probe batch + one matched build row, probe
             # columns untouched — skips the repeat expansion AND every
@@ -3264,7 +3281,8 @@ def dynamic_filter_constraints(
     build = bridge.build_batch
     if build is None:
         return ()
-    live = np.asarray(jax.device_get(build.live_mask())).astype(bool)
+    with host_sync("join.dynamic_filter", build.capacity):
+        live = np.asarray(jax.device_get(build.live_mask())).astype(bool)
     out = []
     for i, bc in enumerate(bridge.build_key_channels):
         if i >= len(key_names):
@@ -3275,10 +3293,11 @@ def dynamic_filter_constraints(
         col = build.columns[bc]
         if getattr(col.data, "ndim", 1) == 2 or col.dictionary is not None:
             continue  # long-decimal limbs / dictionary codes: no raw domain
-        data = np.asarray(jax.device_get(col.data))
-        w = live
-        if col.valid is not None:
-            w = w & np.asarray(jax.device_get(col.valid)).astype(bool)
+        with host_sync("join.dynamic_filter", col.data.nbytes):
+            data = np.asarray(jax.device_get(col.data))
+            w = live
+            if col.valid is not None:
+                w = w & np.asarray(jax.device_get(col.valid)).astype(bool)
         vals = data[w]
         if vals.size == 0:
             continue  # empty build: the join itself yields nothing
@@ -3569,8 +3588,15 @@ class CollectorSink(Operator):
         so the whole tree costs about one read-back, while a
         device-side pack-into-one-buffer program costs a dispatch plus
         a fetch. Don't 'optimize' this into a packing kernel."""
-        host_batches, host_extra = jax.device_get((self.batches, list(extra)))
-        out = []
-        for b in host_batches:
-            out.extend(b.to_pylists())
+        nbytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.batches)
+        ) if profiling() else 0
+        with host_span("result.fetch", batches=len(self.batches)):
+            with host_sync("result", nbytes):
+                host_batches, host_extra = jax.device_get(
+                    (self.batches, list(extra)))
+            out = []
+            with host_span("result.to_rows"):
+                for b in host_batches:
+                    out.extend(b.to_pylists())
         return out, host_extra

@@ -16,6 +16,7 @@ Data pages out in row chunks per poll (the JSON protocol's data field).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 from trino_tpu.analysis import threadreg
@@ -25,6 +26,8 @@ import uuid
 from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
+
+from trino_tpu.runtime.tracing import OFF, host_span
 
 ROWS_PER_PAGE = 4096
 
@@ -85,8 +88,31 @@ class _QueryJob:
         self.drained = False  # final result page delivered to the client
         self.abandoned = False
         self.created_at = time.monotonic()  # admission-queue wait base
+        # the stamps of the response's `stats` and of the `server.*`
+        # spans (perf_counter_ns): POST accepted, handed to the pool,
+        # execution entered, finished; and what the runner measured
+        self.accepted_ns = time.perf_counter_ns()
+        self.handed_off_ns = self.accepted_ns
+        self.entered_ns: Optional[int] = None
+        self.finished_ns: Optional[int] = None
+        self.runner_stats: dict = {}
         self.last_heartbeat = time.monotonic()  # any client poll refreshes
         self.lock = named_lock("_QueryJob.lock")
+
+    def statement_stats(self) -> dict:
+        """The times of the client's StatementStats, in whole
+        milliseconds as the reference sends them: queued is POST
+        accepted to execution entered, elapsed is POST accepted to
+        finished (both up to now while they last), cpu is the executing
+        thread's inside the runner's `execute` phase."""
+        now = time.perf_counter_ns()
+        return {
+            "queuedTimeMillis":
+                ((self.entered_ns or now) - self.accepted_ns) // 1_000_000,
+            "elapsedTimeMillis":
+                ((self.finished_ns or now) - self.accepted_ns) // 1_000_000,
+            "cpuTimeMillis": int(self.runner_stats.get("cpu_ms", 0)),
+        }
 
     def snapshot(self, token: int):
         with self.lock:
@@ -249,9 +275,27 @@ class CoordinatorServer:
                             headers={"Retry-After": f"{ex.retry_after_s:g}"},
                         )
                         return
-                    self._json(200, outer._response(job, 0))
+                    self._respond(job, 0)
                     return
                 self._json(404, {"error": "no route"})
+
+            def _respond(self, job, token: int) -> None:
+                """Build and write one page of a statement's answer
+                (`server.respond` in a profiler trace)."""
+                with host_span("server.respond") as span:
+                    out = outer._response(job, token)
+                    self._json(200, out)
+                    if span is OFF:
+                        return
+                    stats = {"pages": int("data" in out),
+                             "rows": len(out.get("data", ()))}
+                    if "nextUri" not in out and job.finished_ns is not None:
+                        # the last page: how long the answer waited for
+                        # the client to come and fetch it
+                        stats["since_finished_us"] = (
+                            time.perf_counter_ns() - job.finished_ns
+                        ) // 1000
+                    span.set_metadata(**stats)
 
             def do_GET(self):
                 identity = self._auth()
@@ -266,7 +310,7 @@ class CoordinatorServer:
                     if job is None:
                         self._json(404, {"error": "unknown query"})
                         return
-                    self._json(200, outer._response(job, int(parts[4])))
+                    self._respond(job, int(parts[4]))
                     return
                 # observability REST surface (QueryResource /
                 # ClusterStatsResource analogues) + the web UI page
@@ -305,6 +349,11 @@ class CoordinatorServer:
                 ):
                     fn = getattr(outer.runner, "query_chrome_trace", None)
                     tr = fn(parts[2]) if fn is not None else None
+                    job = outer._jobs.get(parts[2])
+                    if tr is None and fn is not None and job is not None:
+                        # a statement's id here is the server's; the
+                        # local runner names its traces by its own
+                        tr = fn(job.runner_stats.get("query_id"))
                     if tr is None:
                         self._json(404, {"error": "no trace for query"})
                     else:
@@ -475,6 +524,15 @@ class CoordinatorServer:
 
     def _submit(self, sql: str, identity=None, transaction_id="NONE",
                 prepared=None) -> _QueryJob:
+        # `server.queued` in a profiler trace: from here to the runner's
+        # `execute`, on this thread and then on the pool's
+        accepted_ns = time.perf_counter_ns()
+        with host_span("server.queued"):
+            return self._enqueue(sql, identity, transaction_id, prepared,
+                                 accepted_ns)
+
+    def _enqueue(self, sql: str, identity, transaction_id, prepared,
+                 accepted_ns: int) -> _QueryJob:
         from trino_tpu.runtime.metrics import METRICS
         from trino_tpu.serving.admission import fast_path_probe
 
@@ -489,10 +547,16 @@ class CoordinatorServer:
         job = _QueryJob(
             uuid.uuid4().hex[:16], sql, getattr(identity, "user", None)
         )
+        job.accepted_ns = accepted_ns
         self._jobs[job.query_id] = job
         METRICS.increment("queries.submitted")
 
         def run():
+            # this thread's part of `server.queued` ends where execution
+            # begins, or with the job
+            queued = contextlib.ExitStack()
+            queued.enter_context(host_span("server.queued", handoff_us=(
+                time.perf_counter_ns() - job.handed_off_ns) // 1000))
             try:
                 # resource-group queueing (lane passed as selector
                 # source); a DELETE or client-abandon while queued flips
@@ -538,12 +602,11 @@ class CoordinatorServer:
                 import inspect
 
                 try:
-                    if "cancel" in inspect.signature(
-                        self.runner.execute
-                    ).parameters:
-                        kwargs["cancel"] = lambda: job.abandoned
+                    accepts = inspect.signature(self.runner.execute).parameters
                 except (TypeError, ValueError):
-                    pass
+                    accepts = ()
+                if "cancel" in accepts:
+                    kwargs["cancel"] = lambda: job.abandoned
                 result = None
                 # resident fast lane first: a pinned point lookup is a
                 # device probe — faster than even a batched execution,
@@ -562,7 +625,11 @@ class CoordinatorServer:
                     result = self.batcher.submit(
                         sql, identity=identity, prepared=prepared or None
                     )
+                queued.close()
+                job.entered_ns = time.perf_counter_ns()
                 if result is None:
+                    if "queued_ns" in accepts:
+                        kwargs["queued_ns"] = job.entered_ns - job.accepted_ns
                     result = self.runner.execute(sql, **kwargs)
                 with job.lock:
                     if job.abandoned:
@@ -584,8 +651,10 @@ class CoordinatorServer:
                     job.cleared_transaction = getattr(
                         result, "cleared_transaction", False
                     )
+                    job.runner_stats = getattr(result, "stats", None) or {}
                     job.state = "finished"
                     job.finished_at = time.monotonic()
+                    job.finished_ns = time.perf_counter_ns()
                 METRICS.increment("queries.finished")
             except Exception as e:
                 METRICS.increment("queries.failed")
@@ -595,6 +664,7 @@ class CoordinatorServer:
                     job.error = str(e)
                     job.state = "failed"
                     job.finished_at = time.monotonic()
+                    job.finished_ns = time.perf_counter_ns()
                     # TransactionManager prunes the transaction even when
                     # COMMIT/ROLLBACK fail — tell the client its id is
                     # dead or every later statement wedges on it
@@ -602,8 +672,10 @@ class CoordinatorServer:
                     if head.startswith("COMMIT") or head.startswith("ROLLBACK"):
                         job.cleared_transaction = True
             finally:
+                queued.close()
                 self.admission.release(reservation)
 
+        job.handed_off_ns = time.perf_counter_ns()
         self._pool.submit(run)
         return job
 
@@ -611,7 +683,7 @@ class CoordinatorServer:
         state, columns, data, total, error = job.snapshot(token)
         out = {
             "id": job.query_id,
-            "stats": {"state": state.upper()},
+            "stats": {"state": state.upper(), **job.statement_stats()},
         }
         if state == "failed":
             out["error"] = {"message": error}

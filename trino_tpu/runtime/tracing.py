@@ -24,7 +24,49 @@ shape and propagation discipline:
 Span kinds form the tree contract the invariant checker enforces:
 ``query`` roots the trace; ``phase`` (parse/analyze/optimize/validate/
 fragment/schedule) and ``stage`` spans hang off it; ``task`` spans hang
-off stages (one per attempt); ``operator`` spans hang off tasks.
+off stages (one per attempt); ``operator`` spans hang off tasks, or off
+the ``execute`` phase on the local runner, which has no tasks.
+
+The same spans in the profiler's trace
+--------------------------------------
+
+While a ``jax.profiler`` trace is running (``TraceAnnotation.
+is_enabled()`` decides, nothing else: no session property, no
+environment variable), the program's spans are also events of that
+trace, in the ``/host:CPU`` plane, one line per thread, on the clock the
+device's events are on. Every event is named ``tpusql.<kind>.<name>``:
+
+- a ``Span`` used as a context manager: ``tpusql.<span kind>.<first word
+  of the span's name>`` (``tpusql.query.query``, ``tpusql.phase.execute``,
+  ``tpusql.phase.analyze``, ``tpusql.stage.stage``), with the stats
+  ``query_id``, ``span_id``, ``parent_id`` and, set at exit, every
+  numeric attribute of the span (``phase.execute``: ``cpu_ns``, the
+  executing thread's CPU time inside it);
+- leaf spans, ``host_span(name, **stats)``: no ``Span``, no id, no lock;
+  with no trace running the one shared no-op ``OFF``. Their names:
+  ``phase.parse``, ``phase.plan`` (stat ``hit``: 1 when the plan cache
+  answered), ``phase.instantiate``, ``phase.release`` (the operators'
+  state and its device buffers are dropped), ``phase.finalize`` (all
+  with ``query_id`` where a query span exists);
+  ``op.<OperatorClass>.<get_output|add_input|finish>`` around every
+  operator call of ``exec/driver.Driver.run``;
+  ``sync.<site>`` (``host_sync``, stat ``nbytes``) around every
+  device-to-host readback; ``scan.batches`` (stat ``cached``),
+  ``scan.host_filter``, ``scan.to_device`` in the memory connector;
+  ``result.fetch`` and ``result.to_rows`` around the result's readback
+  and its conversion to rows; ``server.queued`` (stat ``handoff_us`` on
+  the executing thread's part) and ``server.respond`` (stats ``pages``,
+  ``rows``, and ``since_finished_us`` on the response that delivers the
+  last page) in ``runtime/server.py``.
+
+Leaf spans know their statement by lying inside its ``tpusql.query.*``
+event on the same thread line. The one piece of per-thread state here is
+the operator call that is running (``_RUNNING``), which exists only
+while a trace runs: a ``sync.*`` span adds itself to that call's
+``OpTally``, and ``record_operators`` turns the tallies into one
+``operator`` span each (``calls``, ``batches``, ``host_syncs``,
+``host_sync_ms``, ``busy_ms``) in the tree ``GET /v1/query/{id}/trace``
+serves. ``chipbench/spans.py`` reduces the events to per-layer metrics.
 """
 
 from __future__ import annotations
@@ -35,6 +77,11 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
+# every event the program writes into the profiler's trace starts so
+PROFILE_PREFIX = "tpusql."
+
 # span kinds, in tree order (parent kind of each child kind)
 KIND_QUERY = "query"
 KIND_PHASE = "phase"
@@ -43,10 +90,11 @@ KIND_TASK = "task"
 KIND_OPERATOR = "operator"
 
 _PARENT_KIND = {
-    KIND_PHASE: KIND_QUERY,
-    KIND_STAGE: KIND_QUERY,
-    KIND_TASK: KIND_STAGE,
-    KIND_OPERATOR: KIND_TASK,
+    KIND_PHASE: (KIND_QUERY,),
+    KIND_STAGE: (KIND_QUERY,),
+    KIND_TASK: (KIND_STAGE,),
+    # the local runner has no tasks: its operators hang off `execute`
+    KIND_OPERATOR: (KIND_TASK, KIND_PHASE),
 }
 
 
@@ -61,6 +109,7 @@ class Span:
     __slots__ = (
         "name", "kind", "span_id", "trace_id", "parent_id",
         "start_s", "end_s", "attributes", "events", "_trace",
+        "_annotation",
     )
 
     def __init__(self, trace: "QueryTrace", name: str, kind: str,
@@ -75,6 +124,11 @@ class Span:
         self.attributes: Dict[str, Any] = dict(attributes)
         self.events: List[dict] = []
         self._trace = trace
+        self._annotation = None  # the profiler event, while entered
+
+    @property
+    def query_id(self) -> str:
+        return self._trace.query_id
 
     def child(self, name: str, kind: str, **attributes) -> "Span":
         return self._trace.span(name, kind, parent=self, **attributes)
@@ -102,8 +156,17 @@ class Span:
         return (self.end_s or time.time()) - self.start_s
 
     # `with parent.child("analyze", KIND_PHASE):` — exceptions annotate
-    # the span and it still closes, so no failure path leaks open spans
+    # the span and it still closes, so no failure path leaks open spans.
+    # While a profiler trace runs the block is also an event of it,
+    # entered and left on this thread.
     def __enter__(self) -> "Span":
+        if TraceAnnotation.is_enabled():
+            self._annotation = TraceAnnotation(
+                f"{PROFILE_PREFIX}{self.kind}.{self.name.partition(' ')[0]}",
+                query_id=self.query_id, span_id=self.span_id,
+                parent_id=self.parent_id or "",
+            )
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -112,6 +175,13 @@ class Span:
                        message=str(exc)[:500])
             self.attributes.setdefault("error", True)
         self.end()
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.set_metadata(**{
+                k: v for k, v in self.attributes.items()
+                if isinstance(v, (int, float))
+            })
+            annotation.__exit__(exc_type, exc, tb)
 
     def to_dict(self) -> dict:
         return {
@@ -126,6 +196,139 @@ class Span:
             "attributes": dict(self.attributes),
             "events": [dict(e) for e in self.events],
         }
+
+
+# -- leaf spans: profiler events only -----------------------------------
+
+
+class _Off:
+    """What every leaf span is while no profiler trace runs."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **stats) -> None:
+        pass
+
+
+OFF = _Off()
+
+
+def profiling() -> bool:
+    """Whether a profiler trace is running in this process."""
+    return TraceAnnotation.is_enabled()
+
+
+def host_span(name: str, **stats):
+    """A leaf span for what happens thousands of times a statement: an
+    event ``tpusql.<name>`` of the running profiler trace, or `OFF`."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(PROFILE_PREFIX + name, **stats)
+    return OFF
+
+
+def phase_span(query_span: Optional["Span"], name: str, **stats):
+    """`host_span("phase.<name>")` carrying the statement's id."""
+    if not TraceAnnotation.is_enabled():
+        return OFF
+    if query_span is not None:
+        stats["query_id"] = query_span.query_id
+    return host_span("phase." + name, **stats)
+
+
+class OpTally:
+    """What one operator of one `Driver.run` did, counted only while a
+    profiler trace runs; `record_operators` makes a span of it."""
+
+    __slots__ = ("name", "calls", "batches", "host_syncs", "host_sync_ns",
+                 "busy_ns", "first_s", "last_s")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.calls = self.batches = self.host_syncs = 0
+        self.host_sync_ns = self.busy_ns = 0
+        self.first_s: Optional[float] = None
+        self.last_s = 0.0
+
+    def call(self, method: str) -> "_OpCall":
+        return _OpCall(self, method)
+
+
+# the operator call running on this thread, while a trace runs
+_RUNNING = threading.local()
+
+
+class _OpCall(TraceAnnotation):
+    """`op.<OperatorClass>.<method>`: one operator call, timed into its
+    operator's tally; `sync.*` spans inside it find the tally here."""
+
+    def __init__(self, tally: OpTally, method: str):
+        super().__init__(f"{PROFILE_PREFIX}op.{tally.name}.{method}")
+        self._tally = tally
+
+    def __enter__(self):
+        self._outer = getattr(_RUNNING, "tally", None)
+        _RUNNING.tally = self._tally
+        if self._tally.first_s is None:
+            self._tally.first_s = time.time()
+        self._t0 = time.perf_counter_ns()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        tally = self._tally
+        tally.calls += 1
+        tally.busy_ns += time.perf_counter_ns() - self._t0
+        tally.last_s = time.time()
+        _RUNNING.tally = self._outer
+
+
+class _Sync(TraceAnnotation):
+    """`sync.<site>`: one device-to-host readback, counted into the
+    operator call it happens in."""
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        tally = getattr(_RUNNING, "tally", None)
+        if tally is not None:
+            tally.host_syncs += 1
+            tally.host_sync_ns += time.perf_counter_ns() - self._t0
+
+
+def host_sync(site: str, nbytes: int = 0):
+    """Around a device-to-host readback (`int()`, `bool()`,
+    `np.asarray`, `device_get` of a device value): where the host waits
+    for the device. `nbytes` is what comes back."""
+    if TraceAnnotation.is_enabled():
+        return _Sync(f"{PROFILE_PREFIX}sync.{site}", nbytes=nbytes)
+    return OFF
+
+
+def record_operators(parent: Optional[Span], tallies: List[OpTally]) -> None:
+    """One `operator` span under `parent` for each operator that ran:
+    the leaf spans of a traced statement, aggregated."""
+    if parent is None:
+        return
+    for t in tallies:
+        if not t.calls:
+            continue
+        span = parent.child(
+            t.name, KIND_OPERATOR, calls=t.calls, batches=t.batches,
+            host_syncs=t.host_syncs,
+            host_sync_ms=round(t.host_sync_ns / 1e6, 3),
+            busy_ms=round(t.busy_ns / 1e6, 3),
+        )
+        span.start_s = t.first_s
+        span.end(t.last_s)
 
 
 def wire_context(span: Span) -> dict:
@@ -318,10 +521,10 @@ def check_span_invariants(export: dict) -> List[str]:
                               f"not in trace")
         want = _PARENT_KIND.get(s.get("kind"))
         if want is not None and parent is not None \
-                and parent.get("kind") != want:
+                and parent.get("kind") not in want:
             violations.append(
                 f"span {label} parented on kind "
-                f"{parent.get('kind')!r}, expected {want!r}"
+                f"{parent.get('kind')!r}, expected {' or '.join(want)}"
             )
         if s.get("end_s") is None:
             violations.append(f"unclosed span {label}")
