@@ -89,6 +89,43 @@ def test_grouped_sum_mxu_compiles_for_v5e(one_chip, n, value_columns, capacity):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("path", ["mxu", "dense"])
+def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
+    """The train of issue 26 at the engine's batch: eight batches of 2^20
+    rows, the per-batch body once inside a loop (a `while`) that picks
+    its batch out of the operands (a `conditional`). `mxu` is G3's shape
+    (160 slots, count(*) and a sum) with the Pallas kernel itself inside
+    the loop, not its interpreted form: the operator asks
+    `jax.default_backend()`, which is the CPU here."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, Dictionary, RelBatch
+    from trino_tpu.exec import operators as O
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dims = (7, 4, 3) if path == "mxu" else (3, 2)
+    dicts = [Dictionary([f"{i}.{j}" for j in range(d)]) for i, d in enumerate(dims)]
+
+    def batch():
+        cols = [Column(T.VARCHAR, _sds((BATCH,), jnp.int32, one_chip), None, d)
+                for d in dicts]
+        cols.append(Column(T.decimal(12, 2), _sds((BATCH,), jnp.int64, one_chip)))
+        return RelBatch(cols, None)
+
+    aggs = [O.AggSpec("count_star", None, T.BIGINT),
+            O.AggSpec("sum", len(dims), T.decimal(18, 2))]
+    if path == "dense":
+        aggs.append(O.AggSpec("min", len(dims), T.decimal(12, 2)))
+    compiled = O._agg_ingest_train.lower(
+        tuple(batch() for _ in range(O.TRAIN_BATCHES)),
+        _sds((), jnp.int32, one_chip),
+        tuple(range(len(dims))), tuple(aggs), 256 if path == "mxu" else 16, None,
+        dims if path == "dense" else None, dims if path == "mxu" else None,
+    ).compile()
+    text = compiled.as_text()
+    assert "while" in text and "conditional" in text
+    assert ("tpu_custom_call" in text) == (path == "mxu")
+
+
 def test_mxu_join_probe_page_sums_compiles_for_v5e(one_chip):
     """The MXU join-project contraction at its largest key domain. It is
     behind mxu_join_enabled=False today; a refusal here is recorded, not

@@ -41,6 +41,7 @@ from trino_tpu.ops import groupby as G
 from trino_tpu.ops.gather import take_clip
 from trino_tpu.ops import join as J
 from trino_tpu.ops.sort import SortKey, sort_order
+from trino_tpu.runtime.metrics import METRICS
 from trino_tpu.runtime.tracing import host_span, host_sync, profiling
 
 
@@ -128,7 +129,6 @@ class TableScanOperator(Operator):
                 from trino_tpu.connectors.pushdown import (
                     merge_handle_constraints,
                 )
-                from trino_tpu.runtime.metrics import METRICS
 
                 splits = [
                     _dc.replace(
@@ -169,8 +169,6 @@ class TableScanOperator(Operator):
         if nxt is None:
             self._done = True
             return None
-        from trino_tpu.runtime.metrics import METRICS
-
         if nxt.live is not None:
             with host_sync("scan.rows_scanned", nxt.live.shape[0]):
                 n = int(np.asarray(nxt.live).sum())
@@ -1243,14 +1241,11 @@ def _any_flags(flags: tuple):
     return jnp.any(jnp.stack(flags))
 
 
-@partial(jax.jit, static_argnames=(
-    "groups", "aggs", "cap", "pre_fn", "dense_dims", "mxu_dims"))
-def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
-                dense_dims=None, mxu_dims=None):
-    """Fused upstream filter/project + per-batch group-reduce in ONE
-    device program (scan->filter->project->partial-aggregate is the Q1
-    hot path; separate launches pay a host round trip each on
-    remote-attached devices)."""
+def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
+                  dense_dims, mxu_dims):
+    """The per-batch body both ingest programs trace: the fused upstream
+    filter/project, then one batch's group-reduce. Returns the reduce's
+    7-tuple and the per-slot reducers it ran with."""
     if pre_fn is not None:
         batch = pre_fn(batch)
     keys, valids = [], []
@@ -1281,19 +1276,100 @@ def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
             values.append(col.data)
             vvalids.append(col.valid)
         reds.append(_BATCH_REDUCER[a.kind])
+    reds = tuple(reds)
     if dense_dims is not None:
-        return G.dense_group_reduce(
-            keys, valids, live, values, tuple(vvalids), tuple(reds),
-            dense_dims, cap,
+        out = G.dense_group_reduce(
+            keys, valids, live, values, tuple(vvalids), reds, dense_dims, cap,
         )
-    if mxu_dims is not None:
-        return G.mxu_group_reduce(
-            keys, valids, live, values, tuple(vvalids), tuple(reds),
-            mxu_dims, cap,
+    elif mxu_dims is not None:
+        out = G.mxu_group_reduce(
+            keys, valids, live, values, tuple(vvalids), reds, mxu_dims, cap,
         )
-    return G.sort_group_reduce(
-        keys, valids, live, values, tuple(vvalids), tuple(reds), cap
+    else:
+        out = G.sort_group_reduce(
+            keys, valids, live, values, tuple(vvalids), reds, cap
+        )
+    return out, reds
+
+
+_INGEST_STATICS = ("groups", "aggs", "cap", "pre_fn", "dense_dims", "mxu_dims")
+
+
+@partial(jax.jit, static_argnames=_INGEST_STATICS)
+def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
+                dense_dims=None, mxu_dims=None):
+    """Fused upstream filter/project + ONE batch's group-reduce in one
+    device program (scan->filter->project->partial-aggregate is the Q1
+    hot path; separate launches pay a host round trip each on
+    remote-attached devices). Every path that may overflow its table
+    launches this once per batch; where the plan bounds the table, whole
+    trains of batches go through _agg_ingest_train and only a train of
+    one comes here."""
+    return _ingest_batch(
+        batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims
+    )[0]
+
+
+# Batches one launch of _agg_ingest_train takes. A launch costs the host
+# about as much as the device needs for two batches of 2^20 rows (PERF.md
+# section 6, PR 26), so the train is long enough that the device is what
+# a scan waits for and short enough that its first batch does not wait
+# for a whole scan to be handed over.
+TRAIN_BATCHES = 8
+
+
+@partial(jax.jit, static_argnames=_INGEST_STATICS)
+def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
+                      pre_fn, dense_dims=None, mxu_dims=None):
+    """The first `n` of `batches` (equal in layout; `n` is an operand, so
+    a short train is this same program) through _agg_ingest's body, ONE
+    launch and ONE group state for all of them. The body is traced once,
+    inside a loop: each turn picks its batch out of the operands (a
+    `conditional`, which copies it: a loop cannot index operands),
+    reduces it and folds the result into the running state. Laying the
+    batches side by side and slicing the turn's out costs the device
+    more (PERF.md section 6, PR 26). Only the slot-addressed tables
+    (dense_dims, mxu_dims) come here: slot g is the same group in every
+    batch, so the fold is elementwise (counts and sums add, min/max keep
+    the extreme of the batches that had a row) and the keys are any
+    batch's."""
+    assert dense_dims is not None or mxu_dims is not None
+    flat = [jax.tree_util.tree_flatten(b) for b in batches]
+    treedef = flat[0][1]
+    picks = [lambda leaves=tuple(leaves): leaves for leaves, _ in flat]
+    reds = []  # the body's per-slot reducers, known once it is traced
+
+    def one(i):
+        leaves = list(jax.lax.switch(i, picks))
+        out, r = _ingest_batch(
+            jax.tree_util.tree_unflatten(treedef, leaves),
+            groups, aggs, cap, pre_fn, dense_dims, mxu_dims,
+        )
+        reds[:] = r
+        gk, gv, used, vals, cnts, _ngroups, ovf = out
+        return tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts), ovf
+
+    def fold(i, acc):
+        gk, gv, used, vals, cnts, ovf = one(i)
+        _, _, a_used, a_vals, a_cnts, a_ovf = acc
+        merged = []
+        for red, v, c, av, ac in zip(reds, vals, cnts, a_vals, a_cnts):
+            if red in ("sum", "count"):
+                merged.append(av + v)
+            else:
+                best = jnp.minimum(av, v) if red == "min" else jnp.maximum(av, v)
+                merged.append(jnp.where(ac == 0, v, jnp.where(c == 0, av, best)))
+        return (gk, gv, a_used | used, tuple(merged),
+                tuple(ac + c for ac, c in zip(a_cnts, cnts)), a_ovf | ovf)
+
+    empty = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(one, 0)
     )
+    assert all(r in ("sum", "count", "min", "max") for r in reds), reds
+    gk, gv, used, vals, cnts, ovf = jax.lax.fori_loop(0, n, fold, empty)
+    # the reduce's 7-tuple without its group count: a bounded table is
+    # never grown, so nobody reads one (and an output costs the host)
+    return gk, gv, used, vals, cnts, None, ovf
 
 
 @partial(jax.jit, static_argnames=("aggs", "arg_types"))
@@ -1397,7 +1473,16 @@ class HashAggregationOperator(Operator):
     mesh-exchange partials while this operator reduces each batch by
     sort + segmented scans and then merges per-batch group states the
     same way (partial->final within one operator). Output schema =
-    [group keys..., aggregate results...]; group rows come out dense."""
+    [group keys..., aggregate results...]; group rows come out dense.
+
+    Launches: one per batch on the sort, global, holistic and `final`
+    paths, whose batches may overflow a table or need the raw rows.
+    Where the plan bounds the table and addresses it by slot (dictionary
+    and boolean keys: `_dense_dims`, `_mxu_dims`), batches are held and
+    go TRAIN_BATCHES at a time through one launch of _agg_ingest_train,
+    which leaves one state; finish and revocation flush what is held.
+    METRICS `agg_ingest_batches` over `agg_ingest_launches` is the train
+    length achieved."""
 
     def __init__(
         self,
@@ -1426,8 +1511,9 @@ class HashAggregationOperator(Operator):
         self._global = not self._group_channels
         self._cap = initial_capacity
         # accumulated group state: (keys, valids, used, vals, cnts);
-        # per-batch states collect in _pending and merge in ONE N-way
-        # device program at the next materialization point
+        # per-launch states (one a batch, or one a train of batches)
+        # collect in _pending and merge in ONE N-way device program at
+        # the next materialization point
         self._acc = None
         self._pending: List[tuple] = []
         # a state ingested off the wire (_add_state_input) may carry
@@ -1539,6 +1625,17 @@ class HashAggregationOperator(Operator):
             else None
         )
         self._deferred_ovf: List = []
+        # Trains: where the plan bounds the table AND addresses it by
+        # slot, a batch needs no readback and no replay and every batch's
+        # state has the table's size, so incoming batches are HELD (as
+        # references; the scan's arrays are not copied) until
+        # TRAIN_BATCHES of one layout are there, and one launch of
+        # _agg_ingest_train leaves one state for all of them. Every
+        # other path launches once per batch.
+        self._trains = self._dense_dims is not None or self._mxu_dims is not None
+        self._held: List[RelBatch] = []
+        self._held_layout = None
+        self._launched = 0  # trains launched and not yet in METRICS
         # execution-level list of (device flag, message): checked ONCE
         # after results materialize, so no mid-query host sync
         self._checks = deferred_checks
@@ -1604,17 +1701,28 @@ class HashAggregationOperator(Operator):
                 self._gstate = self._global_init()
             self._gstate = self._update(self._gstate, batch)
             return
+        METRICS.increment("agg_ingest_batches")
+        if self._trains:
+            layout = self._train_layout(batch)
+            with self._state_lock:
+                if layout != self._held_layout:
+                    self._flush_held_locked()
+                    self._held_layout = layout
+                self._held.append(batch)
+                if layout is None or len(self._held) == TRAIN_BATCHES:
+                    self._flush_held_locked()
+            self._report_launches()
+            self._track_memory()
+            return
         # a batch can never have more groups than rows, so the
         # per-batch table caps at the batch capacity regardless of
         # how large the operator's table has grown (an oversized
         # per-batch cap multiplies every state array for nothing).
-        # The dense/MXU paths are exempt: they address slots by
-        # mixed-radix position, so the table must hold the FULL
-        # domain even when the batch has fewer rows than slots.
-        if self._dense_dims is not None or self._mxu_dims is not None:
-            cap = self._cap
-        else:
-            cap = min(self._cap, bucket_capacity(batch.capacity))
+        # The dense/MXU paths (trains, above) are exempt: they address
+        # slots by mixed-radix position, so the table must hold the
+        # FULL domain even when the batch has fewer rows than slots.
+        cap = min(self._cap, bucket_capacity(batch.capacity))
+        METRICS.increment("agg_ingest_launches")
         gk, gv, used, vals, cnts, ngroups, ovf = _agg_ingest(
             batch, tuple(self._group_channels), tuple(self._aggs),
             cap, self._pre, self._dense_dims, self._mxu_dims,
@@ -1649,6 +1757,65 @@ class HashAggregationOperator(Operator):
                     self._resolve_one_locked()
         self._track_memory()
 
+    def _train_layout(self, batch: RelBatch):
+        """What the batches of one train have in common, so that one
+        traced body fits them all; None for a batch that travels alone
+        (nested columns; rows past what the MXU kernel takes in one
+        call, where the reduce falls back to the sort path and its
+        groups are not slot-addressed)."""
+        if self._mxu_dims is not None:
+            from trino_tpu.ops.mxu_groupby import MAX_ROWS
+
+            if batch.capacity > MAX_ROWS:
+                return None
+        cols = []
+        for c in batch.columns:
+            if type(c) is not Column:
+                return None
+            cols.append((c.type, c.data.dtype, c.data.shape,
+                         c.valid is None, id(c.dictionary)))
+        return batch.live is None, tuple(cols)
+
+    def _flush_held_locked(self) -> None:
+        """Launch the held train: ONE program, ONE state in _pending, ONE
+        deferred flag (caller holds _state_lock). A train of one goes
+        through the per-batch program, so a one-batch scan costs what it
+        always did; a short train is the train program with a smaller
+        `n` (its unused operands repeat the last batch), so no scan
+        length mints a lowering of its own."""
+        held, self._held = self._held, []
+        if not held:
+            return
+        # slot-addressed tables hold the FULL domain whatever the batch
+        statics = (
+            tuple(self._group_channels), tuple(self._aggs), self._cap,
+            self._pre, self._dense_dims, self._mxu_dims,
+        )
+        self._launched += 1
+        if len(held) == 1:
+            out = _agg_ingest(held[0], *statics)
+        else:
+            pad = [held[-1]] * (TRAIN_BATCHES - len(held))
+            out = _agg_ingest_train(
+                tuple(held + pad), np.int32(len(held)), *statics
+            )
+        gk, gv, used, vals, cnts, _ngroups, ovf = out
+        # overflow impossible by the plan-time bound: defer the flag and
+        # verify ONCE at finish (fail-loud guard against a runtime
+        # dictionary outgrowing the plan-time one)
+        self._deferred_ovf.append(ovf)
+        self._pending.append(
+            (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
+        )
+
+    def _report_launches(self) -> None:
+        """`agg_ingest_launches` for the trains launched under
+        _state_lock: METRICS takes a lock of its own, so outside it."""
+        with self._state_lock:
+            n, self._launched = self._launched, 0
+        if n:
+            METRICS.increment("agg_ingest_launches", n)
+
     def _resolve_one_locked(self) -> None:
         """Settle the OLDEST deferred per-batch overflow record; its
         flag has been copying to the host since ingest (caller holds
@@ -1676,6 +1843,7 @@ class HashAggregationOperator(Operator):
     def _merge_pending_locked(self) -> None:
         """Fold _pending (+ current acc) into ONE merged state with a
         single N-way device program (caller holds _state_lock)."""
+        self._flush_held_locked()
         self._resolve_pending_locked()
         states = ([self._acc] if self._acc is not None else []) + self._pending
         self._pending = []
@@ -2210,6 +2378,7 @@ class HashAggregationOperator(Operator):
                 self._spiller = FileSpiller()
             self._spiller.spill(self._partial_state_batch())
             self._acc = None
+        self._report_launches()
         self._track_memory()
 
     def _track_memory(self) -> None:
@@ -2227,15 +2396,18 @@ class HashAggregationOperator(Operator):
             gk, gv, used, vals, cnts = st
             for arr in [*gk, *gv, used, *vals, *cnts]:
                 total += arr.size * arr.dtype.itemsize
-        # the depth-1 deferred-rehash queue retains one input batch
+        # the depth-1 deferred-rehash queue retains one input batch, a
+        # held train up to TRAIN_BATCHES - 1 of them
         for _, _, _, b, _ in self._pending_meta:
+            total += batch_bytes(b)
+        for b in list(self._held):
             total += batch_bytes(b)
         try:
             self._memory.set_bytes(total)
         except Exception:
             # pool exhausted even after revoking others: spill our own
             # state (self-revocation) and account the reset footprint
-            if self._acc is None and not self._pending:
+            if self._acc is None and not self._pending and not self._held:
                 raise
             self._revoke_memory()
             return
@@ -2301,6 +2473,7 @@ class HashAggregationOperator(Operator):
             spiller.close()
         with self._state_lock:
             self._merge_pending_locked()
+        self._report_launches()
         if self._memory is not None and not self._global:
             self._memory.set_bytes(0)
             self._memory.set_revocable_bytes(0)
