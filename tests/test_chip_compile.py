@@ -190,3 +190,74 @@ def test_distributed_groupby_step_compiles_for_four_v5e(topo):
         _sds((n,), jnp.bool_, rows), [_sds((n,), jnp.int64, rows)],
     ).compile()
     assert "all-to-all" in compiled.as_text()
+
+
+def test_mesh_hash_exchange_compiles_for_four_v5e(topo):
+    """The mesh plane's hash exchange of a large batch (PR 28): one sort
+    by destination that carries the columns, a send block of a quarter
+    over the even share, one all-to-all per column. 2^18 rows a chip:
+    the sort's compile time grows with the rows, and the 2^22-row chunk
+    `sf30.mesh4` streams takes minutes (PERF.md)."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.parallel.mesh_plan import (
+        AXIS, _exchange_hash, exchange_block, shard_map,
+    )
+
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(AXIS))
+    cap = 1 << 18
+    block = exchange_block(cap, 4)
+    assert cap // 4 < block < cap
+
+    def body(key, value, live):
+        batch = RelBatch([Column(T.BIGINT, key, None, None),
+                          Column(T.BIGINT, value, None, None)], live)
+        out, short = _exchange_hash(batch, [0], 4, block)
+        return out.columns[1].data, out.live, short[None]
+
+    program = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(PartitionSpec(AXIS),) * 3,
+        out_specs=PartitionSpec(AXIS), check_vma=False))
+    compiled = program.lower(
+        _sds((4 * cap,), jnp.int64, rows), _sds((4 * cap,), jnp.int64, rows),
+        _sds((4 * cap,), jnp.bool_, rows),
+    ).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text and "sort" in text
+    assert " scatter(" not in text        # no scatter operation: slices
+
+
+def test_mesh_bounded_group_reduce_compiles_for_four_v5e(topo, monkeypatch):
+    """G3's partial aggregation as the mesh plane runs it on the chips:
+    the Pallas MXU group reduce over one 2^22-row chunk a device, inside
+    a shard_map (160 key slots; `sf30.mesh4`'s step program)."""
+    from trino_tpu.ops import groupby as G
+    from trino_tpu.parallel.mesh_plan import AXIS, shard_map
+
+    # the kernel runs interpreted unless the default backend is a TPU;
+    # the described chips are not the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(AXIS))
+    cap, dims = 1 << 22, (7, 4, 3)
+
+    def body(a, b, c, q, live):
+        ones = jnp.ones_like(live)
+        _gk, _gv, used, sums, counts, _n, _ovf = G.mxu_group_reduce(
+            (a, b, c), (ones, ones, ones), live,
+            (live.astype(jnp.int64), q), (None, None), ("sum", "sum"),
+            dims, 256,
+        )
+        return sums[1], counts[0], used
+
+    program = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(PartitionSpec(AXIS),) * 5,
+        out_specs=PartitionSpec(AXIS), check_vma=False))
+    codes = _sds((4 * cap,), jnp.int32, rows)
+    compiled = program.lower(
+        codes, codes, codes, _sds((4 * cap,), jnp.int64, rows),
+        _sds((4 * cap,), jnp.bool_, rows),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -58,6 +58,9 @@ class _StoredTable:
     bucketed_by: Optional[Tuple[str, ...]] = None
     # (version, n_buckets) -> int32 bucket id per row
     bucket_cache: Dict[tuple, np.ndarray] = dataclasses.field(default_factory=dict)
+    # the mesh plane's scan feeds, sharded over its devices, keyed by
+    # (version, columns, predicate, placement): parallel/mesh_feed.py
+    mesh_feeds: Dict[tuple, object] = dataclasses.field(default_factory=dict)
 
 
 class _Store:
@@ -309,6 +312,79 @@ class MemoryPageSource(ConnectorPageSource):
             # pop, not del: parallel tasks snapshot the same stale keys
             t.device_cache.pop(k, None)
         t.device_cache[cache_key] = out
+
+    @staticmethod
+    def _dealt_columns(t, columns: Sequence[str]):
+        """The stored columns, where the table's rows can be dealt out
+        by position; None under declared bucketing (its splits are hash
+        buckets the planner counted on) or with nested columns."""
+        stored = [t.data[name] for name in columns]
+        if t.bucketed_by or any(
+            isinstance(sc.data, list) or sc.type.is_nested for sc in stored
+        ):
+            return None
+        return stored
+
+    def host_shards(self, handle: TableHandle, columns: Sequence[str],
+                    n: int):
+        """The rows a scan of `handle` reads, dealt into `n` contiguous
+        runs of the host's arrays: what the mesh plane places on its
+        devices shard by shard (parallel/mesh_feed.py), so that no
+        device ever holds the table. A pushed-down predicate is applied
+        here, as `batches` applies it. Returns (rows, fetch): the row
+        count of each shard, and `fetch(j, s)`, the (data, valid | None)
+        of column j in shard s: a view where the scan reads the whole
+        table, a gathered copy under a predicate (safe to call from
+        several threads). None where the table cannot be dealt by
+        position (`_dealt_columns`)."""
+        t = self.store.tables[(handle.schema, handle.table)]
+        stored = self._dealt_columns(t, columns)
+        if stored is None:
+            return None
+        cs = getattr(handle, "constraints", ())
+        total = t.row_count
+        idx = None
+        if cs and total:
+            from trino_tpu.connectors.pushdown import constraint_mask
+
+            with host_span("scan.host_filter", rows=total):
+                idx = np.nonzero(constraint_mask(
+                    cs,
+                    lambda name: (
+                        np.asarray(t.data[name].data[:total]),
+                        None if t.data[name].valid is None
+                        else t.data[name].valid[:total],
+                    ),
+                ))[0]
+            total = len(idx)
+        per = -(-total // n) if total else 0
+        bounds = [(min(s * per, total), min((s + 1) * per, total))
+                  for s in range(n)]
+
+        def fetch(j: int, s: int):
+            lo, hi = bounds[s]
+            sel = slice(lo, hi) if idx is None else idx[lo:hi]
+            sc = stored[j]
+            return sc.data[sel], None if sc.valid is None else sc.valid[sel]
+
+        return [hi - lo for lo, hi in bounds], fetch
+
+    def mesh_feeds(self, handle: TableHandle, columns: Sequence[str]):
+        """(the table's cache of what the mesh plane has placed, the key
+        prefix that names this scan in it: version and predicate, and
+        [(type, dictionary, has nulls)] of `columns`, or None where
+        `host_shards` would refuse the table). What was placed of an
+        older version is dropped here, with its device memory."""
+        t = self.store.tables[(handle.schema, handle.table)]
+        for k in [k for k in t.mesh_feeds if k[0] != t.version]:
+            t.mesh_feeds.pop(k, None)
+        stored = self._dealt_columns(t, columns)
+        meta = None if stored is None else [
+            (sc.type, sc.dictionary, sc.valid is not None) for sc in stored
+        ]
+        return t.mesh_feeds, (
+            t.version, getattr(handle, "constraints", ()),
+        ), meta
 
     def _bucket_ids(self, t, nb: int) -> np.ndarray:
         """Row -> bucket id with the engine's own exchange hash (the
@@ -580,6 +656,7 @@ class MemoryConnector(Connector):
             t.row_count = staging.row_count
             t.version += 1
             t.device_cache.clear()
+            t.mesh_feeds.clear()
 
     def page_sink(self, handle: TableHandle, transaction=None) -> ConnectorPageSink:
         if isinstance(transaction, MemoryTransactionHandle):

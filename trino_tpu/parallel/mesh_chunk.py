@@ -59,6 +59,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as PSpec
 
 from trino_tpu import types as T
+from trino_tpu.analysis import threadreg
 from trino_tpu.analysis.witness import named_lock
 from trino_tpu.block import Column, RelBatch, bucket_capacity
 from trino_tpu.compile.cache import (
@@ -69,10 +70,13 @@ from trino_tpu.compile.cache import (
 from trino_tpu.compile.shapes import CapacityLadder
 from trino_tpu.compile.warmup import WarmupEntry, note_classes_warm
 from trino_tpu.sql import plan as P
+from trino_tpu.parallel import mesh_feed
+from trino_tpu.parallel.mesh_feed import chunk_rows_for
 from trino_tpu.parallel.mesh_plan import (
     AXIS,
     MeshUnsupported,
     _exchange_hash,
+    exchange_block,
     _FragVisitor,
     _local_partition,
     _replicate,
@@ -80,6 +84,7 @@ from trino_tpu.parallel.mesh_plan import (
     _salted_local_partition,
     shard_map,
 )
+from trino_tpu.runtime.tracing import host_span, host_sync
 
 # Most recent chunked run, for tests and EXPLAIN surfaces: chunk shape,
 # fragment classification and attempt count. Observability only, but
@@ -311,12 +316,14 @@ def _classify(mesh_sps, root_child_ids, driver_ids):
 
 
 def build_chunk_plan(mesh_sps, root_child_ids, feeds, shard_caps, session):
-    """Pick a driver scan and classify fragments. Chunking engages only
-    when the session asks for it (mesh_chunk_rows > 0) and some feed
-    admits a non-empty stream set; otherwise every fragment lands in the
-    prelude (single-program execution, preemption checks around it)."""
+    """Pick a driver scan and classify fragments. Chunking engages when
+    the largest feed's shard is past mesh_feed.AUTO_CHUNK_ROWS (one
+    program's exchange buffers would not fit beside it) or the session
+    asks for it (mesh_chunk_rows > 0), and some feed admits a non-empty
+    stream set; otherwise every fragment lands in the prelude
+    (single-program execution, preemption checks around it)."""
     all_fids = frozenset(sp.fragment.id for sp in mesh_sps)
-    chunk_rows = int(getattr(session, "mesh_chunk_rows", 0) or 0)
+    chunk_rows = chunk_rows_for(session, max(shard_caps, default=0))
     if chunk_rows > 0 and feeds:
         ladder = CapacityLadder(
             base=int(getattr(session, "capacity_ladder_base", 2) or 2)
@@ -522,15 +529,25 @@ def _accumulate(carry: RelBatch, contrib: RelBatch):
     comp = contrib.compact()
     live_in = comp.live_mask()
     count = jnp.sum(carry.live_mask().astype(jnp.int32))
-    idx = jnp.arange(comp.capacity, dtype=jnp.int32)
-    # dead rows and overflow both scatter out of range -> mode="drop"
-    tgt = jnp.where(live_in, count + idx, cap_c)
+
+    def put(dst, src):
+        # the packed contribution lands as ONE contiguous run behind the
+        # carry's rows (its dead tail behind it, dead again); what falls
+        # past the capacity is cut, and flagged below
+        room = jnp.concatenate(
+            [dst, jnp.zeros((comp.capacity,) + dst.shape[1:], dst.dtype)]
+        )
+        return jax.lax.dynamic_update_slice_in_dim(
+            room, src.astype(dst.dtype), count, axis=0
+        )[:cap_c]
+
     cols = []
     for cc, sc in zip(carry.columns, comp.columns):
-        data = cc.data.at[tgt].set(sc.data, mode="drop")
-        valid = cc.valid.at[tgt].set(sc.valid_mask(), mode="drop")
-        cols.append(Column(cc.type, data, valid, cc.dictionary))
-    live = carry.live.at[tgt].set(live_in, mode="drop")
+        cols.append(Column(
+            cc.type, put(cc.data, sc.data), put(cc.valid, sc.valid_mask()),
+            cc.dictionary,
+        ))
+    live = put(carry.live, live_in)
     n_new = jnp.sum(live_in.astype(jnp.int32))
     needed = count + n_new
     flag = jnp.where(needed > cap_c, needed, 0).astype(jnp.int32)
@@ -666,6 +683,88 @@ def _merge_out_carry(mine: RelBatch, theirs: RelBatch,
 
 
 @dataclasses.dataclass
+class ExchangeCensus:
+    """What ONE run of a mesh program exchanges: its collective
+    operations by kind, and the bytes that leave their device in them,
+    summed over the mesh (an all_to_all keeps 1/n of each send buffer at
+    home; an all_gather sends a shard's rows to the n - 1 others).
+    Counted from the program's jaxpr after dead-code elimination, so a
+    column that is exchanged in the plan and read by nothing downstream,
+    which XLA drops, is not counted. A runner adds it to the `mesh.*`
+    counters every time it dispatches the program: those count runs
+    (`mesh_plan.MESH_COUNTERS` counts traces)."""
+
+    all_to_all: int = 0
+    all_gather: int = 0
+    bytes_exchanged: int = 0
+
+
+def _dce_sort_payloads(used_outputs, eqn):
+    """Dead-code rule for `lax.sort`: keep the keys and the payload
+    operands whose sorted output something reads."""
+    from jax._src.interpreters import partial_eval as pe
+
+    if not any(used_outputs):
+        return [False] * len(eqn.invars), None
+    keep = [i < eqn.params["num_keys"] or used
+            for i, used in enumerate(used_outputs)]
+    if all(keep):
+        return keep, eqn
+    return keep, pe.new_jaxpr_eqn(
+        [v for v, k in zip(eqn.invars, keep) if k],
+        [v for v, k in zip(eqn.outvars, keep) if k],
+        eqn.primitive, eqn.params, eqn.effects, eqn.source_info, eqn.ctx,
+    )
+
+
+def exchange_census(fn, n: int, *args_sds):
+    """(census, which flattened arguments the program reads) of
+    `fn(*args_sds)`, a shard_map program over an n-wide mesh (also its
+    shape check: tracing raises what eval_shape would)."""
+    closed = jax.make_jaxpr(fn)(*args_sds)
+    jaxpr = closed.jaxpr
+    read = [True] * len(jaxpr.invars)
+    try:
+        from jax._src.interpreters import partial_eval as pe
+
+        # jax drops a sort only whole; XLA drops the payload operands
+        # nothing reads, and so must this count (the hash exchange's
+        # sort carries every column of its batch)
+        had = pe.dce_rules.get(jax.lax.sort_p)
+        pe.dce_rules[jax.lax.sort_p] = _dce_sort_payloads
+        try:
+            jaxpr, read = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+        finally:
+            if had is None:
+                pe.dce_rules.pop(jax.lax.sort_p, None)
+            else:
+                pe.dce_rules[jax.lax.sort_p] = had
+    except Exception:
+        pass  # no DCE in this jax: count what was traced (an upper bound)
+    census = ExchangeCensus()
+
+    def walk(jp) -> None:
+        for eqn in jp.eqns:
+            name = eqn.primitive.name
+            if name in ("all_to_all", "all_gather"):
+                local = sum(
+                    int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize
+                    for v in eqn.invars
+                )
+                if name == "all_to_all":
+                    census.all_to_all += 1
+                    census.bytes_exchanged += local * (n - 1)
+                else:
+                    census.all_gather += 1
+                    census.bytes_exchanged += local * n * (n - 1)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    return census, list(read)
+
+
+@dataclasses.dataclass
 class MeshProgramRecord:
     n_chunks: int
     chunk_cap: int
@@ -683,6 +782,21 @@ class MeshProgramRecord:
     flush_out_meta: List[Tuple[int, bool]]
     warmup_entries: List[WarmupEntry]
     class_keys: set
+    # shapes of what the prelude leaves on the devices for the others
+    pctx_sds: tuple = ()
+    # program name -> thread compiling it ahead of its first dispatch
+    compiling: Dict[str, object] = dataclasses.field(default_factory=dict)
+    compiled_ahead: bool = False
+    # per flattened leaf of the feed tuple: does any of the programs
+    # read it (a scan lists columns its plan never touches)
+    feed_read: Tuple[bool, ...] = ()
+    # what one run of each program exchanges
+    prelude_census: ExchangeCensus = dataclasses.field(
+        default_factory=ExchangeCensus)
+    step_census: ExchangeCensus = dataclasses.field(
+        default_factory=ExchangeCensus)
+    flush_census: ExchangeCensus = dataclasses.field(
+        default_factory=ExchangeCensus)
 
 
 class _ProgramWarmer:
@@ -792,9 +906,20 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
 
     skew_map = _skew_exchange_map(mesh_sps, root_child_ids)
 
-    def emit_exchange(frag, batch, ctx):
+    def emit_exchange(frag, batch, ctx, flags):
         if frag.output_kind == "hash":
             sk = skew_map.get(frag.id)
+            block = exchange_block(batch.capacity, n)
+            if sk is None and not repl[frag.id] and block < batch.capacity:
+                # a large batch reserves less than its capacity for each
+                # destination; the site's flag says when that was short
+                site = f"f{frag.id}:xchg"
+                block = min(caps.setdefault(site, block), batch.capacity)
+                ctx[frag.id], short = _exchange_hash(
+                    batch, frag.output_channels, n, block
+                )
+                flags.append((site, short))
+                return
             if sk is not None:
                 role, hot = sk
                 ctx[frag.id] = (
@@ -824,7 +949,7 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
                 outputs.append(batch)
                 out_meta.append((frag.id, repl[frag.id]))
                 continue
-            emit_exchange(frag, batch, ctx)
+            emit_exchange(frag, batch, ctx, flags)
 
     def flag_array(flags):
         if flags:
@@ -870,10 +995,11 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
         new_carries = list(carry_batches) if carry_batches is not None else None
         for sp in stream_sps:
             frag = sp.fragment
-            vis = _FragVisitor(ex, frag.id, local_feeds, ctx, caps, flags)
+            vis = _FragVisitor(ex, frag.id, local_feeds, ctx, caps, flags,
+                               streaming=True)
             batch = vis.visit(frag.root)
             if frag.id not in root_child_ids:
-                emit_exchange(frag, batch, ctx)
+                emit_exchange(frag, batch, ctx, flags)
             i = carry_index.get(frag.id)
             if i is None:
                 continue  # stream->stream link: flows in-trace
@@ -930,11 +1056,16 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
     feed_tuple_sds = tuple(feed_sds)
     k_sds = jax.ShapeDtypeStruct((), jnp.int32)
 
+    prelude_census = step_census = flush_census = ExchangeCensus()
+    n_feed = len(jax.tree_util.tree_leaves(feed_tuple_sds))
+    feed_read = [False] * n_feed
     prelude_fn = None
     pctx_sds: tuple = ()
     if prelude_sps:
         pf = smap(prelude_body, (PSpec(AXIS),))
         _p_outs, pctx_sds, _p_flags = jax.eval_shape(pf, feed_tuple_sds)
+        prelude_census, read = exchange_census(pf, n, feed_tuple_sds)
+        feed_read = [a or b for a, b in zip(feed_read, read[:n_feed])]
         prelude_fn = jax.jit(pf)
 
     step_fn = None
@@ -948,12 +1079,14 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
                 1, (csds.columns[0].data.shape[0] if csds.columns
                     else csds.live.shape[0]) // n
             )
-            # start near the expected total contribution, capped so a
-            # huge K doesn't pre-allocate the world; the overflow ladder
-            # jumps straight to the flagged exact size on a miss
+            # start near the expected total contribution (room for four
+            # full chunks), capped so a huge K doesn't pre-allocate the
+            # world; the overflow ladder jumps straight to the flagged
+            # exact size on a miss
             initial = bucket_capacity(max(
                 16,
-                min(cplan.n_chunks * contrib_cap, max(contrib_cap, 8192)),
+                min(cplan.n_chunks * contrib_cap,
+                    max(4 * contrib_cap, 8192)),
             ))
             cap = caps.setdefault(f"carry:f{fid}", initial)
             templates.append(_carry_template(csds, cap, n))
@@ -961,7 +1094,10 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
         sf = smap(
             step_body, (PSpec(), PSpec(AXIS), PSpec(AXIS), PSpec(AXIS))
         )
-        jax.eval_shape(sf, k_sds, feed_tuple_sds, pctx_sds, carry_sds)
+        step_census, read = exchange_census(
+            sf, n, k_sds, feed_tuple_sds, pctx_sds, carry_sds
+        )
+        feed_read = [a or b for a, b in zip(feed_read, read[1:1 + n_feed])]
         step_fn = jax.jit(
             sf, donate_argnums=() if cpu_mesh else (3,)
         )
@@ -969,7 +1105,10 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
     flush_fn = None
     if flush_sps:
         ff = smap(flush_body, (PSpec(AXIS), PSpec(AXIS), PSpec(AXIS)))
-        jax.eval_shape(ff, feed_tuple_sds, pctx_sds, carry_sds)
+        flush_census, read = exchange_census(
+            ff, n, feed_tuple_sds, pctx_sds, carry_sds
+        )
+        feed_read = [a or b for a, b in zip(feed_read, read[:n_feed])]
         flush_fn = jax.jit(ff)
 
     # -- warmup entries ----------------------------------------------
@@ -1020,12 +1159,36 @@ def _build_record(ex, mesh_sps, root_child_ids, repl, feeds, feed_sds,
         flush_out_meta=flush_out_meta,
         warmup_entries=entries,
         class_keys=set().union(*(e.keys() for e in entries)) if entries else set(),
+        pctx_sds=pctx_sds,
+        feed_read=tuple(feed_read),
+        prelude_census=prelude_census,
+        step_census=step_census,
+        flush_census=flush_census,
     )
 
 
 # ---------------------------------------------------------------------------
 # Runner
 # ---------------------------------------------------------------------------
+
+
+# program identity at empty caps -> the caps its last overflowing run
+# ended on; a key is a plan shape over feed shapes, not a statement, and
+# the oldest go first
+_LEARNED_CAPS_MAX = 256
+_learned_caps_lock = named_lock("mesh_chunk._learned_caps_lock")
+_LEARNED_CAPS: Dict[tuple, Dict[str, int]] = {}  # guarded_by: _learned_caps_lock
+
+
+def _count_exchanges(census: ExchangeCensus) -> None:
+    """One dispatched program's collectives and bytes, into the plane's
+    run-time counters (`mesh_plan.MESH_COUNTERS` counts traces)."""
+    from trino_tpu.runtime.metrics import METRICS
+
+    METRICS.increment("mesh.all_to_all", census.all_to_all)
+    METRICS.increment("mesh.all_gather", census.all_gather)
+    METRICS.increment("mesh.bytes_exchanged", census.bytes_exchanged)
+
 
 
 class ChunkedMeshRunner:
@@ -1045,31 +1208,52 @@ class ChunkedMeshRunner:
         self.root_child_ids = root_child_ids
         self.repl = repl
         self.feeds = feeds
-        self.sharding = NamedSharding(ex.mesh, PSpec(AXIS))
+        self.sharding = ex.sharding
         self.skew_map = _skew_exchange_map(mesh_sps, root_child_ids)
         n = ex.n
-        shard_caps = [b.capacity // n for b in host_feeds]
+        # a feed the connector deals out by position comes as its shapes
+        # (mesh_feed.Feed.template): its columns go to the devices once
+        # a traced program has said which of them it reads
+        host_feeds = list(host_feeds)
+        shapes = [
+            f.template if isinstance(f, mesh_feed.Feed) else f
+            for f in host_feeds
+        ]
+        shard_caps = [b.capacity // n for b in shapes]
         self.cplan = build_chunk_plan(
             mesh_sps, root_child_ids, feeds, shard_caps, self.session
         )
-        host_feeds = list(host_feeds)
         if self.cplan.chunked:
             pos = self.cplan.driver_pos
-            host_feeds[pos] = _pad_shards(
-                host_feeds[pos], n, shard_caps[pos],
-                self.cplan.n_chunks * self.cplan.chunk_cap,
-            )
+            aligned = self.cplan.n_chunks * self.cplan.chunk_cap
+            if aligned != shard_caps[pos]:
+                if isinstance(host_feeds[pos], mesh_feed.Feed):
+                    # a chunk size the feed's capacity is no multiple of
+                    # (a ladder base other than 2): re-pad through the host
+                    host_feeds[pos] = mesh_feed.place(
+                        host_feeds[pos], [True] * len(
+                            jax.tree_util.tree_leaves(shapes[pos]))
+                    )
+                shapes[pos] = host_feeds[pos] = _pad_shards(
+                    host_feeds[pos], n, shard_caps[pos], aligned
+                )
         self.feed_sigs = tuple(
             (
                 schema_cache_key([(c.type, c.dictionary) for c in b.columns]),
-                b.capacity,
+                # a placed feed has no validity lane for a column
+                # without nulls: another pytree, another program
+                (b.capacity, tuple(c.valid is None for c in b.columns)),
             )
-            for b in host_feeds
+            for b in shapes
         )
-        self.feed_sds = tuple(_sds_of(b) for b in host_feeds)
-        self.feed_args = tuple(
-            jax.device_put(b, self.sharding) for b in host_feeds
-        )
+        self.feed_sds = tuple(_sds_of(b) for b in shapes)
+        self._host_feeds = host_feeds
+        self._placed_read: Tuple[bool, ...] = ()
+        self.feed_args: Optional[tuple] = None
+        if not any(isinstance(f, mesh_feed.Feed) for f in host_feeds):
+            self.feed_args = tuple(
+                jax.device_put(b, self.sharding) for b in host_feeds
+            )
         self.info: Dict[str, object] = {}
         self._last_record_key = None
         # recovery bookkeeping for the current run (chaos harness and
@@ -1085,6 +1269,12 @@ class ChunkedMeshRunner:
         }
 
     # -- program record ----------------------------------------------
+    def _record_key_for(self, caps):
+        return _record_key(
+            self.ex, self.mesh_sps, self.root_child_ids, self.repl,
+            self.feed_sigs, self.cplan, caps,
+        )
+
     def _record(self, caps) -> MeshProgramRecord:
         def build():
             return _build_record(
@@ -1092,17 +1282,81 @@ class ChunkedMeshRunner:
                 self.feeds, self.feed_sds, self.cplan, caps,
             )
 
-        key = _record_key(
-            self.ex, self.mesh_sps, self.root_child_ids, self.repl,
-            self.feed_sigs, self.cplan, caps,
-        )
+        key = self._record_key_for(caps)
         self._last_record_key = key
-        if key is None:
-            return build()
-        record = PROGRAM_CACHE.get_or_create(key, build)
+        record = None
+        if key is not None:
+            record = PROGRAM_CACHE.get_or_create(key, build)
         if not isinstance(record, MeshProgramRecord):
-            return build()  # foreign entry under a colliding key
+            record = build()  # uncacheable, or a foreign entry under the key
+        self._place_feeds(record)
         return record
+
+    def _compile_ahead(self, record: MeshProgramRecord) -> None:
+        """Cold, a run compiles its programs one after the other as it
+        reaches them, and at scale each takes the TPU compiler minutes
+        (its sorts). With the persistent compile cache on, start the
+        step's and the flush's compiles now, on threads of their own,
+        beside the prelude's: `_await_compile` joins each before its
+        program's first dispatch, which then finds it in the cache. Off
+        the TPU, or without the cache, compiles stay where they were."""
+        if (
+            record.compiled_ahead
+            or not jax.config.jax_compilation_cache_dir
+            or self.ex.mesh.devices.flat[0].platform != "tpu"
+        ):
+            return
+        record.compiled_ahead = True
+
+        def placed(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=self.sharding), tree)
+
+        feeds, pctx, carries = (
+            placed(self.feed_sds), placed(record.pctx_sds),
+            placed(record.carry_sds),
+        )
+        k = jax.ShapeDtypeStruct((), jnp.int32)
+        for name, fn, args in (
+            ("step", record.step_fn, (k, feeds, pctx, carries)),
+            ("flush", record.flush_fn, (feeds, pctx, carries)),
+        ):
+            if fn is None or (name == "step" and record.prelude_fn is None):
+                continue    # nothing to overlap with: it is dispatched first
+
+            def compile_it(fn=fn, args=args):
+                try:
+                    fn.lower(*args).compile()
+                except Exception:
+                    pass    # the dispatch compiles, and says what is wrong
+
+            record.compiling[name] = threadreg.spawn(
+                f"mesh-compile-{name}", compile_it, owner="mesh_chunk")
+
+    @staticmethod
+    def _await_compile(record: MeshProgramRecord, name: str) -> None:
+        t = record.compiling.pop(name, None)
+        if t is not None:
+            t.join()
+
+    def _place_feeds(self, record: MeshProgramRecord) -> None:
+        """`feed_args` for `record`'s programs: every leaf they read is
+        on the devices (mesh_feed.place puts the missing columns there)."""
+        if self.feed_args is not None and not any(
+            r and not p for r, p in zip(record.feed_read, self._placed_read)
+        ):
+            return
+        read = iter(record.feed_read)
+        args = []
+        for f, sds in zip(self._host_feeds, self.feed_sds):
+            flags = [next(read) for _ in jax.tree_util.tree_leaves(sds)]
+            args.append(
+                mesh_feed.place(f, flags) if isinstance(f, mesh_feed.Feed)
+                else jax.device_put(f, self.sharding)
+            )
+        self.feed_args = tuple(args)
+        self._placed_read = tuple(record.feed_read)
 
     def _ckpt_key(self) -> Optional[tuple]:
         """Checkpoint-store key: the program identity minus the caps
@@ -1146,7 +1400,18 @@ class ChunkedMeshRunner:
                 # prep. Typed kills and drain checks fire out of the
                 # wait as they do at any boundary.
                 sched_job.scheduler.acquire(sched_job)
-            caps: Dict[str, int] = {}
+            # what an earlier run of this plan over feeds of these
+            # shapes learned on the capacity ladder: start there, not at
+            # the bottom (every rung below is a whole run thrown away)
+            learned_key = self._record_key_for({})
+            with _learned_caps_lock:
+                caps: Dict[str, int] = dict(
+                    _LEARNED_CAPS.get(learned_key, ())
+                ) if learned_key is not None else {}
+            # `caps` holds only what an overflow has widened: every other
+            # site starts from the shapes of the trace it is in (a send
+            # block follows the join capacity before it, and must not
+            # keep the block of a narrower join)
             self._run_stats = {
                 "executed_chunk_steps": 0,
                 "checkpoints": 0,
@@ -1163,6 +1428,7 @@ class ChunkedMeshRunner:
             attempt = 0
             while True:
                 record = self._record(caps)
+                self._compile_ahead(record)
                 try:
                     sources = self._execute(
                         record, preempt, task_span, attempt
@@ -1179,12 +1445,12 @@ class ChunkedMeshRunner:
                         raise RuntimeError(
                             "mesh capacity retry limit exceeded"
                         )
-                    # restart from the record's fully resolved caps so
-                    # the ladder is deterministic across executions
-                    caps = dict(record.resolved_caps)
+                    # deterministic across executions: the widened
+                    # sites and nothing else carry over to the retrace
+                    caps = dict(caps)
                     for site, needed in ov.sites:
                         caps[site] = max(
-                            caps.get(site, 16) * 2,
+                            record.resolved_caps.get(site, 16) * 2,
                             bucket_capacity(max(needed, 16)),
                         )
                     attempt += 1
@@ -1229,6 +1495,11 @@ class ChunkedMeshRunner:
                             resume_from=ckpt.next_chunk,
                         )
                     attempt += 1
+            if overflows and learned_key is not None:
+                with _learned_caps_lock:
+                    _LEARNED_CAPS[learned_key] = dict(caps)
+                    while len(_LEARNED_CAPS) > _LEARNED_CAPS_MAX:
+                        _LEARNED_CAPS.pop(next(iter(_LEARNED_CAPS)))
             if record.warmup_entries:
                 register_mesh_warmup(record.warmup_entries)
                 note_classes_warm(record.class_keys)
@@ -1364,6 +1635,7 @@ class ChunkedMeshRunner:
                 steal = None
             from trino_tpu.runtime.metrics import METRICS
 
+            self._await_compile(record, "step")
             with op_span("MeshChunkStep", attempt=attempt, chunks=K):
                 for k in range(k0, K):
                     if preempt is not None:
@@ -1377,12 +1649,17 @@ class ChunkedMeshRunner:
                     if MESH_FAULT_HOOK is not None:
                         MESH_FAULT_HOOK(k, K)
                     t0 = time.monotonic()
-                    carries, flags = record.step_fn(
-                        jnp.asarray(k, dtype=jnp.int32),
-                        self.feed_args, pctx, carries,
-                    )
-                    # flag readback is the natural device sync point
-                    self._check_flags(record.step_sites, flags, n)
+                    with host_span("mesh.step", chunk=k,
+                                   **dataclasses.asdict(record.step_census)):
+                        carries, flags = record.step_fn(
+                            jnp.asarray(k, dtype=jnp.int32),
+                            self.feed_args, pctx, carries,
+                        )
+                        # flag readback is the natural device sync point
+                        self._check_flags(
+                            record.step_sites, flags, n, "step_flags"
+                        )
+                    _count_exchanges(record.step_census)
                     dt = time.monotonic() - t0
                     self._run_stats["executed_chunk_steps"] = (
                         int(self._run_stats["executed_chunk_steps"]) + 1
@@ -1453,11 +1730,17 @@ class ChunkedMeshRunner:
         if preempt is not None:
             preempt(K, K)
         if record.flush_fn is not None:
-            with op_span("MeshFlush", attempt=attempt):
+            self._await_compile(record, "flush")
+            with op_span("MeshFlush", attempt=attempt), host_span(
+                "mesh.finish", **dataclasses.asdict(record.flush_census)
+            ):
                 f_outs, flags = record.flush_fn(
                     self.feed_args, pctx, carries
                 )
-                self._check_flags(record.flush_sites, flags, n)
+                self._check_flags(
+                    record.flush_sites, flags, n, "finish_flags"
+                )
+            _count_exchanges(record.flush_census)
             for (fid, rep), b in zip(record.flush_out_meta, f_outs):
                 outs[fid] = (b, rep)
 
@@ -1563,9 +1846,14 @@ class ChunkedMeshRunner:
                         task_span.event(
                             "resident_evict", tier="mesh-prelude"
                         )
-        with op_span("MeshPrelude", attempt=attempt):
+        with op_span("MeshPrelude", attempt=attempt), host_span(
+            "mesh.prelude", **dataclasses.asdict(record.prelude_census)
+        ):
             p_outs, pctx, flags = record.prelude_fn(self.feed_args)
-            self._check_flags(record.prelude_sites, flags, n)
+            self._check_flags(
+                record.prelude_sites, flags, n, "prelude_flags"
+            )
+        _count_exchanges(record.prelude_census)
         if rkey is not None:
             import jax.tree_util as jtu
 
@@ -1873,8 +2161,10 @@ class ChunkedMeshRunner:
         except Exception:
             return None
 
-    def _check_flags(self, sites, flag_arr, n):
-        vals = np.asarray(jax.device_get(flag_arr))
+    def _check_flags(self, sites, flag_arr, n, site="flags"):
+        # where the host waits for the program it has just dispatched
+        with host_sync("mesh." + site, nbytes=flag_arr.nbytes):
+            vals = np.asarray(jax.device_get(flag_arr))
         if not sites:
             return
         over = vals.reshape(n, -1).max(axis=0)

@@ -24,14 +24,16 @@ colocated on one host's device mesh (in-process workers); cross-host /
 elastic / FTE execution keeps the pull+ack HTTP exchange.
 
 Static-shape discipline: per-shard batch capacities are fixed at trace
-time; group tables and join fan-out use host-chosen capacities with
-device overflow flags and a double-and-retrace protocol (the tryRehash
-analogue). An all_to_all send block equals the sender's batch capacity,
-so exchange overflow is impossible by construction.
+time; group tables, join fan-out and the send blocks of large hash
+exchanges use host-chosen capacities with device overflow flags and a
+double-and-retrace protocol (the tryRehash analogue). A small batch's
+all_to_all send block equals its capacity, so its exchange cannot
+overflow (`exchange_block`).
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -104,6 +106,11 @@ _counters_lock = named_lock("mesh_plan._counters_lock")
 MESH_COUNTERS = {"queries": 0, "all_to_all": 0, "all_gather": 0, "fallbacks": 0}  # guarded_by: _counters_lock
 
 _METRICS_REGISTERED = False
+
+# (time.perf_counter(), reason) of the process's last mesh->page
+# fallbacks: the times behind the `mesh.fallbacks` counter, for a reader
+# that holds them against a window (chipbench mesh_fallbacks_in_window)
+FALLBACK_LOG: "collections.deque" = collections.deque(maxlen=1024)
 
 
 def bump_mesh_counter(name: str, n: int = 1) -> None:
@@ -203,61 +210,124 @@ def _partition_ids(batch: RelBatch, channels: Sequence[int], n: int):
     return jnp.where(batch.live_mask(), pid, -1)
 
 
-def _scatter_to_blocks(arrays, live, pid, n: int, block: int):
-    """Scatter local rows into (n, block) destination blocks (the
-    PagePartitioner analogue, on device). pid < 0 drops the row. With
-    block == batch capacity overflow is impossible."""
+# sorts with more operands than this gather their remaining payloads
+# through the sorted row ids instead (ops/groupby._MAX_SORT_OPERANDS:
+# XLA:TPU sort compile time grows with the operand count)
+_MAX_SORT_PAYLOADS = 9
+# a batch of at most this many rows keeps one send block of its own
+# capacity per destination: overflow impossible, nothing to learn
+_FULL_BLOCK_ROWS = 1 << 16
+
+
+# a probe batch of at most this many rows gets a pair slot per row
+_FULL_JOIN_ROWS = 1 << 20
+
+
+def exchange_block(capacity: int, n: int) -> int:
+    """Rows a sender reserves for each destination of a hash exchange.
+    Small batches reserve their whole capacity. A large one reserves its
+    even share and a quarter more: the full capacity would make every
+    receiver's batch n times the sender's, nearly all of it dead, and
+    every operator downstream pays for capacity, not for live rows. A
+    destination that gets more than its block raises the site's overflow
+    flag, and the runner's capacity ladder retraces with a wider block
+    (as for group tables and join fan-out)."""
+    if capacity <= _FULL_BLOCK_ROWS:
+        return capacity
+    share = -(-capacity // n)
+    return min(capacity, -(-(share + share // 4) // 1024) * 1024)
+
+
+def _scatter_to_blocks(arrays, pid, n: int, block: int):
+    """Deal local rows into (n, block) destination blocks (the
+    PagePartitioner analogue, on device). pid < 0 drops the row. One
+    stable multi-operand sort by destination carries the columns (sorts
+    are what this hardware does well; a scatter per column cost fifty
+    times as much per row), then each destination's run is one
+    contiguous slice. Returns (blocks, live_b, needed): `needed` is the
+    largest destination run, which exceeds `block` when rows were cut
+    (with block == capacity it cannot)."""
+    cap = pid.shape[0]
     tgt = jnp.where(pid < 0, n, pid).astype(jnp.int32)
-    order = jnp.argsort(tgt, stable=True)
-    st = take_clip(tgt, order)
-    idx = jnp.arange(st.shape[0], dtype=jnp.int32)
-    dest_start = jnp.searchsorted(st, jnp.arange(n, dtype=jnp.int32))
-    slot = idx - take_clip(dest_start, jnp.clip(st, 0, n - 1))
-    flat = jnp.where(
-        st < n,
-        jnp.clip(st, 0, n - 1) * block + jnp.clip(slot, 0, block - 1),
-        n * block,
+    # lax.sort operands share one shape: trailing lanes (long-decimal
+    # (cap, 2) limb pairs) travel as one operand per lane, masks as int8
+    lanes = []
+    for a in arrays:
+        a = a.astype(jnp.int8) if a.dtype == jnp.bool_ else a
+        if a.ndim == 1:
+            lanes.append(a)
+        else:
+            lanes.extend(a[:, i] for i in range(a.shape[1]))
+    carried = lanes[:_MAX_SORT_PAYLOADS]
+    rest = lanes[_MAX_SORT_PAYLOADS:]
+    iota = [jnp.arange(cap, dtype=jnp.int32)] if rest else []
+    out = jax.lax.sort(
+        (tgt, *iota, *carried), num_keys=1, is_stable=True
     )
+    st = out[0]
+    moved = list(out[1 + len(iota):])
+    moved += [take_clip(a, out[1]) for a in rest]
+    starts = jnp.searchsorted(st, jnp.arange(n + 1, dtype=jnp.int32))
+    counts = starts[1:] - starts[:-1]
 
-    def scat(col):
-        # trailing lanes (long-decimal (cap, 2) limb pairs) scatter
-        # row-wise into (n, block, lanes) blocks
-        tail = col.shape[1:]
-        z = jnp.zeros((n * block + 1,) + tail, dtype=col.dtype)
-        taken = take_clip(col, order, axis=0)
-        return z.at[flat].set(taken, mode="drop")[:-1].reshape(
-            (n, block) + tail
-        )
+    def deal(a):
+        # padded so that no slice is clamped back into its neighbour
+        a = jnp.concatenate([a, jnp.zeros((block,), a.dtype)])
+        return jnp.stack([
+            jax.lax.dynamic_slice_in_dim(a, starts[d], block)
+            for d in range(n)
+        ])
 
-    out = [scat(a) for a in arrays]
-    live_b = scat(live)
-    return out, live_b
+    dealt = [deal(a) for a in moved]
+    blocks, i = [], 0
+    for a in arrays:
+        k = 1 if a.ndim == 1 else a.shape[1]
+        b = dealt[i] if a.ndim == 1 else jnp.stack(dealt[i:i + k], axis=-1)
+        blocks.append(b != 0 if a.dtype == jnp.bool_ else b)
+        i += k
+    slot = jnp.arange(block, dtype=jnp.int32)
+    live_b = slot[None, :] < jnp.minimum(counts, block)[:, None]
+    return blocks, live_b, jnp.max(counts)
 
 
-def _exchange_with_pids(batch: RelBatch, pid, n: int) -> RelBatch:
-    """Scatter + all_to_all with caller-supplied destination ids (the
-    shared tail of the plain and salted hash exchanges)."""
-    block = batch.capacity
+def _exchange_with_pids(batch: RelBatch, pid, n: int,
+                        block: Optional[int] = None):
+    """Deal + all_to_all with caller-supplied destination ids (the
+    shared tail of the plain and salted hash exchanges). With `block`
+    given (fewer rows per destination than the batch holds) returns
+    (batch, overflow flag): the rows the fullest destination needed
+    where that is more than `block`, else 0."""
+    full = block is None
+    block = batch.capacity if full else block
     arrays = []
     for c in batch.columns:
         arrays.append(c.data)
-        arrays.append(c.valid_mask())
-    blocks, live_b = _scatter_to_blocks(arrays, batch.live_mask(), pid, n, block)
+        if c.valid is not None:
+            arrays.append(c.valid)
+    blocks, live_b, needed = _scatter_to_blocks(arrays, pid, n, block)
     bump_mesh_counter("all_to_all")
-    ex = [jax.lax.all_to_all(b, AXIS, 0, 0, tiled=True) for b in blocks]
+    ex = iter([jax.lax.all_to_all(b, AXIS, 0, 0, tiled=True) for b in blocks])
     live_ex = jax.lax.all_to_all(live_b, AXIS, 0, 0, tiled=True)
     cols = []
-    for i, c in enumerate(batch.columns):
-        d = ex[2 * i]
+    for c in batch.columns:
+        d = next(ex)
         # (n, block, lanes...) -> rows-major local layout
         d = d.reshape((-1,) + d.shape[2:])
-        cols.append(Column(c.type, d, ex[2 * i + 1].reshape(-1), c.dictionary))
-    return RelBatch(cols, live_ex.reshape(-1))
+        valid = None if c.valid is None else next(ex).reshape(-1)
+        cols.append(Column(c.type, d, valid, c.dictionary))
+    out = RelBatch(cols, live_ex.reshape(-1))
+    if full:
+        return out
+    return out, jnp.where(needed > block, needed, 0).astype(jnp.int32)
 
 
-def _exchange_hash(batch: RelBatch, channels: Sequence[int], n: int) -> RelBatch:
-    """FIXED_HASH remote exchange as partition + all_to_all over ICI."""
-    return _exchange_with_pids(batch, _partition_ids(batch, channels, n), n)
+def _exchange_hash(batch: RelBatch, channels: Sequence[int], n: int,
+                   block: Optional[int] = None):
+    """FIXED_HASH remote exchange as partition + all_to_all over ICI
+    (`block`: see `_exchange_with_pids`)."""
+    return _exchange_with_pids(
+        batch, _partition_ids(batch, channels, n), n, block
+    )
 
 
 # -- skew-aware salted repartition (ISSUE 16, the JSPIM playbook) ------
@@ -366,8 +436,11 @@ class _FragVisitor:
 
     def __init__(self, executor: "MeshExecutor", frag_id: int,
                  feeds: Dict[int, RelBatch], ctx: Dict[int, RelBatch],
-                 caps: Dict[str, int], flags: List[Tuple[str, jnp.ndarray]]):
+                 caps: Dict[str, int], flags: List[Tuple[str, jnp.ndarray]],
+                 streaming: bool = False):
         self.ex = executor
+        # inside a chunk step: the batch is one chunk of the driver scan
+        self.streaming = streaming
         self.frag_id = frag_id
         self.feeds = feeds  # id(ScanNode) -> local RelBatch
         self.ctx = ctx  # fragment id -> post-exchange local RelBatch
@@ -456,21 +529,54 @@ class _FragVisitor:
             for a in node.aggs
         )
 
-    def _initial_agg_cap(self, node, batch: RelBatch) -> int:
-        """Dictionary/boolean-bounded key domains fix the capacity at
-        plan time (the HashAggregationOperator static-bound rule)."""
-        bound = 1
+    @staticmethod
+    def _key_dims(node, batch: RelBatch) -> Optional[List[int]]:
+        """Sizes of the group keys' domains where the plan bounds every
+        one of them (dictionary codes, booleans); else None."""
+        dims = []
         for ch in node.group_channels:
             c = batch.columns[ch]
             if c.type.is_string and c.dictionary is not None and len(c.dictionary) > 0:
-                bound *= len(c.dictionary) + 1
+                dims.append(len(c.dictionary))
             elif c.type.kind == T.TypeKind.BOOLEAN:
-                bound *= 3
+                dims.append(2)
             else:
-                return 1024
-        if 0 < bound <= (1 << 16):
+                return None
+        return dims
+
+    def _initial_agg_cap(self, node, batch: RelBatch) -> int:
+        """Dictionary/boolean-bounded key domains fix the capacity at
+        plan time (the HashAggregationOperator static-bound rule)."""
+        dims = self._key_dims(node, batch)
+        bound = int(np.prod([d + 1 for d in dims])) if dims is not None else 0
+        if dims is not None and 0 < bound <= (1 << 16):
             return max(bucket_capacity(bound), 16)
-        return 1024
+        # unbounded keys: an eighth of the input's capacity and at
+        # least 1024 groups; the site's flag says when that was short
+        return max(1024, bucket_capacity(batch.capacity) // 8)
+
+    def _bounded_reduce(self, node, batch: RelBatch, values, reds):
+        """(group-reduce, key dims) where the plan bounds the key domain
+        (dictionary and boolean keys), as HashAggregationOperator picks
+        them: up to 64 slots the unrolled dense reduce, up to 2048 on a
+        TPU mesh the MXU one-hot contraction (sums and counts of integer
+        values); (None, None) means the sort path. Neither sorts, so a
+        chunk of millions of rows costs one pass over its columns."""
+        dims = self._key_dims(node, batch)
+        if not dims or any(
+            getattr(batch.columns[ch].data, "ndim", 1) != 1
+            for ch in node.group_channels
+        ):
+            return None, None
+        bound = int(np.prod([d + 1 for d in dims]))
+        ints = all(not jnp.issubdtype(v.dtype, jnp.floating) for v in values)
+        if bound <= 64 and all(r in ("sum", "count") for r in reds) and ints:
+            return G.dense_group_reduce, tuple(dims)
+        on_tpu = self.ex.mesh.devices.flat[0].platform == "tpu"
+        if (bound <= 2048 and on_tpu and ints
+                and all(r in ("sum", "count") for r in reds)):
+            return G.mxu_group_reduce, tuple(dims)
+        return None, None
 
     def _batch_agg_inputs(self, aggs, batch: RelBatch):
         """Value slots + reducers per aggregate (long-decimal args split
@@ -513,9 +619,12 @@ class _FragVisitor:
         live, values, vvalids, reds = self._batch_agg_inputs(aggs, batch)
         site = self._site("agg")
         cap = self.caps.setdefault(site, self._initial_agg_cap(node, batch))
-        gk, gv, used, vals, cnts, ngroups, ovf = G.sort_group_reduce(
-            tuple(keys), tuple(valids), live, tuple(values), tuple(vvalids),
-            tuple(reds), cap,
+        reduce, dims = self._bounded_reduce(node, batch, values, reds)
+        args = (tuple(keys), tuple(valids), live, tuple(values),
+                tuple(vvalids), tuple(reds))
+        gk, gv, used, vals, cnts, ngroups, ovf = (
+            G.sort_group_reduce(*args, cap) if reduce is None
+            else reduce(*args, dims, cap)
         )
         self.flags.append((site, jnp.where(ovf, ngroups, 0).astype(jnp.int32)))
         cols: List[Column] = []
@@ -707,7 +816,19 @@ class _FragVisitor:
             valids.append(v)
         lo, counts, total = J.probe_counts(ls, keys, valids, probe.live_mask())
         site = self._site("join")
-        out_cap = self.caps.setdefault(site, bucket_capacity(max(probe.capacity, 16)))
+        # room for one pair per probe row to start with. A large probe
+        # starts at a quarter of that; one chunk of a streamed scan at a
+        # sixty-fourth: the expansion gathers every column once per SLOT
+        # of capacity (at a million slots the gathers were 290 of a
+        # chunk step's 530 ms on the v5e, for 40 thousand pairs: PERF.md,
+        # PR 28), a join under filters fills few, and the site's flag
+        # widens it where it does not (the runner keeps what a plan's
+        # run learned, so that is paid once)
+        start = bucket_capacity(max(probe.capacity, 16))
+        if start > _FULL_JOIN_ROWS:
+            start = (max(start // 64, 1 << 16) if self.streaming
+                     else max(start // 4, _FULL_JOIN_ROWS))
+        out_cap = self.caps.setdefault(site, start)
         self.flags.append(
             (site, jnp.where(total > out_cap, total, 0).astype(jnp.int32))
         )
@@ -981,6 +1102,8 @@ class MeshExecutor:
         devs = list(devices) if devices is not None else list(jax.devices())
         self.n = len(devs)
         self.mesh = Mesh(np.array(devs), (AXIS,))
+        # how every feed, context and carry lies on it: rows over AXIS
+        self.sharding = NamedSharding(self.mesh, PSpec(AXIS))
         self.replica_id = replica_id
         self.drain_check = drain_check
         self.last_run: Dict[str, object] = {}
@@ -1041,13 +1164,17 @@ class MeshExecutor:
 
     # -- planning helpers --
     def _load_scans(self, mesh_sps):
-        """Host side of SOURCE distribution: each shard scans its slice
-        of the connector splits; slices stack into one host RelBatch per
-        ScanNode of global shape (n * cap,) (the
-        SourcePartitionedScheduler assignment collapsed onto the mesh).
-        Device placement is deferred to the chunk runner, which may
-        re-pad the driver feed to a chunk-aligned capacity first."""
+        """Host side of SOURCE distribution: one RelBatch per ScanNode of
+        global shape (n * cap,), shard s's rows in [s * cap, (s + 1) *
+        cap). Where the connector deals its rows out by position the
+        feed is already on the devices, one shard each, and stays there
+        (parallel/mesh_feed.py). Otherwise each shard scans its slice of
+        the connector splits and the slices stack into one host RelBatch
+        (the SourcePartitionedScheduler assignment collapsed onto the
+        mesh); the chunk runner places it, and may re-pad the driver
+        feed to a chunk-aligned capacity first."""
         from trino_tpu.exec.operators import TableScanOperator
+        from trino_tpu.parallel import mesh_feed
 
         feeds: Dict[int, int] = {}  # id(node) -> feed position
         host_feeds: List[RelBatch] = []
@@ -1060,6 +1187,18 @@ class MeshExecutor:
                     # misalign in_specs with feed_args
                     continue
                 conn = self.catalogs.get(node.catalog)
+                self._feed_tables.append((
+                    str(node.catalog).lower(),
+                    str(node.handle.schema).lower(),
+                    str(node.handle.table).lower(),
+                ))
+                dealt = mesh_feed.load(self, conn, node)
+                if dealt is not None:
+                    # each device holds its shard of what the programs
+                    # read (and keeps it for the next statement)
+                    feeds[id(node)] = len(host_feeds)
+                    host_feeds.append(dealt)
+                    continue
                 splits = conn.split_manager.get_splits(
                     node.handle, max(self.session.target_splits, self.n)
                 )
@@ -1086,16 +1225,14 @@ class MeshExecutor:
                         shard_batches.append(_empty_batch(schema))
                 feeds[id(node)] = len(host_feeds)
                 host_feeds.append(_stack_shards(shard_batches, self.n))
-                self._feed_tables.append((
-                    str(node.catalog).lower(),
-                    str(node.handle.schema).lower(),
-                    str(node.handle.table).lower(),
-                ))
         return feeds, host_feeds
 
     # -- host boundary --
     def _shard_pages(self, batch: RelBatch, replicated: bool) -> List[Page]:
-        host = jax.device_get(batch)
+        from trino_tpu.runtime.tracing import host_sync
+
+        with host_sync("mesh.result"):
+            host = jax.device_get(batch)
         global_cap = host.columns[0].data.shape[0] if host.columns else 0
         cap = global_cap // self.n
         shards = range(1) if replicated else range(self.n)

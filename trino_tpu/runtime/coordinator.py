@@ -15,7 +15,7 @@ import itertools
 import threading
 from trino_tpu.analysis.witness import named_condition, named_lock, named_rlock
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from trino_tpu import types as T
 from trino_tpu.connectors.spi import CatalogManager, Connector
@@ -340,6 +340,11 @@ class DistributedQueryRunner:
         register_recovery_metrics()
         # why the last query left the mesh plane (None = it didn't)
         self.last_mesh_fallback: Optional[str] = None
+        # called with the reason once a fallback is recorded, before the
+        # page plane takes the query over; what it raises fails the query
+        # instead (a deployment whose tables only the mesh plane can hold
+        # wants the error, not an hour of page exchange)
+        self.on_mesh_fallback: Optional[Callable[[str], None]] = None
         # resiliency plane: every worker is registered with a
         # NodeManager whose per-node circuit breakers graylist
         # misbehaving workers (ping loop NOT started here — call
@@ -555,8 +560,11 @@ class DistributedQueryRunner:
         computing a result nobody will read."""
         import time as _time
 
+        from trino_tpu.runtime.tracing import host_span
+
         t_parse0 = _time.time()
-        stmt = parse(sql)
+        with host_span("phase.parse"):
+            stmt = parse(sql)
         t_parse1 = _time.time()
         if isinstance(stmt, ast.ExplainStatement):
             output = self._analyze(stmt.query)
@@ -865,10 +873,23 @@ class DistributedQueryRunner:
                 fast_lane = False
             prev = set_compile_attribution(base_qid)
             try:
-                rows = self._execute_mesh(
-                    subplan, preempt, query_span,
-                    fast=fast_lane, query_id=base_qid,
-                )
+                import time as _time
+
+                from trino_tpu.runtime.tracing import phase_span
+
+                # the leaf span the local runner's `execute` phase is in
+                # a profiler trace, with this thread's CPU time inside
+                cpu0 = _time.thread_time_ns()
+                with phase_span(query_span, "execute") as executing:
+                    try:
+                        rows = self._execute_mesh(
+                            subplan, preempt, query_span,
+                            fast=fast_lane, query_id=base_qid,
+                        )
+                    finally:
+                        executing.set_metadata(
+                            cpu_ns=_time.thread_time_ns() - cpu0
+                        )
                 self._last_data_plane = "mesh"
                 return MaterializedResult(
                     rows, *result_meta, data_plane="mesh"
@@ -1409,17 +1430,25 @@ class DistributedQueryRunner:
         (mesh_fallbacks.{slug}) and drop an instant event on the query
         span so the trace timeline shows where the plane switched."""
         import re
+        import time
 
-        from trino_tpu.parallel.mesh_plan import bump_mesh_counter
+        from trino_tpu.parallel.mesh_plan import (
+            FALLBACK_LOG,
+            bump_mesh_counter,
+        )
         from trino_tpu.runtime.metrics import METRICS
 
         bump_mesh_counter("fallbacks")
+        FALLBACK_LOG.append((time.perf_counter(), reason))
+        METRICS.increment("mesh.fallbacks")
         self.last_mesh_fallback = reason
         slug = re.sub(r"[^a-z0-9]+", "_", reason.lower()).strip("_")[:40]
         if slug:
             METRICS.increment(f"mesh_fallbacks.{slug}")
         if query_span is not None:
             query_span.event("mesh_fallback", reason=reason[:300])
+        if self.on_mesh_fallback is not None:
+            self.on_mesh_fallback(reason)
 
     def _mesh_plane_line(self, subplan) -> str:
         """The EXPLAIN ANALYZE data-plane line: which plane `execute`

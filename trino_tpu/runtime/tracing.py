@@ -57,7 +57,17 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   and its conversion to rows; ``server.queued`` (stat ``handoff_us`` on
   the executing thread's part) and ``server.respond`` (stats ``pages``,
   ``rows``, and ``since_finished_us`` on the response that delivers the
-  last page) in ``runtime/server.py``.
+  last page) in ``runtime/server.py``;
+  the mesh plane (``parallel/``): ``mesh.feed`` (a scan's columns go
+  from the host to their devices' shards; stats ``table``, ``columns``,
+  ``rows``, ``nbytes``), ``mesh.prelude``, ``mesh.step`` (stat
+  ``chunk``) and ``mesh.finish``, one dispatch each of a chunked run's
+  three programs with its flag readback inside (stats ``all_to_all``,
+  ``all_gather``, ``bytes_exchanged``: what one run of the program
+  exchanges, ``mesh_chunk.ExchangeCensus``), ``sync.mesh.<site>`` for
+  the plane's readbacks (``prelude_flags``, ``step_flags``,
+  ``finish_flags``, ``result``), and the coordinator's ``phase.parse``
+  and ``phase.execute`` (``cpu_ns``) around a distributed statement.
 
 Leaf spans know their statement by lying inside its ``tpusql.query.*``
 event on the same thread line. The one piece of per-thread state here is
