@@ -493,12 +493,14 @@ def mxu_spy():
     real = mxu_groupby.grouped_sum_mxu
     calls: List[dict] = []
 
-    def spy(gid, values, live, capacity, interpret=False):
+    def spy(gid, values, live, capacity, interpret=False, limbs=None):
         calls.append({
             "n": int(gid.shape[0]), "value_columns": len(values),
             "capacity": int(capacity), "interpret": bool(interpret),
+            "limbs": limbs,
         })
-        return real(gid, values, live, capacity, interpret=interpret)
+        return real(gid, values, live, capacity, interpret=interpret,
+                    limbs=limbs)
 
     mxu_groupby.grouped_sum_mxu = spy
     try:
@@ -527,6 +529,7 @@ def proof_of_device_phase(calls: List[dict]) -> None:
               for _ in range(shape["value_columns"])),
         jax.ShapeDtypeStruct((n,), jnp.bool_),
         capacity=shape["capacity"], interpret=shape["interpret"],
+        limbs=shape["limbs"],
     ).as_text()
     emit("proof_of_device", kernel="grouped_sum_mxu", calls=len(calls),
          shape=shape, tpu_custom_call="tpu_custom_call" in text)

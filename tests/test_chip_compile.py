@@ -67,24 +67,35 @@ def _sds(shape, dtype, sharding):
 
 
 @pytest.mark.parametrize(
-    "n,value_columns,capacity",
+    "n,value_columns,capacity,limbs",
     [
-        pytest.param(BATCH, 9, 16, id="1M-9cols-cap16"),
-        pytest.param(BATCH, 9, 2048, id="1M-9cols-cap2048"),
-        pytest.param(1 << 22, 9, 16, id="4M-9cols-cap16"),
-        # what chip_smoke's G3 produces at SF1: count(*) and
-        # sum(l_quantity) over a 160-slot key domain
-        pytest.param(BATCH, 3, 160, id="g3-1M-3cols-cap160"),
+        pytest.param(BATCH, 9, 16, None, id="1M-9cols-cap16"),
+        pytest.param(BATCH, 9, 2048, None, id="1M-9cols-cap2048"),
+        pytest.param(1 << 22, 9, 16, None, id="4M-9cols-cap16"),
+        # what the parent's G3 handed over at SF1: two 0/1 indicators
+        # and sum(l_quantity) over a 160-slot key domain, all as int64
+        pytest.param(BATCH, 3, 160, None, id="g3-1M-3cols-cap160"),
+        # what G3 hands over since PR 29: the value alone (its counts
+        # ride the kernel's live-row count), at the engine's batch and
+        # at the mesh plane's chunk
+        pytest.param(BATCH, 1, 160, (8,), id="g3-1M-limbs8-cap160"),
+        pytest.param(1 << 22, 1, 160, (8,), id="g3-4M-limbs8-cap160"),
+        # a masked sum: its 0/1 indicator has one limb and no high word
+        pytest.param(BATCH, 2, 160, (1, 8), id="1M-limbs1+8-cap160"),
     ],
 )
-def test_grouped_sum_mxu_compiles_for_v5e(one_chip, n, value_columns, capacity):
+def test_grouped_sum_mxu_compiles_for_v5e(
+    one_chip, n, value_columns, capacity, limbs
+):
+    """Legal for Mosaic and inside the default scoped VMEM at the tile
+    `_row_tile` picks for the shape (no limit is raised in the call)."""
     from trino_tpu.ops.mxu_groupby import grouped_sum_mxu
 
     compiled = grouped_sum_mxu.lower(
         _sds((n,), jnp.int32, one_chip),
         tuple(_sds((n,), jnp.int64, one_chip) for _ in range(value_columns)),
         _sds((n,), jnp.bool_, one_chip),
-        capacity=capacity, interpret=False,
+        capacity=capacity, interpret=False, limbs=limbs,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -51,7 +51,7 @@ def test_served_shapes(served):
     # G3 reached the kernel, at the key domain tests/test_chip_compile.py
     # compiles for the chip
     assert calls
-    assert {(c["value_columns"], c["capacity"]) for c in calls} == {(3, 160)}
+    assert {(c["limbs"], c["capacity"]) for c in calls} == {((8,), 160)}
 
 
 def test_compare_fails_on_a_wrong_answer(loaded, served):

@@ -243,14 +243,93 @@ class TestMxuGroupby:
         self._check(n=3000, c=300, n_vals=2, seed=1)
 
     def test_masked_rows_and_row_padding(self):
-        # n not a multiple of the 256-row tile; 30% dead rows
+        # n not a multiple of the contraction's chunk; 30% dead rows
         self._check(n=1001, c=17, n_vals=1, seed=2, live_frac=0.7)
 
     def test_many_values_multi_sublane_tile(self):
-        # >7 value columns forces a8 > 8 (two sublane tiles of planes)
+        # 9 value columns are 18 word rows: w8 = 24, three sublane tiles
         self._check(n=2048, c=100, n_vals=9, seed=3)
 
-    def test_mxu_group_reduce_contract(self):
+    # PR 29's operand type and tile rule at their edges, each against
+    # the scatter oracle: name -> (value of row i per column, limbs,
+    # one group for all rows, capacity, rows). Cases share shapes so
+    # that they share compiled programs. 100 slots pad to 128, whose
+    # tile is the longest (32,768 rows); 77 rows more make a second,
+    # ragged grid step.
+    _LONG = 32768 + 77
+    _EDGES = {
+        "all-limbs-255": ([lambda i: -1], None, False, 100, _LONG),
+        "int64-min-max": (
+            [lambda i: -(2**63) if i % 2 else 2**63 - 1], None, False,
+            100, _LONG),
+        # the largest sum a grid step can hold: a whole tile in one
+        # group, every limb 255
+        "one-group-fills-a-tile": ([lambda i: -1], None, True, 100, _LONG),
+        "capacity-1": ([lambda i: i - 500], None, False, 1, 1001),
+        "capacity-160": ([lambda i: i - 500], None, False, 160, 1001),
+        "capacity-2048": ([lambda i: i - 500], None, False, 2048, 1001),
+        "limbs-1": ([lambda i: i % 3 == 0], (1,), False, 160, 1001),
+        "limbs-mixed": (
+            [lambda i: -(2**40) * i - 1, lambda i: i % 2,
+             lambda i: 65535 - (i % 7), lambda i: 2**40 - 1 - i],
+            (8, 1, 2, 5), False, 160, 1001),
+        # `select k, count(*) ... group by k`: the live-row count alone
+        "no-columns": ([], None, False, 160, 1001),
+    }
+
+    @pytest.mark.parametrize("edge", sorted(_EDGES))
+    def test_exact_at_the_edges(self, edge):
+        import jax
+        import numpy as np
+        import jax.numpy as jnp
+        from trino_tpu.ops.mxu_groupby import (
+            grouped_sum_mxu, grouped_sum_reference,
+        )
+
+        cols, limbs, one_group, c, n = self._EDGES[edge]
+        rng = np.random.default_rng(29)
+        gid = np.zeros(n, np.int32) if one_group else rng.integers(
+            0, c, n, dtype=np.int32)
+        live = np.ones(n, bool) if one_group else rng.random(n) < 0.9
+        vals = tuple(
+            jnp.asarray(np.array([int(f(i)) for i in range(n)], dtype=np.int64))
+            for f in cols
+        )
+        interp = jax.default_backend() != "tpu"
+        got = grouped_sum_mxu(jnp.asarray(gid), vals, jnp.asarray(live), c,
+                              interpret=interp, limbs=limbs)
+        want = jax.jit(grouped_sum_reference, static_argnums=3)(
+            jnp.asarray(gid), vals, jnp.asarray(live), c)
+        assert len(got) == len(want) == len(cols) + 1
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+        if one_group:
+            assert int(got[1][0]) == n and int(got[0][0]) == -n
+
+    @pytest.mark.parametrize("C", [128, 256, 1024, 2048])
+    @pytest.mark.parametrize("w8", [8, 24, 64])
+    def test_row_tile_bounds(self, C, w8):
+        """The tile rule's promises, for every padded slot count and
+        word-row count: float32 sums exact within a grid step, whole
+        chunks, a one-hot and a word block the scoped VMEM holds."""
+        from trino_tpu.ops.mxu_groupby import MAX_ROWS, MAX_TILE, _row_tile
+
+        assert MAX_TILE * 255 < 2**24 and MAX_ROWS >= 2**23
+        for n in (1, 1000, 1 << 20, MAX_ROWS):
+            tile, chunk = _row_tile(n, C, w8)
+            assert tile * 255 < 2**24 and tile % chunk == 0
+            assert chunk % 128 == 0 and C * chunk <= 1 << 19
+            assert w8 * tile * 4 <= 2 << 20
+            assert tile - chunk < max(n, chunk)  # no chunk of padding alone
+        assert _row_tile(1 << 20, 256, 8) == (16384, 2048)  # G3's
+
+    @pytest.mark.parametrize("reducers,masked", [
+        pytest.param(("sum", "count"), (True, False), id="masked-sum"),
+        # G3's: count(*) and a sum without a validity mask read the one
+        # live-row count the kernel appends
+        pytest.param(("count", "sum"), (False, False), id="shared-count"),
+    ])
+    def test_mxu_group_reduce_contract(self, reducers, masked):
         """mxu_group_reduce matches dense_group_reduce on the same
         bounded-domain inputs (sum/count reducers)."""
         import numpy as np
@@ -272,9 +351,10 @@ class TestMxuGroupby:
             jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int64)),
             jnp.ones(n, dtype=jnp.int64),
         ]
-        vvalids = [jnp.asarray(rng.random(n) < 0.95), None]
+        vvalids = [jnp.asarray(rng.random(n) < 0.95) if m else None
+                   for m in masked]
         args = (keys, valids, mask, values, tuple(vvalids),
-                ("sum", "count"), (d0, d1), 64)
+                reducers, (d0, d1), 64)
         want = dense_group_reduce(*args)
         got = mxu_group_reduce(*args)
         for g, w in zip(got[:5], want[:5]):

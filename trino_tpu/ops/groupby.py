@@ -447,23 +447,33 @@ def mxu_group_reduce(
     assert total <= out_capacity
     gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices)
 
-    # per aggregate: a zero-masked value column plus ONE shared
-    # valid-count column (for count reducers the count IS the value)
-    cols = []
+    # per aggregate: a zero-masked value column (8 limbs) and the count
+    # of its valid rows: a 0/1 indicator of one limb, or, where the
+    # value has no validity mask, the live-row count the kernel appends
+    # anyway (index -1), so that G3's count(*) and its sum's count ride
+    # once; for count reducers the count IS the value
+    cols, limbs = [], []
     col_of_value = []  # per aggregate: index of its value column
     col_of_count = []  # per aggregate: index of its count column
     for v, vv, red in zip(values, value_valids, reducers):
         w = mask if vv is None else (mask & vv)
-        cnt_idx = len(cols)
-        cols.append(w.astype(jnp.int64))
+        cnt_idx = -1
+        if vv is not None:
+            cnt_idx = len(cols)
+            cols.append(w.astype(jnp.int64))
+            limbs.append(1)
         col_of_count.append(cnt_idx)
         if red == "sum":
             col_of_value.append(len(cols))
             cols.append(jnp.where(w, v.astype(jnp.int64), 0))
-        else:  # count: reuse the indicator column
+            limbs.append(8)
+        else:
             col_of_value.append(cnt_idx)
     interpret = jax.default_backend() != "tpu"
-    sums = grouped_sum_mxu(gid, tuple(cols), mask, total, interpret=interpret)
+    sums = grouped_sum_mxu(
+        gid, tuple(cols), mask, total, interpret=interpret,
+        limbs=tuple(limbs),
+    )
     row_count = sums[-1]  # appended live-row count per slot
 
     def pad(x, fill=0):

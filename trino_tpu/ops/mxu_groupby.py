@@ -2,27 +2,44 @@
 
 The GroupByHash + accumulate hot loop (Trino
 main/operator/GroupByHash.java:30 probe + Aggregator.processPage,
-SURVEY.md §3.3) mapped onto the systolic array: per row tile, the
+SURVEY.md §3.3) mapped onto the systolic array: per chunk of rows, the
 transposed group-membership one-hot matrix is contracted against the
 byte-limb decomposition of the value columns on the MXU —
 
-    acc[L, C] += limbs(values_tile)[L, R] @ one_hot_T(gid_tile)[C, R]^T
+    acc[L, C] += limbs(words_chunk)[L, R] @ one_hot_T(gid_chunk)[C, R]^T
 
-Exactness: int64 values are split into eight 8-bit limbs *inside the
-kernel* (from two int32 halves — no HBM blowup); a 256-row tile bounds
-every per-tile limb sum by 256*255 < 2^16, so the f32 MXU contraction
-is exact, and the int32 accumulator holds 2^15 tiles (8.4M rows) per
-call. XLA recombines limbs into int64 afterwards; two's-complement
-wraparound makes the limb sum equal the true int64 sum mod 2^64 —
-exactly SQL BIGINT arithmetic.
+Exactness: every value is cut into 8-bit limbs *inside the kernel*, from
+the 32-bit words the prologue lays side by side (no HBM blowup). Both
+MXU operands are bf16 and exact in it: a limb is an integer <= 255 and
+a one-hot entry is 0 or 1, and bf16 carries 8 significant bits. Their
+products are <= 255 and the MXU accumulates them in float32, which is
+exact while a sum stays under 2^24: one grid step sums at most
+MAX_TILE rows, MAX_TILE * 255 < 2^24. Each step's sums are added to an
+int32 accumulator, which holds MAX_ROWS * 255 < 2^31. XLA recombines
+limbs into int64 afterwards; two's-complement wraparound makes the limb
+sum equal the true int64 sum mod 2^64 — exactly SQL BIGINT arithmetic.
+(float32 operands buy nothing: Mosaic multiplies them in one bf16 pass
+as well, at the same speed, in twice the VMEM; PERF.md section 6, PR 29.)
+
+Only words that can carry data are laid out: a column the caller states
+to be under 2^32 (`limbs` <= 4: a 0/1 indicator has one limb) has no
+high word, and the live-row count rides the gid row, which the kernel
+turns into ones. The words of one call are one (w8, N) int32 plane.
+
+The tile follows from what the call states (`_row_tile`): a grid step
+costs about 0.12 us whatever it holds, so the step is as long as the
+default scoped VMEM lets the one-hot and the word block be, and the
+padded slot count C decides that. Measured on a v5e, 2^20 rows, 160
+slots: 0.759 ms at the 256-row tile this kernel had, 0.183 ms at 16,384
+(PERF.md section 6, PR 29).
 
 Layout notes (the part that makes this TPU-native rather than a CUDA
 translation): all row-major (N, k) arrays with tiny k are poison under
 TPU (8, 128) tiling (the lane dim pads to 128 — measured 128x HBM
-expansion), so every input is transposed to (k, N) with rows as
-sublanes, and the group-id vector rides as an extra row of the lo-limb
-plane. Index-map constants must be np.int32: under jax x64 they trace
-as i64 and Mosaic fails to legalize the index-map signature.
+expansion), so the words are laid out (k, N) with rows as lanes, and
+the group-id vector is the plane's last row. Constants that meet an
+int32 in the kernel must be int32 themselves: under jax x64 a Python int
+traces as i64 and Mosaic fails to legalize it.
 
 CPU/test path: pallas interpret mode computes the identical program.
 """
@@ -30,110 +47,147 @@ CPU/test path: pallas interpret mode computes the identical program.
 from __future__ import annotations
 
 from functools import partial
-from typing import List, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.experimental.pallas as pl
 import jax.numpy as jnp
 import numpy as np
 
-ROW_TILE = 256
 MAX_CAPACITY = 2048
-# per-tile limb sums are < 2^16, so the int32 accumulator holds 2^15
-# tiles before it can wrap — callers must split or fall back past this
-MAX_ROWS = ROW_TILE << 15
+# one grid step's float32 sums stay exact
+MAX_TILE = 1 << 15
+assert MAX_TILE * 255 < 1 << 24
+# the int32 accumulator cannot wrap — callers must split or fall back
+# past this (the mesh plane's 2^22-row chunk and the aggregation's
+# trains count on 2^23)
+MAX_ROWS = 1 << 23
+assert MAX_ROWS * 255 < 1 << 31
+# a grid step's rows are contracted in this many unrolled chunks (a
+# rolled loop cannot overlap one chunk's one-hot with the last one's
+# matmul: 0.235 against 0.183 ms, PERF.md section 6, PR 29)
+_CHUNKS = 8
 _I0 = np.int32(0)
 
 
-def _make_kernel(a8: int):
-    def kernel(lo_ref, hi_ref, out_ref):
+def _row_tile(n: int, C: int, w8: int) -> Tuple[int, int]:
+    """(rows a grid step, rows a contraction) for n rows, C padded slots
+    and w8 word rows: a power of two that keeps a chunk's one-hot at
+    2^19 entries (1 MB of bf16 beside its 2 MB int32 comparison) and the
+    step's word block under 2 MB, so both fit the default scoped VMEM
+    twice over; shorter where the call has fewer rows."""
+    tile = min((1 << 22) // C, (1 << 19) // w8, MAX_TILE)
+    tile = 1 << (tile.bit_length() - 1)
+    chunk = max(tile // _CHUNKS, 128)
+    tile = min(tile, max(1, -(-n // chunk)) * chunk)
+    assert tile % chunk == 0 and tile <= MAX_TILE, (n, C, w8, tile, chunk)
+    return tile, chunk
+
+
+def _bf16(x):
+    # v5e's VPU has no bf16 lanes: integers and masks go through float32
+    return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def _make_kernel(w8: int, planes: int, chunk: int):
+    gid_row = np.int32(w8 - 1)
+
+    def kernel(w_ref, out_ref):
         @pl.when(pl.program_id(0) == 0)
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
 
         C = out_ref.shape[1]
-        R = lo_ref.shape[1]
-        gid = lo_ref[a8 - 1:a8, :]  # (1, R); dead rows carry >= C
-        onehot_t = (
-            jax.lax.broadcasted_iota(jnp.int32, (C, R), 0) == gid
-        ).astype(jnp.float32)  # (C, R)
-        planes = []
-        for src in (lo_ref[:], hi_ref[:]):
-            for j in range(4):
-                planes.append(
-                    ((src >> (8 * j)) & 0xFF).astype(jnp.float32)
-                )
-        limbs = jnp.concatenate(planes, axis=0)  # (8*a8, R)
-        contrib = jax.lax.dot_general(
-            limbs, onehot_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (8*a8, C)
-        out_ref[:] += contrib.astype(jnp.int32)
+        acc = jnp.zeros(out_ref.shape, jnp.float32)
+        for s in range(w_ref.shape[1] // chunk):
+            words = w_ref[:, s * chunk:(s + 1) * chunk]  # (w8, chunk)
+            gid = words[w8 - 1:w8, :]  # dead rows carry >= capacity
+            onehot_t = _bf16(
+                jax.lax.broadcasted_iota(jnp.int32, (C, chunk), 0) == gid
+            )
+            # the gid row becomes the live-row count's row of ones
+            words = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (w8, chunk), 0)
+                == gid_row,
+                jnp.ones_like(words), words,
+            )
+            limbs = jnp.concatenate(
+                [_bf16((words >> (8 * j)) & 0xFF) for j in range(planes)],
+                axis=0,
+            )  # (planes * w8, chunk)
+            acc = acc + jax.lax.dot_general(
+                limbs, onehot_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (planes * w8, C)
+        out_ref[:] += acc.astype(jnp.int32)
 
     return kernel
 
 
-@partial(jax.jit, static_argnames=("capacity", "interpret"))
+@partial(jax.jit, static_argnames=("capacity", "interpret", "limbs"))
 def grouped_sum_mxu(
     gid: jnp.ndarray,
     values: Sequence[jnp.ndarray],
     live: jnp.ndarray,
     capacity: int,
     interpret: bool = False,
+    limbs: Optional[Tuple[int, ...]] = None,
 ) -> List[jnp.ndarray]:
     """Per-group int64 sums of each value column, with the live-row
     count appended last. gid in [0, capacity) for live rows; dead or
-    masked rows are dropped."""
+    masked rows are dropped. `limbs` states, per value column, how many
+    8-bit limbs its values can have: 8 (the default) for any int64, k <
+    8 where the caller knows 0 <= value < 2^(8k), so 1 for a 0/1
+    indicator."""
     assert capacity <= MAX_CAPACITY, capacity
     n = gid.shape[0]
     assert n <= MAX_ROWS, (n, "int32 limb accumulator would overflow")
-    n_pad = -n % ROW_TILE
+    if limbs is None:
+        limbs = (8,) * len(values)
+    assert len(limbs) == len(values) and all(1 <= k <= 8 for k in limbs), limbs
     C = max(128, -(-capacity // 128) * 128)
 
-    gid = jnp.where(live, gid, capacity).astype(jnp.int32)
-    cols = [v.astype(jnp.int64) for v in values]
-    cols.append(jnp.ones(n, dtype=jnp.int64))  # count
-    a = len(cols)
-    a8 = -(-(a + 1) // 8) * 8  # + the gid row, padded to sublane tile
+    # word rows: (column, limbs in this word, first limb)
+    words = []
+    for k, nk in enumerate(limbs):
+        words.append((k, min(nk, 4), 0))
+        if nk > 4:
+            words.append((k, nk - 4, 4))
+    w8 = -(-(len(words) + 1) // 8) * 8  # + the gid row, padded to sublane tile
+    planes = max(w[1] for w in words) if words else 1
+    tile, chunk = _row_tile(n, C, w8)
+    n_pad = -n % tile
 
-    lo_rows, hi_rows = [], []
-    for v in cols:
+    def row(x, fill=0):
+        x = x.astype(jnp.int32)  # truncating wrap: the low 32 bits
         if n_pad:
-            v = jnp.concatenate([v, jnp.zeros(n_pad, v.dtype)])
-        lo_rows.append(v.astype(jnp.int32))  # truncating wrap: low 32
-        hi_rows.append((v >> 32).astype(jnp.int32))
-    if n_pad:
-        gid = jnp.concatenate([gid, jnp.full(n_pad, capacity, jnp.int32)])
-    zero_row = jnp.zeros(n + n_pad, jnp.int32)
-    lo_rows.extend([zero_row] * (a8 - a - 1) + [gid])
-    hi_rows.extend([zero_row] * (a8 - a))
-    lo = jnp.stack(lo_rows, axis=0)  # (a8, N')
-    hi = jnp.stack(hi_rows, axis=0)
+            x = jnp.concatenate([x, jnp.full(n_pad, fill, jnp.int32)])
+        return x
 
-    num_tiles = (n + n_pad) // ROW_TILE
+    cols = [v.astype(jnp.int64) for v in values]
+    rows = [row(cols[k] >> (8 * first)) for k, _nl, first in words]
+    rows.extend([jnp.zeros(n + n_pad, jnp.int32)] * (w8 - len(words) - 1))
+    rows.append(row(jnp.where(live, gid, capacity), capacity))
+    plane = jnp.stack(rows, axis=0)  # (w8, N')
+
     out = pl.pallas_call(
-        _make_kernel(a8),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((a8, ROW_TILE), lambda i: (_I0, i)),
-            pl.BlockSpec((a8, ROW_TILE), lambda i: (_I0, i)),
-        ],
-        out_specs=pl.BlockSpec((8 * a8, C), lambda i: (_I0, _I0)),
-        out_shape=jax.ShapeDtypeStruct((8 * a8, C), jnp.int32),
+        _make_kernel(w8, planes, chunk),
+        grid=((n + n_pad) // tile,),
+        in_specs=[pl.BlockSpec((w8, tile), lambda i: (_I0, i))],
+        out_specs=pl.BlockSpec((planes * w8, C), lambda i: (_I0, _I0)),
+        out_shape=jax.ShapeDtypeStruct((planes * w8, C), jnp.int32),
         interpret=interpret,
-    )(lo, hi)
+    )(plane)
 
-    # XLA epilogue: recombine limb-plane rows -> int64 per value
-    results = []
-    for k in range(a):
-        acc = jnp.zeros(C, dtype=jnp.int64)
-        for j in range(4):
-            acc = acc + (out[j * a8 + k].astype(jnp.int64) << (8 * j))
-            acc = acc + (
-                out[(4 + j) * a8 + k].astype(jnp.int64) << (32 + 8 * j)
+    # XLA epilogue: recombine limb rows -> int64 per value
+    results = [jnp.zeros(C, dtype=jnp.int64) for _ in values]
+    for r, (k, nl, first) in enumerate(words):
+        for j in range(nl):
+            results[k] = results[k] + (
+                out[j * w8 + r].astype(jnp.int64) << (8 * (first + j))
             )
-        results.append(acc[:capacity])
-    return results
+    results.append(out[w8 - 1].astype(jnp.int64))  # limb 0 of the ones row
+    return [x[:capacity] for x in results]
 
 
 def grouped_sum_reference(gid, values, live, capacity):
