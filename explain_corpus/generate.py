@@ -1337,7 +1337,7 @@ def corpus_16_concurrency_analyze():
 
     emit(
         "16_concurrency_analyze.txt",
-        ("QUERY\nbench.py --analyze  (trino_tpu/analysis/ static passes)",
+        ("QUERY\nanalyze_package()  (trino_tpu/analysis/ static passes)",
          ""),
         ("whole-package summary (the CI gate's JSON, one key per line; "
          "a diff\nhere means the engine's locking structure actually "

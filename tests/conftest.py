@@ -3,7 +3,7 @@
 Mirrors the reference's tier-3 strategy (SURVEY.md §4): Trino boots a
 multi-node cluster inside one JVM (DistributedQueryRunner); we boot a
 multi-device mesh inside one process via XLA's host-platform device
-partitioning. The chip is driven by chip_smoke.py (and bench.py), never
+partitioning. The chip is driven by chip_smoke.py and chipbench/, never
 by the test suite; tests/test_chip_compile.py asks the chip's compiler
 about the main-path kernels without a chip attached.
 """

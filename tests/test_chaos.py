@@ -19,11 +19,15 @@ from trino_tpu.connectors.spi import CatalogManager
 from trino_tpu.connectors.tpch import create_tpch_connector
 from trino_tpu.engine import Session
 from trino_tpu.runtime import DistributedQueryRunner, Worker
+from trino_tpu.runtime import chaos
 from trino_tpu.runtime.chaos import (
+    FABRIC_CLASSES,
     FAULT_CLASSES,
+    PREEMPT_CLASSES,
     ChaosHarness,
     DownableWorker,
     generate_schedule,
+    rows_equal,
 )
 from trino_tpu.runtime.failure import FailureInjector
 from trino_tpu.runtime.memory import ExceededMemoryLimitError
@@ -563,3 +567,85 @@ def test_mid_crash_after_spill_no_duplicate_rows(oracle):
     expected = sqlite_rows(oracle, to_sqlite(Q_AGG))
     assert_rows_match(rows, expected, ordered=True, abs_tol=1e-2)
     assert runner.last_fte_stats["retries"] >= 1
+
+
+# -- seeded faults inside the mesh chunk loop -------------------------------
+
+Q_MESH = (
+    "select o_orderpriority, count(*) c from orders join customer "
+    "on o_custkey = c_custkey group by o_orderpriority "
+    "order by o_orderpriority"
+)
+MESH_RUNNERS = {
+    "preempt_park_resume": chaos.run_preempt_park_resume_case,
+    "preempt_under_drain": chaos.run_preempt_under_drain_case,
+    "host_lost_mid_chunk": chaos.run_host_lost_case,
+    "membership_flap": chaos.run_membership_flap_case,
+    "transport_corruption": chaos.run_transport_corruption_case,
+}
+
+
+@pytest.mark.parametrize("scenario", PREEMPT_CLASSES + FABRIC_CLASSES)
+def test_mesh_scenarios(scenario, monkeypatch):
+    """Park/resume and the checkpoint fabric composed with failover, end
+    to end through the coordinator's mesh dispatch: a seeded chunk
+    boundary parks, faults, drains, flaps or wipes the local store, and
+    the query must still answer what its clean run answered, with the
+    counts each maneuver promises (one park, the pull, the refused
+    claim, the rejected digest) and no chunk-step executed twice."""
+    from trino_tpu.analysis import witness
+    from trino_tpu.parallel import mesh_chunk
+    from trino_tpu.recovery import CHECKPOINTS
+    from trino_tpu.runtime.fabric import stop_fabric
+
+    # the fabric cases read the secret with setdefault: set here, it is
+    # taken back when the test ends
+    monkeypatch.setenv("TRINO_TPU_INTERNAL_SECRET", "chaos-fabric")
+    CHECKPOINTS.clear()
+    violations0 = witness.violation_count()
+    try:
+        rows, rep = MESH_RUNNERS[scenario](Q_MESH, SEED)
+    finally:
+        mesh_chunk.MESH_FAULT_HOOK = None
+        stop_fabric()
+        CHECKPOINTS.clear()
+    assert rep["mesh_clean_plane"], "clean run did not take the mesh plane"
+    assert rep["mesh_fault_plane"] == "mesh", rep["mesh_fault_plane"]
+    assert rows_equal(rows, rep.pop("expected"), ordered=True)
+    K = rep["chunks"]
+    if scenario == "preempt_park_resume":
+        # the scheduler's condition wait, the checkpoint store and the
+        # fast-lane seat interleave here under the lock-order witness
+        assert witness.witness_enabled()
+        assert witness.violation_count() == violations0
+        assert rep["parked"] and rep["faulted"], rep
+        assert rep["parks"] == 1 and rep["unparks"] == 1, rep
+        assert rep["resumes"] >= 1, rep
+        assert rep["executed_chunk_steps"] == K, rep
+        assert rep["point_ok"], rep
+    elif scenario == "preempt_under_drain":
+        assert rep["parked"] and rep["drain_requested"], rep
+        assert rep["failovers"] == 1 and rep["checkpoint_resumes"] == 1, rep
+        assert rep["resumed_from_chunk"] == rep["park_chunk"], rep
+        assert rep["chunk_steps"] == K, rep
+        assert rep["replica_drained"], rep
+    elif scenario == "host_lost_mid_chunk":
+        assert rep["fired"] and rep["pushes"] >= 1, rep
+        assert rep["pulls"] == 1 and rep["resumes"] == 1, rep
+        assert rep["resumed_from_chunk"] == rep["fault_chunk"], rep
+        # the re-placed attempt counts its own steps: exactly the
+        # chunks the lost host had not finished
+        assert rep["executed_chunk_steps"] == K - rep["fault_chunk"], rep
+    elif scenario == "membership_flap":
+        assert rep["fired"] and rep["flapped"], rep
+        assert rep["double_refused"] == 1, rep
+        assert rep["epoch_delta"] == 2, rep
+        assert rep["owners_at_end"] == 0, rep
+        assert rep["resumes"] or rep["epoch_fences"], rep
+    else:  # transport_corruption
+        assert rep["fired"] and rep["digest_rejects"] >= 1, rep
+        assert rep["pulls"] == 0 and rep["truncated_import"] is False, rep
+        # a rejected transfer degrades to a clean restart, never a
+        # resume from corrupt carries
+        assert rep["resumes"] == 0, rep
+        assert rep["executed_chunk_steps"] == K, rep

@@ -213,8 +213,7 @@ def test_static_wait_while_holding_flagged():
 
 
 def test_full_package_is_clean():
-    """The committed tree must analyze clean — same assertion as the
-    bench.py --analyze CI gate."""
+    """The committed tree must analyze clean (this test is the gate)."""
     rep = analyze_package()
     assert rep.files > 100
     assert len(rep.graph.locks) > 40

@@ -19,6 +19,7 @@ from trino_tpu.sql import plan as P
 from trino_tpu.sql.fragmenter import (
     PlanFragment,
     SubPlan,
+    plan_distributed,
     push_partial_aggregation_through_exchange,
 )
 from trino_tpu.sql.optimizer import IterativeOptimizer, Rule
@@ -28,6 +29,8 @@ from trino_tpu.sql.validate import (
     PlanValidationError,
     check_plan_determinism,
     check_sql_stability,
+    collect_subplan_violations,
+    collect_violations,
     shape_census,
     validate_logical,
     validate_subplan,
@@ -171,13 +174,38 @@ def test_tpch_sql_formatting_is_stable():
         check_sql_stability(sql, what=f"tpch q{qid}")
 
 
-def test_tpch_q3_validates_in_rules_mode(tpch_runner):
-    tpch_runner.session.plan_validation = "rules"
+@pytest.mark.parametrize("corpus", ["tpch", "tpcds"])
+def test_corpus_validates_in_rules_mode(corpus, tpch_runner):
+    """Every TPC-H query and every TPC-DS template under test plans
+    under `plan_validation = rules` (a check after every rule, and the
+    plan made twice), fragments, and leaves no checker a finding on the
+    logical plan or on the fragments. Planned, not executed."""
+    if corpus == "tpch":
+        runner, queries = tpch_runner, QUERIES
+    else:
+        from tests.test_tpcds import QUERIES as queries
+        from trino_tpu.connectors.tpcds import create_tpcds_connector
+
+        runner = LocalQueryRunner(Session(catalog="tpcds", schema="tiny"))
+        runner.register_catalog("tpcds", create_tpcds_connector())
+    runner.session.plan_validation = "rules"
     try:
-        stmt = parse(QUERIES[3])
-        tpch_runner._analyze(stmt.query if hasattr(stmt, "query") else stmt)
+        for qid, sql in sorted(queries.items(), key=lambda kv: str(kv[0])):
+            check_sql_stability(sql, what=f"{corpus} {qid}")
+            stmt = parse(sql)
+            output = runner._analyze(
+                stmt.query if hasattr(stmt, "query") else stmt
+            )
+            subplan = plan_distributed(
+                output, runner.catalogs, target_splits=2, validation="off"
+            )
+            found = list(collect_violations(output))
+            found += list(collect_subplan_violations(subplan))
+            assert not found, (corpus, qid, [
+                (v.checker, v.node_path, v.message) for v in found
+            ])
     finally:
-        tpch_runner.session.plan_validation = "passes"
+        runner.session.plan_validation = "passes"
 
 
 # -- rules mode catches a mutated optimizer rule ------------------------------

@@ -1,6 +1,7 @@
 """Client protocol, CLI, session properties, config, resource groups
 (SURVEY.md §2.11, §5.6, §2.3)."""
 
+import dataclasses
 import threading
 import time
 
@@ -8,7 +9,11 @@ import pytest
 
 from trino_tpu.client import Client, QueryError
 from trino_tpu.cli import format_table
-from trino_tpu.config import SYSTEM_PROPERTIES, load_properties_file
+from trino_tpu.config import (
+    SYSTEM_PROPERTIES,
+    bind_session,
+    load_properties_file,
+)
 from trino_tpu.connectors.tpch import create_tpch_connector
 from trino_tpu.engine import LocalQueryRunner, Session
 from trino_tpu.runtime.resource_groups import (
@@ -81,6 +86,31 @@ def test_property_registry_validation():
     assert SYSTEM_PROPERTIES.validate("enable_dynamic_filtering", "false") is False
     with pytest.raises(ValueError):
         SYSTEM_PROPERTIES.validate("retry_policy", 7)
+
+
+def test_removed_mesh_scheduler_property_is_unknown():
+    # the MeshScheduler seat is the only guard of a mesh; the switch
+    # that selected a bare lock instead went with the lock
+    with pytest.raises(ValueError, match="unknown session property"):
+        SYSTEM_PROPERTIES.validate("mesh_scheduler", False)
+    lq = LocalQueryRunner(Session(catalog="tpch", schema="tiny"))
+    with pytest.raises(Exception, match="unknown session property"):
+        lq.execute("SET SESSION mesh_scheduler = false")
+    assert not hasattr(lq.session, "mesh_scheduler")
+
+
+def test_registry_and_session_declare_the_same_properties():
+    # every property is declared twice (a registry row and a Session
+    # field); until one declaration derives the other this holds them
+    # to the same names and defaults
+    fields = {f.name for f in dataclasses.fields(Session)}
+    props = SYSTEM_PROPERTIES.all()
+    assert fields - {m.name for m in props} == {
+        "catalog", "schema", "user", "timezone"
+    }
+    bound = Session()
+    bind_session(bound, {m.name: m.default for m in props})
+    assert bound == Session()
 
 
 def test_load_properties_file(tmp_path):

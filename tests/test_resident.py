@@ -230,6 +230,35 @@ class TestFastLane:
         ).rows
         assert RESIDENT.stats()["pins"] == pins0  # no rebuild
 
+    def test_warm_probes_lower_nothing(self, kv_runner):
+        """Once the table is pinned and one probe has run, probes of
+        other keys mint no XLA lowering and no pin, before and after a
+        background compaction of the delta."""
+        from trino_tpu.resident.fastlane import drain_compactions
+        from trino_tpu.runtime.metrics import METRICS
+
+        r = kv_runner
+        assert _fast(r, 7)  # cold build
+        assert _fast(r, 8)  # one warm probe
+        pins0, hits0 = RESIDENT.stats()["pins"], RESIDENT.stats()["hits"]
+        compiles0 = METRICS.counter("xla_compiles")
+        for k in range(20, 40):
+            assert _fast(r, k) is not None, k
+        assert METRICS.counter("xla_compiles") == compiles0
+        assert RESIDENT.stats()["pins"] == pins0
+        assert RESIDENT.stats()["hits"] == hits0 + 20
+        # delta_max_rows=32: compaction from half of it
+        for i in range(600, 620):
+            r.execute(f"insert into kv values ({i}, {i})")
+        drain_compactions()
+        assert RESIDENT.stats()["compactions"] >= 1
+        assert _fast(r, 619) == [[619]]  # first probe after compaction
+        compiles0 = METRICS.counter("xla_compiles")
+        for k in range(40, 60):
+            assert _fast(r, k) is not None, k
+        assert METRICS.counter("xla_compiles") == compiles0
+        assert RESIDENT.stats()["pins"] == pins0
+
     def test_unconfigured_table_declines(self, kv_runner):
         r = kv_runner
         r.session.resident_tables = "s.other"

@@ -335,6 +335,24 @@ def test_park_budget_refusal_degrades_to_run_to_completion():
     assert CHECKPOINTS.parked_count() == 0
 
 
+def test_batched_lookup_text_takes_the_fast_lane():
+    """The micro-batcher sends ONE combined IN-list statement for a
+    group of point lookups (serving/batcher.py `_run_group`); on the
+    mesh that statement is a fast-lane submission and an analytic is
+    not, so coalesced lookups preempt like single ones."""
+    r = mk_runner()
+    r.execute(ANALYTIC)
+    sched = r._mesh_scheduler
+    assert (sched.submitted, sched.fast_submitted) == (1, 0)
+    rows = r.execute(
+        "SELECT o_orderkey, o_custkey FROM orders "
+        "WHERE o_orderkey IN (1, 2, 3, 3)"
+    ).rows
+    assert r._last_data_plane == "mesh", r.last_mesh_fallback
+    assert sorted(row[0] for row in rows) == [1, 2, 3]
+    assert (sched.submitted, sched.fast_submitted) == (2, 1)
+
+
 # -- drain-failover work stealing ---------------------------------------
 
 

@@ -329,16 +329,3 @@ def test_on_batch_enforces_local_deadline():
     t3._on_batch("scan", True)
     assert t3.state != "failed"
 
-
-# -- harness plumbing -------------------------------------------------------
-
-
-def test_exact_percentile():
-    from trino_tpu.serving.harness import exact_percentile
-
-    assert exact_percentile([], 0.5) == 0.0
-    assert exact_percentile([3.0], 0.99) == 3.0
-    xs = [float(i) for i in range(1, 101)]
-    assert exact_percentile(xs, 0.0) == 1.0
-    assert exact_percentile(xs, 0.5) == 51.0
-    assert exact_percentile(xs, 1.0) == 100.0

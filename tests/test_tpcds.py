@@ -256,7 +256,7 @@ def test_tpcds_query(name, runner, oracle):
         # cache and was the single largest tier-1 wall-clock item (the
         # full suite overran its budget even before PR 5); it keeps
         # single-node oracle coverage above and distributed coverage in
-        # the slow tier + bench.py
+        # the slow tier
         pytest.param("q72", marks=pytest.mark.slow),
     ],
 )

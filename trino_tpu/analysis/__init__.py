@@ -14,9 +14,9 @@ thread spawn sites:
   half: named-lock order witness (on under pytest) and the thread
   registry the leak fixture drains.
 
-``bench.py --analyze`` runs the static passes as a CI gate;
-:func:`analyze_package` is its engine and is also what the tier-1
-clean-tree test asserts on.
+:func:`analyze_package` runs the static passes over the package; the
+tier-1 clean-tree test (``tests/test_concurrency_analysis.py``) is the
+gate that asserts on it.
 """
 
 from __future__ import annotations

@@ -99,7 +99,7 @@ def witness_enabled() -> bool:
 
 
 def enable_witness(on: bool = True) -> None:
-    """Flip the witness at runtime (used by bench --chaos-smoke)."""
+    """Flip the witness at runtime."""
     global _ENABLED
     _ENABLED = bool(on)
 

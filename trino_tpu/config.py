@@ -275,11 +275,6 @@ for _name, _type, _default, _desc, _allowed in [
      "seconds an open replica breaker sits out before a half-open "
      "placement probe may try the replica again", None),
     # -- preemptive multi-tenancy (runtime/scheduler.py) --
-    ("mesh_scheduler", bool, True,
-     "run mesh queries through the chunk-granular weighted-fair "
-     "scheduler (per-mesh run queue with fast-lane point lookups and "
-     "virtual-time accounting per resource group) instead of a bare "
-     "exec lock; False restores PR 17 serialization", None),
     ("preemption_enabled", bool, True,
      "allow a fast-lane arrival to park the running analytic at the "
      "next chunk boundary (device carries snapshot to the host "

@@ -188,7 +188,6 @@ class Session:
     # snapshot to the host checkpoint store within park_max_bytes,
     # resume from chunk k warm); drain failover may split the
     # unstarted chunk range across siblings (work stealing)
-    mesh_scheduler: bool = True
     preemption_enabled: bool = True
     park_max_bytes: int = 256 << 20
     mesh_scheduler_weights: str = ""

@@ -22,12 +22,16 @@ Fault classes map onto distinct recovery paths:
                     classed: the partition memory estimator doubles
                     before re-placement)
 
-Lifecycle scenarios (LIFECYCLE_CLASSES) exercise the cluster-lifecycle
-layer end to end: drain_mid_query / drain_all_but_one gracefully drain
-workers while a query is in flight (oracle-equal result, zero accepted
-launches on the drained node after the drain, drain completes), and
-straggler_speculation demands a recorded speculative WIN, not just a
-launched duplicate.
+Beside the injector schedules stand whole-cluster maneuvers, each a
+method or a function here and each called by a tier-1 test: graceful
+drains racing a live query and a straggler that speculation must beat
+(run_drain_case, run_speculation_case: tests/test_chaos.py), a hung
+operator and a vanished client (TIMEBOUND_CLASSES:
+tests/test_deadlines.py), and the seeded faults inside the mesh chunk
+loop (PREEMPT_CLASSES, FABRIC_CLASSES: tests/test_chaos.py). The three
+population cases (run_loaded_cluster_case and the two built on it)
+sleep and race a drain against live client threads, so no test calls
+them (ROADMAP.md, D9).
 """
 
 from __future__ import annotations
@@ -46,15 +50,6 @@ FAULT_CLASSES = (
     "oom",
 )
 
-# cluster-lifecycle scenarios (PR 3): not injector schedules but whole-
-# cluster maneuvers — graceful drains racing a live query, and a
-# straggler that speculation must beat. Run via run_lifecycle_case.
-LIFECYCLE_CLASSES = (
-    "drain_mid_query",
-    "drain_all_but_one",
-    "straggler_speculation",
-)
-
 # time-bounding scenarios (PR 4): a hung operator the worker watchdog
 # must interrupt (and FTE must retry elsewhere — query still correct),
 # and a client that vanishes mid-query (reaper must cancel the query,
@@ -63,45 +58,6 @@ LIFECYCLE_CLASSES = (
 TIMEBOUND_CLASSES = (
     "hung_operator",
     "abandoned_client",
-)
-
-# serving scenarios (PR 8): faults injected while a POPULATION of
-# concurrent HTTP clients is mid-traffic, not around one query in
-# isolation — recovery must stay correct when retries contend with
-# live load for workers, memory, and admission slots. Every query must
-# end oracle-equal, shed (429), or as a TYPED failure, and every client
-# thread must come back (no hangs). Run via run_loaded_cluster_case.
-SERVING_CLASSES = (
-    "loaded_cluster",
-)
-
-# adaptive scenarios (PR 13): the loaded-cluster fault burst + the
-# mid-traffic drain, on a population whose session runs ADAPTIVELY — a
-# query mix seeded with a misestimated join so the coordinator is
-# re-planning mid-query while workers crash and drain out from under
-# it. Re-planned queries must stay oracle-equal and the run must record
-# at least one re-plan (otherwise the scenario proved nothing). Run via
-# run_adaptive_drain_case.
-ADAPTIVE_CLASSES = (
-    "adaptive_loaded_drain",
-)
-
-# recovery scenarios (PR 14): chunk-granular checkpoint/resume for the
-# mesh plane (trino_tpu/recovery/). The injector schedules above land
-# on the page/FTE planes; these land INSIDE the mesh chunk loop via
-# parallel.mesh_chunk.MESH_FAULT_HOOK — a seeded chunk boundary raises
-# MeshStuck (the watchdog classification) or MeshDeviceLost (device
-# loss), and the run must RESUME from its last checkpoint: oracle-equal
-# rows AND strictly fewer re-executed chunks than restarting from chunk
-# 0. Run via run_mesh_recovery_case.
-RECOVERY_CLASSES = (
-    "mesh_fault_mid_chunk",
-    "device_lost_resume",
-)
-
-REPLICA_CLASSES = (
-    "replica_down_mid_serve",
-    "replica_drain_under_load",
 )
 
 # preemptive multi-tenancy scenarios (PR 18): the chunk-granular mesh
@@ -189,73 +145,6 @@ def schedule_max_failures(rules: List[dict]) -> int:
     """Upper bound on injected failures a schedule can cause — the
     bounded-attempt assertion compares observed retries against this."""
     return sum(r.get("max_hits", 0) for r in rules if r.get("stall_s", 0) == 0)
-
-
-def run_mesh_recovery_case(
-    sql: str, fault_class: str, seed: int,
-    checkpoint_interval: int = 1, mesh_chunk_rows: int = 256,
-) -> Tuple[List[list], dict]:
-    """One seeded mesh fault mid-chunk against an in-process (mesh-
-    colocated) runner with chunk checkpointing on. The fault chunk is
-    drawn deterministically from the seed once the chunk count is known
-    (same seed -> same boundary), fires exactly once, and the run must
-    resume from its last checkpoint rather than restart: the report's
-    executed_chunk_steps counts every chunk step across attempts, so
-    `executed_chunk_steps - chunks` is the number of RE-executed chunks
-    (a restart-from-zero re-executes all `fault_chunk` completed ones)."""
-    from trino_tpu.connectors.tpch import create_tpch_connector
-    from trino_tpu.engine import Session
-    from trino_tpu.parallel import mesh_chunk
-    from trino_tpu.runtime.coordinator import DistributedQueryRunner
-
-    if fault_class not in RECOVERY_CLASSES:
-        raise ValueError(f"unknown recovery fault class: {fault_class}")
-    exc = (
-        mesh_chunk.MeshStuck
-        if fault_class == "mesh_fault_mid_chunk"
-        else mesh_chunk.MeshDeviceLost
-    )
-    runner = DistributedQueryRunner(
-        Session(
-            catalog="tpch", schema="tiny",
-            mesh_chunk_rows=mesh_chunk_rows,
-            mesh_checkpoint_interval_chunks=checkpoint_interval,
-        ),
-        n_workers=2, hash_partitions=2,
-    )
-    runner.register_catalog("tpch", create_tpch_connector())
-    expected = runner.execute(sql).rows  # warm run doubles as oracle
-    mesh_clean = runner._last_data_plane == "mesh"
-    rng = random.Random(seed)
-    state = {"target": None, "fired": 0}
-
-    def hook(k: int, K: int) -> None:
-        if state["target"] is None:
-            # any boundary but 0: chunk 0 never has a checkpoint below
-            # it (tests/test_recovery.py covers the k=0 degenerate)
-            state["target"] = 1 + rng.randrange(max(K - 1, 1))
-        if k == state["target"] and not state["fired"]:
-            state["fired"] = 1
-            raise exc(f"chaos[{fault_class}]: injected at chunk {k}/{K}")
-
-    mesh_chunk.MESH_FAULT_HOOK = hook
-    try:
-        rows = runner.execute(sql).rows
-    finally:
-        mesh_chunk.MESH_FAULT_HOOK = None
-    info = mesh_chunk.last_run_info()
-    report = {
-        "mesh_clean_plane": mesh_clean,
-        "mesh_fault_plane": runner._last_data_plane,
-        "fault_chunk": state["target"],
-        "fired": state["fired"],
-        "chunks": info.get("chunks"),
-        "executed_chunk_steps": info.get("executed_chunk_steps"),
-        "resumes": info.get("resumes"),
-        "resumed_from_chunk": info.get("resumed_from_chunk"),
-        "expected": expected,
-    }
-    return rows, report
 
 
 def run_preempt_park_resume_case(
@@ -986,19 +875,6 @@ class ChaosHarness:
 
     # -- cluster-lifecycle scenarios (graceful drain + speculation) --
 
-    def run_lifecycle_case(
-        self, sql: str, scenario: str, seed: int = 0,
-    ) -> Tuple[List[list], dict]:
-        """Drains are one-way (a drained node never rejoins), so run
-        each lifecycle case on a FRESH harness."""
-        if scenario == "drain_mid_query":
-            return self.run_drain_case(sql, seed)
-        if scenario == "drain_all_but_one":
-            return self.run_drain_case(sql, seed, drain_all_but_one=True)
-        if scenario == "straggler_speculation":
-            return self.run_speculation_case(sql, seed)
-        raise ValueError(f"unknown lifecycle scenario: {scenario}")
-
     def run_drain_case(
         self, sql: str, seed: int = 0, drain_all_but_one: bool = False,
         stall_s: float = 0.8, drain_timeout_s: float = 60.0,
@@ -1405,923 +1281,3 @@ class ChaosHarness:
         report["mesh_faults_fired"] = state["fired"]
         report["checkpoint_resumes"] = CHECKPOINTS.resumed - resumed0
         return None, report
-
-    def run_replica_down_case(
-        self, queries: Dict[str, str], seed: int = 0, **kw,
-    ) -> Tuple[None, dict]:
-        """PR 17: hard-kill one replica's sub-mesh mid-chunk under live
-        serving load. Construct the harness with in_process=True and a
-        session with mesh_replicas >= 2 + chunking + checkpointing. A
-        PERSISTENT fault hook kills every chunk loop that reaches a
-        mid-run boundary on replica 0 — the coordinator must fail each
-        one over to the sibling sub-mesh (resuming from the last
-        host-portable checkpoint), replica 0's breaker trips after the
-        configured consecutive failures, and placement routes the tail
-        of the population around the dead sub-mesh. Zero queries may be
-        lost: the delegated loaded-cluster case oracle-checks every
-        completion. The hook ignores the case thread so the oracle
-        pre-pass runs clean; only server-side executions fault."""
-        from trino_tpu.parallel import mesh_chunk
-        from trino_tpu.recovery import CHECKPOINTS
-        from trino_tpu.runtime.metrics import METRICS
-
-        if not self.in_process:
-            raise ValueError(
-                "run_replica_down_case needs in_process=True (the mesh "
-                "plane only engages on colocated workers)"
-            )
-        lock = threading.Lock()
-        state = {"fired": 0}
-        case_thread = threading.current_thread()
-
-        def hook(k: int, K: int) -> None:
-            if threading.current_thread() is case_thread:
-                return  # oracle pre-pass: the clean runs stay clean
-            if mesh_chunk.active_replica() == 0 and K >= 2 \
-                    and k >= max(1, K // 2):
-                with lock:
-                    state["fired"] += 1
-                raise mesh_chunk.MeshDeviceLost(
-                    f"chaos[replica_down]: replica 0 sub-mesh "
-                    f"hard-killed at chunk {k}/{K}"
-                )
-
-        before = METRICS.snapshot()
-        resumed0 = CHECKPOINTS.resumed
-        mesh_chunk.MESH_FAULT_HOOK = hook
-        try:
-            _, report = self.run_loaded_cluster_case(queries, seed, **kw)
-        finally:
-            mesh_chunk.MESH_FAULT_HOOK = None
-        after = METRICS.snapshot()
-        report["mesh_faults_fired"] = state["fired"]
-        report["checkpoint_resumes"] = CHECKPOINTS.resumed - resumed0
-        for name in ("replica.failovers", "replica.breaker_opens"):
-            report[name] = int(after.get(name, 0) - before.get(name, 0))
-        return None, report
-
-    def run_replica_drain_case(
-        self, queries: Dict[str, str], seed: int = 0, **kw,
-    ) -> Tuple[None, dict]:
-        """PR 17: gracefully drain one replica with a chunked query in
-        flight on it, under live serving load. The fault hook does not
-        raise — the FIRST server-side chunk loop to reach a mid-run
-        boundary on replica 0 triggers request_drain(0) synchronously,
-        so that same run's next boundary hits the drain check, raises
-        MeshReplicaDraining, and fails over to the sibling with a query
-        deterministically in flight (no timer races). The drained
-        replica takes no further placements; after the population
-        finishes, drain() must confirm it quiesced to zero inflight."""
-        from trino_tpu.parallel import mesh_chunk
-        from trino_tpu.recovery import CHECKPOINTS
-        from trino_tpu.runtime.metrics import METRICS
-
-        if not self.in_process:
-            raise ValueError(
-                "run_replica_drain_case needs in_process=True (the mesh "
-                "plane only engages on colocated workers)"
-            )
-        lock = threading.Lock()
-        state = {"drain_requested": 0}
-        case_thread = threading.current_thread()
-
-        def hook(k: int, K: int) -> None:
-            if threading.current_thread() is case_thread:
-                return  # oracle pre-pass: don't drain before load starts
-            if mesh_chunk.active_replica() == 0 and K >= 2 \
-                    and k >= max(1, K // 2):
-                rm = getattr(self.runner, "_replicas", None)
-                if rm is None:
-                    return
-                with lock:
-                    if state["drain_requested"]:
-                        return
-                    state["drain_requested"] = 1
-                rm.request_drain(0)
-
-        before = METRICS.snapshot()
-        resumed0 = CHECKPOINTS.resumed
-        mesh_chunk.MESH_FAULT_HOOK = hook
-        try:
-            _, report = self.run_loaded_cluster_case(queries, seed, **kw)
-        finally:
-            mesh_chunk.MESH_FAULT_HOOK = None
-        rm = getattr(self.runner, "_replicas", None)
-        report["drain_requested"] = bool(state["drain_requested"])
-        report["replica_drained"] = bool(
-            rm is not None and state["drain_requested"]
-            and rm.drain(0, timeout_s=30.0)
-        )
-        after = METRICS.snapshot()
-        report["checkpoint_resumes"] = CHECKPOINTS.resumed - resumed0
-        for name in ("replica.failovers", "replica.drains"):
-            report[name] = int(after.get(name, 0) - before.get(name, 0))
-        return None, report
-
-
-def chaos_smoke(
-    seed: int,
-    queries: Dict[str, str],
-    fault_classes=FAULT_CLASSES,
-    verbose: bool = True,
-) -> List[str]:
-    """bench.py --chaos-smoke entry: every (query, fault class) pair
-    must be oracle-equal to the clean run and stay within its injected
-    failure bound. Returns the list of violation descriptions (empty =
-    pass)."""
-    from trino_tpu.connectors.tpch import create_tpch_connector
-
-    harness = ChaosHarness(n_workers=2)
-    harness.register_catalog("tpch", create_tpch_connector())
-    failures: List[str] = []
-    for name, sql in queries.items():
-        expected = harness.run_clean(sql)
-        ordered = "order by" in sql.lower()
-        for fc in fault_classes:
-            try:
-                rows, stats = harness.run_case(sql, fc, seed)
-            except Exception as e:
-                failures.append(f"{name}/{fc}: raised {type(e).__name__}: {e}")
-                continue
-            if not rows_equal(rows, expected, ordered=ordered):
-                failures.append(
-                    f"{name}/{fc}: rows diverged from clean run "
-                    f"({len(rows)} vs {len(expected)})"
-                )
-            bound = stats.get("max_injected_failures", 0)
-            if stats.get("retries", 0) > bound:
-                failures.append(
-                    f"{name}/{fc}: {stats['retries']} retries exceeds "
-                    f"injected-failure bound {bound}"
-                )
-            if verbose:
-                app = stats.get("attempts_per_partition") or {}
-                print(
-                    f"  chaos {name}/{fc}: ok rows={len(rows)} "
-                    f"retries={stats.get('retries')} "
-                    f"spec={stats.get('speculative_hits')} "
-                    f"wins={stats.get('speculation_wins')} "
-                    f"losses={stats.get('speculation_losses')} "
-                    f"max_attempts={max(app.values(), default=0)}"
-                )
-    # lifecycle scenarios: drains are one-way, so each runs on a fresh
-    # 3-worker harness (one spare survives drain_all_but_one)
-    lifecycle_sql = next(iter(queries.values()))
-    for scenario in LIFECYCLE_CLASSES:
-        h = ChaosHarness(n_workers=3)
-        h.register_catalog("tpch", create_tpch_connector())
-        expected = h.run_clean(lifecycle_sql)
-        try:
-            rows, report = h.run_lifecycle_case(
-                lifecycle_sql, scenario, seed
-            )
-        except Exception as e:
-            failures.append(
-                f"lifecycle/{scenario}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        ordered = "order by" in lifecycle_sql.lower()
-        if not rows_equal(rows, expected, ordered=ordered):
-            failures.append(
-                f"lifecycle/{scenario}: rows diverged from clean run "
-                f"({len(rows)} vs {len(expected)})"
-            )
-        if scenario.startswith("drain"):
-            if not all(report["drained"].values()):
-                failures.append(
-                    f"lifecycle/{scenario}: drain timed out "
-                    f"({report['drained']})"
-                )
-            if report["launches_at_end"] != report["launches_at_drain"]:
-                failures.append(
-                    f"lifecycle/{scenario}: drained worker accepted "
-                    f"post-drain launches "
-                    f"({report['launches_at_drain']} -> "
-                    f"{report['launches_at_end']})"
-                )
-        if (
-            scenario == "straggler_speculation"
-            and not report.get("speculation_wins")
-        ):
-            failures.append(
-                f"lifecycle/{scenario}: no speculative win recorded "
-                f"({report})"
-            )
-        if verbose:
-            app = report.get("attempts_per_partition") or {}
-            print(
-                f"  chaos lifecycle/{scenario}: ok rows={len(rows)} "
-                f"retries={report.get('retries')} "
-                f"wins={report.get('speculation_wins')} "
-                f"losses={report.get('speculation_losses')} "
-                f"max_attempts={max(app.values(), default=0)}"
-            )
-    # time-bounding scenarios (PR 4): watchdog + abandonment reaper;
-    # fresh harnesses again (the abandoned case leaves a dead query in
-    # its server, the hung case arms a watchdog). The agg shape is the
-    # right query here: its batch capacities do not depend on which
-    # attempt survives, so one warm run covers every jit shape a retry
-    # can touch. The join's dynamic-filter pruning makes retry batch
-    # capacities attempt-dependent — each retry hits a FRESH >1s XLA
-    # lowering inside one batch, indistinguishable from a hang at any
-    # test-speed threshold
-    timebound_sql = lifecycle_sql
-    for scenario in TIMEBOUND_CLASSES:
-        h = ChaosHarness(
-            n_workers=3,
-            stuck_task_interrupt_s=1.0,
-            memory_pool_bytes=256 << 20,
-        )
-        h.register_catalog("tpch", create_tpch_connector())
-        if scenario == "hung_operator":
-            expected = h.run_clean(timebound_sql)
-            try:
-                rows, report = h.run_hung_operator_case(
-                    timebound_sql, seed
-                )
-            except Exception as e:
-                failures.append(
-                    f"timebound/{scenario}: raised "
-                    f"{type(e).__name__}: {e}"
-                )
-                continue
-            ordered = "order by" in timebound_sql.lower()
-            if not rows_equal(rows, expected, ordered=ordered):
-                failures.append(
-                    f"timebound/{scenario}: rows diverged from clean "
-                    f"run ({len(rows)} vs {len(expected)})"
-                )
-            interrupts = report.get("watchdog_interrupts") or []
-            if not interrupts:
-                failures.append(
-                    f"timebound/{scenario}: watchdog never fired"
-                )
-            elif not any("in operator" in d for d in interrupts):
-                failures.append(
-                    f"timebound/{scenario}: diagnostic does not name "
-                    f"the stuck operator ({interrupts[0]!r})"
-                )
-            overhead = report["elapsed_s"] - report["warm_clean_s"]
-            if overhead >= report["stall_s"]:
-                failures.append(
-                    f"timebound/{scenario}: query waited out the full "
-                    f"stall (recovery overhead {overhead:.2f}s >= "
-                    f"{report['stall_s']}s) — the watchdog did not "
-                    f"unwedge it"
-                )
-            if verbose:
-                print(
-                    f"  chaos timebound/{scenario}: ok rows={len(rows)} "
-                    f"elapsed={report['elapsed_s']:.2f}s "
-                    f"(warm clean {report['warm_clean_s']:.2f}s) "
-                    f"interrupts={len(interrupts)}"
-                )
-        else:  # abandoned_client
-            try:
-                _, report = h.run_abandoned_client_case(
-                    timebound_sql, seed
-                )
-            except Exception as e:
-                failures.append(
-                    f"timebound/{scenario}: raised "
-                    f"{type(e).__name__}: {e}"
-                )
-                continue
-            if not report["reaped"]:
-                failures.append(
-                    f"timebound/{scenario}: query was not reaped "
-                    f"(error={report['error']!r})"
-                )
-            if report["rg_running"] != 0:
-                failures.append(
-                    f"timebound/{scenario}: resource-group slot leaked "
-                    f"({report['rg_running']} still running)"
-                )
-            if any(report["ledgers"].values()):
-                failures.append(
-                    f"timebound/{scenario}: memory ledger not drained "
-                    f"({report['ledgers']})"
-                )
-            if verbose:
-                print(
-                    f"  chaos timebound/{scenario}: ok "
-                    f"peak_reserved={report['peak_reserved_bytes']} "
-                    f"ledgers_drained=True rg_running=0"
-                )
-    # serving scenario (PR 8): the same fault classes, but landing on a
-    # cluster that is actively serving a concurrent client population
-    # through the HTTP path — fresh harness (faults + server leftovers)
-    for scenario in SERVING_CLASSES:
-        h = ChaosHarness(n_workers=3)
-        h.register_catalog("tpch", create_tpch_connector())
-        try:
-            _, report = h.run_loaded_cluster_case(queries, seed)
-        except Exception as e:
-            failures.append(
-                f"serving/{scenario}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        if report["completed"] == 0:
-            failures.append(
-                f"serving/{scenario}: no query completed under load"
-            )
-        if report["ok"] == 0:
-            failures.append(
-                f"serving/{scenario}: zero oracle-equal results "
-                f"({report})"
-            )
-        if report["mismatches"]:
-            failures.append(
-                f"serving/{scenario}: {report['mismatches']} results "
-                f"diverged from clean run under faults"
-            )
-        if report["untyped_error_count"]:
-            failures.append(
-                f"serving/{scenario}: {report['untyped_error_count']} "
-                f"untyped errors (first: {report['untyped_errors'][:1]})"
-            )
-        if report["hung_threads"]:
-            failures.append(
-                f"serving/{scenario}: {report['hung_threads']} client "
-                f"threads never returned"
-            )
-        if not report["drained"]:
-            failures.append(
-                f"serving/{scenario}: mid-traffic drain timed out"
-            )
-        if verbose:
-            print(
-                f"  chaos serving/{scenario}: ok "
-                f"completed={report['completed']} ok={report['ok']} "
-                f"sheds={report['sheds']} "
-                f"typed_failures={report['typed_failures']} "
-                f"drained={report['drained']} hung=0"
-            )
-    # adaptive scenario (PR 13): the same loaded-cluster burst + drain,
-    # on a session that re-plans mid-query. The query mix adds a join
-    # whose build-side filter the stats heuristics misestimate, so with
-    # the permissive threshold every execution crosses the re-plan gate
-    # — the drain and fault burst land while re-planned programs are in
-    # flight, and each completion is still checked against the clean run
-    from trino_tpu.engine import Session
-
-    adaptive_queries = dict(queries)
-    adaptive_queries["replan"] = (
-        "select count(*) from supplier s "
-        "join nation n on s_nationkey = n_nationkey "
-        "where n_nationkey % 2 = 0"
-    )
-    for scenario in ADAPTIVE_CLASSES:
-        h = ChaosHarness(
-            n_workers=3,
-            session=Session(
-                catalog="tpch", schema="tiny", retry_policy="task",
-                adaptive_execution=True,
-                shared_subtree_materialization=True,
-                adaptive_replan_threshold=1.3,
-            ),
-        )
-        h.register_catalog("tpch", create_tpch_connector())
-        try:
-            _, report = h.run_adaptive_drain_case(adaptive_queries, seed)
-        except Exception as e:
-            failures.append(
-                f"adaptive/{scenario}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        if report["ok"] == 0:
-            failures.append(
-                f"adaptive/{scenario}: zero oracle-equal results "
-                f"({report})"
-            )
-        if report["mismatches"]:
-            failures.append(
-                f"adaptive/{scenario}: {report['mismatches']} re-planned "
-                f"results diverged from clean run under faults"
-            )
-        if report["untyped_error_count"]:
-            failures.append(
-                f"adaptive/{scenario}: {report['untyped_error_count']} "
-                f"untyped errors (first: {report['untyped_errors'][:1]})"
-            )
-        if report["hung_threads"]:
-            failures.append(
-                f"adaptive/{scenario}: {report['hung_threads']} client "
-                f"threads never returned"
-            )
-        if not report["drained"]:
-            failures.append(
-                f"adaptive/{scenario}: mid-traffic drain timed out"
-            )
-        if report["adaptive.replans"] < 1:
-            failures.append(
-                f"adaptive/{scenario}: no re-plan happened during the "
-                f"run — the drain never raced a re-planning query"
-            )
-        if verbose:
-            print(
-                f"  chaos adaptive/{scenario}: ok "
-                f"completed={report['completed']} ok={report['ok']} "
-                f"replans={report['adaptive.replans']} "
-                f"spool_hits={report['adaptive.spool_hits']} "
-                f"drained={report['drained']} hung=0"
-            )
-    # recovery scenarios (PR 14): seeded faults INSIDE the mesh chunk
-    # loop must resume from the last checkpoint — oracle-equal rows and
-    # strictly fewer re-executed chunks than restarting from chunk 0
-    recovery_sql = (
-        "select o_orderpriority, count(*) c from orders join customer "
-        "on o_custkey = c_custkey group by o_orderpriority "
-        "order by o_orderpriority"
-    )
-    for fc in RECOVERY_CLASSES:
-        try:
-            rows, rep = run_mesh_recovery_case(recovery_sql, fc, seed)
-        except Exception as e:
-            failures.append(
-                f"recovery/{fc}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        if not rep["mesh_clean_plane"]:
-            failures.append(
-                f"recovery/{fc}: clean run did not take the mesh plane"
-            )
-            continue
-        K = rep["chunks"] or 0
-        steps = rep["executed_chunk_steps"] or 0
-        fault_k = rep["fault_chunk"] or 0
-        re_executed = steps - K
-        if not rows_equal(rows, rep["expected"], ordered=True):
-            failures.append(
-                f"recovery/{fc}: rows diverged from clean run "
-                f"({len(rows)} vs {len(rep['expected'])})"
-            )
-        if not rep["fired"]:
-            failures.append(f"recovery/{fc}: fault never fired ({rep})")
-        elif rep["mesh_fault_plane"] != "mesh":
-            failures.append(
-                f"recovery/{fc}: faulted run left the mesh plane "
-                f"({rep['mesh_fault_plane']})"
-            )
-        elif not rep["resumes"]:
-            failures.append(
-                f"recovery/{fc}: no checkpoint resume recorded ({rep})"
-            )
-        elif re_executed >= max(fault_k, 1) or re_executed >= K:
-            failures.append(
-                f"recovery/{fc}: re-executed {re_executed} of {K} "
-                f"chunks — a restart-from-zero re-executes {fault_k}; "
-                f"the checkpoint saved nothing"
-            )
-        if verbose:
-            print(
-                f"  chaos recovery/{fc}: ok rows={len(rows)} "
-                f"fault_chunk={fault_k}/{K} "
-                f"resumed_from={rep['resumed_from_chunk']} "
-                f"re_executed={re_executed}"
-            )
-    # carry-forward (PR 8 -> PR 14): the drain maneuvers aimed at the
-    # loaded_cluster population, with mesh checkpointing on — device
-    # losses land mid-chunk while a worker drains out from under the
-    # live traffic, and the faulted queries must resume from checkpoint
-    # on what survives
-    h = ChaosHarness(
-        n_workers=3, in_process=True,
-        session=Session(
-            catalog="tpch", schema="tiny",
-            mesh_chunk_rows=256,
-            mesh_checkpoint_interval_chunks=1,
-        ),
-    )
-    h.register_catalog("tpch", create_tpch_connector())
-    scenario = "recovery_loaded_drain"
-    try:
-        _, report = h.run_recovery_drain_case(queries, seed)
-    except Exception as e:
-        failures.append(
-            f"recovery/{scenario}: raised {type(e).__name__}: {e}"
-        )
-        report = None
-    if report is not None:
-        if report["ok"] == 0:
-            failures.append(
-                f"recovery/{scenario}: zero oracle-equal results "
-                f"({report})"
-            )
-        if report["mismatches"]:
-            failures.append(
-                f"recovery/{scenario}: {report['mismatches']} results "
-                f"diverged from clean run under mesh faults"
-            )
-        if report["untyped_error_count"]:
-            failures.append(
-                f"recovery/{scenario}: {report['untyped_error_count']} "
-                f"untyped errors (first: {report['untyped_errors'][:1]})"
-            )
-        if report["hung_threads"]:
-            failures.append(
-                f"recovery/{scenario}: {report['hung_threads']} client "
-                f"threads never returned"
-            )
-        if not report["drained"]:
-            failures.append(
-                f"recovery/{scenario}: mid-traffic drain timed out"
-            )
-        if not report["mesh_faults_fired"]:
-            failures.append(
-                f"recovery/{scenario}: no mesh fault landed — the "
-                f"drain never raced a resuming query"
-            )
-        elif not report["checkpoint_resumes"]:
-            failures.append(
-                f"recovery/{scenario}: faults fired but nothing "
-                f"resumed from checkpoint ({report})"
-            )
-        if verbose:
-            print(
-                f"  chaos recovery/{scenario}: ok "
-                f"completed={report['completed']} ok={report['ok']} "
-                f"faults={report['mesh_faults_fired']} "
-                f"resumes={report['checkpoint_resumes']} "
-                f"drained={report['drained']} hung=0"
-            )
-    # replica scenarios (PR 17): the same live population against a
-    # REPLICATED serving plane (two sub-meshes carved from the device
-    # set) — one replica hard-killed mid-chunk, then (fresh harness)
-    # gracefully drained with a query in flight. In-flight chunked
-    # queries must fail over to the sibling sub-mesh and resume from
-    # the host-portable checkpoint; zero queries lost either way.
-    import jax
-
-    if len(jax.devices()) < 2:
-        if verbose:
-            print(
-                "  chaos replica/*: skipped (needs >= 2 devices to "
-                "carve sub-meshes; run with "
-                "--xla_force_host_platform_device_count)"
-            )
-    else:
-        for scenario in REPLICA_CLASSES:
-            h = ChaosHarness(
-                n_workers=2, in_process=True,
-                session=Session(
-                    catalog="tpch", schema="tiny",
-                    mesh_replicas=2,
-                    mesh_chunk_rows=256,
-                    mesh_checkpoint_interval_chunks=1,
-                    mesh_resume_attempts=0,
-                ),
-            )
-            h.register_catalog("tpch", create_tpch_connector())
-            case = (
-                h.run_replica_down_case
-                if scenario == "replica_down_mid_serve"
-                else h.run_replica_drain_case
-            )
-            try:
-                _, report = case(queries, seed)
-            except Exception as e:
-                failures.append(
-                    f"replica/{scenario}: raised {type(e).__name__}: {e}"
-                )
-                continue
-            if report["ok"] == 0:
-                failures.append(
-                    f"replica/{scenario}: zero oracle-equal results "
-                    f"({report})"
-                )
-            if report["mismatches"]:
-                failures.append(
-                    f"replica/{scenario}: {report['mismatches']} results "
-                    f"diverged from clean run with a replica down"
-                )
-            if report["untyped_error_count"]:
-                failures.append(
-                    f"replica/{scenario}: {report['untyped_error_count']} "
-                    f"untyped errors (first: {report['untyped_errors'][:1]})"
-                )
-            if report["hung_threads"]:
-                failures.append(
-                    f"replica/{scenario}: {report['hung_threads']} client "
-                    f"threads never returned — a query was lost"
-                )
-            if scenario == "replica_down_mid_serve":
-                if not report["mesh_faults_fired"]:
-                    failures.append(
-                        f"replica/{scenario}: the kill never landed on a "
-                        f"mid-chunk boundary ({report})"
-                    )
-                elif not report["replica.failovers"]:
-                    failures.append(
-                        f"replica/{scenario}: replica 0 died but nothing "
-                        f"failed over to the sibling ({report})"
-                    )
-            else:
-                if not report["drain_requested"]:
-                    failures.append(
-                        f"replica/{scenario}: the drain never raced an "
-                        f"in-flight chunked query ({report})"
-                    )
-                elif not report["replica_drained"]:
-                    failures.append(
-                        f"replica/{scenario}: replica 0 never quiesced "
-                        f"to zero inflight ({report})"
-                    )
-                elif not report["replica.failovers"]:
-                    failures.append(
-                        f"replica/{scenario}: drained with a query in "
-                        f"flight but nothing failed over ({report})"
-                    )
-            if verbose:
-                print(
-                    f"  chaos replica/{scenario}: ok "
-                    f"completed={report['completed']} ok={report['ok']} "
-                    f"failovers={report['replica.failovers']} "
-                    f"resumes={report['checkpoint_resumes']} hung=0"
-                )
-    # preemptive multi-tenancy scenarios (PR 18): the chunk-granular
-    # mesh scheduler's park/resume composed with checkpoint recovery
-    # (device loss after a park) and with the replica drain lifecycle
-    # (drain surfacing while parked -> sibling resumes the parked
-    # snapshot). Same device gate as the replica scenarios.
-    if len(jax.devices()) < 2:
-        if verbose:
-            print(
-                "  chaos preempt/*: skipped (needs >= 2 devices; run "
-                "with --xla_force_host_platform_device_count)"
-            )
-        return failures
-    preempt_sql = recovery_sql
-    for scenario in PREEMPT_CLASSES:
-        case = (
-            run_preempt_park_resume_case
-            if scenario == "preempt_park_resume"
-            else run_preempt_under_drain_case
-        )
-        # park_resume doubles as the lock-witness gate: the scheduler's
-        # condition wait, the checkpoint store, and the fast-lane seat
-        # all interleave here, so run it with order checking live and
-        # require zero recorded violations.
-        witness_case = scenario == "preempt_park_resume"
-        if witness_case:
-            from trino_tpu.analysis.witness import (
-                enable_witness,
-                violation_count,
-                witness_enabled,
-            )
-
-            was_enabled = witness_enabled()
-            violations_before = violation_count()
-            enable_witness(True)
-        try:
-            rows, rep = case(preempt_sql, seed)
-        except Exception as e:
-            failures.append(
-                f"preempt/{scenario}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        finally:
-            if witness_case:
-                enable_witness(was_enabled)
-        if witness_case and violation_count() != violations_before:
-            failures.append(
-                f"preempt/{scenario}: "
-                f"{violation_count() - violations_before} lock-witness "
-                f"violation(s) recorded during the park/resume run"
-            )
-        if not rep["mesh_clean_plane"]:
-            failures.append(
-                f"preempt/{scenario}: clean run did not take the mesh "
-                f"plane"
-            )
-            continue
-        if not rows_equal(rows, rep["expected"], ordered=True):
-            failures.append(
-                f"preempt/{scenario}: rows diverged from clean run "
-                f"({len(rows)} vs {len(rep['expected'])})"
-            )
-        if not rep["parked"]:
-            failures.append(
-                f"preempt/{scenario}: the fast-lane seat never parked "
-                f"the analytic ({rep})"
-            )
-        if scenario == "preempt_park_resume":
-            if not rep["faulted"]:
-                failures.append(
-                    f"preempt/{scenario}: the post-resume device loss "
-                    f"never fired ({rep})"
-                )
-            elif rep["mesh_fault_plane"] != "mesh":
-                failures.append(
-                    f"preempt/{scenario}: faulted run left the mesh "
-                    f"plane ({rep['mesh_fault_plane']})"
-                )
-            elif rep["parks"] != 1 or rep["unparks"] != 1:
-                failures.append(
-                    f"preempt/{scenario}: expected exactly one "
-                    f"park/unpark cycle ({rep})"
-                )
-            elif not rep["resumes"]:
-                failures.append(
-                    f"preempt/{scenario}: no in-run checkpoint resume "
-                    f"after the device loss ({rep})"
-                )
-            elif rep["executed_chunk_steps"] != rep["chunks"]:
-                failures.append(
-                    f"preempt/{scenario}: park+fault re-executed "
-                    f"{rep['executed_chunk_steps'] - rep['chunks']} of "
-                    f"{rep['chunks']} chunks"
-                )
-            if not rep["point_ok"]:
-                failures.append(
-                    f"preempt/{scenario}: the preempting point lookup "
-                    f"answered wrong ({rep})"
-                )
-            if verbose and not any(
-                f.startswith(f"preempt/{scenario}") for f in failures
-            ):
-                print(
-                    f"  chaos preempt/{scenario}: ok rows={len(rows)} "
-                    f"park_chunk={rep['park_chunk']} "
-                    f"fault_chunk={rep['fault_chunk']}/{rep['chunks']} "
-                    f"resumes={rep['resumes']} re_executed=0"
-                )
-        else:  # preempt_under_drain
-            if not rep["drain_requested"]:
-                failures.append(
-                    f"preempt/{scenario}: the drain never landed while "
-                    f"the query sat parked ({rep})"
-                )
-            elif not rep["failovers"]:
-                failures.append(
-                    f"preempt/{scenario}: drained while parked but "
-                    f"nothing failed over to the sibling ({rep})"
-                )
-            elif not rep["checkpoint_resumes"]:
-                failures.append(
-                    f"preempt/{scenario}: sibling did not resume from "
-                    f"the parked snapshot ({rep})"
-                )
-            elif rep["resumed_from_chunk"] != rep["park_chunk"]:
-                failures.append(
-                    f"preempt/{scenario}: sibling resumed from chunk "
-                    f"{rep['resumed_from_chunk']}, expected the park "
-                    f"boundary {rep['park_chunk']}"
-                )
-            elif rep["chunk_steps"] != rep["chunks"]:
-                failures.append(
-                    f"preempt/{scenario}: drain-while-parked "
-                    f"re-executed "
-                    f"{rep['chunk_steps'] - rep['chunks']} of "
-                    f"{rep['chunks']} chunks"
-                )
-            if not rep["replica_drained"]:
-                failures.append(
-                    f"preempt/{scenario}: the victim replica never "
-                    f"quiesced to zero inflight ({rep})"
-                )
-            if verbose and not any(
-                f.startswith(f"preempt/{scenario}") for f in failures
-            ):
-                print(
-                    f"  chaos preempt/{scenario}: ok rows={len(rows)} "
-                    f"park_chunk={rep['park_chunk']}/{rep['chunks']} "
-                    f"failovers={rep['failovers']} "
-                    f"resumes={rep['checkpoint_resumes']} re_executed=0"
-                )
-    # multi-host fabric scenarios (PR 19): checkpoint transport +
-    # membership under adversity. Same >= 2 device gate (replicated
-    # sub-meshes) as above — reached only past the earlier early-return.
-    fabric_sql = recovery_sql
-    for scenario in FABRIC_CLASSES:
-        case = {
-            "host_lost_mid_chunk": run_host_lost_case,
-            "membership_flap": run_membership_flap_case,
-            "transport_corruption": run_transport_corruption_case,
-        }[scenario]
-        try:
-            rows, rep = case(fabric_sql, seed)
-        except Exception as e:
-            failures.append(
-                f"fabric/{scenario}: raised {type(e).__name__}: {e}"
-            )
-            continue
-        if not rep["mesh_clean_plane"]:
-            failures.append(
-                f"fabric/{scenario}: clean run did not take the mesh plane"
-            )
-            continue
-        if not rows_equal(rows, rep["expected"], ordered=True):
-            failures.append(
-                f"fabric/{scenario}: rows diverged from clean run "
-                f"({len(rows)} vs {len(rep['expected'])})"
-            )
-        if not rep["fired"]:
-            failures.append(
-                f"fabric/{scenario}: fault never fired ({rep})"
-            )
-            continue
-        K = rep["chunks"] or 0
-        steps = rep["executed_chunk_steps"] or 0
-        if scenario == "host_lost_mid_chunk":
-            if not rep["pushes"]:
-                failures.append(
-                    f"fabric/{scenario}: nothing was ever pushed to the "
-                    f"peer ({rep})"
-                )
-            elif not rep["pulls"]:
-                failures.append(
-                    f"fabric/{scenario}: local store wiped but failover "
-                    f"never pulled from the peer ({rep})"
-                )
-            elif not rep["resumes"]:
-                failures.append(
-                    f"fabric/{scenario}: pulled a checkpoint but never "
-                    f"resumed from it ({rep})"
-                )
-            elif steps != K - (rep["fault_chunk"] or 0):
-                # the failover re-place runs a fresh attempt whose step
-                # counter starts at the resume point: exactly the
-                # not-yet-executed chunks remain
-                failures.append(
-                    f"fabric/{scenario}: re-executed "
-                    f"{steps - (K - (rep['fault_chunk'] or 0))} chunk-steps "
-                    f"after the fabric pull ({steps} steps for "
-                    f"{K - (rep['fault_chunk'] or 0)} remaining chunks)"
-                )
-            if verbose and not any(
-                f.startswith(f"fabric/{scenario}") for f in failures
-            ):
-                print(
-                    f"  chaos fabric/{scenario}: ok rows={len(rows)} "
-                    f"fault_chunk={rep['fault_chunk']}/{K} "
-                    f"pushes={rep['pushes']} pulls={rep['pulls']} "
-                    f"resumed_from={rep['resumed_from_chunk']} "
-                    f"re_executed=0"
-                )
-        elif scenario == "membership_flap":
-            if not rep["flapped"]:
-                failures.append(
-                    f"fabric/{scenario}: the flap never happened ({rep})"
-                )
-            elif rep["double_refused"] != 1:
-                failures.append(
-                    f"fabric/{scenario}: a second claim on an owned "
-                    f"query was NOT refused — double placement across "
-                    f"epochs ({rep})"
-                )
-            elif rep["epoch_delta"] < 2:
-                failures.append(
-                    f"fabric/{scenario}: membership epoch did not "
-                    f"advance across the flap ({rep})"
-                )
-            elif rep["owners_at_end"] != 0:
-                failures.append(
-                    f"fabric/{scenario}: {rep['owners_at_end']} ownership "
-                    f"claims leaked past query completion"
-                )
-            elif not rep["resumes"] and not rep["epoch_fences"]:
-                failures.append(
-                    f"fabric/{scenario}: neither a resume nor a typed "
-                    f"epoch-fence restart happened after the flap ({rep})"
-                )
-            if verbose and not any(
-                f.startswith(f"fabric/{scenario}") for f in failures
-            ):
-                print(
-                    f"  chaos fabric/{scenario}: ok rows={len(rows)} "
-                    f"fault_chunk={rep['fault_chunk']}/{K} "
-                    f"epoch_delta={rep['epoch_delta']} "
-                    f"double_refused=1 owners=0"
-                )
-        else:  # transport_corruption
-            if not rep["digest_rejects"]:
-                failures.append(
-                    f"fabric/{scenario}: corrupted payload was never "
-                    f"digest-rejected ({rep})"
-                )
-            elif rep["pulls"]:
-                failures.append(
-                    f"fabric/{scenario}: a corrupted payload was "
-                    f"IMPORTED ({rep['pulls']} pulls landed)"
-                )
-            elif rep["truncated_import"] is not False:
-                failures.append(
-                    f"fabric/{scenario}: truncated payload import was "
-                    f"not refused ({rep['truncated_import']!r})"
-                )
-            elif rep["resumes"]:
-                failures.append(
-                    f"fabric/{scenario}: resumed after a rejected "
-                    f"transfer — restart expected ({rep})"
-                )
-            if verbose and not any(
-                f.startswith(f"fabric/{scenario}") for f in failures
-            ):
-                print(
-                    f"  chaos fabric/{scenario}: ok rows={len(rows)} "
-                    f"fault_chunk={rep['fault_chunk']}/{K} "
-                    f"digest_rejects={rep['digest_rejects']} "
-                    f"pulls=0 clean_restart=True"
-                )
-    return failures

@@ -439,18 +439,14 @@ class DistributedQueryRunner:
         # (device carving needs jax initialized, which query execution
         # guarantees and construction must not force)
         self._replicas = None
-        # serializes mesh runs on the single full-width mesh: a mesh is
-        # a single-program resource (two programs interleaving
-        # collectives on one device set deadlock their rendezvous).
-        # With a replica plane, the per-replica exec_lock takes over —
-        # replicas are the units of mesh concurrency.
-        self._mesh_exec_lock = named_lock("DistributedQueryRunner._mesh_exec_lock")
         # preemptive multi-tenancy (runtime/scheduler.py): the single
         # full-width mesh's chunk-granular run queue, built lazily on
-        # first scheduled dispatch (replica planes carry one scheduler
-        # per Replica instead); _sched_steals counts completed
-        # work-stealing dispatches, instance-scoped for the EXPLAIN
-        # `scheduler=` line
+        # first mesh dispatch (replica planes carry one scheduler per
+        # Replica instead). Its seat is what serializes mesh runs: a
+        # mesh is a single-program resource (two programs interleaving
+        # collectives on one device set deadlock their rendezvous).
+        # _sched_steals counts completed work-stealing dispatches,
+        # instance-scoped for the EXPLAIN `scheduler=` line
         self._mesh_scheduler = None
         self._sched_steals = 0
         import collections
@@ -1137,13 +1133,12 @@ class DistributedQueryRunner:
         when no sibling remains (or failover is off) does the fault
         re-raise into the caller's page-plane fallback.
 
-        With mesh_scheduler on (the default), the serialization point
-        is the weighted-fair run queue (runtime/scheduler.py) instead
-        of a bare lock: the holder's chunk loop consults the scheduler
-        at every boundary, `fast` submissions ride the preempting fast
-        lane, and a drain fault whose unstarted chunk range is large
-        enough may be SPLIT across two sibling replicas (work
-        stealing) instead of resuming wholesale on one."""
+        The serialization point is the seat of the weighted-fair run
+        queue (runtime/scheduler.py): the holder's chunk loop consults
+        the scheduler at every boundary, `fast` submissions ride the
+        preempting fast lane, and a drain fault whose unstarted chunk
+        range is large enough may be SPLIT across two sibling replicas
+        (work stealing) instead of resuming wholesale on one."""
         from trino_tpu.parallel.mesh_chunk import (
             MeshDeviceLost,
             MeshReplicaDraining,
@@ -1151,9 +1146,6 @@ class DistributedQueryRunner:
         )
         from trino_tpu.parallel.mesh_plan import MeshExecutor
 
-        import contextlib
-
-        use_sched = bool(getattr(self.session, "mesh_scheduler", True))
         group = self._sched_group()
         # multi-host fabric attach (no-op unless fabric_peers is set):
         # checkpoints taken by this run stream asynchronously to peer
@@ -1171,38 +1163,29 @@ class DistributedQueryRunner:
         if rm is None:
             ex = MeshExecutor(self.catalogs, self.session)
             # width-1 meshes run no collectives and keep their historic
-            # concurrency; wider meshes serialize — through the
-            # scheduler's run queue when it is on, else the bare lock
-            if getattr(ex, "n", 1) > 1 and use_sched:
-                sched = self._mesh_scheduler_for()
-                job = sched.submit(
-                    query_id or "q?", group=group, fast=fast,
-                    poll=preempt,
-                )
-                # the chunk runner acquires the seat itself, at device-
-                # phase entry — host planning and feed builds for this
-                # query run before the grant, outside the seat
-                ex.sched_job = job
-                try:
-                    return ex.execute(
-                        subplan, preempt=preempt, query_span=query_span
-                    )
-                finally:
-                    sched.finish(job)
-            guard = (
-                self._mesh_exec_lock if getattr(ex, "n", 1) > 1
-                else contextlib.nullcontext()
-            )
-            with guard:
+            # concurrency; wider meshes serialize on the scheduler's seat
+            if getattr(ex, "n", 1) <= 1:
                 return ex.execute(
                     subplan, preempt=preempt, query_span=query_span
                 )
+            sched = self._mesh_scheduler_for()
+            job = sched.submit(
+                query_id or "q?", group=group, fast=fast, poll=preempt,
+            )
+            # the chunk runner acquires the seat itself, at device-
+            # phase entry — host planning and feed builds for this
+            # query run before the grant, outside the seat
+            ex.sched_job = job
+            try:
+                return ex.execute(
+                    subplan, preempt=preempt, query_span=query_span
+                )
+            finally:
+                sched.finish(job)
         failover_on = bool(
             getattr(self.session, "replica_failover_enabled", True)
         )
-        steal_on = use_sched and bool(
-            getattr(self.session, "mesh_steal_enabled", True)
-        )
+        steal_on = bool(getattr(self.session, "mesh_steal_enabled", True))
         tried: set = set()
         # membership-epoch fencing: a failover remembers the epoch it
         # faulted under; a resume target whose join_epoch moved past it
@@ -1244,36 +1227,28 @@ class DistributedQueryRunner:
                     drain_check=rm.drain_check(rep),
                 )
                 # one mesh program at a time per sub-mesh (see
-                # Replica.exec_lock / Replica.scheduler); concurrent
-                # queries spread across replicas via place() and queue
-                # only when all are busy
-                if use_sched:
-                    sched = rep.scheduler
-                    self._tune_scheduler(sched)
-                    job = sched.submit(
-                        query_id or "q?", group=group, fast=fast,
-                        poll=preempt,
+                # Replica.scheduler); concurrent queries spread across
+                # replicas via place() and queue only when all are busy
+                sched = rep.scheduler
+                self._tune_scheduler(sched)
+                job = sched.submit(
+                    query_id or "q?", group=group, fast=fast,
+                    poll=preempt,
+                )
+                # a drain surfacing while queued (or parked) raises
+                # MeshReplicaDraining out of the wait — failover,
+                # not a grant on decommissioned capacity. The chunk
+                # runner acquires the seat at device-phase entry;
+                # host feed builds run before the grant
+                job.aux_check = rm.drain_check(rep)
+                ex.sched_job = job
+                try:
+                    rows = ex.execute(
+                        subplan, preempt=preempt,
+                        query_span=query_span,
                     )
-                    # a drain surfacing while queued (or parked) raises
-                    # MeshReplicaDraining out of the wait — failover,
-                    # not a grant on decommissioned capacity. The chunk
-                    # runner acquires the seat at device-phase entry;
-                    # host feed builds run before the grant
-                    job.aux_check = rm.drain_check(rep)
-                    ex.sched_job = job
-                    try:
-                        rows = ex.execute(
-                            subplan, preempt=preempt,
-                            query_span=query_span,
-                        )
-                    finally:
-                        sched.finish(job)
-                else:
-                    with rep.exec_lock:
-                        rows = ex.execute(
-                            subplan, preempt=preempt,
-                            query_span=query_span,
-                        )
+                finally:
+                    sched.finish(job)
                 rm.report_success(rep)
                 return rows
             except (MeshStuck, MeshDeviceLost) as e:

@@ -103,16 +103,12 @@ class Replica:
         # interleaving collectives on the SAME device set deadlock the
         # cross-module rendezvous (each program's AllToAll waits for
         # participants the other program occupies). Mesh runs serialize
-        # on this lock per replica — REPLICAS are the serving tier's
-        # units of mesh concurrency, not threads on one mesh.
-        self.exec_lock = named_lock("Replica.exec_lock")
-        # the replica's run queue (runtime/scheduler.py): the same
-        # single-program guarantee as exec_lock, but chunk-granular —
-        # the holder's chunk loop consults the scheduler at every
-        # boundary, so fast-lane arrivals preempt (park) the running
-        # analytic instead of queueing behind its whole run. The
-        # coordinator routes through this when mesh_scheduler is on,
-        # and through the bare exec_lock otherwise.
+        # on the seat of this run queue (runtime/scheduler.py), one per
+        # replica — REPLICAS are the serving tier's units of mesh
+        # concurrency, not threads on one mesh. The holder's chunk loop
+        # consults the scheduler at every boundary, so fast-lane
+        # arrivals preempt (park) the running analytic instead of
+        # queueing behind its whole run.
         from trino_tpu.runtime.scheduler import MeshScheduler
 
         self.scheduler = MeshScheduler(

@@ -1,13 +1,12 @@
 """Chunk-granular weighted-fair mesh scheduling with park/resume.
 
 A sub-mesh is a single-program resource: two chunk loops interleaving
-collectives on one device set deadlock their rendezvous, so PR 17
-serialized mesh runs on a bare per-replica `exec_lock` — and its
-coordinator-tick profile showed the serving tail is pure queueing on
-that lock (exec_lock waits p50 5.4 s vs tick p95 256 µs). The seed's
-resource groups only gate *admission*: once a query holds the mesh it
-runs to completion, so a q72-class analytic streaming chunks starves
-every point lookup behind it.
+collectives on one device set deadlock their rendezvous, so mesh runs
+serialize — on this scheduler's seat, the only guard there is. A bare
+lock would do for that alone, but the serving tail is then pure
+queueing on it. The seed's resource groups only gate *admission*: once
+a query holds the mesh it runs to completion, so a q72-class analytic
+streaming chunks starves every point lookup behind it.
 
 This module is the missing scheduler between those two layers. The
 chunk loop (PR 10) hands the host control at every chunk boundary;

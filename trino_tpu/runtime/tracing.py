@@ -499,7 +499,7 @@ def chrome_trace(export: dict) -> List[dict]:
 
 def check_span_invariants(export: dict) -> List[str]:
     """Structural invariants on an exported trace; returns violations
-    (empty == healthy). Enforced by tests and `bench.py --trace-smoke`:
+    (empty == healthy). Enforced by tests/test_tracing.py:
 
     - exactly one root, and it is the query span
     - every non-root parent_id resolves to a span in the trace

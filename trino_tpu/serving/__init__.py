@@ -9,7 +9,6 @@ Everything below turns the one-query-at-a-time engine into a server:
                    groups, with overload shedding (429 + Retry-After)
 - `batcher`      — inter-query micro-batching of point lookups onto
                    one shared device step
-- `harness`      — open-loop load harness behind `bench.py --serve`
 
 The split mirrors the reference's dispatcher layer (DispatchManager +
 QueryPreparer + resource-group submit path in front of the execution
