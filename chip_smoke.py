@@ -73,8 +73,9 @@ limit 10
 """
 
 # 8 x 5 x 4 = 160 key slots: past the 64-slot unrolled dense path, inside
-# the 2048-slot band the Pallas MXU group-by serves on a TPU. Q1 (12
-# slots) never reaches that kernel; this statement is how the smoke does.
+# the 2048-slot band the Pallas MXU group-by serves on a TPU. Q1's 12
+# slots x 14 value slots reach that kernel too since PR 31; this is the
+# statement whose call the smoke's proof compiles.
 G3 = """
 select l_shipmode, l_shipinstruct, l_returnflag, count(*), sum(l_quantity)
 from lineitem group by 1, 2, 3

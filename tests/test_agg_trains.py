@@ -6,7 +6,9 @@ one state. CPU counts and answers only; what a launch costs is a chip
 reading (PERF.md section 6)."""
 
 import collections
+import types
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,9 +18,10 @@ from trino_tpu.connectors.memory import create_memory_connector
 from trino_tpu.connectors.spi import ColumnMetadata
 from trino_tpu.engine import LocalQueryRunner, Session
 from trino_tpu.exec import operators as O
+from trino_tpu.ops import groupby as G
+from trino_tpu.ops.int128 import from_python, to_python
 from trino_tpu.exec.operators import (
     AggSpec,
-    CollectorSink,
     HashAggregationOperator,
     TableScanOperator,
 )
@@ -29,15 +32,20 @@ BATCH = 256
 K1 = ["a", "b", "c"]                     # (3+1) x (4+1) = 20 slots: dense
 K2 = ["w", "x", "y", "z"]
 K3 = [f"m{i:02d}" for i in range(20)]    # (3+1) x (20+1) = 84 slots: MXU band
-COLUMNS = ["k1", "k2", "k3", "v", "u"]
-TYPES = [T.VARCHAR, T.VARCHAR, T.VARCHAR, T.BIGINT, T.BIGINT]
-DICTS = [Dictionary(K1), Dictionary(K2), Dictionary(K3), None, None]
+K4 = ["F", "O"]                          # (3+1) x (2+1) = 12 slots: Q1's
+D1, D2 = T.decimal(34, 4), T.decimal(38, 6)
+COLUMNS = ["k1", "k2", "k3", "v", "u", "k4", "d1", "d2", "t"]
+TYPES = [T.VARCHAR, T.VARCHAR, T.VARCHAR, T.BIGINT, T.BIGINT, T.VARCHAR, D1, D2, T.BIGINT]
+DICTS = [Dictionary(K1), Dictionary(K2), Dictionary(K3), None, None, Dictionary(K4),
+         None, None, None]
 # full batches and rows of the masked tail batch: 59 = 7 x 8 + 3 leaves a
 # short train at 8, an odd one at 2
 SCANS = {"tail": (59, 85), "even": (58, 0), "one": (0, 100), "two": (2, 0)}
 
 
 def make_table(full, tail, seed=26):
+    """(arrays, valids) as the plain reference reads them: a long
+    decimal is its unscaled python integer."""
     rng = np.random.default_rng(seed)
     n = full * BATCH + tail
     codes = [rng.integers(0, len(d), n).astype(np.int32) for d in (K1, K2, K3)]
@@ -45,7 +53,26 @@ def make_table(full, tail, seed=26):
     v = rng.integers(-10**12, 10**12, n)
     v_valid = rng.random(n) > 0.2                             # NULL arguments
     u = rng.integers(0, 1000, n)
-    return [*codes, v, u], [*key_valid, v_valid, None]
+    k4 = rng.integers(0, len(K4), n).astype(np.int32)
+    # past 2^64 and 2^96 on both sides of zero: every limb slot carries
+    d1 = np.array([int(x) * 10**15 + int(y) for x, y in
+                   zip(rng.integers(-10**15, 10**15, n), rng.integers(0, 10**15, n))],
+                  dtype=object)
+    d2 = np.array([int(x) * 10**18 * (-1) ** int(i) for i, x in
+                   enumerate(rng.integers(0, 10**15, n))], dtype=object)
+    t = rng.integers(-50, 50, n)
+    return ([*codes, v, u, k4, d1, d2, t],
+            [*key_valid, v_valid, None, rng.random(n) > 0.1, rng.random(n) > 0.3,
+             rng.random(n) > 0.05, rng.random(n) > 0.5])
+
+
+def physical(array, type_):
+    """A long decimal as the connector stores it: (signed hi, lo) int64."""
+    if not type_.is_long_decimal:
+        return array
+    pairs = np.array([from_python(int(x)) for x in array], dtype=object)
+    return np.stack([pairs[:, 0].astype(np.int64),
+                     (pairs[:, 1] % 2**64).astype(np.uint64).view(np.int64)], axis=1)
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +82,7 @@ def catalog():
     for name, (full, tail) in SCANS.items():
         arrays, valids = make_table(full, tail)
         mem.load_table("s", name, [ColumnMetadata(c, t) for c, t in zip(COLUMNS, TYPES)],
-                       arrays, valids, DICTS)
+                       [physical(a, t) for a, t in zip(arrays, TYPES)], valids, DICTS)
         tables[name] = (arrays, valids)
     return mem, tables
 
@@ -66,31 +93,59 @@ def scan_op(mem, table):
     return TableScanOperator(mem.page_source, splits, COLUMNS, BATCH)
 
 
+Q1_AGGS = [AggSpec("sum", 3, T.BIGINT), AggSpec("sum", 4, T.BIGINT),
+           AggSpec("sum", 6, T.decimal(38, 4)), AggSpec("sum", 7, T.decimal(38, 6)),
+           AggSpec("avg", 3, T.DOUBLE), AggSpec("avg", 4, T.DOUBLE),
+           AggSpec("avg", 8, T.DOUBLE), AggSpec("count_star", None, T.BIGINT)]
 PATHS = {
-    # path: (group channels, aggregates, operator attribute that must be set)
+    # path: (group channels, aggregates, operator attribute that must be
+    # set, whether the test takes the MXU route the CPU would not)
     "dense": ([0, 1], [AggSpec("count_star", None, T.BIGINT), AggSpec("sum", 3, T.BIGINT),
                        AggSpec("min", 3, T.BIGINT), AggSpec("max", 3, T.BIGINT),
                        AggSpec("count", 3, T.BIGINT), AggSpec("avg", 4, T.DOUBLE)],
-              "_dense_dims"),
+              "_dense_dims", False),
     "mxu": ([0, 2], [AggSpec("count_star", None, T.BIGINT), AggSpec("sum", 3, T.BIGINT),
                      AggSpec("count", 3, T.BIGINT), AggSpec("sum", 4, T.BIGINT)],
-            "_mxu_dims"),
+            "_mxu_dims", True),
+    # Q1's shape (issue 31): 12 slots, 14 value slots, every one a sum or
+    # a count of an int64, by the dense route and by the MXU route
+    "q1-dense": ([0, 5], Q1_AGGS, "_dense_dims", False),
+    "q1-mxu": ([0, 5], Q1_AGGS, "_mxu_dims", True),
 }
+
+
+def exact_rows(batch):
+    """`CollectorSink.rows()` with a long decimal as its exact unscaled
+    integer (rows() divides by the scale in floating point)."""
+    host = jax.device_get(batch)
+    live = np.asarray(host.live_mask())
+    cols = []
+    for c in host.columns:
+        if c.type.is_long_decimal:
+            cols.append([
+                to_python(int(h), int(lo)) if ok else None
+                for (h, lo), ok, keep in zip(np.asarray(c.data), np.asarray(c.valid_mask()), live)
+                if keep])
+        else:
+            cols.append(c.to_pylist(live=live))
+    return [list(row) for row in zip(*cols)]
 
 
 def aggregate(mem, table, path, step="single", k=None, monkeypatch=None,
               before_batch=None, memory=None):
     """The operator's output rows for one scan, and the counters' deltas."""
-    if path == "mxu":
+    groups, aggs, attr, force_mxu = PATHS[path]
+    if force_mxu:
         monkeypatch.setenv("TRINO_TPU_FORCE_MXU", "1")
     if k is not None:
         monkeypatch.setattr(O, "TRAIN_BATCHES", k)
-    groups, aggs, attr = PATHS[path]
     agg = HashAggregationOperator(groups, aggs, list(zip(TYPES, DICTS)), step=step,
                                   memory_context=memory)
     assert getattr(agg, attr) is not None and agg._trains
     scan = scan_op(mem, table)
-    before = {c: METRICS.counter(c) for c in ("agg_ingest_batches", "agg_ingest_launches")}
+    names = ("agg_ingest_batches", "agg_ingest_launches",
+             "agg_ingest_path.dense", "agg_ingest_path.mxu", "agg_ingest_path.sort")
+    before = {c: METRICS.counter(c) for c in names}
     i = 0
     while True:
         batch = scan.get_output()
@@ -101,16 +156,19 @@ def aggregate(mem, table, path, step="single", k=None, monkeypatch=None,
         agg.add_input(batch)
         i += 1
     agg.finish()
-    sink = CollectorSink()
-    sink.add_input(agg.get_output())
-    counts = {c: METRICS.counter(c) - before[c] for c in before}
-    return sorted(sink.rows(), key=repr), counts
+    counts = {c: METRICS.counter(c) - before[c] for c in names}
+    # every batch counts under the route the operator took, and no other
+    route = {"_dense_dims": "dense", "_mxu_dims": "mxu"}[attr]
+    for r in ("dense", "mxu", "sort"):
+        assert counts.pop("agg_ingest_path." + r) == (
+            counts["agg_ingest_batches"] if r == route else 0)
+    return sorted(exact_rows(agg.get_output()), key=repr), counts
 
 
 def reference(tables, table, path):
     """Plain python over the loaded arrays: the `single` step's rows."""
     arrays, valids = tables[table]
-    groups, aggs, _ = PATHS[path]
+    groups, aggs = PATHS[path][:2]
     rows = collections.defaultdict(list)
     for i in range(len(arrays[0])):
         key = tuple(DICTS[g].values[arrays[g][i]] if valids[g][i] else None for g in groups)
@@ -129,7 +187,8 @@ def reference(tables, table, path):
             elif not vals:
                 row.append(None)
             elif a.kind == "avg":
-                row.append(sum(vals) / len(vals))
+                # avg(bigint) is the exact sum divided as a double
+                row.append(float(sum(vals)) / len(vals))
             else:
                 row.append({"sum": sum, "min": min, "max": max}[a.kind](vals))
         out.append(row)
@@ -347,3 +406,92 @@ def test_other_paths_still_launch_once_per_batch(runner, sql, ingest):
     assert counts["agg_ingest_batches"] == (60 if ingest else 0)
     assert counts["agg_ingest_launches"] == counts["agg_ingest_batches"]
     assert _cache_size(O._agg_ingest_train) == 0
+
+
+# -- which reduce a bounded domain gets (issue 31) -----------------------------------
+
+G3_KEYS = [Dictionary([f"{i}.{j}" for j in range(d)]) for i, d in enumerate((7, 4, 3))]
+ONE = Dictionary(["only"])
+CHOICES = {
+    # case: (key (type, dictionary)s, value (type, dictionary)s, aggregates over
+    # the values by position, the route with the MXU kernel at hand, without)
+    "q1": ([(T.VARCHAR, DICTS[0]), (T.VARCHAR, DICTS[5])],
+           [(T.BIGINT, None), (T.BIGINT, None), (D1, None), (D2, None), (T.BIGINT, None)],
+           [("sum", 0), ("sum", 1), ("sum", 2), ("sum", 3), ("avg", 0), ("avg", 1),
+            ("avg", 4), ("count_star", None)], "mxu", "dense"),
+    "min-max": ([(T.VARCHAR, DICTS[0]), (T.VARCHAR, DICTS[5])], [(T.BIGINT, None)],
+                [("sum", 0), ("min", 0), ("max", 0)], "dense", "dense"),
+    "float-sum": ([(T.VARCHAR, DICTS[0]), (T.VARCHAR, DICTS[5])], [(T.DOUBLE, None)],
+                  [("sum", 0), ("count_star", None)], "dense", "dense"),
+    # (1+1) x (1+1) slots x one value slot: a handful of reductions
+    # against a plane and a kernel launch
+    "four-slots-one-value": ([(T.VARCHAR, ONE), (T.VARCHAR, ONE)], [],
+                             [("count_star", None)], "dense", "dense"),
+    "g3": ([(T.VARCHAR, d) for d in G3_KEYS], [(T.decimal(12, 2), None)],
+           [("count_star", None), ("sum", 0)], "mxu", "sort"),
+    "g3-float": ([(T.VARCHAR, d) for d in G3_KEYS], [(T.DOUBLE, None)],
+                 [("sum", 0)], "sort", "sort"),
+    "unbounded": ([(T.BIGINT, None)], [(T.BIGINT, None)],
+                  [("count_star", None), ("sum", 0)], "sort", "sort"),
+}
+
+
+def choice_case(case):
+    keys, vals, aggs, with_mxu, without = CHOICES[case]
+    schema = keys + vals
+    specs = [AggSpec(kind, None if pos is None else len(keys) + pos,
+                     T.BIGINT if pos is None else vals[pos][0]) for kind, pos in aggs]
+    return list(range(len(keys))), specs, schema, with_mxu, without
+
+
+@pytest.mark.parametrize("mxu", [True, False], ids=["forced", "cpu"])
+@pytest.mark.parametrize("case", sorted(CHOICES))
+def test_the_operator_asks_the_chooser(case, mxu, monkeypatch):
+    groups, specs, schema, with_mxu, without = choice_case(case)
+    monkeypatch.setenv("TRINO_TPU_FORCE_MXU", "1" if mxu else "0")
+    agg = HashAggregationOperator(groups, specs, schema)
+    want = with_mxu if mxu else without
+    assert agg._path == want
+    assert (agg._dense_dims is not None) == (want == "dense")
+    assert (agg._mxu_dims is not None) == (want == "mxu")
+    assert agg._trains == (want != "sort")
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("case", sorted(CHOICES))
+def test_the_mesh_plane_asks_the_same_chooser(case, platform):
+    """`_bounded_reduce` of the mesh plane answers what the operator
+    does, but that its dense reduce folds sums and counts of integers
+    only: a min/max or a float sum sorts there."""
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.parallel.mesh_plan import _FragVisitor
+
+    groups, specs, schema, with_mxu, without = choice_case(case)
+    device = types.SimpleNamespace(platform=platform)
+    ex = types.SimpleNamespace(mesh=types.SimpleNamespace(devices=np.array([device])))
+    visitor = _FragVisitor(ex, 0, {}, {}, {}, [])
+    n = 16
+    batch = RelBatch([
+        Column(t, np.zeros((n, 2) if t.is_long_decimal else n, t.dtype), None, d)
+        for t, d in schema], None)
+    node = types.SimpleNamespace(group_channels=groups)
+    _live, values, _vvalids, reds = visitor._batch_agg_inputs(specs, batch)
+    reduce, dims = visitor._bounded_reduce(node, batch, values, reds)
+    want = with_mxu if platform == "tpu" else without
+    if want == "dense" and case in ("min-max", "float-sum"):
+        want = "sort"
+    assert reduce is {"dense": G.dense_group_reduce, "mxu": G.mxu_group_reduce,
+                      "sort": None}[want]
+    assert (dims is None) == (want == "sort")
+    if want != "sort":
+        assert dims == tuple(len(d) for _t, d in schema[:len(groups)])
+
+
+@pytest.mark.parametrize("table", sorted(SCANS))
+@pytest.mark.parametrize("path", ["q1-dense", "q1-mxu"])
+def test_q1_shape_equals_the_plain_reference_by_both_routes(catalog, table, path, monkeypatch):
+    mem, tables = catalog
+    rows, counts = aggregate(mem, table, path, monkeypatch=monkeypatch)
+    assert rows == reference(tables, table, path)
+    full, tail = SCANS[table]
+    assert counts["agg_ingest_batches"] == full + bool(tail)

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 import trino_tpu  # noqa: F401  (x64)
 
 BATCH = 1 << 20  # the engine's default batch_rows
+Q1_LIMBS = (8, 8, 1, 4, 4, 4, 8, 1, 4, 4, 4, 8, 8, 8, 8)
 
 
 @pytest.fixture(scope="module")
@@ -82,6 +83,10 @@ def _sds(shape, dtype, sharding):
         pytest.param(1 << 22, 1, 160, (8,), id="g3-4M-limbs8-cap160"),
         # a masked sum: its 0/1 indicator has one limb and no high word
         pytest.param(BATCH, 2, 160, (1, 8), id="1M-limbs1+8-cap160"),
+        # what Q1 hands over since PR 31: five BIGINT sums, two long
+        # decimal sums as (4, 4, 4, 8) limb slots behind one indicator
+        # each; 22 word rows and the gid row, w8 = 24, 12 slots
+        pytest.param(BATCH, 15, 12, Q1_LIMBS, id="q1-1M-cap12"),
     ],
 )
 def test_grouped_sum_mxu_compiles_for_v5e(
@@ -100,14 +105,17 @@ def test_grouped_sum_mxu_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("path", ["mxu", "dense"])
+@pytest.mark.parametrize("path", ["mxu", "dense", "q1"])
 def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     """The train of issue 26 at the engine's batch: eight batches of 2^20
     rows, the per-batch body once inside a loop (a `while`) that picks
     its batch out of the operands (a `conditional`). `mxu` is G3's shape
     (160 slots, count(*) and a sum) with the Pallas kernel itself inside
     the loop, not its interpreted form: the operator asks
-    `jax.default_backend()`, which is the CPU here."""
+    `jax.default_backend()`, which is the CPU here. `q1` is Q1's (issue
+    31): 12 slots, 14 value slots of which eight are two long decimals'
+    limb slots behind one validity mask each, through the same kernel
+    at `w8` = 24."""
     from trino_tpu import types as T
     from trino_tpu.block import Column, Dictionary, RelBatch
     from trino_tpu.exec import operators as O
@@ -115,26 +123,41 @@ def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     dims = (7, 4, 3) if path == "mxu" else (3, 2)
     dicts = [Dictionary([f"{i}.{j}" for j in range(d)]) for i, d in enumerate(dims)]
+    k = len(dims)
+    short, longs = T.decimal(12, 2), (T.decimal(34, 4), T.decimal(38, 6))
 
     def batch():
         cols = [Column(T.VARCHAR, _sds((BATCH,), jnp.int32, one_chip), None, d)
                 for d in dicts]
-        cols.append(Column(T.decimal(12, 2), _sds((BATCH,), jnp.int64, one_chip)))
+        cols.append(Column(short, _sds((BATCH,), jnp.int64, one_chip)))
+        if path == "q1":
+            cols.extend(Column(short, _sds((BATCH,), jnp.int64, one_chip))
+                        for _ in range(2))
+            cols.extend(Column(t, _sds((BATCH, 2), jnp.int64, one_chip),
+                               _sds((BATCH,), jnp.bool_, one_chip)) for t in longs)
         return RelBatch(cols, None)
 
     aggs = [O.AggSpec("count_star", None, T.BIGINT),
-            O.AggSpec("sum", len(dims), T.decimal(18, 2))]
+            O.AggSpec("sum", k, T.decimal(18, 2))]
     if path == "dense":
-        aggs.append(O.AggSpec("min", len(dims), T.decimal(12, 2)))
+        aggs.append(O.AggSpec("min", k, short))
+    if path == "q1":
+        aggs = [O.AggSpec("sum", k, short), O.AggSpec("sum", k + 1, short),
+                O.AggSpec("sum", k + 3, T.decimal(38, 4)),
+                O.AggSpec("sum", k + 4, T.decimal(38, 6)),
+                O.AggSpec("avg", k, short), O.AggSpec("avg", k + 1, short),
+                O.AggSpec("avg", k + 2, short), aggs[0]]
     compiled = O._agg_ingest_train.lower(
         tuple(batch() for _ in range(O.TRAIN_BATCHES)),
         _sds((), jnp.int32, one_chip),
-        tuple(range(len(dims))), tuple(aggs), 256 if path == "mxu" else 16, None,
-        dims if path == "dense" else None, dims if path == "mxu" else None,
+        tuple(range(k)), tuple(aggs), 256 if path == "mxu" else 16, None,
+        dims if path == "dense" else None, dims if path != "dense" else None,
     ).compile()
     text = compiled.as_text()
     assert "while" in text and "conditional" in text
-    assert ("tpu_custom_call" in text) == (path == "mxu")
+    assert ("tpu_custom_call" in text) == (path != "dense")
+    # the word rows go to the kernel as they are: no stacked plane
+    assert "s32[24,1048576]" not in text and "s32[8,1048576]" not in text
 
 
 def test_mxu_join_probe_page_sums_compiles_for_v5e(one_chip):

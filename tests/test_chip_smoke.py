@@ -48,10 +48,13 @@ def test_served_shapes(served):
     results, calls = served
     assert len(results["point"]) == chip_smoke.N_POINT_LOOKUPS
     assert len(results["q3"]) == 10 and len(results["g3"]) > 64
-    # G3 reached the kernel, at the key domain tests/test_chip_compile.py
-    # compiles for the chip
+    # G3 and Q1 reached the kernel, at the key domains and word rows
+    # tests/test_chip_compile.py compiles for the chip; the proof of
+    # device compiles the first call, G3's
     assert calls
-    assert {(c["limbs"], c["capacity"]) for c in calls} == {((8,), 160)}
+    assert (calls[0]["limbs"], calls[0]["capacity"]) == ((8,), 160)
+    q1 = (8, 8, 1, 4, 4, 4, 8, 1, 4, 4, 4, 8, 8, 8, 8)
+    assert {(c["limbs"], c["capacity"]) for c in calls} == {((8,), 160), (q1, 12)}
 
 
 def test_compare_fails_on_a_wrong_answer(loaded, served):

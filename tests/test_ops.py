@@ -323,21 +323,43 @@ class TestMxuGroupby:
             assert tile - chunk < max(n, chunk)  # no chunk of padding alone
         assert _row_tile(1 << 20, 256, 8) == (16384, 2048)  # G3's
 
-    @pytest.mark.parametrize("reducers,masked", [
-        pytest.param(("sum", "count"), (True, False), id="masked-sum"),
+    # Q1's 14 value slots (issue 31): five BIGINT sums without a
+    # validity mask, two long decimal sums of four limb slots each
+    # behind one mask each, count(*)
+    _Q1 = (
+        ("sum",) * 13 + ("count",),
+        (None, None, "a", "a", "a", "a", "b", "b", "b", "b", None, None, None, None),
+        (8, 8, 4, 4, 4, 8, 4, 4, 4, 8, 8, 8, 8, 8),
+    )
+
+    @pytest.mark.parametrize("reducers,masks,value_limbs,want_limbs", [
+        pytest.param(("sum", "count"), ("a", None), None, (1, 8), id="masked-sum"),
         # G3's: count(*) and a sum without a validity mask read the one
         # live-row count the kernel appends
-        pytest.param(("count", "sum"), (False, False), id="shared-count"),
+        pytest.param(("count", "sum"), (None, None), None, (8,), id="shared-count"),
+        # slots that share a validity array share its indicator column
+        pytest.param(("sum", "sum", "count", "sum"), ("a", "a", "a", "b"), None,
+                     (1, 8, 8, 1, 8), id="shared-valid"),
+        # a slot stated under 2^32 has four limbs and no high word
+        pytest.param(("sum", "sum", "sum"), (None, "a", None), (4, 4, 8),
+                     (4, 1, 4, 8), id="under-2^32"),
+        pytest.param(*_Q1, (8, 8, 1, 4, 4, 4, 8, 1, 4, 4, 4, 8, 8, 8, 8), id="q1"),
     ])
-    def test_mxu_group_reduce_contract(self, reducers, masked):
+    def test_mxu_group_reduce_contract(self, reducers, masks, value_limbs,
+                                       want_limbs, monkeypatch):
         """mxu_group_reduce matches dense_group_reduce on the same
-        bounded-domain inputs (sum/count reducers)."""
+        bounded-domain inputs (sum/count reducers), and its word plane
+        carries each thing once: the columns and `limbs` it hands to
+        grouped_sum_mxu."""
         import numpy as np
         import jax.numpy as jnp
-        from trino_tpu.ops.groupby import dense_group_reduce, mxu_group_reduce
+        from trino_tpu.ops import mxu_groupby
+        from trino_tpu.ops.groupby import (
+            dense_group_reduce, mxu_group_reduce, shared_valids,
+        )
 
         rng = np.random.default_rng(4)
-        n, d0, d1 = 5000, 5, 7
+        n, d0, d1 = 5000, 3, 2
         keys = [
             jnp.asarray(rng.integers(0, d0, n).astype(np.int64)),
             jnp.asarray(rng.integers(0, d1, n).astype(np.int64)),
@@ -347,16 +369,33 @@ class TestMxuGroupby:
             jnp.ones(n, dtype=jnp.bool_),
         ]
         mask = jnp.asarray(rng.random(n) < 0.8)
+        limbs_in = value_limbs or (8,) * len(reducers)
         values = [
-            jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int64)),
-            jnp.ones(n, dtype=jnp.int64),
+            jnp.asarray(rng.integers(0, 2**32, n) if k == 4
+                        else rng.integers(-2**62, 2**62, n))
+            for k in limbs_in
         ]
-        vvalids = [jnp.asarray(rng.random(n) < 0.95) if m else None
-                   for m in masked]
-        args = (keys, valids, mask, values, tuple(vvalids),
-                reducers, (d0, d1), 64)
+        by_name = {m: jnp.asarray(rng.random(n) < 0.95)
+                   for m in sorted(set(masks) - {None})}
+        vvalids = tuple(by_name.get(m) for m in masks)
+        args = (keys, valids, mask, values, vvalids, reducers, (d0, d1), 16)
         want = dense_group_reduce(*args)
-        got = mxu_group_reduce(*args)
+        handed = []
+        real = mxu_groupby.grouped_sum_mxu
+
+        def spy(gid, cols, live, capacity, interpret=False, limbs=None):
+            handed.append(limbs)
+            assert len(cols) == len(limbs)
+            return real(gid, cols, live, capacity, interpret=interpret, limbs=limbs)
+
+        monkeypatch.setattr(mxu_groupby, "grouped_sum_mxu", spy)
+        # the function under the jit, so that the spy sees this call
+        got = mxu_group_reduce.__wrapped__(
+            *args, value_limbs=value_limbs, valid_of=shared_valids(vvalids))
+        assert handed == [want_limbs]
+        # 32-bit word rows of the call, the gid row among them
+        word_rows = sum(1 + (k > 4) for k in want_limbs) + 1
+        assert word_rows <= 23
         for g, w in zip(got[:5], want[:5]):
             for ga, wa in zip(
                 (g if isinstance(g, (list, tuple)) else [g]),
@@ -364,6 +403,13 @@ class TestMxuGroupby:
             ):
                 assert np.array_equal(np.asarray(ga), np.asarray(wa))
         assert int(got[5]) == int(want[5])
+
+    def test_shared_valids_is_by_identity(self):
+        import jax.numpy as jnp
+        from trino_tpu.ops.groupby import shared_valids
+
+        a, b = jnp.ones(4, bool), jnp.ones(4, bool)  # equal, not the same
+        assert shared_valids([None, a, a, b, None, a]) == (0, 1, 1, 3, 4, 1)
 
     def test_engine_routes_through_mxu(self, monkeypatch):
         """A bounded-dictionary GROUP BY in the (64, 2048] band runs

@@ -1105,6 +1105,34 @@ def _agg_slot_count(spec: "AggSpec", arg_type: Optional[T.DataType]) -> int:
     return 1
 
 
+def _value_slot_layout(aggs, arg_types):
+    """Per value slot of the ingest paths (_agg_slot_count's layout):
+    its batch reducer, the dtype of its values and the 8-bit limbs they
+    can have. A long decimal's sum is four int64 limb slots of which the
+    three low ones are under 2^32 by construction (_limb_split); its
+    extremes are the coupled (hi, lo) reducers only the sort path has."""
+    reds, dtypes, limbs = [], [], []
+    for a, t in zip(aggs, arg_types):
+        k = _agg_slot_count(a, t)
+        red = _BATCH_REDUCER.get(a.kind)
+        if k == 2 and a.kind in ("min", "max"):
+            red = f"{a.kind}128"
+        reds.extend([red] * k)
+        wide = t is None or t.is_long_decimal
+        dtypes.extend([np.dtype(np.int64) if wide else t.dtype] * k)
+        limbs.extend((4, 4, 4, 8) if k == 4 else (8,) * k)
+    return tuple(reds), tuple(dtypes), tuple(limbs)
+
+
+def _mxu_word_layout(aggs, batch: RelBatch, vvalids) -> dict:
+    """G.mxu_group_reduce's keyword arguments for one batch's value
+    slots: what its word rows need not carry twice."""
+    types = [None if a.arg_channel is None
+             else batch.columns[a.arg_channel].type for a in aggs]
+    return dict(value_limbs=_value_slot_layout(aggs, types)[2],
+                valid_of=G.shared_valids(vvalids))
+
+
 def _slots_to_state(spec: "AggSpec", arg_type: Optional[T.DataType],
                     vals, cnts, si: int):
     """One aggregate's finalize-ready state from its value/count slots
@@ -1284,6 +1312,7 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
     elif mxu_dims is not None:
         out = G.mxu_group_reduce(
             keys, valids, live, values, tuple(vvalids), reds, mxu_dims, cap,
+            **_mxu_word_layout(aggs, batch, vvalids),
         )
     else:
         out = G.sort_group_reduce(
@@ -1482,7 +1511,8 @@ class HashAggregationOperator(Operator):
     go TRAIN_BATCHES at a time through one launch of _agg_ingest_train,
     which leaves one state; finish and revocation flush what is held.
     METRICS `agg_ingest_batches` over `agg_ingest_launches` is the train
-    length achieved."""
+    length achieved; `agg_ingest_path.dense`, `.mxu` and `.sort` count
+    the same batches by the reduce the plan got (`_path`)."""
 
     def __init__(
         self,
@@ -1578,52 +1608,24 @@ class HashAggregationOperator(Operator):
                 bound = 0
                 break
         self._static_bound = bound if 0 < bound <= (1 << 16) else None
-        # dense-slot reduce: tiny bounded domains skip sorting entirely
-        # (per-group masked reductions unroll into one fused program)
-        self._dense_dims = (
-            tuple(dims)
-            if self._static_bound is not None
-            and bound <= 64
-            and self._group_channels
-            and all(
-                _BATCH_REDUCER.get(a.kind) in ("sum", "count", "min", "max")
-                # long-decimal extremes need the coupled (hi, lo)
-                # reducers only the sort path implements
-                and not (
-                    a.kind in ("min", "max")
-                    and a.arg_channel is not None
-                    and self._schema[a.arg_channel][0].is_long_decimal
-                )
-                for a in self._aggs
+        # Which reduce the bounded domain gets is the kernels' layer's
+        # rule (ops/groupby.choose_bounded_reduce): the dense slot
+        # reduce (per-group masked reductions unrolled into one fused
+        # program), the MXU one-hot contraction (ops/mxu_groupby.py
+        # Pallas kernel) on a TPU, or the sort path
+        self._path = "sort"
+        if self._static_bound is not None and self._group_channels:
+            reds, dtypes, _ = _value_slot_layout(
+                self._aggs, [m[0] for m in self._arg_meta]
             )
-            else None
-        )
-        # MXU one-hot contraction (ops/mxu_groupby.py Pallas kernel) for
-        # the mid-cardinality band where the unrolled dense path would
-        # emit one reduction per slot: sum/count of integer-kind values
-        # over bounded domains up to 2048 slots
-        def _int_kind(a: AggSpec) -> bool:
-            if a.arg_channel is None:
-                return True
-            t, _ = self._schema[a.arg_channel]
-            return not t.is_floating
-        self._mxu_dims = (
-            tuple(dims)
-            if self._dense_dims is None
-            and self._static_bound is not None
-            and bound <= 2048
-            and self._group_channels
-            and all(
-                _BATCH_REDUCER.get(a.kind) in ("sum", "count")
-                and _int_kind(a)
-                for a in self._aggs
+            self._path = G.choose_bounded_reduce(
+                bound, reds, dtypes,
+                mxu=jax.default_backend() == "tpu"
+                or _os.environ.get("TRINO_TPU_FORCE_MXU") == "1",
             )
-            and (
-                jax.default_backend() == "tpu"
-                or _os.environ.get("TRINO_TPU_FORCE_MXU") == "1"
-            )
-            else None
-        )
+        self._path_counter = "agg_ingest_path." + self._path
+        self._dense_dims = tuple(dims) if self._path == "dense" else None
+        self._mxu_dims = tuple(dims) if self._path == "mxu" else None
         self._deferred_ovf: List = []
         # Trains: where the plan bounds the table AND addresses it by
         # slot, a batch needs no readback and no replay and every batch's
@@ -1652,26 +1654,6 @@ class HashAggregationOperator(Operator):
             )
 
     # -- grouped path --
-    def _batch_values(self, batch: RelBatch):
-        live = batch.live_mask()
-        values, vvalids, reds = [], [], []
-        for a in self._aggs:
-            if a.arg_channel is None:
-                values.append(live.astype(jnp.int64))
-                vvalids.append(None)
-            elif getattr(batch.columns[a.arg_channel].data, "ndim", 1) == 2:
-                _append_long_decimal_slots(
-                    a, batch.columns[a.arg_channel], live,
-                    values, vvalids, reds,
-                )
-                continue
-            else:
-                col = batch.columns[a.arg_channel]
-                values.append(col.data)
-                vvalids.append(col.valid)
-            reds.append(_BATCH_REDUCER[a.kind])
-        return live, values, vvalids, tuple(reds)
-
     def add_input(self, batch: RelBatch) -> None:
         if self._holistic:
             if self._pre is not None:
@@ -1702,6 +1684,7 @@ class HashAggregationOperator(Operator):
             self._gstate = self._update(self._gstate, batch)
             return
         METRICS.increment("agg_ingest_batches")
+        METRICS.increment(self._path_counter)
         if self._trains:
             layout = self._train_layout(batch)
             with self._state_lock:

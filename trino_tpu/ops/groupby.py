@@ -413,7 +413,64 @@ def _dense_gid(keys, valids, mask, dims, radices):
     return gid, out_of_domain
 
 
-@partial(jax.jit, static_argnames=("dims", "reducers", "out_capacity"))
+# Which bounded-domain reduce a plan gets (choose_bounded_reduce). The
+# dense reduce unrolls one masked whole-column reduction per slot and
+# value slot; the MXU reduce cuts the values into 32-bit word rows and
+# makes one pass over them. The jitted reduce alone on a v5e, device
+# clock, ms per 2^20 rows, dense / MXU by (slots, value slots: 1 is
+# count(*), 2 adds a BIGINT sum, 14 are Q1's) (PERF.md section 6, PR 31):
+#   (4, 1) 0.053 / 0.109    (12, 1) 0.126 / 0.109    (60, 1) 0.555 / 0.116
+#   (4, 2) 0.085 / 0.126    (12, 2) 0.196 / 0.126    (60, 2) 0.844 / 0.133
+#   (4, 14) 0.363 / 0.315   (12, 14) 0.854 / 0.315   (60, 14) 3.927 / 0.323
+# The dense reduce grows with slots x value slots, the MXU reduce with
+# the word rows alone from a floor of 0.11 ms: they cross between a work
+# of 8 and of 12, and every point measured falls on its faster side.
+DENSE_MAX_SLOTS = 64
+MXU_MAX_SLOTS = 2048
+MXU_MIN_WORK = 12  # slots x value slots from which the MXU reduce is taken
+
+
+def choose_bounded_reduce(bound: int, reducers: Sequence[str],
+                          dtypes: Sequence, mxu: bool,
+                          dense_sums_only: bool = False) -> str:
+    """"mxu", "dense" or "sort": the group reduce for a key domain the
+    plan bounds at `bound` slots (NULL digits included), by what the
+    callers can observe: the bound, the per-value-slot reducers, the
+    value dtypes and whether the backend has the MXU kernel (`mxu`: a
+    TPU, or the tests' hook). Sums and counts of integer-kind values go
+    to the MXU reduce up to MXU_MAX_SLOTS, unless the domain is one the
+    dense reduce takes and the work (slots x value slots) is under
+    MXU_MIN_WORK. The dense reduce takes up to DENSE_MAX_SLOTS: sums,
+    counts, minima and maxima of any dtype, or, where the caller's folds
+    only add (`dense_sums_only`: the mesh plane's), integer sums and
+    counts alone. Everything else sorts."""
+    adds = all(r in ("sum", "count") for r in reducers)
+    ints = not any(jnp.issubdtype(d, jnp.floating) for d in dtypes)
+    dense = bound <= DENSE_MAX_SLOTS and (
+        adds and ints if dense_sums_only
+        else all(r in ("sum", "count", "min", "max") for r in reducers)
+    )
+    if mxu and adds and ints and bound <= MXU_MAX_SLOTS and not (
+        dense and bound * len(reducers) < MXU_MIN_WORK
+    ):
+        return "mxu"
+    return "dense" if dense else "sort"
+
+
+def shared_valids(value_valids: Sequence) -> tuple:
+    """Per value slot, the first slot that carries the same validity
+    array (the same object, as a long decimal's four limb slots do); a
+    slot without one points at itself. Identity is lost at a jit
+    boundary, so the caller of mxu_group_reduce states it."""
+    first = {}
+    return tuple(
+        i if vv is None else first.setdefault(id(vv), i)
+        for i, vv in enumerate(value_valids)
+    )
+
+
+@partial(jax.jit, static_argnames=(
+    "dims", "reducers", "out_capacity", "value_limbs", "valid_of"))
 def mxu_group_reduce(
     keys: Sequence[jnp.ndarray],
     valids: Sequence[jnp.ndarray],
@@ -423,13 +480,20 @@ def mxu_group_reduce(
     reducers: tuple,
     dims: tuple,
     out_capacity: int,
+    value_limbs: Optional[tuple] = None,
+    valid_of: Optional[tuple] = None,
 ):
     """dense_group_reduce contract, executed by the Pallas MXU one-hot
-    contraction kernel (ops/mxu_groupby.py) — for bounded key domains in
-    the band where the unrolled dense path explodes (one masked
-    whole-column reduction per slot) but the domain still fits VMEM.
-    Restrictions (caller gates): reducers in {sum, count}; integer-kind
-    value dtypes (BIGINT/decimal-scaled/bool)."""
+    contraction kernel (ops/mxu_groupby.py) in one pass over rows of
+    32-bit words, for bounded key domains that fit VMEM.
+    Restrictions (caller gates, choose_bounded_reduce): reducers in
+    {sum, count}; integer-kind value dtypes (BIGINT/decimal-scaled/bool).
+    The word rows carry each thing once. `value_limbs` states, per value
+    slot, how many 8-bit limbs its values can have (8 unless the caller
+    knows 0 <= value < 2^(8k): a long decimal's three low limb slots
+    are 4 and have no high word). `valid_of` (shared_valids) names, per
+    slot, the first slot with the same validity array: they share one
+    indicator column."""
     from trino_tpu.ops.mxu_groupby import MAX_ROWS, grouped_sum_mxu
 
     assert all(r in ("sum", "count") for r in reducers), reducers
@@ -446,19 +510,26 @@ def mxu_group_reduce(
         total *= r
     assert total <= out_capacity
     gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices)
+    if value_limbs is None:
+        value_limbs = (8,) * len(values)
+    if valid_of is None:
+        valid_of = tuple(range(len(values)))
 
-    # per aggregate: a zero-masked value column (8 limbs) and the count
-    # of its valid rows: a 0/1 indicator of one limb, or, where the
-    # value has no validity mask, the live-row count the kernel appends
-    # anyway (index -1), so that G3's count(*) and its sum's count ride
-    # once; for count reducers the count IS the value
+    # per aggregate: a zero-masked value column and the count of its
+    # valid rows: a 0/1 indicator of one limb (one for all the slots
+    # that share the validity array), or, where the value has no
+    # validity mask, the live-row count the kernel appends anyway (index
+    # -1), so that G3's count(*) and its sum's count ride once; for
+    # count reducers the count IS the value
     cols, limbs = [], []
     col_of_value = []  # per aggregate: index of its value column
     col_of_count = []  # per aggregate: index of its count column
-    for v, vv, red in zip(values, value_valids, reducers):
+    for i, (v, vv, red) in enumerate(zip(values, value_valids, reducers)):
         w = mask if vv is None else (mask & vv)
         cnt_idx = -1
-        if vv is not None:
+        if valid_of[i] != i:
+            cnt_idx = col_of_count[valid_of[i]]
+        elif vv is not None:
             cnt_idx = len(cols)
             cols.append(w.astype(jnp.int64))
             limbs.append(1)
@@ -466,7 +537,7 @@ def mxu_group_reduce(
         if red == "sum":
             col_of_value.append(len(cols))
             cols.append(jnp.where(w, v.astype(jnp.int64), 0))
-            limbs.append(8)
+            limbs.append(value_limbs[i])
         else:
             col_of_value.append(cnt_idx)
     interpret = jax.default_backend() != "tpu"

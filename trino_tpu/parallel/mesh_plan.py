@@ -64,6 +64,7 @@ from trino_tpu.exec.operators import (
     _lex128_reduce,
     _limb_join,
     _limb_split,
+    _mxu_word_layout,
     _right_unmatched,
     _segment_any,
     _slot_merge_reducers,
@@ -557,26 +558,27 @@ class _FragVisitor:
 
     def _bounded_reduce(self, node, batch: RelBatch, values, reds):
         """(group-reduce, key dims) where the plan bounds the key domain
-        (dictionary and boolean keys), as HashAggregationOperator picks
-        them: up to 64 slots the unrolled dense reduce, up to 2048 on a
-        TPU mesh the MXU one-hot contraction (sums and counts of integer
-        values); (None, None) means the sort path. Neither sorts, so a
-        chunk of millions of rows costs one pass over its columns."""
+        (dictionary and boolean keys), by the rule HashAggregationOperator
+        asks too (ops/groupby.choose_bounded_reduce): on a TPU mesh the
+        MXU one-hot contraction or, where the work is small, the unrolled
+        dense reduce, for sums and counts of integer values (all this
+        plane's dense reduce folds); (None, None) means the sort path.
+        Neither sorts, so a chunk of millions of rows costs one pass over
+        its columns."""
         dims = self._key_dims(node, batch)
         if not dims or any(
             getattr(batch.columns[ch].data, "ndim", 1) != 1
             for ch in node.group_channels
         ):
             return None, None
-        bound = int(np.prod([d + 1 for d in dims]))
-        ints = all(not jnp.issubdtype(v.dtype, jnp.floating) for v in values)
-        if bound <= 64 and all(r in ("sum", "count") for r in reds) and ints:
-            return G.dense_group_reduce, tuple(dims)
-        on_tpu = self.ex.mesh.devices.flat[0].platform == "tpu"
-        if (bound <= 2048 and on_tpu and ints
-                and all(r in ("sum", "count") for r in reds)):
-            return G.mxu_group_reduce, tuple(dims)
-        return None, None
+        path = G.choose_bounded_reduce(
+            int(np.prod([d + 1 for d in dims])), reds,
+            [v.dtype for v in values],
+            mxu=self.ex.mesh.devices.flat[0].platform == "tpu",
+            dense_sums_only=True,
+        )
+        reduce = {"dense": G.dense_group_reduce, "mxu": G.mxu_group_reduce}
+        return (reduce[path], tuple(dims)) if path in reduce else (None, None)
 
     def _batch_agg_inputs(self, aggs, batch: RelBatch):
         """Value slots + reducers per aggregate (long-decimal args split
@@ -622,9 +624,11 @@ class _FragVisitor:
         reduce, dims = self._bounded_reduce(node, batch, values, reds)
         args = (tuple(keys), tuple(valids), live, tuple(values),
                 tuple(vvalids), tuple(reds))
+        layout = (_mxu_word_layout(aggs, batch, vvalids)
+                  if reduce is G.mxu_group_reduce else {})
         gk, gv, used, vals, cnts, ngroups, ovf = (
             G.sort_group_reduce(*args, cap) if reduce is None
-            else reduce(*args, dims, cap)
+            else reduce(*args, dims, cap, **layout)
         )
         self.flags.append((site, jnp.where(ovf, ngroups, 0).astype(jnp.int32)))
         cols: List[Column] = []
