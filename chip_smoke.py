@@ -81,6 +81,23 @@ select l_shipmode, l_shipinstruct, l_returnflag, count(*), sum(l_quantity)
 from lineitem group by 1, 2, 3
 """
 
+# TPC-H Q18 at its validation value (QUANTITY 300): 6 M rows into 1.5 M
+# groups on the aggregation's sort path, HAVING, the semi-join placed
+# on `orders`, two joins, top-100 (chipbench/Q18.md; the cell sf10.q18
+# runs it at SF10)
+Q18 = """
+select c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+  sum(l_quantity)
+from customer, orders, lineitem
+where o_orderkey in (
+    select l_orderkey from lineitem group by l_orderkey
+    having sum(l_quantity) > 300)
+  and c_custkey = o_custkey and o_orderkey = l_orderkey
+group by c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+order by o_totalprice desc, o_orderdate
+limit 100
+"""
+
 POINT = "select o_custkey, o_totalprice from orders where o_orderkey = {key}"
 N_POINT_LOOKUPS = 20
 
@@ -95,13 +112,13 @@ TABLE_COLUMNS = {
         "o_orderkey", "o_custkey", "o_orderdate", "o_shippriority",
         "o_totalprice",
     ],
-    "customer": ["c_custkey", "c_mktsegment"],
+    "customer": ["c_custkey", "c_mktsegment", "c_name"],
 }
 
-# statements of the one-chip run, in execution order. TPC-H Q18 is not
-# among them: cold, its programs take the TPU compiler over 15 minutes,
-# more than the whole run may take (CHANGES.md, PR 22)
-STATEMENTS = (("q6", Q6), ("g3", G3), ("q3", Q3), ("q1", Q1))
+# statements of the one-chip run, in execution order; Q3 and Q18 are
+# the ones whose programs take the TPU compiler minutes when cold
+# (CHANGES.md, PR 33, has the seconds): give the call its time
+STATEMENTS = (("q6", Q6), ("g3", G3), ("q3", Q3), ("q1", Q1), ("q18", Q18))
 # statements of the four-chip run (the mesh plane), cheapest compile
 # first. Q1 is not among them: its one mesh program alone takes the TPU
 # compiler minutes, and four chips cost four times as much per second
@@ -416,11 +433,11 @@ def reference_q6(tables):
 
 
 def cell_reference(name: str, tables):
-    """G3's and Q3's expected rows are the benchmark's: the plain
+    """G3's, Q3's and Q18's expected rows are the benchmark's: the plain
     reference its cells hold every answer against
     (chipbench/references/<name>.py), at the parameters this script's
     text of the statement has (the statement file's `validation`). One
-    copy of what the two statements mean, here and in the cells."""
+    copy of what the three statements mean, here and in the cells."""
     from chipbench import traffic
 
     statement = traffic.load_statement(name)
@@ -433,6 +450,7 @@ def cell_reference(name: str, tables):
 
 reference_q3 = functools.partial(cell_reference, "q3")
 reference_g3 = functools.partial(cell_reference, "g3")
+reference_q18 = functools.partial(cell_reference, "q18")
 
 
 def reference_point(tables, seed: int):
@@ -450,10 +468,10 @@ def reference_point(tables, seed: int):
 
 REFERENCES = {
     "q1": reference_q1, "q6": reference_q6, "q3": reference_q3,
-    "g3": reference_g3,
+    "g3": reference_g3, "q18": reference_q18,
 }
 # statements whose SQL fixes the row order; the others compare as sets
-ORDERED = {"q1", "q3", "q6"}
+ORDERED = {"q1", "q3", "q6", "q18"}
 
 
 def _same_rows(name: str, got: list, want: list) -> bool:

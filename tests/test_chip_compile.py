@@ -210,6 +210,27 @@ def test_join_build_lookup_compiles_for_v5e(one_chip):
     assert "sort" in compiled.as_text()
 
 
+def test_key_set_dynamic_filter_compiles_for_v5e(one_chip):
+    """The dynamic filter by key set (exec/operators.py `_df_filter_set`,
+    PR 33) at the engine's batch against the largest build side it
+    takes: 2^20 x 2^12 equality tests have to stay ONE fused reduction
+    (the compare materialised would be 4 GB), which the compiled
+    program's scratch shows."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+
+    key = _sds((BATCH,), jnp.int64, one_chip)
+    batch = RelBatch([Column(T.BIGINT, key, None, None),
+                      Column(T.decimal(12, 2), key, None, None)], None)
+    compiled = O._df_filter_set.lower(
+        batch, (key, None),
+        _sds((O.DF_SET_MAX_SLOTS,), jnp.int32, one_chip),
+        _sds((), jnp.bool_, one_chip),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_distributed_groupby_step_compiles_for_four_v5e(topo):
     """The partial -> all_to_all -> final aggregation step as one SPMD
     program over the four described chips."""

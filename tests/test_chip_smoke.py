@@ -36,11 +36,13 @@ def served(loaded):
     return results, calls
 
 
-@pytest.mark.parametrize("name", ["q6", "g3", "q3", "q1", "point"])
+@pytest.mark.parametrize("name", ["q6", "g3", "q3", "q1", "q18", "point"])
 def test_served_statement_equals_numpy_reference(loaded, served, name):
     _, tables = loaded
     results, _ = served
-    assert results[name], name
+    # (no order of `tiny` sums to over Q18's validation QUANTITY, 300:
+    # its answer there is no row, from the engine and the reference both)
+    assert results[name] or name == "q18", name
     chip_smoke.compare_phase({name: results[name]}, tables, SEED)
 
 

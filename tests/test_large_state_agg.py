@@ -1,0 +1,506 @@
+"""The aggregation's large-state path and the filtering semi-join
+(issues 32 and 33): the sort path folds its group states FOLD_STATES at a time
+and sizes its last merge from the group counts the launches left; a
+semi-join is planned on the source that holds its key; a small build
+side filters probes by its key set and packs what is left. CPU counts
+and answers only; what any of it costs is a chip reading (PERF.md
+section 6)."""
+
+import collections
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests.oracle import assert_rows_match, oracle_rows
+from trino_tpu import types as T
+from trino_tpu.block import Column, RelBatch
+from trino_tpu.exec import operators as O
+from trino_tpu.exec.operators import AggSpec, HashAggregationOperator
+from trino_tpu.ops.int128 import from_python, to_python
+from trino_tpu.runtime.metrics import METRICS
+from trino_tpu.sql import plan as P
+
+BATCH = 256
+D = T.decimal(38, 2)
+SCHEMA = [(T.BIGINT, None), (D, None), (T.BIGINT, None)]
+AGGS = [AggSpec("sum", 1, D), AggSpec("count_star", None, T.BIGINT),
+        AggSpec("min", 2, T.BIGINT), AggSpec("max", 2, T.BIGINT)]
+
+
+def make_rows(n_batches, keys_per_batch, seed, overlap):
+    """(key, 128-bit value, small value) per row. Values sit just under
+    2^64 and 2^96, so a group's sum carries across every limb; `overlap`
+    makes every batch draw from the same keys (the merge's worst case),
+    else batch i has keys of its own (a clustered scan: Q18's)."""
+    rng = np.random.default_rng(seed)
+    rows = []
+    for b in range(n_batches):
+        base = 0 if overlap else b * keys_per_batch
+        keys = base + rng.integers(0, keys_per_batch, BATCH)
+        big = [(2**64 - 1 - int(x)) * (2**32 if i % 2 else 1) * (-1) ** (i % 3 == 0)
+               for i, x in enumerate(rng.integers(0, 1000, BATCH))]
+        small = rng.integers(-1000, 1000, BATCH)
+        rows.append((keys, big, small))
+    return rows
+
+
+def to_batch(keys, big, small):
+    pairs = [from_python(int(x)) for x in big]
+    limbs = np.stack([
+        np.array([p[0] for p in pairs], dtype=np.int64),
+        (np.array([p[1] % 2**64 for p in pairs], dtype=object)
+         .astype(np.uint64).view(np.int64)),
+    ], axis=1)
+    return RelBatch([
+        Column(T.BIGINT, jnp.asarray(keys, dtype=jnp.int64), None, None),
+        Column(D, jnp.asarray(limbs), None, None),
+        Column(T.BIGINT, jnp.asarray(small, dtype=jnp.int64), None, None),
+    ], None)
+
+
+def exact_rows(batch):
+    host = jax.device_get(batch)
+    live = np.asarray(host.live_mask())
+    out = []
+    for i in np.nonzero(live)[0]:
+        h, lo = np.asarray(host.columns[1].data)[i]
+        out.append((int(host.columns[0].data[i]), to_python(int(h), int(lo)),
+                    *(int(c.data[i]) for c in host.columns[2:])))
+    return sorted(out)
+
+
+def want_rows(rows):
+    groups = collections.defaultdict(lambda: [0, 0, None, None])
+    for keys, big, small in rows:
+        for k, v, s in zip(keys, big, small):
+            g = groups[int(k)]
+            g[0] += v
+            g[1] += 1
+            g[2] = int(s) if g[2] is None else min(g[2], int(s))
+            g[3] = int(s) if g[3] is None else max(g[3], int(s))
+    return sorted((k, *g) for k, g in groups.items())
+
+
+def aggregate(rows, watch=None):
+    names = ("agg_merge_launches", "agg_merge_retries", "agg_ingest_launches")
+    before = {k: METRICS.counter(k) for k in names}
+    agg = HashAggregationOperator([0], AGGS, SCHEMA)
+    for i, r in enumerate(rows):
+        agg.add_input(to_batch(*r))
+        if watch is not None:
+            watch(agg, i)
+    agg.finish()
+    return exact_rows(agg.get_output()), {
+        k: METRICS.counter(k) - v for k, v in before.items()}
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["shared-keys", "keys-of-its-own"])
+@pytest.mark.parametrize("n_batches", [1, 3, 7, 8, 9, 27, 64, 65, 70])
+def test_folding_step_by_step_equals_the_one_shot_merge(n_batches, overlap, monkeypatch):
+    """128-bit sums with carries, counts, min and max: folds of
+    FOLD_STATES (two tiers from 65 batches on: the 65th settles the
+    64th, whose fold fills tier 0) against ONE merge of all the states,
+    and both against python's integers."""
+    rows = make_rows(n_batches, 40, seed=32 + n_batches, overlap=overlap)
+    pending = []
+    folded, counts = aggregate(
+        rows, watch=lambda agg, i: pending.append(
+            (len(agg._pending), [len(t) for t in agg._folded])))
+    assert folded == want_rows(rows)
+    # what is pending is bounded by the tiers, never by the scan
+    assert max(p for p, _ in pending) <= O.FOLD_STATES
+    assert all(n < O.FOLD_STATES for _, tiers in pending for n in tiers)
+    # a fold once FOLD_STATES states have settled (the newest is still in
+    # flight), a fold of folds once FOLD_STATES of those are there, and
+    # the last merge of what is left
+    folds = (n_batches - 1) // O.FOLD_STATES
+    last = 1 if n_batches > 1 else 0        # one batch is one state: nothing to merge
+    assert counts["agg_merge_launches"] == folds + folds // O.FOLD_STATES + last
+    assert counts["agg_merge_retries"] == 0
+    monkeypatch.setattr(O, "FOLD_STATES", 10**6)      # never fold: the parent's merge
+    one_shot, counts = aggregate(rows)
+    assert one_shot == folded and counts["agg_merge_launches"] == last
+
+
+def test_the_last_merge_is_sized_by_the_group_counts_and_runs_once():
+    """Six batches of keys of their own, some 225 groups each. The
+    operator's table (1,024 slots) is too small for them all, and the
+    parent launched the merge there first and again after the overflow;
+    the counts the launches left add up to over 1,024, so the merge is
+    launched once, at 2,048."""
+    rows = make_rows(6, 1000, seed=7, overlap=False)
+    seen = []
+    inner = O._merge_group_states
+
+    def spy(states, reducers, out_capacity):
+        seen.append((len(states), out_capacity))
+        return inner(states, reducers, out_capacity)
+
+    try:
+        O._merge_group_states = spy
+        got, counts = aggregate(rows)
+    finally:
+        O._merge_group_states = inner
+    assert got == want_rows(rows) and 1024 < len(got) <= 6 * BATCH
+    assert seen == [(6, 2048)]
+    assert counts["agg_merge_launches"] == 1 and counts["agg_merge_retries"] == 0
+
+
+def test_a_fold_takes_its_states_slots_whatever_they_hold():
+    """A fold's table follows from its operands' shapes alone, so one
+    program serves every fold of a scan."""
+    rows = make_rows(17, 30, seed=3, overlap=True)
+    seen = []
+    inner = O._merge_group_states
+
+    def spy(states, reducers, out_capacity):
+        seen.append((tuple(int(s[2].shape[0]) for s in states), out_capacity))
+        return inner(states, reducers, out_capacity)
+
+    try:
+        O._merge_group_states = spy
+        got, _ = aggregate(rows)
+    finally:
+        O._merge_group_states = inner
+    assert got == want_rows(rows)
+    folds, last = seen[:-1], seen[-1]
+    assert len(folds) == 2 and all(len(caps) == O.FOLD_STATES for caps, _ in folds)
+    assert all(out == O.bucket_capacity(sum(caps)) for caps, out in folds)
+    # 2 folded, then what is left (1 state) made up to FOLD_STATES
+    assert len(last[0]) == 2 + O.FOLD_STATES and last[1] < sum(last[0])
+
+
+@pytest.mark.parametrize("n_batches", [17, 20, 24])
+def test_the_last_merge_of_a_folded_scan_has_one_shape_whatever_is_left(n_batches):
+    """Two folds and 1, 4 or 8 states left: empty states make up what is
+    left to FOLD_STATES, so the three scans hand their last merge the
+    same operand shapes (its table follows the group counts), and answer
+    right."""
+    rows = make_rows(n_batches, 30, seed=5, overlap=True)
+    seen = []
+    inner = O._merge_group_states
+
+    def spy(states, reducers, out_capacity):
+        seen.append((tuple(int(s[2].shape[0]) for s in states), out_capacity))
+        return inner(states, reducers, out_capacity)
+
+    try:
+        O._merge_group_states = spy
+        got, counts = aggregate(rows)
+    finally:
+        O._merge_group_states = inner
+    assert got == want_rows(rows)
+    assert counts["agg_merge_launches"] == 3 and counts["agg_merge_retries"] == 0
+    (fold_in, fold_out), (caps, out) = seen[0], seen[-1]
+    assert caps == (fold_out,) * 2 + (fold_in[0],) * O.FOLD_STATES
+    assert out < sum(caps)                                  # sized by the counts
+
+
+def test_revocation_between_folds_loses_nothing():
+    from trino_tpu.runtime.memory import MemoryContext, MemoryPool
+
+    rows = make_rows(20, 40, seed=11, overlap=True)
+    pool = MemoryPool(1 << 30)
+    agg = HashAggregationOperator([0], AGGS, SCHEMA,
+                                  memory_context=MemoryContext(pool))
+    for i, r in enumerate(rows):
+        agg.add_input(to_batch(*r))
+        if i in (4, 11):
+            agg._revoke_memory()
+            assert agg._pending == [] and agg._folded == [] and agg._acc is None
+    agg.finish()
+    assert exact_rows(agg.get_output()) == want_rows(rows)
+
+
+# -- the dynamic filter by key set -------------------------------------------------------
+
+
+def keyed_batch(keys, valid=None, live=None, payload=None):
+    keys = np.asarray(keys, dtype=np.int64)
+    payload = np.arange(len(keys)) if payload is None else payload
+    return RelBatch([
+        Column(T.BIGINT, jnp.asarray(keys), None if valid is None else jnp.asarray(valid), None),
+        Column(T.BIGINT, jnp.asarray(payload, dtype=jnp.int64), None, None),
+    ], None if live is None else jnp.asarray(live))
+
+
+def test_the_set_filter_keeps_the_rows_that_will_match_and_no_null():
+    probe = keyed_batch([5, 7, 9, 7, 11, 13, 5, 2],
+                        valid=[True, True, True, False, True, True, True, True],
+                        live=[True] * 7 + [False])
+    build_keys = jnp.asarray([7, 5, 2, 11], dtype=jnp.int64)
+    usable = jnp.asarray([True, True, True, False])                 # 11: a dead slot
+    key = (probe.columns[0].data, probe.columns[0].valid)
+    out, kept = O._df_filter_set(probe, key, *O._df_key_set(build_keys, usable))
+    assert int(kept) == 3
+    assert np.asarray(out.live_mask()).tolist() == [
+        True, True, False, False, False, False, True, False]
+    # no live key: nothing passes
+    out, kept = O._df_filter_set(probe, key, *O._df_key_set(
+        build_keys, jnp.zeros(4, dtype=jnp.bool_)))
+    assert int(kept) == 0 and not np.asarray(out.live_mask()).any()
+    # the comparison is on the low 32 bits: a key 2^32 away passes the
+    # filter (and the join, which compares whole keys, drops it)
+    far = keyed_batch([5 + 2**32, 6 + 2**32])
+    out, kept = O._df_filter_set(far, (far.columns[0].data, None),
+                                 *O._df_key_set(build_keys, usable))
+    assert np.asarray(out.live_mask()).tolist() == [True, False]
+
+
+def test_front_rows_packs_the_live_rows_in_order():
+    live = np.zeros(64, dtype=bool)
+    live[[3, 17, 40, 63]] = True
+    batch = keyed_batch(np.arange(64) * 10, live=live)
+    out = O._front_rows(batch, 16)
+    assert out.capacity == 16
+    assert np.asarray(out.live_mask()).tolist() == [True] * 4 + [False] * 12
+    assert np.asarray(out.columns[0].data)[:4].tolist() == [30, 170, 400, 630]
+    assert np.asarray(out.columns[1].data)[:4].tolist() == [3, 17, 40, 63]
+
+
+@pytest.mark.parametrize("build_rows, exact", [(100, True), (O.DF_SET_MAX_SLOTS + 1, False)])
+def test_a_small_build_filters_by_its_set_a_large_one_by_its_range(build_rows, exact):
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(np.arange(build_rows) * 2))       # even keys
+    sink.finish()
+    df = O.DynamicFilterOperator(bridge, [0])
+    probe = keyed_batch(np.arange(8192))                          # 0..8191
+    df.add_input(probe)
+    if not exact:
+        out = df.get_output()
+        assert int(np.asarray(out.live_mask()).sum()) == 8192 and out.capacity == 8192
+        return
+    # packed, and gathered with the next batches' survivors: nothing
+    # comes out until the gathered batch is full or the scan ends
+    assert df.get_output() is None and df.needs_input()
+    df.add_input(keyed_batch(np.arange(8192) + 100))              # 100..8291: 50 more
+    assert df.get_output() is None
+    df.finish()
+    out = df.get_output()
+    assert out.capacity == O.DF_PACK_MIN_SLOTS and df.get_output() is None and df.is_finished()
+    live = np.asarray(out.live_mask())
+    assert live.tolist() == [True] * 150 + [False] * (out.capacity - 150)
+    assert np.asarray(out.columns[0].data)[:150].tolist() == (
+        list(range(0, 200, 2)) + list(range(100, 200, 2)))
+
+
+def drain(op):
+    out = []
+    while (batch := op.get_output()) is not None:
+        out.append(batch)
+    return out
+
+
+def joined_keys(bridge, filtered):
+    """The probe keys an inner join emits for the batches a dynamic
+    filter let through (the build side's keys are distinct)."""
+    join = O.LookupJoinOperator(bridge, [0], "inner", [(T.BIGINT, None), (T.BIGINT, None)])
+    keys = []
+    for batch in filtered:
+        join.add_input(batch)
+        keys += [np.asarray(b.columns[0].data)[np.asarray(b.live_mask())] for b in drain(join)]
+    join.finish()
+    keys += [np.asarray(b.columns[0].data)[np.asarray(b.live_mask())] for b in drain(join)]
+    return sorted(np.concatenate(keys).tolist()) if keys else []
+
+
+@pytest.mark.parametrize("build_rows", [0, 1, O.DF_SET_MAX_SLOTS, O.DF_SET_MAX_SLOTS + 1])
+def test_the_set_filter_and_the_range_filter_hand_the_join_the_same_matches(build_rows):
+    """Random keys, three probe batches with NULLs and dead rows: the
+    key-set filter's survivors joined equal the range filter's survivors
+    joined equal what numpy says matches; up to DF_SET_MAX_SLOTS build
+    slots the filter itself is the set's (it keeps the matches and
+    nothing else), one above it is the range's."""
+    rng = np.random.default_rng(33 + build_rows)
+    build_keys = rng.choice(1 << 16, size=build_rows, replace=False)
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(build_keys) if build_rows else keyed_batch(
+        [0] * 16, live=[False] * 16))
+    sink.finish()
+    probes, matches = [], []
+    for _ in range(3):
+        keys = rng.integers(0, 1 << 16, 8192)
+        valid, live = rng.random(8192) < 0.95, rng.random(8192) < 0.9
+        probes.append(keyed_batch(keys, valid=valid, live=live))
+        matches += keys[valid & live & np.isin(keys, build_keys)].tolist()
+    by = {}
+    for name in ("chosen", "range"):
+        df = O.DynamicFilterOperator(bridge, [0])
+        filtered = []
+        for probe in probes:
+            if name == "range" and df._active_channels is None:
+                df._prepare(probe)
+                if df._key_set is not None:
+                    df._use_range()
+            df.add_input(probe)
+            filtered += drain(df)
+        df.finish()
+        filtered += drain(df)
+        by[name] = (df, filtered)
+    chosen, filtered = by["chosen"]
+    assert (chosen._domains is None) is (build_rows <= O.DF_SET_MAX_SLOTS)
+    assert by["range"][0]._key_set is None
+    if chosen._domains is None:
+        kept = [np.asarray(b.columns[0].data)[np.asarray(b.live_mask())] for b in filtered]
+        assert sorted(np.concatenate(kept).tolist() if kept else []) == sorted(matches)
+    assert joined_keys(bridge, filtered) == joined_keys(bridge, by["range"][1]) == sorted(matches)
+
+
+@pytest.mark.parametrize("second_keeps, then_by", [(1500, "set"), (8192, "range")])
+def test_a_batch_that_keeps_more_than_the_gathered_batch_holds_ends_the_readbacks(
+        second_keeps, then_by):
+    """The set is not that selective on this probe: from that batch on
+    the filter hands on what it masked, at the batch's own capacity and
+    without reading a count; where the batch kept over a quarter of its
+    slots, by the build side's range, as a large build side's filter."""
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(np.arange(600) * 2))               # even keys 0..1198
+    sink.finish()
+    df = O.DynamicFilterOperator(bridge, [0])
+    df.add_input(keyed_batch(np.arange(8192)))                    # keeps 600: gathered
+    assert df.get_output() is None
+    keys = np.full(8192, -1)
+    keys[:second_keeps] = (np.arange(second_keeps) % 600) * 2
+    df.add_input(keyed_batch(keys))
+    gathered, whole = df.get_output(), df.get_output()
+    assert gathered.capacity == O.DF_PACK_MIN_SLOTS and whole.capacity == 8192
+    assert int(np.asarray(whole.live_mask()).sum()) == second_keeps and not df._gathering
+    syncs = []
+    inner = O.host_sync
+    try:
+        O.host_sync = lambda site, *a, **k: syncs.append(site) or inner(site, *a, **k)
+        df.add_input(keyed_batch(np.arange(8192)))
+    finally:
+        O.host_sync = inner
+    out = df.get_output()
+    assert out.capacity == 8192 and syncs == []
+    assert int(np.asarray(out.live_mask()).sum()) == {"set": 600, "range": 1199}[then_by]
+    df.finish()
+    assert df.get_output() is None and df.is_finished()
+
+
+def test_the_gathered_batch_is_emitted_when_the_next_would_overfill_it():
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(np.arange(700)))                   # keys 0..699
+    sink.finish()
+    df = O.DynamicFilterOperator(bridge, [0])
+    df.add_input(keyed_batch(np.arange(8192)))                    # keeps 700
+    assert df.get_output() is None
+    df.add_input(keyed_batch(np.arange(8192) - 7600))             # keeps 592: 1,292 in all
+    first = df.get_output()
+    assert int(np.asarray(first.live_mask()).sum()) == 700 and df.get_output() is None
+    df.finish()
+    second = df.get_output()
+    assert int(np.asarray(second.live_mask()).sum()) == 592 and df.is_finished()
+
+
+@pytest.mark.parametrize("live_rows, on_device", [(66, True), (6000, False)])
+def test_a_sparse_build_side_is_packed_on_the_device_when_small(live_rows, on_device,
+                                                                monkeypatch):
+    """What a HAVING leaves of a large group table: up to
+    `_DEVICE_PACK_MAX_SLOTS` rows are picked out on the device, so the
+    table's slots never cross to the host; a wider build keeps the
+    host's pass. Either way the lookup source holds the live rows."""
+    from trino_tpu.exec import serde
+
+    crossed = []
+    inner = serde.Page.from_batch
+    monkeypatch.setattr(serde.Page, "from_batch",
+                        staticmethod(lambda b: crossed.append(b.capacity) or inner(b)))
+    n = O._SHRINK_MIN_CAPACITY
+    live = np.zeros(n, dtype=bool)
+    live[np.linspace(0, n - 1, live_rows).astype(int)] = True
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(np.arange(n) * 3, live=live))
+    sink.finish()
+    build = bridge.build_batch
+    assert build.capacity == O.bucket_capacity(live_rows)
+    assert (crossed == []) is on_device
+    kept = np.asarray(build.columns[0].data)[np.asarray(build.live_mask())]
+    assert kept.tolist() == (np.nonzero(live)[0] * 3).tolist()
+
+
+# -- the semi-join: where it is planned, what it answers ------------------------------------
+
+
+def plan_of(runner, sql):
+    text = runner.execute("explain " + sql).rows[0][0]
+    return [line for line in text.splitlines() if line.strip()]
+
+
+def depth_of(plan, prefix):
+    return [len(line) - len(line.lstrip()) for line in plan
+            if line.lstrip().startswith(prefix)]
+
+
+def test_a_semi_join_is_planned_on_the_source_that_holds_its_key(tpch_local):
+    plan = plan_of(tpch_local, """
+        select o_orderkey, c_name from customer, orders
+        where c_custkey = o_custkey
+        and o_orderkey in (select l_orderkey from lineitem where l_quantity > 49)""")
+    (semi,), (inner,) = depth_of(plan, "Join semi"), depth_of(plan, "Join inner")
+    assert semi > inner
+    below = plan[[i for i, l in enumerate(plan) if l.lstrip().startswith("Join semi")][0] + 1]
+    assert "orders" in below
+
+
+def test_a_semi_join_stays_above_an_outer_join(tpch_local):
+    plan = plan_of(tpch_local, """
+        select c_custkey, o_orderkey from customer left join orders on c_custkey = o_custkey
+        where o_orderkey in (select l_orderkey from lineitem where l_quantity > 49)""")
+    (semi,), (left,) = depth_of(plan, "Join semi"), depth_of(plan, "Join left")
+    assert semi < left
+
+
+def test_the_rule_moves_a_semi_join_through_filter_project_and_join():
+    from trino_tpu.expr import ir
+    from trino_tpu.sql.optimizer import IterativeOptimizer, PushSemiJoinDown
+
+    def values(n):
+        return P.ValuesNode(tuple(P.Field(f"c{i}", T.BIGINT) for i in range(n)), ())
+
+    a, b, s = values(2), values(3), values(1)
+    join = P.JoinNode("inner", a, b, (0,), (1,), None, a.fields + b.fields)
+    keep = P.FilterNode(join, ir.comparison(
+        "gt", ir.InputRef(0, T.BIGINT), ir.Literal(1, T.BIGINT)), join.fields)
+    swap = P.ProjectNode(
+        keep, (ir.InputRef(3, T.BIGINT), ir.InputRef(0, T.BIGINT)),
+        (P.Field("x", T.BIGINT), P.Field("y", T.BIGINT)))
+    semi = P.JoinNode("semi", swap, s, (0,), (0,), None, swap.fields)
+    out = IterativeOptimizer((PushSemiJoinDown(),)).optimize(semi)
+    # Project(Filter(Join(a, Semi(b, s)))): channel 0 of the projection
+    # is channel 3 of the join, which is channel 1 of its right side
+    assert isinstance(out, P.ProjectNode) and isinstance(out.child, P.FilterNode)
+    moved = out.child.child.right
+    assert isinstance(moved, P.JoinNode) and moved.kind == "semi"
+    assert moved.left == b and moved.left_keys == (1,) and moved.fields == b.fields
+    # a semi-join with a residual reads both sides' columns: it stays
+    residual = P.JoinNode("semi", swap, s, (0,), (0,), ir.comparison(
+        "gt", ir.InputRef(1, T.BIGINT), ir.InputRef(2, T.BIGINT)), swap.fields)
+    assert IterativeOptimizer((PushSemiJoinDown(),)).optimize(residual) == residual
+
+
+@pytest.mark.parametrize("name, having", [
+    ("an-empty-build", "sum(l_quantity) > 100000"),
+    ("every-key", "sum(l_quantity) > 0"),
+    ("a-few-keys", "sum(l_quantity) > 270"),
+])
+def test_semi_join_over_joins_answers_what_the_oracle_answers(name, having, tpch_local):
+    sql = f"""
+        select c_custkey, o_orderkey, sum(l_quantity), count(*)
+        from customer, orders, lineitem
+        where o_orderkey in (select l_orderkey from lineitem group by l_orderkey
+                             having {having})
+        and c_custkey = o_custkey and o_orderkey = l_orderkey
+        group by c_custkey, o_orderkey"""
+    got = tpch_local.execute(sql).rows
+    want = oracle_rows(0.01, sql)
+    assert len(want) == {"an-empty-build": 0, "every-key": 15000}.get(name, len(want))
+    assert_rows_match(got, want, ordered=False)

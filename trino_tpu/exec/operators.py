@@ -1264,6 +1264,30 @@ def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
     )
 
 
+@partial(jax.jit, static_argnames=("capacity",))
+def _pad_state(state: tuple, capacity: int):
+    """A group state with unused slots appended up to `capacity`."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.pad(a, (0, capacity - a.shape[0])), state
+    )
+
+
+@jax.jit
+def _empty_state(state: tuple):
+    """A group state of `state`'s shape with no slot used."""
+    return jax.tree_util.tree_map(jnp.zeros_like, state)
+
+
+def _common_capacity(states: list) -> list:
+    """The states of one tier at the largest capacity among them: the
+    merge programs of a long scan then differ by how many states they
+    take, not by which of them came out of a smaller batch."""
+    top = max(int(s[2].shape[0]) for s in states)
+    return [
+        s if int(s[2].shape[0]) == top else _pad_state(s, top) for s in states
+    ]
+
+
 @jax.jit
 def _any_flags(flags: tuple):
     return jnp.any(jnp.stack(flags))
@@ -1338,6 +1362,15 @@ def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
         batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims
     )[0]
 
+
+# States one fold of the sort path merges (HashAggregationOperator.
+# _fold_settled_locked). A scan of N batches leaves N group states; held
+# until finish they are N operands of one program (a program per N, and
+# every state resident until the last batch). Folding eight at a time
+# keeps at most 8 states a tier pending, gives every fold the same arity
+# whatever the scan's length, and costs one more pass over the states
+# per tier: 58 batches are 7 folds and one last merge of 9 states.
+FOLD_STATES = 8
 
 # Batches one launch of _agg_ingest_train takes. A launch costs the host
 # about as much as the device needs for two batches of 2^20 rows (PERF.md
@@ -1546,6 +1579,16 @@ class HashAggregationOperator(Operator):
         # the next materialization point
         self._acc = None
         self._pending: List[tuple] = []
+        # per pending state, what bounds its groups: the group count its
+        # launch left on the device (read when a merge is sized), or
+        # None where only the state's capacity does (trains, wire input)
+        self._pending_groups: List = []
+        self._acc_groups = None
+        # sort path: tier t holds (state, group count) pairs that t + 1
+        # folds of FOLD_STATES states produced (_fold_settled_locked)
+        self._folded: List[List[tuple]] = []
+        # merges launched / retried under _state_lock, not yet in METRICS
+        self._merges = [0, 0]
         # a state ingested off the wire (_add_state_input) may carry
         # DUPLICATE group keys within one batch (a spooled-stage replay
         # concatenates several producer pages into one values batch), so
@@ -1717,7 +1760,7 @@ class HashAggregationOperator(Operator):
             # a runtime dictionary outgrowing the plan-time one)
             self._deferred_ovf.append(ovf)
             with self._state_lock:
-                self._pending.append(new)
+                self._push_pending_locked(new)
         else:
             # Deferred rehash: reading `ovf` here would stall the host
             # on the device PER BATCH. The flag + group count start an
@@ -1732,13 +1775,19 @@ class HashAggregationOperator(Operator):
                 except AttributeError:
                     pass
             with self._state_lock:
-                self._pending.append(new)
+                self._push_pending_locked(new, ngroups)
                 self._pending_meta.append(
                     (len(self._pending) - 1, ovf, ngroups, batch, cap)
                 )
                 while len(self._pending_meta) > 1:
                     self._resolve_one_locked()
+                self._fold_settled_locked()
+            self._report_launches()
         self._track_memory()
+
+    def _push_pending_locked(self, state: tuple, groups=None) -> None:
+        self._pending.append(state)
+        self._pending_groups.append(groups)
 
     def _train_layout(self, batch: RelBatch):
         """What the batches of one train have in common, so that one
@@ -1787,7 +1836,7 @@ class HashAggregationOperator(Operator):
         # verify ONCE at finish (fail-loud guard against a runtime
         # dictionary outgrowing the plan-time one)
         self._deferred_ovf.append(ovf)
-        self._pending.append(
+        self._push_pending_locked(
             (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
         )
 
@@ -1796,8 +1845,13 @@ class HashAggregationOperator(Operator):
         _state_lock: METRICS takes a lock of its own, so outside it."""
         with self._state_lock:
             n, self._launched = self._launched, 0
+            (merges, retries), self._merges = self._merges, [0, 0]
         if n:
             METRICS.increment("agg_ingest_launches", n)
+        if merges:
+            METRICS.increment("agg_merge_launches", merges)
+        if retries:
+            METRICS.increment("agg_merge_retries", retries)
 
     def _resolve_one_locked(self) -> None:
         """Settle the OLDEST deferred per-batch overflow record; its
@@ -1817,24 +1871,52 @@ class HashAggregationOperator(Operator):
             self._pending[idx] = (
                 tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts)
             )
+            self._pending_groups[idx] = ngroups
 
     def _resolve_pending_locked(self) -> None:
         """Drain every deferred overflow record (merge points)."""
         while self._pending_meta:
             self._resolve_one_locked()
 
-    def _merge_pending_locked(self) -> None:
-        """Fold _pending (+ current acc) into ONE merged state with a
-        single N-way device program (caller holds _state_lock)."""
-        self._flush_held_locked()
-        self._resolve_pending_locked()
-        states = ([self._acc] if self._acc is not None else []) + self._pending
-        self._pending = []
-        if not states:
+    def _fold_settled_locked(self) -> None:
+        """Sort path: once FOLD_STATES ingest states have settled (their
+        overflow flags read; the newest, still in flight, stays), merge
+        them into ONE state of the next tier, and so on up the tiers
+        (caller holds _state_lock). What is pending is then bounded by
+        the tiers, not by the scan, and every fold has FOLD_STATES
+        operands."""
+        if len(self._pending) - len(self._pending_meta) < FOLD_STATES:
             return
-        if len(states) == 1 and not self._unreduced_state:
-            self._acc = states[0]
-            return
+        states = _common_capacity(self._pending[:FOLD_STATES])
+        del self._pending[:FOLD_STATES]
+        del self._pending_groups[:FOLD_STATES]
+        self._pending_meta = [
+            (idx - FOLD_STATES, *rest) for idx, *rest in self._pending_meta
+        ]
+        tier = 0
+        while True:
+            folded = self._merge_states_locked(states)
+            if len(self._folded) == tier:
+                self._folded.append([])
+            self._folded[tier].append(folded)
+            if len(self._folded[tier]) < FOLD_STATES:
+                return
+            full, self._folded[tier] = self._folded[tier], []
+            states = _common_capacity([s for s, _ in full])
+            tier += 1
+
+    def _merge_states_locked(self, states: list, groups=None):
+        """ONE device program merges `states` into one; returns (state,
+        its group count on the device). The table is sized from what
+        the states can hold between them, so the merge does not
+        overflow on a count and runs once: a fold (`groups` None) takes
+        the states' slots (its shape then follows from theirs alone,
+        and one program serves every fold of a scan); the last merge
+        takes the group counts the launches left (`groups`, one a
+        state; None: the state's capacity), which is what the output is
+        sized by. The flag still covers sort_group_reduce's
+        hash-collision detector, whose retry doubles the table to
+        reseed."""
         reducers = []
         for i, x in enumerate(self._aggs):
             reducers.extend(_slot_merge_reducers(x, self._arg_meta[i][0]))
@@ -1842,21 +1924,76 @@ class HashAggregationOperator(Operator):
         # distinct groups across N states cannot exceed the concatenated
         # slot count, so the merge table caps there (bounds the output
         # arrays by the data, not by a possibly-overgrown _cap)
-        concat_len = sum(int(s[2].shape[0]) for s in states)
-        while True:
+        caps = [int(s[2].shape[0]) for s in states]
+        concat_len = sum(caps)
+        if self._static_bound is not None:
             cap = min(
                 max(self._cap, 16), bucket_capacity(max(concat_len, 16))
             )
-            merged, ngroups, ovf = _merge_group_states(
-                tuple(states), reducers, cap
-            )
+        elif groups is None:
+            cap = bucket_capacity(concat_len)
+        else:
+            with host_sync("agg.merge_groups", 4 * len(states)) as span:
+                counts = jax.device_get(
+                    [0 if g is None else g for g in groups]
+                )
+                bound = sum(
+                    c if g is None else min(int(n), c)
+                    for c, g, n in zip(caps, groups, counts)
+                )
+                span.set_metadata(groups=bound)
+            cap = bucket_capacity(max(bound, 16))
+        retry = 0
+        while True:
+            with host_span("agg.merge", states=len(states),
+                           slots_in=concat_len, cap=cap, retry=retry):
+                merged, ngroups, ovf = _merge_group_states(
+                    tuple(states), reducers, cap
+                )
+            self._merges[0] += 1
             if self._static_bound is not None:
                 self._deferred_ovf.append(ovf)
                 break
             if not _flag("agg.merge_overflow", ovf):
                 break
-            self._cap = max(self._cap * 2, bucket_capacity(int(ngroups)))
-        self._acc = merged
+            retry += 1
+            self._merges[1] += 1
+            cap = max(cap * 2, bucket_capacity(int(ngroups)))
+            self._cap = max(self._cap, cap)
+        return merged, ngroups
+
+    def _merge_pending_locked(self) -> None:
+        """Fold the current acc, the folded tiers and _pending into ONE
+        merged state with a single N-way device program (caller holds
+        _state_lock)."""
+        self._flush_held_locked()
+        self._resolve_pending_locked()
+        states = [] if self._acc is None else [self._acc]
+        groups = [] if self._acc is None else [self._acc_groups]
+        for tier in reversed(self._folded):  # oldest rows first
+            if tier:
+                states.extend(_common_capacity([s for s, _ in tier]))
+                groups.extend(g for _, g in tier)
+        pending, left = self._pending, self._pending_groups
+        if self._folded and pending:
+            # a scan long enough to fold brings what is left to one
+            # shape and one count, empty states making it up: the last
+            # merge's program then follows from the tiers, not from the
+            # scan's length modulo FOLD_STATES. A short scan merges its
+            # states as they are.
+            pending = _common_capacity(pending)
+            spare = FOLD_STATES - len(pending)
+            pending = pending + [_empty_state(pending[0])] * spare
+            left = left + [0] * spare
+        states.extend(pending)
+        groups.extend(left)
+        self._pending, self._pending_groups, self._folded = [], [], []
+        if not states:
+            return
+        if len(states) == 1 and not self._unreduced_state:
+            self._acc, self._acc_groups = states[0], groups[0]
+            return
+        self._acc, self._acc_groups = self._merge_states_locked(states, groups)
         self._unreduced_state = False
 
     # -- final step: consume serialized accumulator state --
@@ -1900,7 +2037,7 @@ class HashAggregationOperator(Operator):
                 cnts.append(cnt)
         new = (tuple(keys), tuple(valids), live, tuple(vals), tuple(cnts))
         with self._state_lock:
-            self._pending.append(new)
+            self._push_pending_locked(new)
             self._unreduced_state = True
         self._track_memory()
 
@@ -2360,7 +2497,7 @@ class HashAggregationOperator(Operator):
 
                 self._spiller = FileSpiller()
             self._spiller.spill(self._partial_state_batch())
-            self._acc = None
+            self._acc = self._acc_groups = None
         self._report_launches()
         self._track_memory()
 
@@ -2375,7 +2512,9 @@ class HashAggregationOperator(Operator):
         from trino_tpu.runtime.memory import batch_bytes
 
         total = 0
-        for st in ([self._acc] if self._acc is not None else []) + list(self._pending):
+        folded = [st for tier in list(self._folded) for st, _ in tier]
+        for st in ([self._acc] if self._acc is not None else []) \
+                + folded + list(self._pending):
             gk, gv, used, vals, cnts = st
             for arr in [*gk, *gv, used, *vals, *cnts]:
                 total += arr.size * arr.dtype.itemsize
@@ -2390,7 +2529,8 @@ class HashAggregationOperator(Operator):
         except Exception:
             # pool exhausted even after revoking others: spill our own
             # state (self-revocation) and account the reset footprint
-            if self._acc is None and not self._pending and not self._held:
+            if self._acc is None and not self._pending and not self._held \
+                    and not any(self._folded):
                 raise
             self._revoke_memory()
             return
@@ -2534,7 +2674,9 @@ class HashAggregationOperator(Operator):
                 and self._mxu_dims is None:
             # sort-path group rows are prefix-dense: hand downstream
             # operators the live size, not the table capacity
-            out = _shrink_prefix(out, _count("agg.group_rows", used))
+            groups = _count("agg.group_rows", used)
+            METRICS.increment("agg_groups_out", groups)
+            out = _shrink_prefix(out, groups)
         self._out = out
 
     def get_output(self) -> Optional[RelBatch]:
@@ -2597,6 +2739,13 @@ GRACE_PARTITIONS = 8
 # ops/groupby.py — while sort itself compiles in ~20-60s at any
 # multi-million-row shape. Compaction remains worthwhile for runtime.)
 _SHRINK_MIN_CAPACITY = 1 << 17
+# up to this many live rows a sparse build side is packed on the device
+# (_pack_parts: top_k over the live positions, small gathers: 37 ms for
+# 66 live rows in 2^24 slots of two 8-byte columns on a v5e); the host's
+# pass brings every slot over first (84 ms there, and more a column;
+# PERF.md section 6, PR 33) and is kept for the wide outputs a top_k is
+# no good at
+_DEVICE_PACK_MAX_SLOTS = 1 << 12
 
 
 def _flag(site: str, flag) -> bool:
@@ -2607,9 +2756,12 @@ def _flag(site: str, flag) -> bool:
 
 
 def _count(site: str, mask) -> int:
-    """Live rows of a device mask, read back (`sync.<site>`)."""
-    with host_sync(site, 8):
-        return int(jnp.sum(mask))
+    """Live rows of a device mask, read back (`sync.<site>`, with the
+    count as its stat `rows`)."""
+    with host_sync(site, 8) as span:
+        n = int(jnp.sum(mask))
+        span.set_metadata(rows=n)
+    return n
 
 
 def _shrink_prefix(batch: RelBatch, live_count: int) -> RelBatch:
@@ -2733,20 +2885,24 @@ class HashBuildSink(Operator):
             # sparse build side (e.g. a HAVING-filtered aggregate):
             # host-compact so the lookup build and every probe compile
             # at the live size, not the upstream capacity
-            with host_sync("join.build_rows", 4 * len(parts)):
+            with host_sync("join.build_rows", 4 * len(parts)) as span:
                 counts = jax.device_get(
                     [jnp.sum(b.live_mask().astype(jnp.int32)) for b in parts]
                 )
-            n_live = int(sum(int(c) for c in counts))
+                n_live = int(sum(int(c) for c in counts))
+                span.set_metadata(rows=n_live)
             target = max(bucket_capacity(n_live), 16)
             if target * 4 <= total_cap:
-                from trino_tpu.exec.serde import Page as _Page
-                from trino_tpu.exec.serde import concat_pages
+                if target <= _DEVICE_PACK_MAX_SLOTS:
+                    parts = (_pack_parts(parts, target),)
+                else:
+                    from trino_tpu.exec.serde import Page as _Page
+                    from trino_tpu.exec.serde import concat_pages
 
-                merged_host = concat_pages(
-                    [_Page.from_batch(b) for b in parts]
-                )
-                parts = (merged_host.to_batch(target),)
+                    merged_host = concat_pages(
+                        [_Page.from_batch(b) for b in parts]
+                    )
+                    parts = (merged_host.to_batch(target),)
         ls, merged = _consolidate_build(parts, tuple(self._keys))
         self._bridge.lookup_source = ls
         self._bridge.build_batch = merged
@@ -3155,8 +3311,9 @@ class LookupJoinOperator(Operator):
         if not rec.get("remapped") and self._bridge.build_key_channels:
             pkc = tuple(self._keys)
             bkc = tuple(self._bridge.build_key_channels)
-        with host_sync("join.match_total", 8):
+        with host_sync("join.match_total", 8) as span:
             total = int(rec["total"])
+            span.set_metadata(rows=total, probe_slots=probe.capacity)
         dense = total * 4 >= rec["probe"].capacity
         if dense and "fan1" in rec and _flag("join.fanout_one", rec["fan1"]):
             # fanout<=1 (PK-side FK join) AND most probe rows match:
@@ -3351,6 +3508,65 @@ def _df_filter(batch: RelBatch, keys, domains):
     return batch.mask(keep)
 
 
+# A build side of at most this many slots, on one integer key, filters
+# probes by its key SET, not by its key range: every probe key is
+# compared with every build key, on their low 32 bits (one fused
+# compare-and-reduce, no gather, sort or scatter; a filter may pass a
+# row the join then drops, never drop one that matches). A 2^20-row
+# batch against 128 / 1,024 / 4,096 keys takes 2.1 / 2.4 / 4.9 ms on a
+# v5e, launch included, where the range takes 1.1 and the probe the
+# filter spares (probe_counts) 16.3 (PERF.md section 6, PR 33); larger
+# sets were not measured, and the range is what they get.
+DF_SET_MAX_SLOTS = 1 << 12
+# The slots of the ONE batch the set filter gathers a scan's survivors
+# into, and the most rows a batch may keep and still be gathered: below
+# it a sort is no faster, and every smaller power of two would be one
+# more shape for each of the join's programs to compile at.
+DF_PACK_MIN_SLOTS = 1 << 10
+
+
+@jax.jit
+def _df_key_set(build_keys, usable):
+    """What `_df_filter_set` compares with: the low 32 bits of the build
+    side's keys, a dead or NULL slot repeating a live one, and whether
+    there is a live one at all."""
+    low = build_keys.astype(jnp.int32)
+    return jnp.where(usable, low, low[jnp.argmax(usable)]), jnp.any(usable)
+
+
+@jax.jit
+def _df_filter_set(batch: RelBatch, key, key_set, any_key):
+    """Keep the probe rows whose key is one of the build side's, by its
+    low 32 bits (NULL keys never match an inner/semi join). Returns
+    (batch, rows kept)."""
+    c_data, c_valid = key
+    low = c_data.astype(jnp.int32)
+    hit = jnp.any(low[:, None] == key_set[None, :], axis=1) & any_key
+    keep = batch.live_mask() & hit
+    if c_valid is not None:
+        keep = keep & c_valid
+    return batch.mask(keep), jnp.sum(keep.astype(jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _front_rows(batch: RelBatch, capacity: int) -> RelBatch:
+    """The first `capacity` live rows of a sparse batch, in order, as a
+    batch of that capacity: top_k over the live positions and one
+    `capacity`-sized gather a column (no full-length sort)."""
+    n = batch.capacity
+    pos = jnp.where(
+        batch.live_mask(), jnp.arange(n, dtype=jnp.int32), jnp.int32(n)
+    )
+    first = -jax.lax.top_k(-pos, capacity)[0]
+    return RelBatch([c.gather(first) for c in batch.columns], first < n)
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _pack_parts(parts: Tuple[RelBatch, ...], capacity: int) -> RelBatch:
+    """The live rows of `parts`, in order, as ONE batch of `capacity`."""
+    return _front_rows(concat_batches(list(parts)), capacity)
+
+
 class DynamicFilterOperator(Operator):
     """Probe-side pruning from build-side key domains — the LOCAL form
     of dynamic filtering (DynamicFilterSourceOperator + DynamicFilter
@@ -3360,14 +3576,31 @@ class DynamicFilterOperator(Operator):
     shipped to remote scan fragments) rides the same domain computation.
     Applies to inner/semi probes only; dictionary-coded keys are skipped
     unless both sides share the dictionary (code order is only
-    meaningful within one dictionary)."""
+    meaningful within one dictionary).
+
+    A small build side on one integer key (DF_SET_MAX_SLOTS) filters by
+    its key set: the probe then sees only rows that will match. While
+    the batches keep no more rows than one small batch holds
+    (DF_PACK_MIN_SLOTS), their count is read back and the survivors of
+    successive batches are gathered into that one batch (emitted when
+    it is full, and at finish) before the join sorts anything; the
+    first batch that keeps more ends the reading, and the rest of the
+    scan leaves masked and unread: by the set still, or by the range
+    where that batch kept over a quarter of its slots."""
 
     def __init__(self, bridge: JoinBridge, key_channels: Sequence[int]):
         self._bridge = bridge
         self._keys = list(key_channels)
         self._domains = None
+        self._key_set = None
         self._active_channels: Optional[List[int]] = None
-        self._out: Optional[RelBatch] = None
+        self._outs: List[RelBatch] = []
+        # the set filter's survivors, gathered over the scan's batches
+        # into ONE batch of DF_PACK_MIN_SLOTS slots: the join and what
+        # follows it then run once, not once a scan batch
+        self._gathering = True
+        self._gathered: Optional[RelBatch] = None
+        self._gathered_rows = 0
 
     def _prepare(self, probe: RelBatch) -> None:
         build = self._bridge.build_batch
@@ -3385,33 +3618,83 @@ class DynamicFilterOperator(Operator):
             elif key_dicts[i] is not None and key_dicts[i] == probe_dict:
                 active.append((i, c))
         self._active_channels = active
-        if active:
-            all_domains = _df_domains(
-                build, tuple(self._bridge.build_key_channels)
+        key = None
+        if len(active) == 1 and build.capacity <= DF_SET_MAX_SLOTS:
+            key = build.columns[self._bridge.build_key_channels[active[0][0]]]
+        if key is not None and jnp.issubdtype(key.data.dtype, jnp.integer):
+            self._key_set = _df_key_set(
+                key.data, build.live_mask() & key.valid_mask()
             )
-            self._domains = [all_domains[i] for i, _ in active]
+        elif active:
+            self._use_range()
+
+    def _use_range(self) -> None:
+        all_domains = _df_domains(
+            self._bridge.build_batch, tuple(self._bridge.build_key_channels)
+        )
+        self._domains = [all_domains[i] for i, _ in self._active_channels]
+        self._key_set = None
 
     def needs_input(self) -> bool:
-        return self._out is None and not self._finishing
+        return not self._outs and not self._finishing
 
     def add_input(self, batch: RelBatch) -> None:
         if self._active_channels is None:
             self._prepare(batch)
         if not self._active_channels:
-            self._out = batch
+            self._outs.append(batch)
             return
         keys = tuple(
             (batch.columns[c].data, batch.columns[c].valid)
             for _, c in self._active_channels
         )
-        self._out = _df_filter(batch, keys, tuple(self._domains))
+        if self._key_set is None:
+            self._outs.append(_df_filter(batch, keys, tuple(self._domains)))
+            return
+        out, kept = _df_filter_set(batch, keys[0], *self._key_set)
+        if not self._gathering or out.capacity < 4 * DF_PACK_MIN_SLOTS:
+            # (a batch that small gains nothing from being packed)
+            self._outs.append(out)
+            return
+        with host_sync("join.dynamic_filter", 4) as span:
+            kept = int(kept)
+            span.set_metadata(rows=kept)
+        if not kept:
+            return
+        if kept > DF_PACK_MIN_SLOTS:
+            # the set is not that selective here: no more readbacks, and
+            # where it keeps most of a batch, no more of its compares
+            # (4.9 ms a 2^20-row batch at 4,096 keys against the range's
+            # 1.1, PERF.md section 6, PR 33): the range from here on
+            self._gathering = False
+            if kept * 4 > out.capacity:
+                self._use_range()
+            self._emit_gathered()
+            self._outs.append(out)
+            return
+        if self._gathered_rows + kept > DF_PACK_MIN_SLOTS:
+            self._emit_gathered()
+        packed = _front_rows(out, DF_PACK_MIN_SLOTS)
+        self._gathered = packed if self._gathered is None else _pack_parts(
+            (self._gathered, packed), DF_PACK_MIN_SLOTS
+        )
+        self._gathered_rows += kept
+
+    def _emit_gathered(self) -> None:
+        if self._gathered is not None:
+            self._outs.append(self._gathered)
+        self._gathered, self._gathered_rows = None, 0
+
+    def finish(self) -> None:
+        if not self._finishing:
+            self._finishing = True
+            self._emit_gathered()
 
     def get_output(self) -> Optional[RelBatch]:
-        out, self._out = self._out, None
-        return out
+        return self._outs.pop(0) if self._outs else None
 
     def is_finished(self) -> bool:
-        return self._finishing and self._out is None
+        return self._finishing and not self._outs
 
 
 def dynamic_filter_constraints(

@@ -966,6 +966,52 @@ class PushAggregationThroughOuterJoin(Rule):
         return P.ProjectNode(new_join, tuple(exprs), node.fields)
 
 
+class PushSemiJoinDown(Rule):
+    """A filtering semi-join belongs on the narrowest source that has
+    its key (PredicatePushDown.java plans the SemiJoinNode on the
+    source that supplies its value symbol). The analyzer wraps the
+    whole FROM clause in the semi-join of an IN (subquery), so every
+    row of every join is carried out before the IN set is asked;
+    a semi-join without a residual only drops probe rows, so it
+    commutes with an inner or cross join (onto the side that holds the
+    key), with a filter, and with a projection that passes the key
+    through. The joins above then see the surviving rows only, and the
+    join's own dynamic filter carries the set across its key equality
+    to the other side's scan."""
+
+    name = "push_semi_join_down"
+
+    def apply(self, node, ctx):
+        if (not isinstance(node, P.JoinNode) or node.kind != "semi"
+                or node.residual is not None or len(node.left_keys) != 1):
+            return None
+        key = node.left_keys[0]
+        left = ctx.resolve(node.left)
+
+        def semi(child, ch):
+            # hot keys were seen on the probe side it had, not this one
+            return dataclasses.replace(
+                node, left=child, left_keys=(ch,),
+                fields=ctx.resolve(child).fields, skew_hot_keys=(),
+            )
+
+        if isinstance(left, P.FilterNode):
+            return dataclasses.replace(left, child=semi(left.child, key))
+        if isinstance(left, P.ProjectNode):
+            ref = left.exprs[key]
+            if not isinstance(ref, ir.InputRef):
+                return None
+            return dataclasses.replace(left, child=semi(left.child, ref.index))
+        if isinstance(left, P.JoinNode) and left.kind in ("inner", "cross"):
+            width = len(ctx.resolve(left.left).fields)
+            if key < width:
+                return dataclasses.replace(left, left=semi(left.left, key))
+            return dataclasses.replace(
+                left, right=semi(left.right, key - width)
+            )
+        return None
+
+
 SIMPLIFICATION_RULES: Tuple[Rule, ...] = (
     MergeFilters(),
     InlineProjections(),
@@ -986,6 +1032,7 @@ SIMPLIFICATION_RULES: Tuple[Rule, ...] = (
     PushFilterThroughUnion(),
     RemoveRedundantDistinct(),
     PushAggregationThroughOuterJoin(),
+    PushSemiJoinDown(),
 )
 
 
