@@ -504,3 +504,271 @@ def test_semi_join_over_joins_answers_what_the_oracle_answers(name, having, tpch
     want = oracle_rows(0.01, sql)
     assert len(want) == {"an-empty-build": 0, "every-key": 15000}.get(name, len(want))
     assert_rows_match(got, want, ordered=False)
+
+
+# -- states whose key ranges ascend are laid end to end (issue 34) ------------------------
+
+I64 = np.iinfo(np.int64)
+# merge reducers of sum, count, min, max and any, slot by slot
+SEAM_REDUCERS = ("sum", "sum", "min", "max", "first")
+STATE_CAP, TABLE = 16, 256
+
+
+def one_state(keys, rng, cap=STATE_CAP, holes=()):
+    """A group state of `cap` slots holding `keys` from slot 0 (None: the
+    NULL group), random slot values; a slot that counted no row holds
+    what a reduce leaves there. `holes`: used slots switched off."""
+    n = len(keys)
+    cnts = rng.integers(0, 3, (len(SEAM_REDUCERS), n))
+    cnts[:2] = np.maximum(cnts[:2], 1)
+    vals = rng.integers(-99, 99, (len(SEAM_REDUCERS), n))
+    vals[2][cnts[2] == 0], vals[3][cnts[3] == 0] = I64.max, I64.min
+
+    def slots(a, dtype=np.int64):
+        return jnp.asarray(np.concatenate([a, np.zeros(cap - n, a.dtype)]).astype(dtype))
+
+    used = np.arange(n) >= 0
+    used[list(holes)] = False
+    return (
+        (slots(np.array([0 if k is None else k for k in keys], np.int64)),),
+        (slots(np.array([k is not None for k in keys], bool), bool),),
+        slots(used, bool),
+        tuple(slots(v) for v in vals), tuple(slots(c) for c in cnts),
+    )
+
+
+def seam_case(name, n_states):
+    """Key lists, one a state, and whether they ascend as operands."""
+    own = [list(range(10 * i, 10 * i + 5)) for i in range(n_states)]
+    if name == "ranges_of_their_own":
+        return own, True
+    if name == "meeting_in_one_group_at_every_seam":
+        return [list(range(5 * i, 5 * i + 6)) for i in range(n_states)], True
+    if name == "one_key_spanning_three_states":
+        return [[0, 1, 2], [2]] + ([[2, 3, 4]] + own[3:] if n_states > 2 else []), True
+    if name == "an_empty_state_in_the_middle_and_at_the_end":
+        own[n_states // 2], own[-1] = [], []
+        return own, True
+    if name == "the_null_group_across_the_last_seam":
+        own[-2], own[-1] = own[-2] + [None], [None]
+        return own, True
+    if name == "a_key_twice_in_a_state":
+        own[-1] = own[-1][:2] + own[-1][1:]
+        return own, False
+    if name == "two_states_swapped":
+        own[0], own[-1] = own[-1], own[0]
+        return own, False
+    if name == "a_null_before_a_value":
+        own[0] = [None] + own[0]
+        return own, False
+    assert name == "a_hole_in_used"
+    return own, False
+
+
+@pytest.fixture(scope="module")
+def resort():
+    return jax.jit(O._resort_states, static_argnames=("reducers", "out_capacity"))
+
+
+@pytest.mark.parametrize("n_states", [2, 8, 9])
+@pytest.mark.parametrize("name", [
+    "ranges_of_their_own", "meeting_in_one_group_at_every_seam",
+    "one_key_spanning_three_states", "an_empty_state_in_the_middle_and_at_the_end",
+    "the_null_group_across_the_last_seam", "a_key_twice_in_a_state",
+    "two_states_swapped", "a_null_before_a_value", "a_hole_in_used",
+])
+def test_states_laid_end_to_end_equal_the_states_sorted(name, n_states, resort):
+    """sum, count, min, max and first over 2, 8 and 9 single-key states:
+    the merge says which way it went and equals the concatenate-and-
+    reduce slot for slot."""
+    rng = np.random.default_rng(34 + n_states)
+    key_lists, ascend = seam_case(name, n_states)
+    states = tuple(
+        one_state(keys, rng, holes=(1,) if name == "a_hole_in_used" and i == 1 else ())
+        for i, keys in enumerate(key_lists))
+    want, want_groups, want_ovf = resort(states, SEAM_REDUCERS, TABLE)
+    got, groups, word = O._merge_group_states(states, SEAM_REDUCERS, TABLE)
+    assert bool(int(word) & O.G.ORDERED) == ascend
+    assert not int(word) & 1 and not bool(want_ovf)
+    distinct = {k for keys, s in zip(key_lists, states)
+                for k, u in zip(keys, np.asarray(s[2])) if u}
+    assert int(groups) == int(want_groups) == len(distinct)
+    assert_same_state(got, want, states, ascend)
+
+
+def assert_same_state(got, want, states, laid):
+    (gk,), (gv,), used, vals, cnts = jax.device_get(got)
+    (wk,), (wv,), w_used, w_vals, w_cnts = jax.device_get(want)
+    np.testing.assert_array_equal(used, w_used)
+    np.testing.assert_array_equal(gv, wv)
+    np.testing.assert_array_equal(gk[w_used & wv], wk[w_used & wv])
+    for v, c, wv_, wc, red in zip(vals, cnts, w_vals, w_cnts, SEAM_REDUCERS):
+        np.testing.assert_array_equal(c[w_used], wc[w_used])
+        if red != "first":
+            np.testing.assert_array_equal(v[w_used], wv_[w_used])
+    # `first` is any of the group's values that counted a row (the sort
+    # is unstable); states laid end to end keep the earliest state's
+    firsts = collections.defaultdict(list)
+    for (k,), (kv,), s_used, s_vals, s_cnts in jax.device_get(states):
+        for i in np.nonzero(s_used & (s_cnts[-1] > 0))[0]:
+            firsts[int(k[i]) if kv[i] else None].append(int(s_vals[-1][i]))
+    for i in np.nonzero(w_used & (w_cnts[-1] > 0))[0]:
+        among = firsts[int(gk[i]) if gv[i] else None]
+        assert int(w_vals[-1][i]) in among
+        assert int(vals[-1][i]) in (among[:1] if laid else among)
+
+
+@pytest.mark.parametrize("name", ["ranges_of_their_own", "two_states_swapped"])
+def test_more_groups_than_the_table_raise_the_flag_either_way(name, resort):
+    key_lists, ascend = seam_case(name, 8)           # 40 groups into 32 slots
+    rng = np.random.default_rng(5)
+    states = tuple(one_state(keys, rng) for keys in key_lists)
+    _, groups, word = O._merge_group_states(states, SEAM_REDUCERS, 32)
+    _, want_groups, want_ovf = resort(states, SEAM_REDUCERS, 32)
+    assert int(word) & 1 and bool(want_ovf) and int(groups) == int(want_groups) == 40
+    assert bool(int(word) & O.G.ORDERED) == ascend
+
+
+def test_a_table_smaller_than_the_last_offset_plus_a_state_is_written_whole():
+    """Three states of 64 slots, 20 groups each, into a table of 64: the
+    third is written at 40, where 64 more slots do not fit; the buffer
+    is the table plus a state, so it lands at 40 all the same."""
+    rng = np.random.default_rng(6)
+    states = tuple(one_state(list(range(20 * i, 20 * i + 20)), rng, cap=64) for i in range(3))
+    got, groups, word = O._merge_group_states(states, SEAM_REDUCERS, 64)
+    assert int(word) == O.G.ORDERED and int(groups) == 60
+    assert np.asarray(got[0][0])[:60].tolist() == list(range(60))
+    assert np.asarray(got[2]).tolist() == [True] * 60 + [False] * 4
+    np.testing.assert_array_equal(
+        np.asarray(got[3][0])[40:60], np.asarray(states[2][3][0])[:20])
+
+
+def test_multi_key_states_keep_the_plain_flag_and_todays_program(resort):
+    rng = np.random.default_rng(8)
+    single = [one_state(list(range(5 * i, 5 * i + 5)), rng) for i in range(2)]
+    states = tuple((k + k, v + v, *rest) for k, v, *rest in single)
+    got, groups, flag = O._merge_group_states(states, SEAM_REDUCERS, TABLE)
+    assert flag.dtype == jnp.bool_ and not bool(flag) and int(groups) == 10
+    want = resort(states, SEAM_REDUCERS, TABLE)[0]
+    np.testing.assert_array_equal(np.asarray(got[3][0]), np.asarray(want[3][0]))
+
+
+@pytest.mark.parametrize("kind", ["min", "max"])
+def test_a_128_bit_extreme_across_a_seam(kind, resort):
+    """The (hi, lo) pair of a long-decimal min / max folds as one
+    number: lo compares unsigned among equal hi, and a side that
+    counted no row gives way."""
+    reducers = (f"{kind}128h", f"{kind}128l")
+    pairs = [(5, -1), (5, 3), (-2, 7), (5, -1), (9, 9)]       # (hi, lo); lo -1 is 2^64 - 1
+    for (ah, al), (bh, bl), ac, bc in [
+            (pairs[0], pairs[1], 1, 1), (pairs[1], pairs[0], 2, 1), (pairs[2], pairs[3], 1, 1),
+            (pairs[4], pairs[2], 0, 1), (pairs[2], pairs[4], 1, 0)]:
+        def state(keys, hi, lo, cnt):
+            pad = np.zeros(STATE_CAP - 2, np.int64)
+            col = lambda *x: jnp.asarray(np.concatenate([np.array(x, np.int64), pad]))  # noqa: E731
+            return ((col(*keys),), (col(1, 1).astype(bool),), col(1, 1).astype(bool),
+                    (col(*hi), col(*lo)), (col(*cnt), col(*cnt)))
+        # key 2 ends the first state and begins the second
+        states = (state((1, 2), (1, ah), (1, al), (1, ac)),
+                  state((2, 3), (bh, 1), (bl, 1), (bc, 1)))
+        got, groups, word = O._merge_group_states(states, reducers, 64)
+        want, _, _ = resort(states, reducers, 64)
+        assert int(word) == O.G.ORDERED and int(groups) == 3
+        if ac and bc:       # the reduce's extreme is over every slot, counted or not
+            for i in (0, 1):
+                assert int(got[3][i][1]) == int(want[3][i][1])
+        a, b = to_python(ah, al), to_python(bh, bl)
+        best = (min if kind == "min" else max)(a, b) if ac and bc else (a if ac else b)
+        assert to_python(int(got[3][0][1]), int(got[3][1][1])) == best
+        assert int(got[4][0][1]) == ac + bc
+
+
+def test_a_long_decimal_sum_across_a_seam():
+    """Batches whose keys overlap by one group with the next (an order's
+    rows straddling two batches): four limb slots and their carries add
+    across every seam, and every merge lays its states end to end."""
+    rng = np.random.default_rng(9)
+    rows = []
+    for b in range(18):
+        keys = np.sort(39 * b + rng.integers(0, 40, BATCH))
+        keys[0], keys[-1] = 39 * b, 39 * b + 39              # both seams are there
+        big = [(2**64 - 1 - int(x)) * (2**32 if i % 2 else 1) for i, x in
+               enumerate(rng.integers(0, 1000, BATCH))]
+        rows.append((keys, big, rng.integers(-1000, 1000, BATCH)))
+    names = ("agg_merge_launches", "agg_ordered_merge.launches",
+             "agg_ordered_input.batches", "agg_ingest_path.sort")
+    before = {k: METRICS.counter(k) for k in names}
+    got, _ = aggregate(rows)
+    moved = {k: METRICS.counter(k) - v for k, v in before.items()}
+    assert got == want_rows(rows)
+    assert moved["agg_ordered_merge.launches"] == moved["agg_merge_launches"] == 3
+    assert moved["agg_ordered_input.batches"] == moved["agg_ingest_path.sort"] == 18
+
+
+def test_keys_out_of_order_sort_as_before_and_count_nothing():
+    rows = make_rows(18, 40, seed=3, overlap=True)
+    names = ("agg_merge_launches", "agg_ordered_merge.launches", "agg_ordered_input.batches")
+    before = {k: METRICS.counter(k) for k in names}
+    got, _ = aggregate(rows)
+    moved = {k: METRICS.counter(k) - v for k, v in before.items()}
+    assert got == want_rows(rows)
+    assert moved == {"agg_merge_launches": 3, "agg_ordered_merge.launches": 0,
+                     "agg_ordered_input.batches": 0}
+
+
+# -- Q18 at `tiny`, over the ordered tables and over a permuted lineitem ------------------
+
+Q18_SQL = """
+select c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice, sum(l_quantity)
+from customer, orders, lineitem
+where o_orderkey in (
+    select l_orderkey from lineitem group by l_orderkey having sum(l_quantity) > 250)
+  and c_custkey = o_custkey and o_orderkey = l_orderkey
+group by c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+order by o_totalprice desc, o_orderdate
+limit 100
+"""
+Q18_TABLES = {
+    "lineitem": ["l_orderkey", "l_quantity"],
+    "orders": ["o_orderkey", "o_custkey", "o_orderdate", "o_totalprice"],
+    "customer": ["c_custkey", "c_name"],
+}
+
+
+def q18_runner(permute):
+    from trino_tpu.connectors.memory import create_memory_connector
+    from trino_tpu.connectors.spi import ColumnMetadata
+    from trino_tpu.connectors.tpch import TABLES, base_row_count, generate_column
+    from trino_tpu.engine import LocalQueryRunner, Session
+
+    mem = create_memory_connector()
+    for table, names in Q18_TABLES.items():
+        cols = [generate_column(table, c, 0.01, 0, base_row_count(table, 0.01)) for c in names]
+        data = [d for d, _ in cols]
+        if permute and table == "lineitem":
+            order = np.random.default_rng(34).permutation(len(data[0]))
+            data = [np.asarray(d)[order] for d in data]
+        types = dict(TABLES[table])
+        mem.load_table("tiny", table, [ColumnMetadata(n, types[n]) for n in names],
+                       data, None, [d for _, d in cols])
+    runner = LocalQueryRunner(Session(catalog="memory", schema="tiny", batch_rows=4096))
+    runner.register_catalog("memory", mem)
+    return runner
+
+
+@pytest.mark.parametrize("permute", [False, True], ids=["ordered", "permuted_lineitem"])
+def test_q18_takes_the_ordered_branch_where_lineitem_is_in_key_order(permute):
+    """15 batches of 4,096 rows, a fold and a last merge in the
+    sub-query's aggregation (the statement's second aggregation has
+    five keys: one more batch on the sort path, never checked)."""
+    names = ("agg_ingest_path.sort", "agg_ordered_input.batches",
+             "agg_merge_launches", "agg_ordered_merge.launches", "agg_merge_retries")
+    before = {k: METRICS.counter(k) for k in names}
+    rows = q18_runner(permute).execute(Q18_SQL).rows
+    moved = {k: METRICS.counter(k) - v for k, v in before.items()}
+    assert len(rows) == 63
+    assert_rows_match(rows, oracle_rows(0.01, Q18_SQL), ordered=True)
+    assert moved["agg_ingest_path.sort"] == 15 + 1 and moved["agg_merge_launches"] == 2
+    assert moved["agg_merge_retries"] == 0
+    assert moved["agg_ordered_input.batches"] == (0 if permute else 15)
+    assert moved["agg_ordered_merge.launches"] == (0 if permute else 2)

@@ -431,3 +431,83 @@ class TestMxuGroupby:
         r2.register_catalog("tpch", create_tpch_connector())
         assert forced == r2.execute(sql).rows
         assert len(forced) == 100  # one row per supplier
+
+
+# -- the sort path's order check (issue 34) -----------------------------------
+
+N_ORD = 64
+
+
+def _ordered_case(name):
+    """(keys, valids, mask) of N_ORD rows, four to a key."""
+    keys = np.repeat(np.arange(N_ORD // 4, dtype=np.int64) * 7 - 20, 4)
+    valids = np.ones(N_ORD, bool)
+    mask = np.ones(N_ORD, bool)
+    if name == "reversed":
+        keys = keys[::-1].copy()
+    elif name == "shuffled":
+        keys = np.random.default_rng(34).permutation(keys)
+    elif name == "a_late_key_out_of_place":
+        keys[-1] = keys[0]
+    elif name == "dead_row_in_the_middle":
+        mask[N_ORD // 2] = False
+    elif name == "dead_rows_last":
+        mask[-5:] = False
+    elif name == "nulls_last":
+        valids[-6:] = False
+    elif name == "nulls_first":
+        valids[:6] = False
+    else:
+        assert name == "ordered"
+    return keys, valids, mask
+
+
+@pytest.mark.parametrize("dtype", [np.int64, np.float64], ids=["bigint", "double"])
+@pytest.mark.parametrize("name,in_order", [
+    ("ordered", True), ("dead_rows_last", True), ("nulls_last", True),
+    ("reversed", False), ("shuffled", False), ("a_late_key_out_of_place", False),
+    ("dead_row_in_the_middle", False), ("nulls_first", False),
+])
+@pytest.mark.parametrize("cap", [8, 32], ids=["top_k", "carried"])
+def test_sort_group_reduce_skips_the_key_sort_of_rows_in_key_order(
+        name, in_order, cap, dtype):
+    """With `check_order` the reduce says which way the rows went (bit
+    ORDERED of its flag word) and answers what it answers without: the
+    same groups in the same slots, whichever compaction the table's
+    size picks."""
+    keys, valids, mask = _ordered_case(name)
+    rng = np.random.default_rng(7)
+    args = (
+        [jnp.asarray(keys.astype(dtype))], [jnp.asarray(valids)], jnp.asarray(mask),
+        [jnp.asarray(rng.integers(-50, 50, N_ORD)), jnp.asarray(rng.integers(0, 9, N_ORD)),
+         jnp.asarray(rng.integers(0, 9, N_ORD)), jnp.ones(N_ORD, jnp.int64)],
+        (None, jnp.asarray(rng.random(N_ORD) < 0.7), None, None),
+        ("sum", "min", "max", "count"), cap,
+    )
+    *want, want_flag = groupby.sort_group_reduce(*args)
+    *got, word = groupby.sort_group_reduce(*args, check_order=True)
+    assert bool(int(word) & groupby.ORDERED) == in_order
+    assert bool(int(word) & 1) == bool(want_flag) == (cap == 8)
+    gk, gv, used, results, counts, n_groups = got
+    wk, wv, w_used, w_results, w_counts, w_groups = want
+    live_keys = keys[mask & valids]
+    assert int(n_groups) == int(w_groups) == len(set(live_keys)) + bool((mask & ~valids).any())
+    u = np.asarray(w_used)
+    np.testing.assert_array_equal(np.asarray(used), u)
+    np.testing.assert_array_equal(np.asarray(gv[0]), np.asarray(wv[0]))
+    live_key = u & np.asarray(wv[0])
+    np.testing.assert_array_equal(np.asarray(gk[0])[live_key], np.asarray(wk[0])[live_key])
+    for a, b in zip(list(results) + list(counts), list(w_results) + list(w_counts)):
+        np.testing.assert_array_equal(np.asarray(a)[u], np.asarray(b)[u])
+
+
+def test_the_order_check_is_for_a_single_key_and_for_those_who_ask():
+    """Several keys sort by the tuple hash and keep the plain flag; so
+    does a single key whose caller did not ask."""
+    k = jnp.arange(N_ORD, dtype=jnp.int64)
+    ones = jnp.ones(N_ORD, bool)
+    for keys, check in (([k, k], True), ([k], False)):
+        flag = groupby.sort_group_reduce(
+            keys, [ones] * len(keys), ones, [k], (None,), ("sum",), N_ORD,
+            check_order=check)[-1]
+        assert flag.dtype == jnp.bool_ and not bool(flag)

@@ -335,6 +335,19 @@ def install_cache_event_listener() -> bool:
     return True
 
 
+def cpu_process() -> bool:
+    """A CPU process is one whose JAX_PLATFORMS puts the CPU first: in
+    "tpu,cpu" the CPU is only where host-side arrays live."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu"
+
+
+def tpu_compiler_options(options: dict) -> Optional[dict]:
+    """`compiler_options` for a jax.jit whose program needs a TPU
+    compiler option: None in a CPU process, whose compiler refuses an
+    option it does not know."""
+    return None if cpu_process() else options
+
+
 def configure_persistent_cache() -> Optional[PersistentCompileCache]:
     """jaxcfg entry point, run once at import. TPU-targeted processes
     only (XLA:CPU AOT entries record compile-option pseudo-features the
@@ -344,13 +357,7 @@ def configure_persistent_cache() -> Optional[PersistentCompileCache]:
     global ACTIVE_PERSISTENT_CACHE
     if ACTIVE_PERSISTENT_CACHE is not None:
         return ACTIVE_PERSISTENT_CACHE
-    # a CPU process is one whose JAX_PLATFORMS puts the CPU first: in
-    # "tpu,cpu" the CPU is only where host-side arrays live
-    first_platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
-    if (
-        os.environ.get("TRINO_TPU_NO_COMPILE_CACHE") == "1"
-        or first_platform == "cpu"
-    ):
+    if os.environ.get("TRINO_TPU_NO_COMPILE_CACHE") == "1" or cpu_process():
         return None
     cache = PersistentCompileCache()
     cache.activate()

@@ -36,6 +36,7 @@ from trino_tpu.block import (
     bucket_capacity,
     concat_batches,
 )
+from trino_tpu.compile.cache import tpu_compiler_options
 from trino_tpu.expr.compile import Bound
 from trino_tpu.ops import groupby as G
 from trino_tpu.ops.gather import take_clip
@@ -1231,11 +1232,10 @@ _MERGE_REDUCER = {"sum": "sum", "avg": "sum", "count": "sum",
                   "count_star": "sum", "min": "min", "max": "max",
                   "any": "first"}
 
-@partial(jax.jit, static_argnames=("reducers", "out_capacity"))
-def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
-    """Concat N (keys, valids, used, vals, cnts) group-state sets and
-    re-group-reduce them — the whole N-way merge is ONE device program
-    (per-batch pairwise merges would cost a program launch each)."""
+def _resort_states(states: tuple, reducers: tuple, out_capacity: int):
+    """N (keys, valids, used, vals, cnts) group-state sets as one:
+    concatenated and group-reduced again, whatever order their slots are
+    in."""
     n_keys = len(states[0][0])
     keys = [
         jnp.concatenate([s[0][i] for s in states]) for i in range(n_keys)
@@ -1262,6 +1262,166 @@ def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
         ngroups,
         ovf,
     )
+
+
+def _states_ascend(states: tuple):
+    """Whether single-key states, taken in operand order, are one
+    ascending run of groups: every state's `used` a dense prefix, its
+    (class, key) pairs strictly ascending over that prefix (what the
+    sort path's reduce leaves; a state off the wire may hold a key
+    twice), and each state's first group not before the last group so
+    far (empty states skipped). Read off the slots themselves, one pass
+    over the keys. Returns (the answer, each state's group count, each
+    state's `seam`: its first group IS the last one so far, an order
+    whose rows straddled two batches)."""
+    ok = jnp.bool_(True)
+    counts, seams = [], []
+    last = None  # (class, key) of the last group so far
+    for (key,), (valid,), used, _, _ in states:
+        n = jnp.sum(used.astype(jnp.int32))
+        cls, kb = G.class_and_key(key, valid, used)
+        ok &= jnp.all(used == (jnp.arange(used.shape[0]) < n))
+        ok &= jnp.all(G.ascends(cls, kb, strict=True) | ~used[1:])
+        holds = n > 0
+        first = cls[0], kb[0]
+        end = jnp.maximum(n - 1, 0)
+        if last is None:
+            seams.append(jnp.bool_(False))
+            last = cls[end], kb[end]
+            some = holds
+        else:
+            ok &= ~(holds & some & G.pair_lt(*first, *last))
+            seams.append(holds & some & ~G.pair_lt(*last, *first))
+            last = tuple(
+                jnp.where(holds, a[end], b) for a, b in zip((cls, kb), last)
+            )
+            some |= holds
+        counts.append(n)
+    # a seam is read only where `ok` holds, and there "not after" is "equal"
+    return ok, counts, seams
+
+
+def _fold_seam(reducers: tuple, a_vals, a_cnts, b_vals, b_cnts):
+    """One group's value slots out of two states as one state's: slot
+    by slot the merge reducer (sums add; min and max keep the extreme of
+    the sides that counted a row, as _agg_ingest_train's fold does, a
+    128-bit one as its (hi, lo) pair; first keeps the earlier state's
+    where that counted one). Counts add."""
+    from trino_tpu.ops import int128 as I128
+
+    vals = []
+    for i, red in enumerate(reducers):
+        a, b, ac, bc = a_vals[i], b_vals[i], a_cnts[i], b_cnts[i]
+        if red == "sum":
+            vals.append(a + b)
+        elif red in ("min", "max"):
+            best = jnp.minimum(a, b) if red == "min" else jnp.maximum(a, b)
+            vals.append(jnp.where(ac == 0, b, jnp.where(bc == 0, a, best)))
+        elif red == "first":
+            vals.append(jnp.where(ac > 0, a, b))
+        elif red in ("min128h", "max128h"):
+            pair_a, pair_b = (a, a_vals[i + 1]), (b, b_vals[i + 1])
+            better = (I128.lt(*pair_b, *pair_a) if red == "min128h"
+                      else I128.lt(*pair_a, *pair_b))
+            take_b = (ac == 0) | ((bc > 0) & better)
+            vals.append(jnp.where(take_b, b, a))
+            vals.append(jnp.where(take_b, b_vals[i + 1], a_vals[i + 1]))
+        elif red not in ("min128l", "max128l"):  # those came with their hi
+            raise ValueError(red)
+    return vals, [ac + bc for ac, bc in zip(a_cnts, b_cnts)]
+
+
+def _lay_end_to_end(states: tuple, counts, seams, reducers: tuple,
+                    out_capacity: int, like):
+    """The merge of states that _states_ascend passed: state after state
+    written at a running end offset, each over the dead tail of the one
+    before, and where a state begins with the group the last one ended
+    with (`seams`), that one group folded and the state written one slot
+    earlier. `like` gives the merged state's dtypes. A copy and no sort.
+
+    dynamic_update_slice clamps a start so that the update fits, and the
+    table may be smaller than the last offset plus a state's capacity,
+    so the buffers are the table plus the widest state and are cut to
+    the table at the end."""
+    width = out_capacity + max(s[2].shape[0] for s in states)
+    (like_key,), _, _, like_vals, like_cnts = like
+    key = jnp.zeros(width, like_key.dtype)
+    valid = jnp.zeros(width, jnp.bool_)
+    vals = [jnp.zeros(width, v.dtype) for v in like_vals]
+    cnts = [jnp.zeros(width, c.dtype) for c in like_cnts]
+    end = jnp.int32(0)
+
+    def put(buf, arr, at):
+        return jax.lax.dynamic_update_slice(buf, arr.astype(buf.dtype), (at,))
+
+    for ((s_key,), (s_valid,), _, s_vals, s_cnts), n, seam in zip(
+            states, counts, seams):
+        at = end - seam.astype(jnp.int32)
+        heads = [v[0].astype(b.dtype) for v, b in zip(s_vals, vals)]
+        head_cnts = [c[0].astype(b.dtype) for c, b in zip(s_cnts, cnts)]
+        folded, folded_cnts = _fold_seam(
+            reducers, [v[at] for v in vals], [c[at] for c in cnts],
+            heads, head_cnts,
+        )
+        key, valid = put(key, s_key, at), put(valid, s_valid, at)
+        for bufs, arrs, whole, own in ((vals, s_vals, folded, heads),
+                                       (cnts, s_cnts, folded_cnts, head_cnts)):
+            for i, arr in enumerate(arrs):
+                bufs[i] = put(bufs[i], arr, at)
+                bufs[i] = put(
+                    bufs[i], jnp.where(seam, whole[i], own[i])[None], at
+                )
+        end = at + n
+    used = jnp.arange(out_capacity, dtype=jnp.int32) < end
+
+    def cut(buf):
+        return jnp.where(used, buf[:out_capacity], jnp.zeros((), buf.dtype))
+
+    return (
+        ((cut(key),), (cut(valid),), used,
+         tuple(cut(v) for v in vals), tuple(cut(c) for c in cnts)),
+        end,
+        end > out_capacity,
+    )
+
+
+# The TPU compiler gives a 64-bit reduce-window (the reduce's scans) 19 MB
+# of scoped VMEM inside a conditional's branch, 64 MB where it splits a
+# long 1-D scan itself, and refuses both at its limit of 16 MB ("it
+# should not be possible to run out of scoped vmem"); outside a branch
+# the same scans fit. The chip has 128 MB.
+_MERGE_COMPILER_OPTIONS = tpu_compiler_options(
+    {"xla_tpu_scoped_vmem_limit_kib": 96 * 1024}
+)
+
+
+@partial(jax.jit, static_argnames=("reducers", "out_capacity"),
+         compiler_options=_MERGE_COMPILER_OPTIONS)
+def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
+    """N (keys, valids, used, vals, cnts) group-state sets merged into
+    one — the whole N-way merge is ONE device program (per-batch
+    pairwise merges would cost a program launch each). States come out
+    of the sort path dense and ascending in their key, and a scan of a
+    table clustered on that key hands them over in ascending ranges
+    that meet in at most one group a seam: single-key states are looked
+    at first (_states_ascend), and where that is what they are, laid
+    end to end (_lay_end_to_end); otherwise, and for several keys
+    (hash order) always, concatenated and group-reduced again
+    (_resort_states). The flag of a single-key merge is a G.flag_word:
+    overflow, and which of the two it did."""
+    if len(states[0][0]) != 1:
+        return _resort_states(states, reducers, out_capacity)
+    ordered, counts, seams = _states_ascend(states)
+    like = jax.eval_shape(
+        lambda s: _resort_states(s, reducers, out_capacity), states
+    )[0]
+    merged, ngroups, ovf = jax.lax.cond(
+        ordered,
+        lambda: _lay_end_to_end(
+            states, counts, seams, reducers, out_capacity, like),
+        lambda: _resort_states(states, reducers, out_capacity),
+    )
+    return merged, ngroups, G.flag_word(ovf, ordered)
 
 
 @partial(jax.jit, static_argnames=("capacity",))
@@ -1291,6 +1451,12 @@ def _common_capacity(states: list) -> list:
 @jax.jit
 def _any_flags(flags: tuple):
     return jnp.any(jnp.stack(flags))
+
+
+@jax.jit
+def _overflow_bit(word):
+    """The overflow flag of a G.flag_word."""
+    return (word & 1) != 0
 
 
 def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
@@ -1340,7 +1506,8 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
         )
     else:
         out = G.sort_group_reduce(
-            keys, valids, live, values, tuple(vvalids), reds, cap
+            keys, valids, live, values, tuple(vvalids), reds, cap,
+            check_order=True,
         )
     return out, reds
 
@@ -1369,7 +1536,9 @@ def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
 # every state resident until the last batch). Folding eight at a time
 # keeps at most 8 states a tier pending, gives every fold the same arity
 # whatever the scan's length, and costs one more pass over the states
-# per tier: 58 batches are 7 folds and one last merge of 9 states.
+# per tier (a copy where their key ranges ascend, a sort where they do
+# not: _merge_group_states): 58 batches are 7 folds and one last merge
+# of 7 folded states and 8 of what is left.
 FOLD_STATES = 8
 
 # Batches one launch of _agg_ingest_train takes. A launch costs the host
@@ -1533,9 +1702,16 @@ class HashAggregationOperator(Operator):
     group-reduce (ops/groupby.sort_group_reduce) — XLA lowers scatters
     near-serially on TPU, so the linear-probe table is reserved for the
     mesh-exchange partials while this operator reduces each batch by
-    sort + segmented scans and then merges per-batch group states the
-    same way (partial->final within one operator). Output schema =
-    [group keys..., aggregate results...]; group rows come out dense.
+    sort + segmented scans and then merges per-batch group states
+    (partial->final within one operator): by the same reduce over their
+    concatenation, or, where single-key states hold ascending key
+    ranges (a scan of a table clustered on the key), by laying them end
+    to end. A batch that arrives in key order skips its key sort too.
+    Both are read off the data on the device, and METRICS
+    `agg_ordered_input.batches` (of `agg_ingest_path.sort`) and
+    `agg_ordered_merge.launches` (of `agg_merge_launches`) count how
+    often. Output schema = [group keys..., aggregate results...]; group
+    rows come out dense.
 
     Launches: one per batch on the sort, global, holistic and `final`
     paths, whose batches may overflow a table or need the raw rows.
@@ -1589,6 +1765,9 @@ class HashAggregationOperator(Operator):
         self._folded: List[List[tuple]] = []
         # merges launched / retried under _state_lock, not yet in METRICS
         self._merges = [0, 0]
+        # likewise the batches and the merges whose reduce found its
+        # input in key order already (G.flag_word)
+        self._ordered = [0, 0]
         # a state ingested off the wire (_add_state_input) may carry
         # DUPLICATE group keys within one batch (a spooled-stage replay
         # concatenates several producer pages into one values batch), so
@@ -1846,12 +2025,14 @@ class HashAggregationOperator(Operator):
         with self._state_lock:
             n, self._launched = self._launched, 0
             (merges, retries), self._merges = self._merges, [0, 0]
-        if n:
-            METRICS.increment("agg_ingest_launches", n)
-        if merges:
-            METRICS.increment("agg_merge_launches", merges)
-        if retries:
-            METRICS.increment("agg_merge_retries", retries)
+            (batches, laid), self._ordered = self._ordered, [0, 0]
+        for name, moved in (("agg_ingest_launches", n),
+                            ("agg_merge_launches", merges),
+                            ("agg_merge_retries", retries),
+                            ("agg_ordered_input.batches", batches),
+                            ("agg_ordered_merge.launches", laid)):
+            if moved:
+                METRICS.increment(name, moved)
 
     def _resolve_one_locked(self) -> None:
         """Settle the OLDEST deferred per-batch overflow record; its
@@ -1861,7 +2042,11 @@ class HashAggregationOperator(Operator):
         reseeds via _order_seed) until it comes back clean — same
         semantics as the old per-batch retry ladder."""
         idx, ovf, ngroups, batch, cap = self._pending_meta.pop(0)
-        while _flag("agg.ingest_overflow", ovf):
+        while True:
+            overflowed, ordered = _flag_word("agg.ingest_overflow", ovf)
+            if not overflowed:
+                self._ordered[0] += ordered
+                break
             cap = max(cap * 2, bucket_capacity(int(ngroups)))
             self._cap = max(self._cap, cap)
             gk, gv, used, vals, cnts, ngroups, ovf = _agg_ingest(
@@ -1945,16 +2130,22 @@ class HashAggregationOperator(Operator):
             cap = bucket_capacity(max(bound, 16))
         retry = 0
         while True:
+            # the span ends once the flag is read (`sync.agg.
+            # merge_overflow`, inside it): which way the merge went is
+            # known no sooner
             with host_span("agg.merge", states=len(states),
-                           slots_in=concat_len, cap=cap, retry=retry):
+                           slots_in=concat_len, cap=cap, retry=retry) as span:
                 merged, ngroups, ovf = _merge_group_states(
                     tuple(states), reducers, cap
                 )
-            self._merges[0] += 1
-            if self._static_bound is not None:
-                self._deferred_ovf.append(ovf)
-                break
-            if not _flag("agg.merge_overflow", ovf):
+                self._merges[0] += 1
+                if self._static_bound is not None:
+                    self._deferred_ovf.append(ovf)
+                    break
+                overflowed, ordered = _flag_word("agg.merge_overflow", ovf)
+                span.set_metadata(ordered=int(ordered))
+            self._ordered[1] += ordered
+            if not overflowed:
                 break
             retry += 1
             self._merges[1] += 1
@@ -2601,7 +2792,10 @@ class HashAggregationOperator(Operator):
             self._memory.set_bytes(0)
             self._memory.set_revocable_bytes(0)
         if self._deferred_ovf:
-            flag = _any_flags(tuple(self._deferred_ovf))
+            flag = _any_flags(tuple(
+                f if f.dtype == jnp.bool_ else _overflow_bit(f)
+                for f in self._deferred_ovf
+            ))
             msg = (
                 "group table overflowed its plan-time bound "
                 "(runtime dictionary larger than planned)"
@@ -2753,6 +2947,15 @@ def _flag(site: str, flag) -> bool:
     it (`sync.<site>` in a profiler trace)."""
     with host_sync(site, 1):
         return bool(flag)
+
+
+def _flag_word(site: str, flag):
+    """_flag for a flag that may be a G.flag_word: (overflowed, the
+    reduce found its input in key order); a plain flag never says the
+    second."""
+    with host_sync(site, 1):
+        word = int(flag)
+    return bool(word & 1), bool(word & G.ORDERED)
 
 
 def _count(site: str, mask) -> int:

@@ -263,6 +263,57 @@ def split_limb_keys(keys, valids):
     return tuple(nk), tuple(nv)
 
 
+def class_and_key(k, v, mask):
+    """A single grouping key as the pair the sort path orders by: class
+    (0 valid / 1 NULL / 2 dead) and the order-mapped key, zeroed where
+    NULL or dead (-0.0 normalized to +0.0 first: SQL groups them
+    together)."""
+    from trino_tpu.ops.sort import _order_value
+
+    if jnp.issubdtype(k.dtype, jnp.floating):
+        kb = _order_value(
+            jnp.where(k == 0, jnp.zeros((), k.dtype), k), False
+        )
+    else:
+        kb = k
+    kb = jnp.where(v & mask, kb, jnp.zeros((), kb.dtype))
+    cls = jnp.where(mask, jnp.where(v, 0, 1), 2).astype(jnp.int8)
+    return cls, kb
+
+
+def _key_lt(a, b):
+    """`a` before `b` in lax.sort's order (floats: NaN last)."""
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return (a < b) | (jnp.isnan(b) & ~jnp.isnan(a))
+    return a < b
+
+
+def pair_lt(ca, ka, cb, kb):
+    """(class, key) pair a before pair b, lexicographically."""
+    return (ca < cb) | ((ca == cb) & _key_lt(ka, kb))
+
+
+def ascends(cls, kb, strict: bool):
+    """Per adjacent pair of slots: the (class, key) pair at i + 1 comes
+    after the one at i (`strict`), or at least not before it. All true
+    without `strict` means the array is what the key sort would return
+    (the sort is unstable: ties carry no promise)."""
+    if strict:
+        return pair_lt(cls[:-1], kb[:-1], cls[1:], kb[1:])
+    return ~pair_lt(cls[1:], kb[1:], cls[:-1], kb[:-1])
+
+
+# A reduce that looked at its input's order answers with a flag WORD
+# where the others answer with the overflow flag: bit 0 is that flag (a
+# bool reads as a word with no other bit set), bit 1 says the input was
+# in key order already and was not sorted.
+ORDERED = 2
+
+
+def flag_word(overflowed, ordered):
+    return overflowed.astype(jnp.int32) | jnp.where(ordered, ORDERED, 0)
+
+
 def _key_order(keys, valids, mask, order=None, seed: int = 0):
     """Stable permutation grouping equal key tuples (NULL == NULL),
     live rows first. MUST order groups exactly like sort_group_reduce
@@ -274,22 +325,12 @@ def _key_order(keys, valids, mask, order=None, seed: int = 0):
     family). An incoming `order` acts as the least-significant
     pre-ordering (within-group value order for order statistics —
     stability preserves it)."""
-    from trino_tpu.ops.sort import _order_value
-
     keys, valids = split_limb_keys(keys, valids)
     n = mask.shape[0]
     if order is None:
         order = jnp.arange(n, dtype=jnp.int32)
     if len(keys) == 1:
-        k, v = keys[0], valids[0]
-        if jnp.issubdtype(k.dtype, jnp.floating):
-            kb = _order_value(
-                jnp.where(k == 0, jnp.zeros((), k.dtype), k), False
-            )
-        else:
-            kb = k
-        kb = jnp.where(v & mask, kb, jnp.zeros((), kb.dtype))
-        cls = jnp.where(mask, jnp.where(v, 0, 1), 2).astype(jnp.int8)
+        cls, kb = class_and_key(keys[0], valids[0], mask)
         order = take_clip(
             order, jnp.argsort(take_clip(kb, order), stable=True)
         )
@@ -733,7 +774,7 @@ def _segment_sums_at(c: jnp.ndarray, ends, used):
     return jnp.where(used, at_ends - prev, jnp.zeros((), c.dtype))
 
 
-@partial(jax.jit, static_argnames=("reducers", "out_capacity"))
+@partial(jax.jit, static_argnames=("reducers", "out_capacity", "check_order"))
 def sort_group_reduce(
     keys: Sequence[jnp.ndarray],
     valids: Sequence[jnp.ndarray],
@@ -742,6 +783,7 @@ def sort_group_reduce(
     value_valids: Sequence[Optional[jnp.ndarray]],
     reducers: tuple,  # per value: 'sum' | 'count' | 'min' | 'max' | 'first'
     out_capacity: int,
+    check_order: bool = False,
 ):
     """Group by `keys` and reduce each value column in one pass.
 
@@ -750,16 +792,22 @@ def sort_group_reduce(
     `results[i]` is reducer i's per-group result; `counts[i]` the number
     of non-null contributions (for SQL empty-group NULL semantics).
 
-    Engine hot path (GroupByHash analogue). ONE multi-operand lax.sort
-    does all the data movement: the grouping key (exact (class, key)
-    for a single key column; the 62-bit tuple hash for several) sorts
-    value columns riding as payload operands, so per-column random
-    gathers — ~10ms per 1M rows on TPU, the old design's dominant cost —
-    disappear. Segment boundaries come from the sorted key itself, and
-    boundary compaction uses top_k instead of a second full sort.
-    """
-    from trino_tpu.ops.sort import _order_value
+    Engine hot path (GroupByHash analogue). A multi-operand lax.sort on
+    the grouping key (exact (class, key) for a single key column; the
+    62-bit tuple hash for several) moves the rows: value columns ride
+    as payload operands, so per-column random gathers — ~10ms per 1M
+    rows on TPU, the old design's dominant cost — disappear. Segment
+    boundaries come from the sorted key itself. Compaction of the
+    boundary positions uses top_k where the table is small beside the
+    batch and a second sort, carrying the per-group outputs, where it
+    is not.
 
+    `check_order` (the aggregation's ingest asks; a single key only): a
+    compare over the keys first, and rows that are in key order already
+    (a scan of a table clustered on the key) skip the key sort. The
+    answer is the same either way; `overflowed` is then a flag_word
+    that also says which way the rows went.
+    """
     n = mask.shape[0]
     seed = _order_seed(out_capacity)
     iota = jnp.arange(n, dtype=jnp.int32)
@@ -775,15 +823,7 @@ def sort_group_reduce(
     if single_key:
         # exact: class (0 valid / 1 NULL / 2 dead) + order-mapped key
         # (-0.0 normalized to +0.0 first: SQL groups them together)
-        k, v = keys[0], valids[0]
-        if jnp.issubdtype(k.dtype, jnp.floating):
-            kb = _order_value(
-                jnp.where(k == 0, jnp.zeros((), k.dtype), k), False
-            )
-        else:
-            kb = k
-        kb = jnp.where(v & mask, kb, jnp.zeros((), kb.dtype))
-        cls = jnp.where(mask, jnp.where(v, 0, 1), 2).astype(jnp.int8)
+        cls, kb = class_and_key(keys[0], valids[0], mask)
         sort_keys = (cls, kb)
         num_keys = 2
         extra = []
@@ -833,9 +873,15 @@ def sort_group_reduce(
                 payloads.append(v)
             carried_kv.append(kvi)
 
-    sorted_ops = jax.lax.sort(
-        sort_keys + tuple(payloads), num_keys=num_keys, is_stable=False
-    )
+    key_sort = partial(jax.lax.sort, num_keys=num_keys, is_stable=False)
+    ordered = None
+    if check_order and single_key:
+        ordered = jnp.all(ascends(cls, kb, strict=False))
+        sorted_ops = jax.lax.cond(
+            ordered, tuple, key_sort, sort_keys + tuple(payloads)
+        )
+    else:
+        sorted_ops = key_sort(sort_keys + tuple(payloads))
     order = sorted_ops[num_keys]
 
     first = iota == 0
@@ -1151,6 +1197,8 @@ def sort_group_reduce(
                 gv2.append(group_valids[i])
             i += l
         group_keys, group_valids = gk2, gv2
+    if ordered is not None:
+        overflowed = flag_word(overflowed, ordered)
     return group_keys, group_valids, used, results, counts, n_groups, overflowed
 
 
