@@ -197,17 +197,48 @@ def test_mxu_join_probe_page_sums_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_join_build_lookup_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("key_columns", [1, 2])
+def test_join_build_lookup_compiles_for_v5e(one_chip, key_columns):
     """One packed uint64 sort: the program every join's build side runs
-    (2^14 rows; see the module docstring for why not 2^20)."""
-    from trino_tpu.ops.join import build_lookup
+    (2^14 rows; see the module docstring for why not 2^20). With two
+    integer key columns asked to be exact (PR 35) the sorted words are
+    42 bits wide, and a probe of as many rows compiles against them."""
+    from trino_tpu.ops.join import build_lookup, probe_counts
 
     n = 1 << 14
-    compiled = build_lookup.lower(
-        (_sds((n,), jnp.int64, one_chip),), (_sds((n,), jnp.bool_, one_chip),),
-        _sds((n,), jnp.bool_, one_chip),
-    ).compile()
+    keys = tuple(_sds((n,), jnp.int64, one_chip) for _ in range(key_columns))
+    valids = tuple(_sds((n,), jnp.bool_, one_chip) for _ in range(key_columns))
+    lowered = build_lookup.lower(
+        keys, valids, _sds((n,), jnp.bool_, one_chip), exact_keys=key_columns > 1)
+    compiled = lowered.compile()
     assert "sort" in compiled.as_text()
+    if key_columns > 1:
+        ls = jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, one_chip), lowered.out_info)
+        assert ls.hash_bits == 42 and ls.sorted_hash.dtype == jnp.uint64
+        probe = probe_counts.lower(ls, keys, valids, _sds((n,), jnp.bool_, one_chip)).compile()
+        assert "sort" in probe.as_text()
+
+
+def test_packed_parts_are_placed_and_taken_on_v5e(one_chip):
+    """`_pack_place` writes a packed part into the buffer of a batch and
+    a quarter at a position the host gives, `_pack_take` cuts a batch
+    off its front (PR 35): copies, no sort, seconds to compile at the
+    engine's batch."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+
+    def batch(n):
+        return RelBatch([Column(T.BIGINT, _sds((n,), jnp.int64, one_chip), None, None)
+                         for _ in range(6)], _sds((n,), jnp.bool_, one_chip))
+
+    room = batch(BATCH + BATCH // 4)
+    for slots in (BATCH // O.DF_PACK_PARTS, 2 * BATCH // O.DF_PACK_PARTS):
+        placed = O._pack_place.lower(room, batch(slots), _sds((), jnp.int32, one_chip)).compile()
+        assert "dynamic-update-slice" in placed.as_text()
+    taken = O._pack_take.lower(room, capacity=BATCH).compile()
+    assert taken.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_key_set_dynamic_filter_compiles_for_v5e(one_chip):
@@ -226,9 +257,40 @@ def test_key_set_dynamic_filter_compiles_for_v5e(one_chip):
     compiled = O._df_filter_set.lower(
         batch, (key, None),
         _sds((O.DF_SET_MAX_SLOTS,), jnp.int32, one_chip),
-        _sds((), jnp.bool_, one_chip),
+        _sds((), jnp.bool_, one_chip), _sds((2,), jnp.int64, one_chip),
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_key_bits_dynamic_filter_compiles_for_v5e(one_chip):
+    """The dynamic filter by key bits (`_df_filter_bits`, PR 35) at the
+    engine's batch against the table of 2 M part keys (2^16 words) and
+    against the widest it takes (DF_BITS_MAX_DOMAIN: 2^22 words), and
+    the scatter that makes the table from a build side of 2^17 slots
+    (`_df_bit_table`). One gather a row, no sort: the programs are small
+    and compile in seconds. (`_pack_rows`, the sort that carries a
+    batch's columns, takes the compiler 171 s at 2^20 rows and 33 s at
+    2^14: not here, see the module docstring.)"""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+
+    key = _sds((BATCH,), jnp.int64, one_chip)
+    batch = RelBatch([Column(T.BIGINT, key, None, None) for _ in range(6)], None)
+    scalar = _sds((), jnp.int64, one_chip)
+    for n_words in (1 << 16, O.DF_BITS_MAX_DOMAIN // 32):
+        compiled = O._df_filter_bits.lower(
+            batch, (key, None), _sds((n_words,), jnp.uint32, one_chip),
+            scalar, scalar, _sds((2,), jnp.int64, one_chip),
+        ).compile()
+        assert "gather" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    slots = 1 << 17
+    compiled = O._df_bit_table.lower(
+        _sds((slots,), jnp.int64, one_chip), _sds((slots,), jnp.bool_, one_chip),
+        scalar, n_words=1 << 16,
+    ).compile()
+    assert "scatter" in compiled.as_text()
 
 
 def test_distributed_groupby_step_compiles_for_four_v5e(topo):

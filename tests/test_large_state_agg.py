@@ -233,20 +233,54 @@ def test_the_set_filter_keeps_the_rows_that_will_match_and_no_null():
     build_keys = jnp.asarray([7, 5, 2, 11], dtype=jnp.int64)
     usable = jnp.asarray([True, True, True, False])                 # 11: a dead slot
     key = (probe.columns[0].data, probe.columns[0].valid)
-    out, kept = O._df_filter_set(probe, key, *O._df_key_set(build_keys, usable))
+    none = jnp.zeros(2, dtype=jnp.int64)
+    out, kept, totals = O._df_filter_set(
+        probe, key, *O._df_key_set(build_keys, usable, 4), none)
     assert int(kept) == 3
+    # (rows in, rows kept) so far: 7 live rows came, 3 stay
+    assert np.asarray(totals).tolist() == [7, 3]
     assert np.asarray(out.live_mask()).tolist() == [
         True, True, False, False, False, False, True, False]
     # no live key: nothing passes
-    out, kept = O._df_filter_set(probe, key, *O._df_key_set(
-        build_keys, jnp.zeros(4, dtype=jnp.bool_)))
+    out, kept, totals = O._df_filter_set(probe, key, *O._df_key_set(
+        build_keys, jnp.zeros(4, dtype=jnp.bool_), 4), totals)
     assert int(kept) == 0 and not np.asarray(out.live_mask()).any()
+    assert np.asarray(totals).tolist() == [14, 3]
     # the comparison is on the low 32 bits: a key 2^32 away passes the
     # filter (and the join, which compares whole keys, drops it)
     far = keyed_batch([5 + 2**32, 6 + 2**32])
-    out, kept = O._df_filter_set(far, (far.columns[0].data, None),
-                                 *O._df_key_set(build_keys, usable))
+    out, kept, _ = O._df_filter_set(far, (far.columns[0].data, None),
+                                    *O._df_key_set(build_keys, usable, 4), none)
     assert np.asarray(out.live_mask()).tolist() == [True, False]
+
+
+def test_a_sparse_build_sides_key_set_takes_the_slots_its_keys_need():
+    """A build side HashBuildSink does not pack (under 2^17 slots) may be
+    mostly dead: its usable keys are counted once and compared in the
+    power of two that holds them (DF_SET_MIN_SLOTS at least), and the
+    filter keeps what it kept; a build side of at most DF_SET_MIN_SLOTS
+    slots is not counted."""
+    rng = np.random.default_rng(351)
+    keys = rng.choice(50_000, 2048, replace=False)
+    live = np.zeros(2048, dtype=bool)
+    live[rng.choice(2048, 150, replace=False)] = True
+    probed = rng.integers(0, 50_000, 8192)
+    syncs = []
+    inner = O.host_sync
+    try:
+        O.host_sync = lambda site, *a, **k: syncs.append(site) or inner(site, *a, **k)
+        df, out = filtered_by(build_of(keys, live=live), [keyed_batch(probed)])
+        assert df._path == "set" and df._key_set[0].shape == (256,)
+        assert sorted(np.asarray(df._key_set[0]).tolist()) == sorted(
+            keys[live].tolist() + [int(keys[live][0])] * 106)
+        assert syncs.count("join.dynamic_filter_keys") == 1
+        kept = [k for k, _ in live_rows(out)]
+        assert kept == probed[np.isin(probed, keys[live])].tolist()
+        del syncs[:]
+        small, _ = filtered_by(build_of(keys[:128], live=live[:128]), [keyed_batch(probed)])
+        assert small._key_set[0].shape == (128,) and "join.dynamic_filter_keys" not in syncs
+    finally:
+        O.host_sync = inner
 
 
 def test_front_rows_packs_the_live_rows_in_order():
@@ -313,7 +347,7 @@ def test_the_set_filter_and_the_range_filter_hand_the_join_the_same_matches(buil
     key-set filter's survivors joined equal the range filter's survivors
     joined equal what numpy says matches; up to DF_SET_MAX_SLOTS build
     slots the filter itself is the set's (it keeps the matches and
-    nothing else), one above it is the range's."""
+    nothing else), one above it the bits' (PR 35: so does it)."""
     rng = np.random.default_rng(33 + build_rows)
     build_keys = rng.choice(1 << 16, size=build_rows, replace=False)
     bridge = O.JoinBridge()
@@ -342,7 +376,10 @@ def test_the_set_filter_and_the_range_filter_hand_the_join_the_same_matches(buil
         filtered += drain(df)
         by[name] = (df, filtered)
     chosen, filtered = by["chosen"]
-    assert (chosen._domains is None) is (build_rows <= O.DF_SET_MAX_SLOTS)
+    # (one above the set's limit, 4,097 keys scattered over 2^16 values:
+    # the bits, which keep the matches and nothing else too)
+    assert (chosen._key_set is not None) is (build_rows <= O.DF_SET_MAX_SLOTS)
+    assert (chosen._bits is not None) is (build_rows > O.DF_SET_MAX_SLOTS)
     assert by["range"][0]._key_set is None
     if chosen._domains is None:
         kept = [np.asarray(b.columns[0].data)[np.asarray(b.live_mask())] for b in filtered]
@@ -350,13 +387,19 @@ def test_the_set_filter_and_the_range_filter_hand_the_join_the_same_matches(buil
     assert joined_keys(bridge, filtered) == joined_keys(bridge, by["range"][1]) == sorted(matches)
 
 
-@pytest.mark.parametrize("second_keeps, then_by", [(1500, "set"), (8192, "range")])
-def test_a_batch_that_keeps_more_than_the_gathered_batch_holds_ends_the_readbacks(
+@pytest.mark.parametrize("second_keeps, then_by", [(1500, "packed"), (8192, "range")])
+def test_a_batch_that_keeps_more_than_the_gathered_batch_holds_ends_the_gathering(
         second_keeps, then_by):
-    """The set is not that selective on this probe: from that batch on
-    the filter hands on what it masked, at the batch's own capacity and
-    without reading a count; where the batch kept over a quarter of its
-    slots, by the build side's range, as a large build side's filter."""
+    """The set is not that selective on this probe. A count is read one
+    batch late (the next batch's filter is on the device by then). A
+    batch that keeps over DF_PACK_MIN_SLOTS rows and at most a quarter
+    of its slots is packed into a power of two of slots, and comes out
+    put behind the parts before it (they come out as one batch of the
+    power of two that holds their rows where they fill no batch of the
+    scan's capacity); one that keeps
+    more ends the readbacks: from it on the filter hands on what it
+    masked, at the batch's own capacity and without reading a count, by
+    the build side's range, as a large build side's filter."""
     bridge = O.JoinBridge()
     sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
     sink.add_input(keyed_batch(np.arange(600) * 2))               # even keys 0..1198
@@ -367,9 +410,33 @@ def test_a_batch_that_keeps_more_than_the_gathered_batch_holds_ends_the_readback
     keys = np.full(8192, -1)
     keys[:second_keeps] = (np.arange(second_keeps) % 600) * 2
     df.add_input(keyed_batch(keys))
-    gathered, whole = df.get_output(), df.get_output()
-    assert gathered.capacity == O.DF_PACK_MIN_SLOTS and whole.capacity == 8192
-    assert int(np.asarray(whole.live_mask()).sum()) == second_keeps and not df._gathering
+    assert df.get_output() is None                # the first batch's count is read now
+    df.add_input(keyed_batch(np.arange(8192)))    # and the second's now: keeps 600
+    gathered = df.get_output()
+    assert gathered.capacity == O.DF_PACK_MIN_SLOTS
+    assert int(np.asarray(gathered.live_mask()).sum()) == 600
+    if then_by == "packed":
+        assert df.get_output() is None and df._gathering and df._packing
+        assert (df._room.capacity, df._room_taken) == (8192 + 2048, 1500)
+        df.finish()
+        (whole,) = drain(df)
+        # 1,500 rows packed into 2,048 slots, the last batch's 600 into
+        # 512: one behind the other, in the power of two that holds them
+        assert whole.capacity == 4096
+        live = np.asarray(whole.live_mask())
+        assert live.tolist() == [True] * 2100 + [False] * (4096 - 2100)
+        assert np.asarray(whole.columns[0].data)[live].tolist() == (
+            keys[:1500].tolist() + list(range(0, 1200, 2)))
+        assert np.asarray(whole.columns[1].data)[live].tolist() == (
+            list(range(1500)) + list(range(0, 1200, 2)))
+        assert df.is_finished()
+        return
+    whole, third = drain(df)
+    assert whole.capacity == third.capacity == 8192 and not df._gathering
+    assert int(np.asarray(whole.live_mask()).sum()) == second_keeps
+    # the batch that was on the device meanwhile (by the set still)
+    # leaves masked too, and from here on nothing is read
+    assert int(np.asarray(third.live_mask()).sum()) == 600
     syncs = []
     inner = O.host_sync
     try:
@@ -377,9 +444,9 @@ def test_a_batch_that_keeps_more_than_the_gathered_batch_holds_ends_the_readback
         df.add_input(keyed_batch(np.arange(8192)))
     finally:
         O.host_sync = inner
-    out = df.get_output()
-    assert out.capacity == 8192 and syncs == []
-    assert int(np.asarray(out.live_mask()).sum()) == {"set": 600, "range": 1199}[then_by]
+    (fourth,) = drain(df)
+    assert fourth.capacity == 8192 and syncs == []
+    assert int(np.asarray(fourth.live_mask()).sum()) == 1199      # by the range
     df.finish()
     assert df.get_output() is None and df.is_finished()
 
@@ -393,6 +460,8 @@ def test_the_gathered_batch_is_emitted_when_the_next_would_overfill_it():
     df.add_input(keyed_batch(np.arange(8192)))                    # keeps 700
     assert df.get_output() is None
     df.add_input(keyed_batch(np.arange(8192) - 7600))             # keeps 592: 1,292 in all
+    assert df.get_output() is None            # (its count is read a batch late)
+    df.add_input(keyed_batch(np.arange(8192) + 10000))            # keeps none
     first = df.get_output()
     assert int(np.asarray(first.live_mask()).sum()) == 700 and df.get_output() is None
     df.finish()
@@ -400,13 +469,15 @@ def test_the_gathered_batch_is_emitted_when_the_next_would_overfill_it():
     assert int(np.asarray(second.live_mask()).sum()) == 592 and df.is_finished()
 
 
-@pytest.mark.parametrize("live_rows, on_device", [(66, True), (6000, False)])
+@pytest.mark.parametrize("live_rows, on_device", [(66, True), (6000, True)])
 def test_a_sparse_build_side_is_packed_on_the_device_when_small(live_rows, on_device,
                                                                 monkeypatch):
     """What a HAVING leaves of a large group table: up to
-    `_DEVICE_PACK_MAX_SLOTS` rows are picked out on the device, so the
-    table's slots never cross to the host; a wider build keeps the
-    host's pass. Either way the lookup source holds the live rows."""
+    `_DEVICE_PACK_MAX_SLOTS` rows are picked out on the device by a
+    top_k, more by one sort that carries the columns (`_pack_sorted`),
+    so the table's slots never cross to the host (the host's pass is
+    left for nested columns). Either way the lookup source holds the
+    live rows, in order."""
     from trino_tpu.exec import serde
 
     crossed = []
@@ -425,6 +496,353 @@ def test_a_sparse_build_side_is_packed_on_the_device_when_small(live_rows, on_de
     assert (crossed == []) is on_device
     kept = np.asarray(build.columns[0].data)[np.asarray(build.live_mask())]
     assert kept.tolist() == (np.nonzero(live)[0] * 3).tolist()
+
+
+# -- the dynamic filter by key bits, and the packing behind it (PR 35) -----------------------
+
+
+def build_of(keys, valid=None, live=None):
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(keys, valid=valid, live=live))
+    sink.finish()
+    return bridge
+
+
+def filtered_by(bridge, probes):
+    df = O.DynamicFilterOperator(bridge, [0])
+    out = []
+    for probe in probes:
+        df.add_input(probe)
+        out += drain(df)
+    df.finish()
+    return df, out + drain(df)
+
+
+def live_rows(batches):
+    """[(key, payload)] of the live rows, in order."""
+    rows = []
+    for b in batches:
+        live = np.asarray(b.live_mask())
+        rows += list(zip(np.asarray(b.columns[0].data)[live].tolist(),
+                         np.asarray(b.columns[1].data)[live].tolist()))
+    return rows
+
+
+@pytest.mark.parametrize("case", ["scattered", "negative_keys", "null_and_dead_build_slots",
+                                  "two_keys_far_apart", "wide_domain_inside_the_limit"])
+def test_the_bits_filter_keeps_what_numpy_isin_keeps(case):
+    """Seeded keys, three probe batches with NULLs and dead rows, the
+    domain's edges among the probe keys: behind a build side of more
+    than DF_SET_MAX_SLOTS slots whose keys lie scattered over a narrow
+    domain the filter keeps exactly the rows `numpy.isin` keeps, once
+    each and in order, and the join finds all of them."""
+    rng = np.random.default_rng(3500 + len(case))
+    slots = 2 * O.DF_SET_MAX_SLOTS
+    lo, hi = {"scattered": (1, 200_000), "negative_keys": (-150_000, 50_000),
+              "null_and_dead_build_slots": (7, 90_000), "two_keys_far_apart": (41, 150_041),
+              "wide_domain_inside_the_limit": (-(1 << 25), 1 << 25)}[case]
+    n_keys = 2 if case == "two_keys_far_apart" else 6000
+    keys = np.zeros(slots, dtype=np.int64)
+    keys[1:n_keys - 1] = rng.choice(np.arange(lo + 1, hi), n_keys - 2, replace=False)
+    keys[0], keys[n_keys - 1] = lo, hi                      # the edges are keys
+    live = np.arange(slots) < n_keys
+    valid = np.ones(slots, dtype=bool)
+    if case == "null_and_dead_build_slots":
+        valid[5:400:7] = False
+        live[1000:1100] = False
+    bridge = build_of(keys, valid=valid, live=live)
+    usable = keys[live & valid]
+    probes, want = [], []
+    for _ in range(3):
+        pk = rng.integers(lo - 50, hi + 51, 8192)
+        pk[:6] = [lo - 1, lo, lo + 1, hi - 1, hi, hi + 1]
+        pv, pl = rng.random(8192) < 0.95, rng.random(8192) < 0.9
+        pv[:6] = pl[:6] = True
+        probes.append(keyed_batch(pk, valid=pv, live=pl))
+        hit = pv & pl & np.isin(pk, usable)
+        want += [(int(k), i) for i, (k, h) in enumerate(zip(pk, hit)) if h]
+    df, filtered = filtered_by(bridge, probes)
+    assert df._bits is not None and df._key_set is None and df._path == "bits"
+    assert live_rows(filtered) == want
+    assert joined_keys(bridge, filtered) == sorted(k for k, _ in want)
+
+
+@pytest.mark.parametrize("case, path", [
+    ("few_slots", "set"), ("scattered", "bits"), ("dense_domain", "range"),
+    ("domain_too_wide", "range"), ("dead_build", "range"), ("two_keys", "range"),
+    ("too_many_slots", "range")])
+def test_which_of_the_three_filters_a_build_side_gets(case, path, monkeypatch):
+    """By what the build side is: its slots, its live keys, hi - lo."""
+    slots = 2 * O.DF_SET_MAX_SLOTS
+    keys = np.arange(slots, dtype=np.int64) * 5               # a fifth of the domain
+    live = None
+    if case == "few_slots":
+        keys = keys[:O.DF_SET_MAX_SLOTS]
+    elif case == "dense_domain":
+        keys = np.arange(slots, dtype=np.int64) + 10           # every value of the domain
+    elif case == "domain_too_wide":
+        keys[-1] = O.DF_BITS_MAX_DOMAIN + 5
+    elif case == "dead_build":
+        live = np.zeros(slots, dtype=bool)
+    elif case == "too_many_slots":
+        monkeypatch.setattr(O, "DF_BITS_MAX_SLOTS", slots // 2)
+    before = {p: METRICS.counter(f"df_filter_path.{p}") for p in ("set", "bits", "range")}
+    rows = (METRICS.counter("df_rows_in"), METRICS.counter("df_rows_kept"))
+    if case == "two_keys":
+        bridge = O.JoinBridge()
+        sink = O.HashBuildSink(bridge, [0, 1], [(T.BIGINT, None), (T.BIGINT, None)])
+        sink.add_input(keyed_batch(keys))
+        sink.finish()
+        df = O.DynamicFilterOperator(bridge, [0, 1])
+    else:
+        df = O.DynamicFilterOperator(build_of(keys, live=live), [0])
+    probed = np.arange(8192) * 7
+    df.add_input(keyed_batch(probed))
+    df.finish()
+    out = drain(df)
+    assert df._path == path
+    assert (df._key_set is not None, df._bits is not None, df._domains is not None) == (
+        path == "set", path == "bits", path == "range")
+    moved = {p: METRICS.counter(f"df_filter_path.{p}") - n for p, n in before.items()}
+    assert moved == {p: int(p == path) for p in moved}
+    kept = sum(int(np.asarray(b.live_mask()).sum()) for b in out)
+    usable = keys if live is None else keys[live]
+    if path == "range":
+        want = int(((probed >= usable.min()) & (probed <= usable.max())).sum()) if len(usable) else 0
+    else:
+        want = int(np.isin(probed, usable).sum())
+    assert want == {"few_slots": 586, "scattered": 1171, "dense_domain": 1170,
+                    "domain_too_wide": 8192, "dead_build": 0, "two_keys": 5851,
+                    "too_many_slots": 5851}[case]
+    assert kept == want
+    assert (METRICS.counter("df_rows_in") - rows[0],
+            METRICS.counter("df_rows_kept") - rows[1]) == (8192, want)
+
+
+def test_the_bit_table_holds_one_bit_a_key_and_nothing_for_a_dead_slot():
+    keys = jnp.asarray([10, 41, 42, 10, 73, 500], dtype=jnp.int64)
+    usable = jnp.asarray([True, True, True, True, True, False])
+    words = np.asarray(O._df_bit_table(keys, usable, jnp.int64(10), 128))
+    assert words.dtype == np.uint32 and words.shape == (128,)
+    bits = np.nonzero((words[:, None] >> np.arange(32, dtype=np.uint32)) & 1)
+    assert (bits[0] * 32 + bits[1] + 10).tolist() == [10, 41, 42, 73]
+
+
+def wide_batch(n, live, rng):
+    """A batch of every lane a sort has to carry: a bigint with NULLs, a
+    varchar's codes, a boolean, a long decimal's two limbs."""
+    from trino_tpu.block import Dictionary
+
+    limbs = rng.integers(-(1 << 40), 1 << 40, (n, 2))
+    return RelBatch([
+        Column(T.BIGINT, jnp.arange(n, dtype=jnp.int64), jnp.asarray(rng.random(n) < 0.9), None),
+        Column(T.VARCHAR, jnp.asarray(rng.integers(0, 3, n).astype(np.int32)), None,
+               Dictionary(["a", "b", "c"])),
+        Column(T.BOOLEAN, jnp.asarray(rng.random(n) < 0.5), None, None),
+        Column(T.decimal(38, 2), jnp.asarray(limbs), None, None),
+    ] + [Column(T.BIGINT, jnp.asarray(rng.integers(0, 99, n)), None, None) for _ in range(9)],
+        jnp.asarray(live))
+
+
+@pytest.mark.parametrize("live_rows_, capacity", [(0, 64), (1, 64), (37, 64), (64, 64), (300, 64)])
+def test_packing_keeps_every_live_row_once_and_in_order(live_rows_, capacity):
+    """`_pack_rows`: one sort that carries every lane (13 columns: more
+    than one sort's operands, so two sorts on the same key). The first
+    `capacity` live rows come out, at the front, in order, with their
+    NULLs, codes, booleans and limbs."""
+    rng = np.random.default_rng(35 + live_rows_)
+    n = 1024
+    live = np.zeros(n, dtype=bool)
+    live[rng.choice(n, live_rows_, replace=False)] = True
+    batch = wide_batch(n, live, rng)
+    packed = O._pack_rows(batch, capacity)
+    assert packed.capacity == capacity and packed.width == batch.width
+    kept = min(live_rows_, capacity)
+    assert np.asarray(packed.live_mask()).tolist() == [True] * kept + [False] * (capacity - kept)
+    at = np.nonzero(live)[0][:kept]
+    for got, had in zip(packed.columns, batch.columns):
+        assert got.type == had.type and got.dictionary == had.dictionary
+        assert got.data.dtype == had.data.dtype
+        assert np.array_equal(np.asarray(got.data)[:kept], np.asarray(had.data)[at])
+        assert (got.valid is None) == (had.valid is None)
+        if had.valid is not None:
+            assert np.array_equal(np.asarray(got.valid)[:kept], np.asarray(had.valid)[at])
+    assert packed.to_pylists() == [r for r, keep in zip(
+        RelBatch(batch.columns, None).to_pylists(), live) if keep][:kept]
+
+
+@pytest.mark.parametrize("cap, packed_by, build_keys, batches_out", [
+    (32768, "_front_rows", 10_000, 3), (131072, "_pack_rows", 10_000, 3),
+    (131072, "_pack_rows", 16_600, 4)])
+def test_packed_parts_fill_batches_of_the_scans_capacity(
+        cap, packed_by, build_keys, batches_out, monkeypatch):
+    """Behind the bits a scan of 40 batches that keep about a twentieth
+    each comes out as 3 batches of the scan's own capacity, every
+    survivor once and in order: a batch is packed into a power of two of
+    slots (a sixteenth of its own at least) and put where the part
+    before it ends, a sixteenth of the scan's slots on at least, so the
+    join's programs see one shape and scans that differ by a few rows
+    the same number of batches. A build side whose keys keep a little
+    over a sixteenth of each batch (a twelfth: parts of an eighth) fills
+    4 batches, its rows' worth, not the 5 its parts' slots would. A part
+    of at most _DEVICE_PACK_MAX_SLOTS slots is picked out by top_k, a
+    larger one by the sort that carries the columns."""
+    rng = np.random.default_rng(350)
+    keys = rng.choice(200_000, build_keys, replace=False)
+    bridge = build_of(np.concatenate([keys, np.zeros(32768 - build_keys, dtype=np.int64)]),
+                      live=np.arange(32768) < build_keys)
+    calls = []
+    for name in ("_front_rows", "_pack_rows"):
+        inner = getattr(O, name)
+        monkeypatch.setattr(
+            O, name, lambda *a, _inner=inner, _name=name, **k: calls.append(_name) or _inner(*a, **k))
+    probes, want = [], []
+    for b in range(40):
+        pk = rng.integers(0, 200_000, cap)
+        probes.append(keyed_batch(pk, payload=np.arange(cap) + b * cap))
+        hit = np.nonzero(np.isin(pk, keys))[0]
+        want += [(int(pk[i]), int(i) + b * cap) for i in hit]
+    taken, at = [], 0
+    for b in range(40):
+        kept = sum(1 for _, payload in want if payload // cap == b)
+        taken.append((at, kept))
+        at += max(kept, cap // O.DF_PACK_PARTS)
+    assert -(-at // cap) == batches_out
+    before = (METRICS.counter("df_pack_batches_in"), METRICS.counter("df_pack_batches_out"))
+    df, out = filtered_by(bridge, probes)
+    assert df._path == "bits" and df._packing
+    assert calls == [packed_by] * 40
+    assert [b.capacity for b in out] == [cap] * batches_out
+    live = np.concatenate([np.asarray(b.live_mask()) for b in out])
+    assert np.nonzero(live)[0].tolist() == [
+        start + i for start, kept in taken for i in range(kept)]
+    assert live_rows(out) == want
+    assert (METRICS.counter("df_pack_batches_in") - before[0],
+            METRICS.counter("df_pack_batches_out") - before[1]) == (40, batches_out)
+    # a column no sort can carry (a nested one): the batch leaves masked
+    monkeypatch.setattr(O, "_sortable", lambda batch: False)
+    df, out = filtered_by(bridge, probes[:3])
+    assert [b.capacity for b in out] == [cap] * 3 and not df._packing
+    assert live_rows(out) == [w for w in want if w[1] < 3 * cap]
+
+
+# -- columns nothing reads after a join (issue 35) ---------------------------------------------
+
+
+def joined(bridge, probes, kind="inner", **kwargs):
+    join = O.LookupJoinOperator(
+        bridge, [0], kind, [(T.BIGINT, None), (T.BIGINT, None)], **kwargs)
+    out = []
+    for probe in probes:
+        join.add_input(probe)
+        out += drain(join)
+    join.finish()
+    return join, out + drain(join)
+
+
+@pytest.mark.parametrize("build_keys, path", [
+    (np.arange(512) * 3, "fanout_one"), (np.arange(512) % 128 * 3, "general")])
+def test_a_join_hands_on_zeros_for_the_columns_nothing_reads(build_keys, path, monkeypatch):
+    """An inner join told that nothing downstream reads output channels
+    1 (the probe's payload) and 2 (the build side's key) puts out the
+    same pairs with zeros there, on the fanout-one path and on the
+    general one; the join still verifies the build side's key, which it
+    reads itself. A join with a residual, or one that is not inner,
+    keeps every column."""
+    calls = []
+    for name in ("_expand_pairs", "_expand_pairs_fanout1"):
+        inner = getattr(O, name)
+        monkeypatch.setattr(
+            O, name, lambda *a, _inner=inner, _name=name, **k: calls.append(_name) or _inner(*a, **k))
+    rng = np.random.default_rng(352)
+    probes = [keyed_batch(rng.integers(0, 1536, 2048), payload=np.arange(2048) + 7 + 2048 * b)
+              for b in range(2)]
+    bridge = build_of(build_keys)
+    _, whole = joined(bridge, probes)
+    _, pruned = joined(bridge, probes, unread=(1, 2))
+    assert set(calls) == {"_expand_pairs_fanout1" if path == "fanout_one" else "_expand_pairs"}
+    assert len(whole) == len(pruned) == 2
+    pairs = 0
+    for a, b in zip(whole, pruned):
+        live = np.asarray(a.live_mask())
+        pairs += int(live.sum())
+        assert np.array_equal(live, np.asarray(b.live_mask()))
+        for c in (0, 3):
+            assert np.array_equal(np.asarray(a.columns[c].data)[live],
+                                  np.asarray(b.columns[c].data)[live])
+        assert np.asarray(a.columns[1].data)[live].min() >= 7
+        for c in (1, 2):
+            assert b.columns[c].type == a.columns[c].type
+            assert not np.asarray(b.columns[c].data).any() and b.columns[c].valid is None
+    assert pairs == sum(int(np.isin(build_keys, np.asarray(p.columns[0].data)[i]).sum())
+                        for p in probes for i in range(2048))
+    for kind, kwargs in (("left", {}), ("inner", {"residual_fn": lambda pairs: pairs.live_mask()})):
+        join, out = joined(bridge, probes, kind=kind, unread=(1, 2), **kwargs)
+        assert join._unread == ()
+        assert np.asarray(out[0].columns[1].data)[np.asarray(out[0].live_mask())].min() >= 7
+
+
+def test_what_nothing_reads_of_a_joins_output_is_found_from_the_plan(tpch_local, monkeypatch):
+    """`sql/local_planner.unread_join_outputs` on Q3 and Q9: the last
+    join of Q9 hands on the six columns the projection reads of its
+    seventeen, every join below it those and the keys of the joins above
+    it; a semi-join's and a left join's own columns are all read."""
+    from trino_tpu.sql import local_planner as LP
+    from trino_tpu.sql import plan as P
+
+    found = []
+    inner = LP.unread_join_outputs
+
+    def spy(root):
+        unread = inner(root)
+        joins = []
+
+        def walk(node):
+            if isinstance(node, P.JoinNode):
+                joins.append((node, unread.get(id(node))))
+            for child in node.children():
+                walk(child)
+
+        walk(root)
+        found.append(joins)
+        return unread
+
+    monkeypatch.setattr(LP, "unread_join_outputs", spy)
+
+    def read_of(sql):
+        del found[:]
+        tpch_local.execute(sql)
+        return [(node.kind, None if unread is None else
+                 sorted(f.name for i, f in enumerate(node.fields) if i not in unread))
+                for node, unread in found[-1]]
+
+    q9 = read_of("""
+        select n_name, extract(year from o_orderdate), sum(l_extendedprice * (1 - l_discount)
+               - ps_supplycost * l_quantity)
+        from part, supplier, lineitem, partsupp, orders, nation
+        where s_suppkey = l_suppkey and ps_suppkey = l_suppkey and ps_partkey = l_partkey
+        and p_partkey = l_partkey and o_orderkey = l_orderkey and s_nationkey = n_nationkey
+        and p_name like '%green%' group by 1, 2""")
+    assert len(q9) == 5 and all(kind == "inner" for kind, _ in q9)
+    assert q9[0][1] == sorted(["l_quantity", "l_extendedprice", "l_discount", "o_orderdate",
+                               "ps_supplycost", "n_name"])
+    for (_, above), (_, below) in zip(q9, q9[1:]):
+        assert below is not None and len(below) <= len(above) + 2
+    assert all("p_name" not in read for _, read in q9)
+    q3 = read_of("""
+        select l_orderkey, sum(l_extendedprice * (1 - l_discount)), o_orderdate, o_shippriority
+        from customer, orders, lineitem
+        where c_mktsegment = 'BUILDING' and c_custkey = o_custkey and l_orderkey = o_orderkey
+        and o_orderdate < date '1995-03-15' and l_shipdate > date '1995-03-15'
+        group by l_orderkey, o_orderdate, o_shippriority""")
+    assert [kind for kind, _ in q3] == ["inner", "inner"]
+    assert set(q3[0][1]) == {"l_orderkey", "l_extendedprice", "l_discount", "o_orderdate",
+                             "o_shippriority"}
+    others = read_of("""
+        select o_orderkey, c_name from orders left join customer on c_custkey = o_custkey
+        where o_orderkey in (select l_orderkey from lineitem where l_quantity > 49)""")
+    assert others and all(read is None for _, read in others)
 
 
 # -- the semi-join: where it is planned, what it answers ------------------------------------

@@ -152,6 +152,156 @@ def test_join_null_keys_never_match():
     assert int(total) == 0
 
 
+@pytest.mark.parametrize("case", ["dense", "negative_and_sparse", "int32_against_int64",
+                                  "span_too_wide", "nulls_and_dead", "empty_build"])
+def test_exact_keys_count_matches_and_never_candidates(case):
+    """`build_lookup(exact_keys=True)` (PR 35): ONE integer key whose
+    values span under 2^32 is sorted by its distance from the least, so
+    `probe_counts` counts each probe row's true matches: no second
+    candidate from a 32-bit hash both keys happen to share, which at
+    millions of build keys kept every probe batch off the fanout-one
+    path. A probe key outside the build's range (far outside too) finds
+    nothing; a wider span falls back to the hash, whose counts are an
+    upper bound the verify still culls."""
+    rng = np.random.default_rng(35)
+    nb, npr = 5000, 20000
+    bvalid, blive = np.ones(nb, bool), np.ones(nb, bool)
+    pdtype = np.int64
+    if case == "dense":
+        bk = rng.permutation(np.arange(100, 100 + nb)).astype(np.int64)
+        pk = rng.integers(0, 6000, npr)
+    elif case == "negative_and_sparse":
+        bk = rng.choice(np.arange(-3_000_000, 3_000_000, 7), nb, replace=False).astype(np.int64)
+        pk = np.concatenate([rng.choice(bk, npr - 4), [-2**63, 2**63 - 1, -3_000_001, 3_000_001]])
+    elif case == "int32_against_int64":
+        bk = rng.integers(0, 3000, nb).astype(np.int64)          # repeats: true fan-out
+        pk, pdtype = rng.integers(-5, 3005, npr), np.int32
+    elif case == "span_too_wide":
+        bk = rng.choice(np.arange(0, 2**40, 2**20 + 1), nb, replace=False).astype(np.int64)
+        pk = np.concatenate([rng.choice(bk, npr // 2), rng.integers(0, 2**40, npr // 2)])
+    elif case == "nulls_and_dead":
+        bk = rng.integers(0, 4000, nb).astype(np.int64)
+        bvalid, blive = rng.random(nb) > 0.1, rng.random(nb) > 0.1
+        bk[~blive] = 2**62                                        # a dead slot's value bounds nothing
+        pk = rng.integers(0, 4000, npr)
+    else:
+        bk, blive = np.zeros(nb, np.int64), np.zeros(nb, bool)
+        pk = rng.integers(-10, 10, npr)
+    pk = pk.astype(pdtype)
+    pvalid = rng.random(npr) > 0.05
+    plive = rng.random(npr) > 0.05
+    ls = join.build_lookup([jnp.asarray(bk)], [jnp.asarray(bvalid)], jnp.asarray(blive),
+                           exact_keys=True)
+    lo, counts, total = join.probe_counts(
+        ls, [jnp.asarray(pk)], [jnp.asarray(pvalid)], jnp.asarray(plive))
+    usable = bk[bvalid & blive]
+    values, multiplicity = np.unique(usable, return_counts=True)
+    at = np.searchsorted(values, pk.astype(np.int64))
+    at[at == len(values)] = 0
+    true_counts = np.where(
+        (len(values) > 0) & (values[at] == pk if len(values) else False), multiplicity[at]
+        if len(values) else 0, 0) * (pvalid & plive)
+    exact = bool(ls.exact_base[1])
+    assert exact == (case not in ("span_too_wide", "empty_build"))
+    if exact:
+        assert np.asarray(counts).tolist() == true_counts.tolist()
+    else:
+        assert (np.asarray(counts) >= true_counts).all()
+    assert int(total) == int(np.asarray(counts).sum())
+    cap = max(16, 1 << int(np.ceil(np.log2(max(1, int(total))))))
+    pi, bi, ok = join.expand_matches(
+        ls, [jnp.asarray(pk)], [jnp.asarray(pvalid)], lo, counts, cap)
+    got = sorted((int(p), int(b)) for p, b, o in zip(np.asarray(pi), np.asarray(bi), np.asarray(ok)) if o)
+    want = sorted((p, b) for p in np.nonzero(pvalid & plive)[0]
+                  for b in np.nonzero((bk == pk[p]) & bvalid & blive)[0]) if case != "int32_against_int64" else None
+    if want is not None:
+        assert got == want
+    else:
+        assert len(got) == int(true_counts.sum())
+    # not asked: the hash, as before
+    plain = join.build_lookup([jnp.asarray(bk)], [jnp.asarray(bvalid)], jnp.asarray(blive))
+    assert plain.exact_base is None and plain.hash_bits == 32
+
+
+@pytest.mark.parametrize("case", ["pairs", "negative_and_int32", "too_wide", "nulls_and_dead",
+                                  "empty_build", "three_columns", "probe_in_chunks"])
+def test_exact_keys_of_several_columns_count_matches_and_never_candidates(case):
+    """`build_lookup(exact_keys=True)` on SEVERAL integer key columns (PR
+    35: `partsupp`'s (partkey, suppkey)): the columns' distances from
+    their least values make one word of up to 42 bits (what the build
+    side's slot numbers leave of 64), the build side is sorted by it and
+    `probe_counts` counts true matches; a probe key with a column outside
+    the build side's values finds nothing; columns whose values take more
+    bits than the word has fall back to the hash, whose counts are an
+    upper bound the verify still culls; a probe batch too long for a
+    42-bit value, two tag bits and a position in 64 is bounded in
+    chunks."""
+    rng = np.random.default_rng(3535)
+    nb, npr = 6000, 20000
+    columns = 2
+    spans = [2_000_000, 100_000]
+    lows = [1, 1]
+    dtypes = [np.int64, np.int64]
+    if case == "negative_and_int32":
+        lows, dtypes = [-1_000_000, -50_000], [np.int64, np.int32]
+    elif case == "too_wide":
+        spans = [2**30, 2**20]
+    elif case == "three_columns":
+        columns, spans, lows, dtypes = 3, [5000, 300, 7], [10, -3, 0], [np.int64] * 3
+    elif case == "probe_in_chunks":
+        npr = (1 << 20) + 5000
+    bks = [rng.integers(lo, lo + span, nb).astype(np.int64) for lo, span in zip(lows, spans)]
+    if case in ("pairs", "three_columns", "probe_in_chunks"):
+        bks = [np.concatenate([k, k[:50]]) for k in bks]       # repeats: true fan-out
+    nb = len(bks[0])
+    bvalid = [np.ones(nb, bool) for _ in bks]
+    blive = np.ones(nb, bool)
+    if case == "nulls_and_dead":
+        bvalid = [rng.random(nb) > 0.1 for _ in bks]
+        blive = rng.random(nb) > 0.1
+        bks[0][~blive] = 2**62                                 # a dead slot's value bounds nothing
+    elif case == "empty_build":
+        blive = np.zeros(nb, bool)
+    hit = rng.integers(0, nb, npr)
+    pks = [np.where(rng.random(npr) < 0.5, k[hit], rng.integers(lo - 5, lo + span + 5, npr))
+           for k, lo, span in zip(bks, lows, spans)]
+    same = rng.random(npr) < 0.5                               # whole build keys, half the time
+    pks = [np.where(same, k[hit], p).astype(dt) for k, p, dt in zip(bks, pks, dtypes)]
+    pks[0][:3] = [-2**31, 2**31 - 1, 0] if dtypes[0] is np.int32 else [-2**63, 2**63 - 1, 0]
+    pvalid = [rng.random(npr) > 0.03 for _ in pks]
+    plive = rng.random(npr) > 0.05
+    ls = join.build_lookup([jnp.asarray(k) for k in bks], [jnp.asarray(v) for v in bvalid],
+                           jnp.asarray(blive), exact_keys=True)
+    assert ls.hash_bits == 42 and ls.sorted_hash.dtype == jnp.uint64
+    lo, counts, total = join.probe_counts(
+        ls, [jnp.asarray(k) for k in pks], [jnp.asarray(v) for v in pvalid], jnp.asarray(plive))
+    busable = blive & np.logical_and.reduce(bvalid)
+    pusable = plive & np.logical_and.reduce(pvalid)
+    have = {}
+    for i in np.nonzero(busable)[0]:
+        have.setdefault(tuple(int(k[i]) for k in bks), []).append(int(i))
+    true_counts = np.array([len(have.get(tuple(int(k[i]) for k in pks), ())) if pusable[i] else 0
+                            for i in range(npr)])
+    exact = bool(ls.exact_base[2])
+    assert exact == (case not in ("too_wide", "empty_build"))
+    if exact:
+        assert np.array_equal(np.asarray(counts), true_counts)
+    else:
+        assert (np.asarray(counts) >= true_counts).all()
+    assert int(total) == int(np.asarray(counts).sum())
+    if case == "probe_in_chunks":
+        return
+    cap = max(16, 1 << int(np.ceil(np.log2(max(1, int(total))))))
+    pi, bi, ok = join.expand_matches(
+        ls, [jnp.asarray(k) for k in pks], [jnp.asarray(v) for v in pvalid], lo, counts, cap)
+    got = sorted((int(p), int(b)) for p, b, o in zip(np.asarray(pi), np.asarray(bi), np.asarray(ok)) if o)
+    assert got == sorted((p, b) for p in np.nonzero(pusable)[0]
+                         for b in have.get(tuple(int(k[p]) for k in pks), ()))
+    # a build side of so many slots that they leave a word of 32 bits at most: the hash
+    with pytest.raises(TypeError):
+        join.probe_counts(ls, [jnp.asarray(pks[0])], [jnp.asarray(pvalid[0])], jnp.asarray(plive))
+
+
 def test_semi_and_outer_flags():
     bk = jnp.asarray([1, 1, 3], dtype=jnp.int64)
     pk = jnp.asarray([1, 2, 3, 4], dtype=jnp.int64)

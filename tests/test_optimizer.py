@@ -7,6 +7,9 @@ eliminated) and result correctness through the engine (the whole
 TPC-H oracle suite also runs with the optimizer on, in test_tpch.py).
 """
 
+import os
+import sys
+
 import pytest
 
 from trino_tpu import types as T
@@ -322,3 +325,351 @@ def test_push_limit_through_union():
     assert isinstance(union, P.UnionAllNode)
     for inp in union.inputs:
         assert isinstance(inp, P.LimitNode) and inp.count == 4
+
+
+# -- estimates a deep join tree rests on (TPC-H Q9, PR 35) ---------------------------------
+# a key of several columns, a LIKE on a dictionary-coded column, and
+# which side of a join the wide rows end up on
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = 0.01
+CELL_STATEMENTS = ("q1", "q3", "q6", "q18", "g3")
+
+
+def _chipbench():
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from chipbench import traffic
+
+    return traffic
+
+
+def _validation_sql(name, **params):
+    traffic = _chipbench()
+    spec = traffic.load_json(os.path.join(ROOT, "chipbench", "statements", f"{name}.json"))
+    return traffic.instantiate(
+        traffic.load_statement(name), {**spec["validation"], **params}).sql
+
+
+class _MemoryTiny:
+    """chipbench's `local` runner kind over the columns ONE cell's
+    statement reads, at `tiny` (the memory connector's sampled
+    statistics; a cell loads what its statements name and no more)."""
+
+    def __init__(self):
+        self._runners = {}
+
+    def runner(self, name):
+        from trino_tpu.connectors.tpch import base_row_count, generate_column
+
+        if name not in self._runners:
+            traffic = _chipbench()
+            tables = {
+                t: {c: generate_column(t, c, TINY, 0, base_row_count(t, TINY)) for c in cols}
+                for t, cols in traffic.load_statement(name).tables.items()}
+            local = traffic.load_module(os.path.join(ROOT, "chipbench", "runners", "local.py"))
+            self._runners[name] = local.build(
+                {"schema": "chipbench", "connector": "memory", "batch_rows": 16384}, tables)
+        return self._runners[name]
+
+
+class _OneRunner:
+    def __init__(self, runner):
+        self._runner = runner
+
+    def runner(self, name):
+        return self._runner
+
+
+@pytest.fixture(scope="module")
+def memory_tiny():
+    return _MemoryTiny()
+
+
+@pytest.fixture(scope="module")
+def tpch_sf10():
+    """The tpch connector's analytic statistics at SF10: plans only."""
+    from trino_tpu.connectors.tpch import create_tpch_connector
+    from trino_tpu.engine import LocalQueryRunner, Session
+
+    r = LocalQueryRunner(Session(catalog="tpch", schema="sf10"))
+    r.register_catalog("tpch", create_tpch_connector())
+    return _OneRunner(r)
+
+
+def _explain(runner, sql):
+    return runner.execute("explain " + sql).rows[0][0]
+
+
+def test_a_two_column_join_key_is_estimated_as_the_foreign_key_it_is(memory_tiny):
+    """`lineitem x partsupp` on (partkey, suppkey): every lineitem finds
+    its one partsupp row. The product of the columns' NDVs (2,000 x 100)
+    made 60,064 x 8,000 / 200,000 = 2,402 rows of it; bounded by the
+    sides' rows and with the tuples of the side whose columns hold no
+    more values taken to lie among the other's, it is the truth within
+    a factor of two."""
+    from trino_tpu.sql import stats as S
+
+    sql = ("select count(*) from lineitem, partsupp "
+           "where ps_partkey = l_partkey and ps_suppkey = l_suppkey")
+    runner = memory_tiny.runner("q9")
+    (true_rows,), = runner.execute(sql).rows
+    assert true_rows == 60064
+    catalogs = runner.catalogs
+    calc = StatsCalculator(catalogs)
+
+    def scan(table, columns):
+        conn, handle = catalogs.resolve_table("memory", "chipbench", table)
+        meta = conn.metadata.get_table_metadata(handle)
+        types = {c.name: c.type for c in meta.columns}
+        return P.ScanNode("memory", handle, tuple(columns),
+                          tuple(P.Field(c, types[c]) for c in columns))
+
+    line = scan("lineitem", ["l_partkey", "l_suppkey"])
+    ps = scan("partsupp", ["ps_partkey", "ps_suppkey"])
+    for left, right in ((line, ps), (ps, line)):
+        join = P.JoinNode("inner", left, right, (0, 1), (0, 1), None,
+                          left.fields + right.fields)
+        assert true_rows / 2 <= calc.stats(join).row_count <= true_rows * 2
+    # what the product alone says, for the record
+    assert S._composite_key_ndv([(2000.0, 2000.0), (100.0, 100.0)], 60064.0, 8000.0) == 8000.0
+    # a side cut down to a few rows is contained in the other, not the
+    # other in it
+    assert S._composite_key_ndv([(50.0, 2000.0), (40.0, 100.0)], 60.0, 8000.0) == 8000.0
+    # no containment either way: the sides' bounds, the larger
+    assert S._composite_key_ndv([(50.0, 20.0), (4.0, 100.0)], 150.0, 1000.0) == 1000.0
+
+
+def test_like_takes_its_selectivity_from_the_dictionary(memory_tiny, tpch_sf10):
+    from trino_tpu.connectors.tpch import generate_column
+    from trino_tpu.sql import stats as S
+
+    _codes, dictionary = generate_column("part", "p_name", TINY, 0, 1)
+    share = sum("green" in v for v in dictionary.values) / len(dictionary.values)
+    assert 0.03 < share < 0.08                       # 5 of 92 words
+    pred = ir.Call("like", (ir.InputRef(1, T.VARCHAR), ir.Literal("%green%", T.VARCHAR)),
+                   T.BOOLEAN)
+    child = S.PlanStats(2000.0, {
+        0: S.ColStats(2000.0, 0.0, 1.0, 2000.0),
+        1: S.ColStats(1000.0, 0.0, None, None, dictionary=lambda: dictionary)})
+    assert S._selectivity(pred, child) == pytest.approx(share)
+    # under AND, OR and NOT the parts are estimated as before
+    both = ir.Call("and", (pred, ir.comparison("lt", ir.InputRef(0, T.BIGINT),
+                                               ir.Literal(501, T.BIGINT))), T.BOOLEAN)
+    assert S._selectivity(both, child) == pytest.approx(share * 500 / 1999)
+    # no dictionary in hand, an empty one, another column count: unknown
+    for stats in (S.PlanStats(2000.0, {}),
+                  S.PlanStats(2000.0, {1: S.ColStats(dictionary=lambda: None)})):
+        assert S._selectivity(pred, stats) == S.UNKNOWN_FILTER_COEFFICIENT
+    two = ir.comparison("eq", ir.InputRef(0, T.VARCHAR), ir.InputRef(1, T.VARCHAR))
+    assert S._selectivity(two, child) == S.UNKNOWN_FILTER_COEFFICIENT
+    # through the planner: both connectors hand their dictionary over
+    for source in (memory_tiny, tpch_sf10):
+        node = _filter_over_part(source.runner("q9"))
+        estimated = StatsCalculator(source.runner("q9").catalogs).stats(node)
+        rows = StatsCalculator(source.runner("q9").catalogs).stats(node.child).row_count
+        assert estimated.row_count == pytest.approx(rows * share)
+
+
+def _filter_over_part(runner):
+    """Filter(p_name like '%green%') over Scan(part [p_partkey, p_name])
+    of the runner's first catalog that has the table."""
+    catalogs = runner.catalogs
+    catalog = "memory" if "memory" in catalogs.catalogs() else "tpch"
+    schema = "chipbench" if catalog == "memory" else "sf10"
+    _conn, handle = catalogs.resolve_table(catalog, schema, "part")
+    fields = (P.Field("p_partkey", T.BIGINT), P.Field("p_name", T.VARCHAR))
+    scan = P.ScanNode(catalog, handle, ("p_partkey", "p_name"), fields)
+    pred = ir.Call("like", (ir.InputRef(1, T.VARCHAR), ir.Literal("%green%", T.VARCHAR)),
+                   T.BOOLEAN)
+    return P.FilterNode(scan, pred, fields)
+
+
+def _joins(plan_text):
+    """[(depth, line)] of EXPLAIN's plan lines."""
+    return [(len(line) - len(line.lstrip()), line.strip())
+            for line in plan_text.splitlines() if line.strip()]
+
+
+def lineitem_under_a_build_side(plan_text):
+    """Whether `lineitem`'s scan lies under the build (second) child of a
+    join of EXPLAIN's plan."""
+    lines = _joins(plan_text)
+    for i, (depth, line) in enumerate(lines):
+        if not line.startswith("Join "):
+            continue
+        children = [j for j in range(i + 1, len(lines)) if lines[j][0] == depth + 2]
+        # (the subtree of a join ends at the next line no deeper than it)
+        end = next((j for j in range(i + 1, len(lines)) if lines[j][0] <= depth), len(lines))
+        children = [j for j in children if j < end]
+        if len(children) < 2:
+            continue
+        build = lines[children[1]:end]
+        if any("Scan" in text and ".lineitem " in text for _d, text in build):
+            return True
+    return False
+
+
+@pytest.mark.parametrize("source", ["memory_tiny", "tpch_sf10"])
+@pytest.mark.parametrize("color", ["green", "midnight"])
+def test_q9_keeps_lineitem_on_the_probe_side_of_every_join(source, color, request):
+    runner = request.getfixturevalue(source).runner("q9")
+    text = _explain(runner, _validation_sql("q9", color=color))
+    lines = _joins(text)
+    joins = [line for _d, line in lines if line.startswith("Join ")]
+    assert len(joins) == 5 and all(j.startswith("Join inner") for j in joins)
+    assert any(j.startswith("Join inner L[2, 1]=R[1, 0]")
+               or "," in j.split("=")[0] for j in joins)       # the two-column key
+    assert not lineitem_under_a_build_side(text)
+    # the first build the fact table meets is the filtered `part`
+    at = next(i for i, (_d, line) in enumerate(lines)
+              if "Scan" in line and ".lineitem " in line)
+    assert lines[at + 1][1].startswith("Filter like(") and ".part " in lines[at + 2][1]
+    assert lines[at + 1][0] == lines[at][0]
+    # the parent's plans fail the same check (chipbench/Q9.md, step 0)
+    assert lineitem_under_a_build_side(
+        "Join inner L[0]=R[0]\n  Scan memory.chipbench.orders ['o_orderkey']\n"
+        "  Join inner L[2, 1]=R[1, 0]\n    Scan memory.chipbench.lineitem ['l_orderkey']\n"
+        "    Scan memory.chipbench.partsupp ['ps_partkey']\n")
+
+
+@pytest.mark.parametrize("source", ["memory_tiny", "tpch_sf10"])
+@pytest.mark.parametrize("name", CELL_STATEMENTS)
+def test_the_cells_statements_keep_the_parents_plans(name, source, request):
+    """EXPLAIN of the five statements the benchmark's other cells run,
+    at their validation parameters: the parent's text (commit ecdd279),
+    letter for letter."""
+    import json
+
+    with open(os.path.join(ROOT, "tests", "explain_cells_parent.json")) as fh:
+        parent = json.load(fh)
+    runner = request.getfixturevalue(source).runner(name)
+    assert _explain(runner, _validation_sql(name)) == parent[f"{name}.{source}"]
+
+
+def test_a_wide_build_side_costs_what_its_columns_cost_to_gather():
+    """The same rows either way round: the join that gathers two columns
+    an output row is cheaper than the one that gathers eight, and a
+    build side whose key is not unique pays for the probe side's
+    columns too."""
+    from trino_tpu.sql import stats as S
+
+    class Fixed(StatsCalculator):
+        def __init__(self, table):
+            super().__init__(None)
+            self._table = table
+
+        def stats(self, node):
+            if id(node) in self._table:
+                return self._table[id(node)]
+            return super().stats(node)
+
+    wide = values(0, *[f"w{i}" for i in range(8)])
+    narrow = values(0, "n0", "n1")
+    table = {
+        id(wide): S.PlanStats(3000.0, {0: S.ColStats(15000.0)}),
+        id(narrow): S.PlanStats(15000.0, {0: S.ColStats(15000.0, unique=True)}),
+    }
+    calc = CostCalculator(Fixed(table))
+    wide_probes = P.JoinNode("inner", wide, narrow, (0,), (0,), None,
+                             wide.fields + narrow.fields)
+    narrow_probes = P.JoinNode("inner", narrow, wide, (0,), (0,), None,
+                               narrow.fields + wide.fields)
+    assert calc.cost(wide_probes).total < calc.cost(narrow_probes).total
+    # before the gathers were counted the smaller side always built
+    from trino_tpu.sql import cost as C
+
+    gathers = 3000.0 * C._CPU_PAIR_COLUMN
+    assert calc.cost(wide_probes).cpu - 2 * gathers > calc.cost(narrow_probes).cpu - 10 * gathers
+
+
+def test_a_clustered_columns_ndv_is_bounded_by_its_runs():
+    """The memory connector samples a column by a stride, and a stride
+    never meets the neighbours that repeat a value: a fact table's order
+    key (four rows an order, side by side) read as one value a row, and
+    so as a key of its table. The runs of equal neighbours, counted
+    exactly, bound the distinct values; a scattered column keeps the
+    sample's estimate, a table the sample holds whole is counted as
+    before."""
+    import numpy as np
+
+    from trino_tpu.connectors.memory import create_memory_connector
+    from trino_tpu.connectors.spi import ColumnMetadata
+
+    rng = np.random.default_rng(35)
+    n = 1_200_000
+    columns = {
+        "clustered": np.repeat(np.arange(n // 4, dtype=np.int64) * 7, 4),
+        "key": np.arange(n, dtype=np.int64),
+        "scattered": rng.integers(0, 2000, n),
+        "shuffled_fk": rng.permutation(np.repeat(np.arange(n // 4, dtype=np.int64), 4)),
+    }
+    mem = create_memory_connector()
+    mem.load_table("s", "big", [ColumnMetadata(c, T.BIGINT) for c in columns],
+                   list(columns.values()), None, [None] * len(columns))
+    mem.load_table("s", "small", [ColumnMetadata("clustered", T.BIGINT)],
+                   [columns["clustered"][:1000]], None, [None])
+    stats = mem.metadata.get_table_statistics(mem.metadata.get_table_handle("s", "big"))
+    ndv = {c: v[0] for c, v in stats.columns.items()}
+    assert ndv["clustered"] == n // 4            # the sample alone says n
+    assert ndv["key"] == n
+    assert 1900 <= ndv["scattered"] <= 2100
+    assert n // 8 <= ndv["shuffled_fk"] <= n     # no runs to count: the sample's estimate
+    small = mem.metadata.get_table_statistics(mem.metadata.get_table_handle("s", "small"))
+    assert small.columns["clustered"][0] == 250
+
+
+def test_q9_keeps_its_plan_under_the_sampled_statistics_of_a_loaded_scale():
+    """Tables of SF1's row counts and TPC-H's key shapes (synthetic
+    values, made in a second): past the memory connector's 262,144-row
+    sample `l_orderkey` and `ps_partkey` lie four equal neighbours in a
+    row. Read as keys of their tables (the sample's NDV), they made
+    `orders` and `partsupp` probe what was left of the fact table, two
+    builds of 3.5 M rows a statement at SF10 (PERF.md section 6, PR 35,
+    call 2); bounded by their runs, the fact table probes all five."""
+    import numpy as np
+
+    from trino_tpu.connectors.tpch import generate_column
+
+    rng = np.random.default_rng(9)
+    orders, parts, suppliers = 1_500_000, 200_000, 10_000
+    o_key = (np.arange(orders, dtype=np.int64) // 8) * 32 + np.arange(orders) % 8 + 1
+    ps_part = np.repeat(np.arange(1, parts + 1, dtype=np.int64), 4)
+    ps_supp = (ps_part + np.tile(np.arange(4), parts) * (suppliers // 4)) % suppliers + 1
+    l_order = np.repeat(o_key, 4)
+    lines = len(l_order)
+    pick = rng.integers(0, len(ps_part), lines)
+    _codes, names = generate_column("part", "p_name", TINY, 0, 1)
+    n_codes, nations = generate_column("nation", "n_name", TINY, 0, 25)
+    small = lambda hi, n: rng.integers(1, hi, n).astype(np.int64)  # noqa: E731
+    tables = {
+        "lineitem": {"l_orderkey": (l_order, None), "l_partkey": (ps_part[pick], None),
+                     "l_suppkey": (ps_supp[pick], None), "l_quantity": (small(51, lines) * 100, None),
+                     "l_extendedprice": (small(10_000_000, lines), None),
+                     "l_discount": (small(11, lines), None)},
+        "orders": {"o_orderkey": (o_key, None),
+                   "o_orderdate": (rng.integers(8035, 10441, orders).astype(np.int32), None)},
+        "partsupp": {"ps_partkey": (ps_part, None), "ps_suppkey": (ps_supp, None),
+                     "ps_supplycost": (small(100_000, len(ps_part)), None)},
+        "part": {"p_partkey": (np.arange(1, parts + 1, dtype=np.int64), None),
+                 "p_name": (rng.integers(0, len(names.values), parts).astype(np.int32), names)},
+        "supplier": {"s_suppkey": (np.arange(1, suppliers + 1, dtype=np.int64), None),
+                     "s_nationkey": (rng.integers(0, 25, suppliers).astype(np.int64), None)},
+        "nation": {"n_nationkey": (np.arange(25, dtype=np.int64), None),
+                   "n_name": (n_codes, nations)},
+    }
+    traffic = _chipbench()
+    local = traffic.load_module(os.path.join(ROOT, "chipbench", "runners", "local.py"))
+    runner = local.build(
+        {"schema": "chipbench", "connector": "memory", "batch_rows": 1 << 20}, tables)
+    conn, handle = runner.catalogs.resolve_table("memory", "chipbench", "lineitem")
+    sampled = conn.metadata.get_table_statistics(handle)
+    assert sampled.columns["l_orderkey"][0] == orders
+    for color in ("green", "navajo"):
+        text = _explain(runner, _validation_sql("q9", color=color))
+        assert not lineitem_under_a_build_side(text)
+        lines_ = _joins(text)
+        at = next(i for i, (_d, line) in enumerate(lines_)
+                  if "Scan" in line and ".lineitem " in line)
+        assert lines_[at + 1][1].startswith("Filter like(") and ".part " in lines_[at + 2][1]

@@ -144,6 +144,12 @@ class MemoryMetadata(ConnectorMetadata):
             # samples extrapolate to ~pop, saturated samples stay at d
             denom = 1.0 - ((pop - s) / pop) * (f1 / max(s, 1))
             ndv = min(d / max(denom, 1e-9), float(pop))
+            if s < pop:
+                # a stride never meets the neighbours that repeat a value,
+                # so a clustered column (a fact table's order key) reads
+                # as all singletons; its runs of equal neighbours, counted
+                # exactly, bound its distinct values whatever the sample
+                ndv = min(ndv, float(np.count_nonzero(arr[1:] != arr[:-1]) + 1))
             lo = hi = None
             if not sc.type.is_string and arr.dtype.kind in "iuf":
                 lo, hi = float(arr.min()), float(arr.max())

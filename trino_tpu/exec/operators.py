@@ -2920,7 +2920,9 @@ def _consolidate_build(parts: Tuple[RelBatch, ...], key_channels: Tuple[int, ...
         else:
             keys.append(col.data)
             valids.append(v)
-    return J.build_lookup(keys, valids, merged.live_mask()), merged
+    return J.build_lookup(
+        keys, valids, merged.live_mask(), exact_keys=True
+    ), merged
 
 
 GRACE_PARTITIONS = 8
@@ -2934,11 +2936,12 @@ GRACE_PARTITIONS = 8
 # multi-million-row shape. Compaction remains worthwhile for runtime.)
 _SHRINK_MIN_CAPACITY = 1 << 17
 # up to this many live rows a sparse build side is packed on the device
-# (_pack_parts: top_k over the live positions, small gathers: 37 ms for
-# 66 live rows in 2^24 slots of two 8-byte columns on a v5e); the host's
+# by _pack_parts (top_k over the live positions, small gathers: 37 ms for
+# 66 live rows in 2^24 slots of two 8-byte columns on a v5e); a larger
+# one by _pack_sorted, one sort that carries the columns. The host's
 # pass brings every slot over first (84 ms there, and more a column;
-# PERF.md section 6, PR 33) and is kept for the wide outputs a top_k is
-# no good at
+# PERF.md section 6, PR 33) and is kept for nested columns, which no
+# sort carries
 _DEVICE_PACK_MAX_SLOTS = 1 << 12
 
 
@@ -3098,6 +3101,8 @@ class HashBuildSink(Operator):
             if target * 4 <= total_cap:
                 if target <= _DEVICE_PACK_MAX_SLOTS:
                     parts = (_pack_parts(parts, target),)
+                elif all(_sortable(b) for b in parts):
+                    parts = (_pack_sorted(parts, target),)
                 else:
                     from trino_tpu.exec.serde import Page as _Page
                     from trino_tpu.exec.serde import concat_pages
@@ -3240,9 +3245,20 @@ class MxuJoinAggOperator(Operator):
         return self._finishing and not self._outputs
 
 
-@partial(jax.jit, static_argnames=("out_cap", "pkc", "bkc"))
+def _without_unread(cols: List[Column], unread: Tuple[int, ...]) -> List[Column]:
+    """`cols` with zeros for the channels nothing downstream reads
+    (`sql/local_planner.unread_join_outputs`): inside a jitted program
+    the gather that made such a column is then dead code."""
+    return [
+        Column(c.type, jnp.zeros_like(c.data), None, c.dictionary)
+        if i in unread and type(c) is Column else c
+        for i, c in enumerate(cols)
+    ]
+
+
+@partial(jax.jit, static_argnames=("out_cap", "pkc", "bkc", "unread"))
 def _expand_pairs(ls, probe: RelBatch, build: RelBatch, keys, valids,
-                  lo, counts, out_cap: int, pkc=None, bkc=None):
+                  lo, counts, out_cap: int, pkc=None, bkc=None, unread=()):
     """Expansion + pair gather in one device program (JoinProbe +
     LookupJoinPageBuilder fused — join/LookupJoinOperator.java:36).
 
@@ -3269,7 +3285,7 @@ def _expand_pairs(ls, probe: RelBatch, build: RelBatch, keys, valids,
             if b.valid is not None:
                 ok = ok & b.valid
     cols = list(pairs_probe.columns) + list(pairs_build.columns)
-    return pi, bi, ok, RelBatch(cols, ok)
+    return pi, bi, ok, RelBatch(_without_unread(cols, unread), ok)
 
 
 @jax.jit
@@ -3278,9 +3294,9 @@ def _fanout_le_one(counts):
     return jnp.all(counts <= 1)
 
 
-@partial(jax.jit, static_argnames=("pkc", "bkc"))
+@partial(jax.jit, static_argnames=("pkc", "bkc", "unread"))
 def _expand_pairs_fanout1(ls, probe: RelBatch, build: RelBatch, keys,
-                          valids, lo, counts, pkc=None, bkc=None):
+                          valids, lo, counts, pkc=None, bkc=None, unread=()):
     """Fanout<=1 expansion (every probe row matches at most one build
     row — the PK-side FK join that dominates TPC-H/DS): the pair batch
     IS the probe batch with the matched build row appended. The probe
@@ -3315,7 +3331,7 @@ def _expand_pairs_fanout1(ls, probe: RelBatch, build: RelBatch, keys,
     live = probe.live_mask() & ok
     pi = jnp.arange(probe.capacity, dtype=jnp.int32)
     cols = list(probe.columns) + list(pairs_build.columns)
-    return pi, bi, live, RelBatch(cols, live)
+    return pi, bi, live, RelBatch(_without_unread(cols, unread), live)
 
 
 @jax.jit
@@ -3399,6 +3415,7 @@ class LookupJoinOperator(Operator):
         probe_schema: Sequence[Tuple[T.DataType, Optional[Dictionary]]],
         residual: Optional[Bound] = None,
         residual_fn=None,
+        unread: Sequence[int] = (),
     ):
         self._bridge = bridge
         self._keys = list(key_channels)
@@ -3409,6 +3426,13 @@ class LookupJoinOperator(Operator):
             residual_fn
             if residual_fn is not None
             else (make_residual_fn(residual) if residual is not None else None)
+        )
+        # output channels no operator downstream reads: an inner join
+        # whose pairs nothing else looks at (no residual) hands them on
+        # as zeros, and its expansion gathers nothing for them
+        self._unread = (
+            tuple(unread)
+            if join_type == "inner" and self._residual_fn is None else ()
         )
         self._outputs: List[RelBatch] = []
         self._remap_cache: Dict[tuple, jnp.ndarray] = {}
@@ -3529,6 +3553,7 @@ class LookupJoinOperator(Operator):
             pi, bi, ok, pairs = _expand_pairs_fanout1(
                 ls, probe, build, rec["keys"], rec["valids"],
                 rec["lo"], rec["counts"], pkc=pkc, bkc=bkc,
+                unread=self._unread,
             )
             if self._residual_fn is not None:
                 ok = ok & self._residual_fn(pairs)
@@ -3536,9 +3561,19 @@ class LookupJoinOperator(Operator):
             matched = ok
         else:
             out_cap = bucket_capacity(max(total, 1))
+            if dense:
+                # most probe rows match: the pairs take the probe
+                # batch's slots at least, so that what follows compiles
+                # for ONE shape whatever the batch holds (Q9's last
+                # packed batch is 50 to 62 % full by the colour: half of
+                # 2^20 slots for a few colours, 14 more programs and
+                # 466 s of compiling for each of them; PERF.md section
+                # 6, PR 35)
+                out_cap = max(out_cap, probe.capacity)
             pi, bi, ok, pairs = _expand_pairs(
                 ls, probe, build, rec["keys"], rec["valids"],
                 rec["lo"], rec["counts"], out_cap, pkc=pkc, bkc=bkc,
+                unread=self._unread,
             )
             if self._residual_fn is not None:
                 ok = ok & self._residual_fn(pairs)
@@ -3698,8 +3733,17 @@ def _df_domains(build: RelBatch, channels: tuple):
     return out
 
 
+def _df_count(totals, batch: RelBatch, keep):
+    """`totals` (rows in, rows kept) of a filter's batches so far, with
+    this batch's added: the operator reads them back once, at finish."""
+    return totals + jnp.stack([
+        jnp.sum(batch.live_mask().astype(jnp.int64)),
+        jnp.sum(keep.astype(jnp.int64)),
+    ])
+
+
 @jax.jit
-def _df_filter(batch: RelBatch, keys, domains):
+def _df_filter(batch: RelBatch, keys, domains, totals):
     """Drop probe rows outside [lo, hi] on every key (NULL keys never
     match an inner/semi join, so they drop too)."""
     keep = batch.live_mask()
@@ -3708,7 +3752,7 @@ def _df_filter(batch: RelBatch, keys, domains):
         if c_valid is not None:
             ok = ok & c_valid
         keep = keep & ok
-    return batch.mask(keep)
+    return batch.mask(keep), _df_count(totals, batch, keep)
 
 
 # A build side of at most this many slots, on one integer key, filters
@@ -3718,37 +3762,122 @@ def _df_filter(batch: RelBatch, keys, domains):
 # row the join then drops, never drop one that matches). A 2^20-row
 # batch against 128 / 1,024 / 4,096 keys takes 2.1 / 2.4 / 4.9 ms on a
 # v5e, launch included, where the range takes 1.1 and the probe the
-# filter spares (probe_counts) 16.3 (PERF.md section 6, PR 33); larger
-# sets were not measured, and the range is what they get.
+# filter spares (probe_counts) 16.3 (PERF.md section 6, PR 33).
 DF_SET_MAX_SLOTS = 1 << 12
+# A build side of more slots than this has its usable keys counted (one
+# readback a scan) and is compared in the power of two that holds them,
+# this at least: a build side too small for HashBuildSink to pack
+# (_SHRINK_MIN_CAPACITY) may be mostly dead slots (TPC-H Q9 at `tiny`:
+# 110 coloured parts in 2,048 slots, 50 of the statement's 90 ms on a
+# CPU). Not measured on the chip, where the builds that take the set
+# are packed already (PERF.md section 6, PR 35).
+DF_SET_MIN_SLOTS = 1 << 7
+# A larger build side on one integer key filters by its key BITS where
+# its keys lie scattered over a domain narrow enough to hold as one bit
+# a value (2 M part keys are 256 KB, 60 M order keys 8 MB): a word
+# gathered and a bit tested a row, exact, where the range would keep
+# every row between the least key and the greatest. Measured on a v5e
+# (PERF.md section 6, PR 35; a 2^20-row batch, launch and a readback
+# included): the bits take 9.5-9.6 ms whatever the table's size (2^16
+# to 2^22 words: the gather is paid a row, not a byte), the range 1.8,
+# the set 3.1 / 5.5 at 1,024 / 4,096 keys, and the probe a dropped
+# batch is spared (probe_counts) 14.4 against a build of 2^17 slots
+# and 78.1 against one of 2^23. So the bits earn their 9.5 ms only
+# where the batches behind them are packed (at most a quarter of the
+# slots kept, below): a build side that fills more than
+# DF_BITS_MAX_FILL of its domain gets the range. The table is made by
+# one scatter of the build side's slots (1.5 ms for 2^17 slots into 2^16
+# words, 10.1 for 2^20 into 2^22), which is what bounds them
+# (DF_BITS_MAX_SLOTS); a wider domain (DF_BITS_MAX_DOMAIN: 16 MB of
+# bits) was not measured. Whatever fits none of these gets the range.
+DF_BITS_MAX_SLOTS = 1 << 20
+DF_BITS_MAX_DOMAIN = 1 << 27
+DF_BITS_MAX_FILL = 0.25
 # The slots of the ONE batch the set filter gathers a scan's survivors
 # into, and the most rows a batch may keep and still be gathered: below
 # it a sort is no faster, and every smaller power of two would be one
 # more shape for each of the join's programs to compile at.
 DF_PACK_MIN_SLOTS = 1 << 10
+# Behind a set or a bit table a batch that keeps more than that, and at
+# most a quarter of its slots, is packed by one sort that carries its
+# columns into a power of two of slots, no fewer than this share of the
+# batch; the packed parts fill batches of the scan's own capacity, the
+# shape the join's programs compile for anyway. A part is put where the
+# rows of the one before it end, this share of the scan's slots on at
+# least: a batch that keeps a little over a sixteenth (TPC-H Q9's
+# `tomato`: 70,096 rows of 2^20, parts of 2^17 slots) then costs the
+# joins its rows' worth of batches and not its slots' (4 for 8:
+# PERF.md section 6, PR 35), and scans that keep a few rows in a
+# hundred more or fewer hand the joins the same number of batches.
+DF_PACK_PARTS = 16
 
 
-@jax.jit
-def _df_key_set(build_keys, usable):
+@partial(jax.jit, static_argnames=("slots",))
+def _df_key_set(build_keys, usable, slots: int):
     """What `_df_filter_set` compares with: the low 32 bits of the build
-    side's keys, a dead or NULL slot repeating a live one, and whether
-    there is a live one at all."""
+    side's keys in `slots` slots (no fewer than it has usable keys: they
+    are moved to the front where the build side has more slots), a dead
+    or NULL slot repeating a live one, and whether there is a live one
+    at all."""
     low = build_keys.astype(jnp.int32)
+    if slots < low.shape[0]:
+        front = jnp.argsort(~usable, stable=True)[:slots]
+        low, usable = low[front], usable[front]
     return jnp.where(usable, low, low[jnp.argmax(usable)]), jnp.any(usable)
 
 
 @jax.jit
-def _df_filter_set(batch: RelBatch, key, key_set, any_key):
+def _df_filter_set(batch: RelBatch, key, key_set, any_key, totals):
     """Keep the probe rows whose key is one of the build side's, by its
     low 32 bits (NULL keys never match an inner/semi join). Returns
-    (batch, rows kept)."""
+    (batch, rows kept, totals)."""
     c_data, c_valid = key
     low = c_data.astype(jnp.int32)
     hit = jnp.any(low[:, None] == key_set[None, :], axis=1) & any_key
     keep = batch.live_mask() & hit
     if c_valid is not None:
         keep = keep & c_valid
-    return batch.mask(keep), jnp.sum(keep.astype(jnp.int32))
+    return (batch.mask(keep), jnp.sum(keep.astype(jnp.int32)),
+            _df_count(totals, batch, keep))
+
+
+@partial(jax.jit, static_argnames=("n_words",))
+def _df_bit_table(build_keys, usable, lo, n_words: int):
+    """One bit a value of [lo, lo + 32 * n_words), set where the build
+    side has the key, as uint32 words: one scatter of the build side's
+    slots (a dead or NULL slot lands outside and is dropped) and a
+    reduce over the 32 bits of each word."""
+    slot = jnp.where(
+        usable, build_keys.astype(jnp.int64) - lo, jnp.int64(32 * n_words)
+    )
+    there = jnp.zeros(32 * n_words, dtype=jnp.bool_).at[slot].set(
+        True, mode="drop"
+    )
+    bit = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+    return jnp.sum(
+        jnp.where(there.reshape(n_words, 32), bit, jnp.uint32(0)),
+        axis=1, dtype=jnp.uint32,
+    )
+
+
+@jax.jit
+def _df_filter_bits(batch: RelBatch, key, words, lo, hi, totals):
+    """Keep the probe rows whose key the build side has: inside
+    [lo, hi], and its bit set in `words` (one gather a row; NULL keys
+    never match an inner/semi join). Returns (batch, rows kept,
+    totals)."""
+    c_data, c_valid = key
+    k = c_data.astype(jnp.int64)
+    slot = k - lo
+    word = take_clip(
+        words, jnp.clip(slot >> 5, 0, words.shape[0] - 1).astype(jnp.int32)
+    )
+    bit = (word >> (slot & 31).astype(jnp.uint32)) & jnp.uint32(1)
+    keep = batch.live_mask() & (k >= lo) & (k <= hi) & (bit == 1)
+    if c_valid is not None:
+        keep = keep & c_valid
+    return (batch.mask(keep), jnp.sum(keep.astype(jnp.int32)),
+            _df_count(totals, batch, keep))
 
 
 @partial(jax.jit, static_argnames=("capacity",))
@@ -3770,6 +3899,121 @@ def _pack_parts(parts: Tuple[RelBatch, ...], capacity: int) -> RelBatch:
     return _front_rows(concat_batches(list(parts)), capacity)
 
 
+def _sortable(batch: RelBatch) -> bool:
+    """Whether every column can ride a sort as payload (flat columns;
+    nested ones keep their element stores elsewhere)."""
+    return all(type(c) is Column for c in batch.columns)
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _pack_rows(batch: RelBatch, capacity: int) -> RelBatch:
+    """The first `capacity` live rows of `batch`, in order, at the front
+    of a batch of `capacity` slots: ONE sort on the live rows' positions
+    that carries every column as payload (a dead slot sorts last). No
+    gather: a carried column costs a fraction of what gathering it at
+    `capacity` places would (ops/groupby.py, the carried compaction)."""
+    n = batch.capacity
+    pos = jnp.where(
+        batch.live_mask(), jnp.arange(n, dtype=jnp.int32), jnp.int32(n)
+    )
+    lanes, layout = [], []
+    for col in batch.columns:
+        if getattr(col.data, "ndim", 1) == 2:  # long-decimal limbs
+            data = [col.data[:, j] for j in range(col.data.shape[1])]
+        else:
+            data = [col.data]
+        layout.append((len(data), col.valid is not None))
+        lanes.extend(data)
+        if col.valid is not None:
+            lanes.append(col.valid)
+    lanes = [x.astype(jnp.int8) if x.dtype == jnp.bool_ else x for x in lanes]
+    carried, order = [], pos
+    budget = G._MAX_SORT_OPERANDS - 1
+    for at in range(0, len(lanes), budget):
+        out = jax.lax.sort(
+            tuple([pos] + lanes[at:at + budget]), num_keys=1, is_stable=False
+        )
+        order = out[0]
+        carried.extend(x[:capacity] for x in out[1:])
+    cols, at = [], 0
+    for col, (n_data, has_valid) in zip(batch.columns, layout):
+        data = carried[at:at + n_data]
+        at += n_data
+        data = data[0] if n_data == 1 else jnp.stack(data, axis=1)
+        valid = None
+        if has_valid:
+            valid = carried[at].astype(jnp.bool_)
+            at += 1
+        cols.append(Column(col.type, data.astype(col.data.dtype), valid,
+                           col.dictionary))
+    return RelBatch(cols, order[:capacity] < n)
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _pack_sorted(parts: Tuple[RelBatch, ...], capacity: int) -> RelBatch:
+    """The live rows of `parts`, in order, as ONE batch of `capacity`
+    (`_pack_parts` for a capacity a top_k is no good at)."""
+    return _pack_rows(concat_batches(list(parts)), capacity)
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _pack_room(part: RelBatch, capacity: int) -> RelBatch:
+    """`capacity` empty slots of `part`'s columns: where a scan's packed
+    parts are put one behind the other (`_pack_place`)."""
+
+    def room(x):
+        return jnp.zeros((capacity,) + x.shape[1:], x.dtype)
+
+    cols = [
+        Column(c.type, room(c.data),
+               None if c.valid is None else room(c.valid), c.dictionary)
+        for c in part.columns
+    ]
+    return RelBatch(cols, jnp.zeros(capacity, dtype=jnp.bool_))
+
+
+@jax.jit
+def _pack_place(room: RelBatch, part: RelBatch, at) -> RelBatch:
+    """`part` written into `room` from slot `at` on, its mask with it. A
+    packed part's live rows are its first, so the next part, put where
+    they end, overwrites only dead slots."""
+
+    def put(x, y):
+        return jax.lax.dynamic_update_slice(
+            x, y, (at,) + (0,) * (y.ndim - 1)
+        )
+
+    cols = [
+        Column(c.type, put(c.data, p.data),
+               None if c.valid is None else put(c.valid, p.valid),
+               c.dictionary)
+        for c, p in zip(room.columns, part.columns)
+    ]
+    return RelBatch(cols, put(room.live, part.live_mask()))
+
+
+@partial(jax.jit, static_argnames=("capacity",))
+def _pack_take(room: RelBatch, capacity: int):
+    """(the first `capacity` slots of `room` as a batch, `room` with what
+    lay behind them moved to its front)."""
+
+    def head(x):
+        return x[:capacity]
+
+    def rest(x):
+        return jnp.concatenate([x[capacity:], jnp.zeros_like(x[:capacity])])
+
+    def cut(f):
+        cols = [
+            Column(c.type, f(c.data),
+                   None if c.valid is None else f(c.valid), c.dictionary)
+            for c in room.columns
+        ]
+        return RelBatch(cols, f(room.live))
+
+    return cut(head), cut(rest)
+
+
 class DynamicFilterOperator(Operator):
     """Probe-side pruning from build-side key domains — the LOCAL form
     of dynamic filtering (DynamicFilterSourceOperator + DynamicFilter
@@ -3781,29 +4025,61 @@ class DynamicFilterOperator(Operator):
     unless both sides share the dictionary (code order is only
     meaningful within one dictionary).
 
-    A small build side on one integer key (DF_SET_MAX_SLOTS) filters by
-    its key set: the probe then sees only rows that will match. While
-    the batches keep no more rows than one small batch holds
-    (DF_PACK_MIN_SLOTS), their count is read back and the survivors of
-    successive batches are gathered into that one batch (emitted when
-    it is full, and at finish) before the join sorts anything; the
-    first batch that keeps more ends the reading, and the rest of the
-    scan leaves masked and unread: by the set still, or by the range
-    where that batch kept over a quarter of its slots."""
+    One of three filters, by what the build side is (`_prepare`): on one
+    integer key a small build side (DF_SET_MAX_SLOTS) filters by its key
+    SET, a larger one whose keys lie scattered over a narrow domain by
+    its key BITS, and everything else by the RANGE of each key. Behind
+    the set and the bits the probe sees only rows that will match, so
+    their batches are mostly dead slots, and the operator packs them
+    before the join sorts anything. A batch's count of survivors is read
+    back one batch late (the next batch's filter is on the device by
+    then). While batches keep no more rows than one small batch holds
+    (DF_PACK_MIN_SLOTS), the survivors of successive batches are
+    gathered into that one batch; from the first batch that keeps more,
+    each batch is packed (`_front_rows` while the part is small, one
+    sort that carries the columns beyond) into a part of a sixteenth of
+    its slots or more (DF_PACK_PARTS) and put behind the parts before it
+    (`_pack_place`: where their rows end, a sixteenth of the scan's slots
+    on at least), and what they fill goes out in batches of the scan's
+    capacity, as they fill and at finish (a scan whose parts fill no
+    batch puts out one batch, of the power of two that holds them).
+    The first batch that keeps over a quarter of its slots ends the
+    reading: the rest of the scan leaves masked and unread, by the range
+    where the set would cost more than it drops."""
 
     def __init__(self, bridge: JoinBridge, key_channels: Sequence[int]):
         self._bridge = bridge
         self._keys = list(key_channels)
         self._domains = None
         self._key_set = None
+        self._bits = None
         self._active_channels: Optional[List[int]] = None
         self._outs: List[RelBatch] = []
+        # (rows in, rows kept) so far, on the device until finish, and
+        # what the host knows of them: batches, their slots, the filter
+        self._totals = None
+        self._batches = self._slots = self._key_bytes = 0
+        self._path = None
+        # filtered batches whose count of survivors is not read yet
+        self._unread: List[tuple] = []
+        # whether the survivors' counts are still read and acted on
+        self._gathering = True
         # the set filter's survivors, gathered over the scan's batches
         # into ONE batch of DF_PACK_MIN_SLOTS slots: the join and what
         # follows it then run once, not once a scan batch
-        self._gathering = True
         self._gathered: Optional[RelBatch] = None
         self._gathered_rows = 0
+        # packed parts put one behind the other until they fill a batch
+        # of the scan's capacity: the slots they lie in (a batch and the
+        # largest part more), how many of them are taken, the scan's
+        # capacity
+        self._packing = False
+        self._room: Optional[RelBatch] = None
+        self._room_taken = 0
+        self._part_capacity = 0
+        # whether a batch of laid parts, or one left unpacked, has gone
+        # out at the scan's capacity
+        self._laid_full = False
 
     def _prepare(self, probe: RelBatch) -> None:
         build = self._bridge.build_batch
@@ -3821,22 +4097,63 @@ class DynamicFilterOperator(Operator):
             elif key_dicts[i] is not None and key_dicts[i] == probe_dict:
                 active.append((i, c))
         self._active_channels = active
+        if not active:
+            return
+        self._totals = jnp.zeros(2, dtype=jnp.int64)
         key = None
-        if len(active) == 1 and build.capacity <= DF_SET_MAX_SLOTS:
+        if len(active) == 1:
             key = build.columns[self._bridge.build_key_channels[active[0][0]]]
-        if key is not None and jnp.issubdtype(key.data.dtype, jnp.integer):
-            self._key_set = _df_key_set(
-                key.data, build.live_mask() & key.valid_mask()
-            )
-        elif active:
+            if not jnp.issubdtype(key.data.dtype, jnp.integer):
+                key = None
+        with host_span("df.prepare", build_slots=build.capacity) as span:
+            if key is not None and build.capacity <= DF_SET_MAX_SLOTS:
+                usable = build.live_mask() & key.valid_mask()
+                slots = build.capacity
+                if slots > DF_SET_MIN_SLOTS:
+                    # every probe row is compared with every slot: a
+                    # sparse build side's keys are counted, once, and
+                    # compared in the power of two that holds them
+                    slots = min(slots, max(
+                        bucket_capacity(_count("join.dynamic_filter_keys", usable)),
+                        DF_SET_MIN_SLOTS,
+                    ))
+                self._key_set = _df_key_set(key.data, usable, slots)
+                self._path = "set"
+                span.set_metadata(path="set", slots=slots)
+                return
             self._use_range()
+            if key is None or build.capacity > DF_BITS_MAX_SLOTS:
+                span.set_metadata(path="range")
+                return
+            usable = build.live_mask() & key.valid_mask()
+            lo, hi, any_key = self._domains[0]
+            with host_sync("join.dynamic_filter_domain", 32):
+                lo, hi, any_key, keys = jax.device_get(
+                    (lo, hi, any_key, jnp.sum(usable.astype(jnp.int32)))
+                )
+            domain = int(hi) - int(lo) + 1 if any_key else 0
+            span.set_metadata(keys=int(keys), domain=domain)
+            if not 0 < domain <= DF_BITS_MAX_DOMAIN or (
+                keys > domain * DF_BITS_MAX_FILL
+            ):
+                span.set_metadata(path="range")
+                return
+            n_words = max(bucket_capacity(-(-domain // 32)), 128)
+            low = jnp.asarray(int(lo), dtype=jnp.int64)
+            self._bits = (
+                _df_bit_table(key.data, usable, low, n_words), low,
+                jnp.asarray(int(hi), dtype=jnp.int64),
+            )
+            self._domains, self._path = None, "bits"
+            span.set_metadata(path="bits", table_bytes=4 * n_words)
 
     def _use_range(self) -> None:
         all_domains = _df_domains(
             self._bridge.build_batch, tuple(self._bridge.build_key_channels)
         )
         self._domains = [all_domains[i] for i, _ in self._active_channels]
-        self._key_set = None
+        self._key_set = self._bits = None
+        self._path = "range"
 
     def needs_input(self) -> bool:
         return not self._outs and not self._finishing
@@ -3851,12 +4168,43 @@ class DynamicFilterOperator(Operator):
             (batch.columns[c].data, batch.columns[c].valid)
             for _, c in self._active_channels
         )
-        if self._key_set is None:
-            self._outs.append(_df_filter(batch, keys, tuple(self._domains)))
+        self._batches += 1
+        self._slots += batch.capacity
+        self._key_bytes = sum(data.dtype.itemsize for data, _ in keys)
+        if self._key_set is not None:
+            METRICS.increment("df_filter_path.set")
+            out, kept, self._totals = _df_filter_set(
+                batch, keys[0], *self._key_set, self._totals
+            )
+        elif self._bits is not None:
+            METRICS.increment("df_filter_path.bits")
+            out, kept, self._totals = _df_filter_bits(
+                batch, keys[0], *self._bits, self._totals
+            )
+        else:
+            METRICS.increment("df_filter_path.range")
+            out, self._totals = _df_filter(
+                batch, keys, tuple(self._domains), self._totals
+            )
+            self._outs.append(out)
             return
-        out, kept = _df_filter_set(batch, keys[0], *self._key_set)
         if not self._gathering or out.capacity < 4 * DF_PACK_MIN_SLOTS:
             # (a batch that small gains nothing from being packed)
+            self._outs.append(out)
+            return
+        try:
+            kept.copy_to_host_async()
+        except AttributeError:
+            pass
+        self._unread.append((out, kept))
+        # this batch's filter is on the device: settle the one before
+        # (and this one too, once nothing more is read)
+        while len(self._unread) > 1 or self._unread and not self._gathering:
+            self._settle_oldest()
+
+    def _settle_oldest(self) -> None:
+        out, kept = self._unread.pop(0)
+        if not self._gathering:
             self._outs.append(out)
             return
         with host_sync("join.dynamic_filter", 4) as span:
@@ -3864,34 +4212,101 @@ class DynamicFilterOperator(Operator):
             span.set_metadata(rows=kept)
         if not kept:
             return
-        if kept > DF_PACK_MIN_SLOTS:
-            # the set is not that selective here: no more readbacks, and
-            # where it keeps most of a batch, no more of its compares
-            # (4.9 ms a 2^20-row batch at 4,096 keys against the range's
-            # 1.1, PERF.md section 6, PR 33): the range from here on
+        if kept <= DF_PACK_MIN_SLOTS and not self._packing:
+            if self._gathered_rows + kept > DF_PACK_MIN_SLOTS:
+                self._emit_gathered()
+            packed = _front_rows(out, DF_PACK_MIN_SLOTS)
+            self._gathered = packed if self._gathered is None else _pack_parts(
+                (self._gathered, packed), DF_PACK_MIN_SLOTS
+            )
+            self._gathered_rows += kept
+            return
+        self._emit_gathered()
+        slots = max(bucket_capacity(kept), out.capacity // DF_PACK_PARTS)
+        if slots * 4 > out.capacity or not _sortable(out):
+            # the filter is not that selective here: no more readbacks,
+            # and where the set keeps most of a batch, no more of its
+            # compares (4.9 ms a 2^20-row batch at 4,096 keys against
+            # the range's 1.1, PERF.md section 6, PR 33): the range from
+            # here on
             self._gathering = False
-            if kept * 4 > out.capacity:
+            if self._key_set is not None and kept * 4 > out.capacity:
                 self._use_range()
-            self._emit_gathered()
+            self._emit_parts()
+            self._laid_full = True
             self._outs.append(out)
             return
-        if self._gathered_rows + kept > DF_PACK_MIN_SLOTS:
-            self._emit_gathered()
-        packed = _front_rows(out, DF_PACK_MIN_SLOTS)
-        self._gathered = packed if self._gathered is None else _pack_parts(
-            (self._gathered, packed), DF_PACK_MIN_SLOTS
+        self._packing = True
+        METRICS.increment("df_pack_batches_in")
+        if out.capacity > self._part_capacity:
+            # (a scan's last batch may be a smaller one: its part goes
+            # behind the others all the same)
+            self._emit_parts()
+        # (HashBuildSink's rule: top_k while a part is small, one sort
+        # that carries the columns beyond)
+        pack = _front_rows if slots <= _DEVICE_PACK_MAX_SLOTS else _pack_rows
+        part = pack(out, slots)
+        if self._room is None:
+            # a part has a quarter of the scan's slots at most
+            self._room = _pack_room(part, out.capacity + out.capacity // 4)
+            self._part_capacity = out.capacity
+        self._room = _pack_place(
+            self._room, part, np.int32(self._room_taken)
         )
-        self._gathered_rows += kept
+        # the next part goes where this one's rows end, a sixteenth of
+        # the scan's slots on at least: scans that differ by a few rows
+        # in a hundred hand the joins the same number of batches, and a
+        # part that needs more slots than that (the next power of two:
+        # twice as many) takes its rows' worth, not its slots'
+        self._room_taken += max(kept, out.capacity // DF_PACK_PARTS)
+        while self._room_taken >= self._part_capacity:
+            self._laid_full = True
+            self._take_packed(self._part_capacity)
 
     def _emit_gathered(self) -> None:
         if self._gathered is not None:
             self._outs.append(self._gathered)
         self._gathered, self._gathered_rows = None, 0
 
+    def _take_packed(self, capacity: int) -> None:
+        batch, self._room = _pack_take(self._room, capacity)
+        self._room_taken = max(self._room_taken - capacity, 0)
+        METRICS.increment("df_pack_batches_out")
+        self._outs.append(batch)
+
+    def _emit_parts(self, last: bool = False) -> None:
+        """What is left of the packed parts, as one batch."""
+        if self._room_taken:
+            capacity = self._part_capacity
+            if last and not self._laid_full:
+                # the scan's only packed batch: no join has compiled for
+                # the scan's capacity on its account, so it takes the
+                # power of two that holds its parts
+                capacity = min(
+                    capacity, max(bucket_capacity(self._room_taken), 16)
+                )
+            self._laid_full = True
+            self._take_packed(capacity)
+        self._room, self._room_taken = None, 0
+
     def finish(self) -> None:
-        if not self._finishing:
-            self._finishing = True
-            self._emit_gathered()
+        if self._finishing:
+            return
+        self._finishing = True
+        while self._unread:
+            self._settle_oldest()
+        self._emit_gathered()
+        self._emit_parts(last=True)
+        if self._totals is not None:
+            with host_sync("join.dynamic_filter_totals", 16) as span:
+                rows_in, kept = (int(x) for x in jax.device_get(self._totals))
+                span.set_metadata(
+                    rows_in=rows_in, rows_kept=kept, batches=self._batches,
+                    slots=self._slots, path=self._path,
+                    key_bytes=self._key_bytes,
+                )
+            METRICS.increment("df_rows_in", rows_in)
+            METRICS.increment("df_rows_kept", kept)
 
     def get_output(self) -> Optional[RelBatch]:
         return self._outs.pop(0) if self._outs else None

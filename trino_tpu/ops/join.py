@@ -47,11 +47,15 @@ _NO_MATCH_HASH = jnp.uint32(0xFFFFFFFE)  # probes that must find nothing
 _DEAD_BUILD_HASH = jnp.uint32(0xFFFFFFFF)  # dead build rows sort last
 
 
-def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray):
+def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray,
+                      value_bits: int = 32):
     """For each query, the run [lo, hi) of equal values in a sorted
     array — the PagesHash probe (DefaultPagesHash.java:159). Values of
-    both inputs must fit in uint32 (key hashes and expansion offsets
-    do by construction).
+    both inputs must fit in `value_bits` bits (32: key hashes and
+    expansion offsets do by construction; more for the exact words of
+    several key columns, `_wide_bits`), and a value, two tag bits and a
+    query's position share one 64-bit word: queries beyond what is left
+    for the position are bounded in chunks.
 
     TPU-native formulation (r4): on this hardware gathers run at
     ~16.5ms/M, scatters at ~117ms/M, XLA searchsorted at ~135ms/M, and
@@ -76,11 +80,19 @@ def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray):
         z = jnp.zeros(N, jnp.int32)
         return z, z
     id_bits = max(int(N - 1).bit_length(), 1)
-    if id_bits > 30:  # 32-bit value + 2-bit tag + id must fit 64 bits
-        raise ValueError(
-            f"sorted_run_bounds: query batch of {N} rows exceeds the "
-            "2^30 packed-word id budget; split the batch"
-        )
+    if value_bits + 2 + id_bits > 64:
+        if value_bits <= 32:
+            raise ValueError(
+                f"sorted_run_bounds: query batch of {N} rows exceeds the "
+                "2^30 packed-word id budget; split the batch"
+            )
+        step = 1 << (62 - value_bits)
+        bounds = [
+            sorted_run_bounds(sorted_arr, q[at:at + step], value_bits)
+            for at in range(0, N, step)
+        ]
+        return (jnp.concatenate([lo for lo, _ in bounds]),
+                jnp.concatenate([hi for _, hi in bounds]))
     vshift = jnp.uint64(2 + id_bits)
     tshift = jnp.uint64(id_bits)
     qv = q.astype(jnp.uint64)
@@ -112,13 +124,111 @@ def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray):
     return pair[:, 0], pair[:, 1]
 
 
-def _key_hash(keys, valids, usable, sentinel):
-    """Clamped 32-bit key hash; rows not usable get the sentinel."""
+def _one_integer_key(keys) -> bool:
+    return len(keys) == 1 and _integer_keys(keys)
+
+
+def _integer_keys(keys) -> bool:
+    return bool(keys) and all(
+        getattr(k, "ndim", 1) == 1 and jnp.issubdtype(k.dtype, jnp.integer)
+        for k in keys
+    )
+
+
+# the most bits an exact word of several key columns may take: a probe
+# batch of 2^20 rows still packs (value, tag, position) into 64 bits
+_WIDE_BITS = 42
+
+
+def _wide_bits(keys, build_capacity: int) -> int:
+    """Bits of the sorted word for SEVERAL integer key columns asked to
+    be exact: what the build side's slot numbers leave of 64, at most
+    _WIDE_BITS; 32 (the hash's own) where that is no more."""
+    slot_bits = max(int(build_capacity - 1).bit_length(), 1)
+    return max(min(64 - slot_bits, _WIDE_BITS), 32)
+
+
+def _real_max(bits: int) -> int:
+    """The greatest word a usable key may have: the two above it are
+    the sentinels (_NO_MATCH_HASH and _DEAD_BUILD_HASH at 32 bits)."""
+    return (1 << bits) - 3
+
+
+def _exact_base(keys, usable):
+    """(least usable key, whether every usable key lies within the real
+    hash range above it) of ONE integer key column: where it holds, a
+    key's distance from the least IS its hash, and no two keys share
+    one. A 32-bit hash of 15 M order keys gives one probe row in 300 a
+    second candidate, which alone kept every probe batch off the
+    fanout-one path (PERF.md section 6, PR 35)."""
+    k = keys[0].astype(jnp.int64)
+    info = jnp.iinfo(jnp.int64)
+    lo = jnp.min(jnp.where(usable, k, info.max))
+    hi = jnp.max(jnp.where(usable, k, info.min))
+    span = hi - lo          # (wraps below zero where the keys span over 2^63)
+    return lo, (hi >= lo) & (span >= 0) & (span <= jnp.int64(int(_H_REAL_MAX)))
+
+
+def _exact_bases(keys, usable, bits: int):
+    """`_exact_base` for SEVERAL integer key columns: (each column's
+    least usable value, each column's count of values from its least to
+    its greatest, whether the counts' bits together fit a word of `bits`
+    bits). Where they do, a key's word is its columns' distances from
+    their least, each weighted by the counts of the columns after it,
+    and no two keys share one: `partsupp`'s 8 M (partkey, suppkey) pairs
+    take 21 + 17 bits, and a 32-bit hash of them gave one probe row in
+    500 a second candidate (PERF.md section 6, PR 35)."""
+    info = jnp.iinfo(jnp.int64)
+    los, spans = [], []
+    fits = jnp.any(usable)
+    taken = jnp.int64(0)
+    for key in keys:
+        k = key.astype(jnp.int64)
+        lo = jnp.min(jnp.where(usable, k, info.max))
+        hi = jnp.max(jnp.where(usable, k, info.min))
+        span = hi - lo + 1      # (wraps where the values span over 2^63)
+        fits = fits & (hi >= lo) & (span > 0)
+        span = jnp.where(fits, span, 1)
+        taken = taken + (64 - jax.lax.clz(span - 1))
+        los.append(lo)
+        spans.append(span)
+    # (under 2^(bits - 1) words: the two sentinels stay above them)
+    return tuple(los), tuple(spans), fits & (taken < bits)
+
+
+def _key_hash(keys, valids, usable, sentinel, base=None):
+    """Clamped 32-bit key hash; rows not usable get the sentinel. With
+    `base` (`_exact_base` of the build side) and the flag it carries
+    set, the key's distance from the base instead; a key outside the
+    range finds nothing."""
     if keys:
         h = jnp.minimum(hash32(list(keys), list(valids)), _H_REAL_MAX)
     else:
         h = jnp.zeros(usable.shape[0], dtype=jnp.uint32)
+    if base is not None:
+        lo, exact = base
+        d = keys[0].astype(jnp.int64) - lo
+        inside = (d >= 0) & (d <= jnp.int64(int(_H_REAL_MAX)))
+        h = jnp.where(exact, d.astype(jnp.uint32), h)
+        usable = usable & (~exact | inside)
     return jnp.where(usable, h, sentinel)
+
+
+def _wide_key_word(keys, valids, usable, sentinel: int, bases):
+    """`_key_hash` for `_exact_bases`: a 64-bit word, the exact one where
+    the flag is set (a key with a column outside the build side's values
+    finds nothing) and the clamped 32-bit hash where it is not."""
+    los, spans, exact = bases
+    h = jnp.minimum(hash32(list(keys), list(valids)), _H_REAL_MAX)
+    word = jnp.zeros(usable.shape[0], dtype=jnp.int64)
+    inside = jnp.ones(usable.shape[0], dtype=jnp.bool_)
+    for key, lo, span in zip(keys, los, spans):
+        d = key.astype(jnp.int64) - lo
+        inside = inside & (d >= 0) & (d < span)
+        word = word * span + d
+    word = jnp.where(exact & inside, word, h.astype(jnp.int64))
+    usable = usable & (~exact | inside)
+    return jnp.where(usable, word.astype(jnp.uint64), jnp.uint64(sentinel))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -126,42 +236,73 @@ def _key_hash(keys, valids, usable, sentinel):
 class LookupSource:
     """Device-resident build side: sorted hashes + row permutation."""
 
-    sorted_hash: jnp.ndarray  # (B,) uint32, dead rows = 0xFFFFFFFF
+    sorted_hash: jnp.ndarray  # (B,) uint32, dead rows = 0xFFFFFFFF (uint64: hash_bits)
     perm: jnp.ndarray  # (B,) int32 — build row index at each sorted pos
     key_cols: List[jnp.ndarray]  # original (unsorted) build key columns
     key_valids: List[jnp.ndarray]
     build_live: jnp.ndarray  # (B,) bool
+    # `_exact_base` of the keys where the build asked for it: the probe
+    # must hash its keys the same way (`_exact_bases` where
+    # `hash_bits` is over 32: several key columns, and `sorted_hash`
+    # holds uint64 words of that many bits)
+    exact_base: Optional[tuple] = None
+    hash_bits: int = 32
 
     def tree_flatten(self):
         return (
-            (self.sorted_hash, self.perm, self.key_cols, self.key_valids, self.build_live),
-            (),
+            (self.sorted_hash, self.perm, self.key_cols, self.key_valids,
+             self.build_live, self.exact_base),
+            (self.hash_bits,),
         )
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        sh, perm, kc, kv, bl = children
-        return cls(sh, perm, list(kc), list(kv), bl)
+        sh, perm, kc, kv, bl, base = children
+        return cls(sh, perm, list(kc), list(kv), bl, base, *aux)
 
     @property
     def build_capacity(self) -> int:
         return int(self.perm.shape[0])
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("exact_keys",))
 def build_lookup(
     keys: Sequence[jnp.ndarray],
     valids: Sequence[jnp.ndarray],
     live: jnp.ndarray,
+    exact_keys: bool = False,
 ) -> LookupSource:
     """Build phase — HashBuilderOperator analogue, ONE single-operand
     packed sort instead of row-at-a-time inserts
-    (join/HashBuilderOperator.java:58)."""
+    (join/HashBuilderOperator.java:58). `exact_keys`: where the key is
+    one integer column whose values span under 2^32, sort by the key's
+    distance from the least (no two keys then share a run); where it is
+    several integer columns, by their distances from their least in one
+    word of up to _WIDE_BITS bits (`_exact_bases`)."""
     any_null = None
     for v in valids:
         any_null = ~v if any_null is None else (any_null | ~v)
     usable = live if any_null is None else (live & ~any_null)
-    h = _key_hash(keys, valids, usable, _DEAD_BUILD_HASH)
+    bits = 32
+    if exact_keys and len(keys) > 1 and _integer_keys(keys):
+        bits = _wide_bits(keys, live.shape[0])
+    if bits > 32:
+        bases = _exact_bases(keys, usable, bits)
+        word = _wide_key_word(
+            keys, valids, usable, _real_max(bits) + 2, bases
+        )
+        shift = jnp.uint64(64 - bits)
+        sp = jnp.sort(
+            (word << shift) | jnp.arange(live.shape[0], dtype=jnp.uint64)
+        )
+        perm = (sp & ((jnp.uint64(1) << shift) - jnp.uint64(1))).astype(jnp.int32)
+        return LookupSource(
+            sp >> shift, perm, list(keys), list(valids), usable, bases, bits
+        )
+    base = None
+    if exact_keys and _one_integer_key(keys):
+        base = _exact_base(keys, usable)
+    h = _key_hash(keys, valids, usable, _DEAD_BUILD_HASH, base)
     B = h.shape[0]
     packed = (h.astype(jnp.uint64) << jnp.uint64(32)) | jnp.arange(
         B, dtype=jnp.uint64
@@ -169,7 +310,7 @@ def build_lookup(
     sp = jnp.sort(packed)
     sorted_hash = (sp >> jnp.uint64(32)).astype(jnp.uint32)
     perm = (sp & jnp.uint64(0xFFFFFFFF)).astype(jnp.int32)
-    return LookupSource(sorted_hash, perm, list(keys), list(valids), usable)
+    return LookupSource(sorted_hash, perm, list(keys), list(valids), usable, base)
 
 
 @jax.jit
@@ -186,8 +327,26 @@ def probe_counts(
     for v in probe_valids:
         any_null = ~v if any_null is None else (any_null | ~v)
     usable = probe_live if any_null is None else (probe_live & ~any_null)
-    ph = _key_hash(probe_keys, probe_valids, usable, _NO_MATCH_HASH)
-    lo, hi = sorted_run_bounds(ls.sorted_hash, ph)
+    if ls.hash_bits > 32:
+        if len(probe_keys) != len(ls.key_cols) or not _integer_keys(probe_keys):
+            raise TypeError(
+                "probe_counts: the build side sorted its integer key "
+                "columns' exact word; the probe must bring as many"
+            )
+        ph = _wide_key_word(
+            probe_keys, probe_valids, usable, _real_max(ls.hash_bits) + 1,
+            ls.exact_base,
+        )
+    else:
+        if ls.exact_base is not None and not _one_integer_key(probe_keys):
+            raise TypeError(
+                "probe_counts: the build side sorted ONE integer key by its "
+                "distance from the least; the probe must bring one too"
+            )
+        ph = _key_hash(
+            probe_keys, probe_valids, usable, _NO_MATCH_HASH, ls.exact_base
+        )
+    lo, hi = sorted_run_bounds(ls.sorted_hash, ph, ls.hash_bits)
     counts = hi - lo
     return lo, counts, jnp.sum(counts)
 

@@ -51,7 +51,16 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   ``op.<OperatorClass>.<get_output|add_input|finish>`` around every
   operator call of ``exec/driver.Driver.run``;
   ``sync.<site>`` (``host_sync``, stat ``nbytes``) around every
-  device-to-host readback; ``scan.batches`` (stat ``cached``),
+  device-to-host readback; ``df.prepare`` around a
+  ``DynamicFilterOperator``'s choice of its filter (stats ``path``:
+  ``set``, ``bits`` or ``range``; ``build_slots``; where the build
+  side's domain was read, ``keys`` and ``domain``; for the bits,
+  ``table_bytes``; for a set of over 128 slots, ``slots``, after
+  ``sync.join.dynamic_filter_keys`` counted its keys) and, at its
+  finish, ``sync.join.dynamic_filter_totals``
+  (stats ``rows_in``, ``rows_kept``, ``batches``, ``slots``, ``path``,
+  ``key_bytes``: what the filter saw and kept over the scan, counted on
+  the device and read back once); ``scan.batches`` (stat ``cached``),
   ``scan.host_filter``, ``scan.to_device`` in the memory connector;
   ``result.fetch`` and ``result.to_rows`` around the result's readback
   and its conversion to rows; ``server.queued`` (stat ``handoff_us`` on

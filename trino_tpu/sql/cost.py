@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 
 from trino_tpu.sql import plan as P
-from trino_tpu.sql.stats import StatsCalculator
+from trino_tpu.sql.stats import StatsCalculator, key_is_unique
 
 # relative per-row weights
 _CPU_SCAN = 1.0
@@ -27,6 +27,13 @@ _CPU_FILTER = 0.5
 _CPU_PROJECT = 0.5
 _CPU_PROBE = 2.0
 _CPU_BUILD = 4.0       # sort-based lookup build: costlier than probe
+# every output row of a join gathers the build side's columns; the
+# probe side's pass through untouched where the build side's key is
+# unique (no probe row matches twice) and are gathered too where it is
+# not. A random gather of one element costs what sorting several rows
+# does (16.5 ms against 2 ms a million on a v5e, ops/join.py), so a wide
+# side under a gather is dear whatever its row count
+_CPU_PAIR_COLUMN = 3.0
 _CPU_AGG = 3.0
 _CPU_SORT = 6.0
 _NET_PER_ROW = 8.0     # exchange: dominant on the host data plane
@@ -96,8 +103,17 @@ class CostCalculator:
             # the host data plane once — this is what actually biases
             # the reorderer toward small intermediates
             # (CostCalculatorWithEstimatedExchanges discipline)
+            columns = len(node.right.fields)
+            # (a key of several columns has no unique flag to carry: the
+            # estimate that no probe row finds two rows stands in for it)
+            if not (
+                key_is_unique(self._stats.stats(node.right), node.right_keys)
+                or len(node.right_keys) > 1 and out <= probe
+            ):
+                columns += len(node.left.fields)
+            gathered = out * columns * _CPU_PAIR_COLUMN
             return PlanCost(
-                probe * _CPU_PROBE + build * _CPU_BUILD + out,
+                probe * _CPU_PROBE + build * _CPU_BUILD + out + gathered,
                 build * _MEM_PER_ROW,
                 (probe + build) * _NET_PER_ROW,
             )

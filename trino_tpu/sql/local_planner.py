@@ -123,6 +123,63 @@ class _AggWarmer:
                 break
 
 
+def unread_join_outputs(root: P.PlanNode) -> Dict[int, frozenset]:
+    """By `id` of each inner JoinNode without a residual: the channels
+    of its output that nothing above it reads (a key a later join no
+    longer needs, a column only a filter below looked at). The join
+    hands those on as zeros and gathers nothing for them
+    (`LookupJoinOperator`, `unread`). What a node reads of its child is
+    followed through filters, projections, aggregations and joins; any
+    other node reads all of it."""
+    from trino_tpu.sql.optimizer import expr_refs
+
+    unread: Dict[int, frozenset] = {}
+
+    def walk(node: P.PlanNode, read: Optional[set]) -> None:
+        """`read`: the channels of `node`'s output its parent reads,
+        None for all of them."""
+        if isinstance(node, P.JoinNode):
+            width_l = len(node.left.fields)
+            pairs = node.kind in ("inner", "left", "full")
+            if read is None or not pairs and node.kind != "semi":
+                walk(node.left, None)
+                walk(node.right, None)
+                return
+            if node.kind == "inner" and node.residual is None:
+                mine = frozenset(range(len(node.fields))) - frozenset(read)
+                seen = unread.get(id(node))
+                unread[id(node)] = mine if seen is None else (seen & mine)
+            refs = expr_refs(node.residual) if node.residual is not None else set()
+            left = {c for c in read | refs if c < width_l} | set(node.left_keys)
+            walk(node.left, left)
+            if pairs:
+                walk(node.right, {c - width_l for c in read | refs
+                                  if c >= width_l} | set(node.right_keys))
+            else:   # a semi-join hands on the probe's columns only
+                walk(node.right, None)
+            return
+        if isinstance(node, P.FilterNode):
+            walk(node.child,
+                 None if read is None else read | expr_refs(node.predicate))
+            return
+        if isinstance(node, P.ProjectNode):
+            kept = range(len(node.exprs)) if read is None else sorted(read)
+            walk(node.child, set().union(*[expr_refs(node.exprs[c]) for c in kept]))
+            return
+        if isinstance(node, P.AggregateNode) and node.step in ("single", "partial"):
+            reads = set(node.group_channels)
+            for a in node.aggs:
+                reads |= {c for c in (a.arg_channel, a.arg2_channel, a.arg3_channel)
+                          if c is not None}
+            walk(node.child, reads)
+            return
+        for child in node.children():
+            walk(child, None)
+
+    walk(root, None)
+    return unread
+
+
 class _JoinWarmer:
     """Dead-batch join warmup: build an empty lookup source at the
     build side's predicted capacity, then probe it at the entry's
@@ -130,11 +187,12 @@ class _JoinWarmer:
     dispatches."""
 
     def __init__(self, lkeys, rkeys, kind, probe_schema, build_schema,
-                 build_cap):
+                 build_cap, unread=()):
         self.lkeys, self.rkeys, self.kind = list(lkeys), list(rkeys), kind
         self.probe_schema = list(probe_schema)
         self.build_schema = list(build_schema)
         self.build_cap = int(build_cap)
+        self.unread = tuple(unread)
 
     def __call__(self, batch):
         from trino_tpu.compile.warmup import zeros_batch
@@ -144,7 +202,8 @@ class _JoinWarmer:
         sink.add_input(zeros_batch(self.build_schema, self.build_cap))
         sink.finish()
         op = LookupJoinOperator(
-            bridge, self.lkeys, self.kind, self.probe_schema
+            bridge, self.lkeys, self.kind, self.probe_schema,
+            unread=self.unread,
         )
         op.add_input(batch)
         op.finish()
@@ -186,9 +245,11 @@ class LocalPlanner:
         self._next_key = 0
         self._warmup_entries: List = []
         self._stats_calc = None
+        self._unread: Dict[int, frozenset] = {}
 
     # -- public --
     def plan(self, root: P.PlanNode) -> PhysicalPlan:
+        self._unread = unread_join_outputs(root)
         chain, schema = self._visit(root)
         return PhysicalPlan(
             self.pipelines, chain, schema,
@@ -748,10 +809,11 @@ class LocalPlanner:
             probe_chain.append(
                 lambda ctx: DynamicFilterOperator(bridge_of(ctx), lkeys)
             )
+        unread = tuple(sorted(self._unread.get(id(node), ())))
         probe_chain.append(
             lambda ctx: LookupJoinOperator(
                 bridge_of(ctx), lkeys, kind, probe_schema,
-                residual_fn=residual_fn,
+                residual_fn=residual_fn, unread=unread,
             )
         )
         if node.kind in ("semi", "anti"):
@@ -766,7 +828,7 @@ class LocalPlanner:
             self._record_kernel_warmup(
                 "LookupJoinOperator",
                 _JoinWarmer(lkeys, rkeys, kind, probe_schema,
-                            build_schema, build_caps[0]),
+                            build_schema, build_caps[0], unread),
                 probe_schema, out_schema, probe_caps,
             )
         return probe_chain, out_schema

@@ -11,12 +11,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
+
+import numpy as np
 
 from trino_tpu.expr import ir
 from trino_tpu.sql import plan as P
 
 UNKNOWN_FILTER_COEFFICIENT = 0.33  # fallback selectivity
+# a scanned column with this share of its table's rows in distinct values
+# is taken for a key (the memory connector's NDVs are sampled estimates)
+_UNIQUE_NDV_SHARE = 0.98
 
 
 @dataclasses.dataclass
@@ -25,6 +30,12 @@ class ColStats:
     null_fraction: Optional[float] = None
     low: Optional[float] = None
     high: Optional[float] = None
+    # a dictionary-coded column's table-stable dictionary, asked for only
+    # when a predicate over the column has no estimate of its own
+    dictionary: Optional[Callable[[], object]] = None
+    # no two rows share a value (a key of its table, and what filters and
+    # joins that repeat no row of its side have left of one)
+    unique: bool = False
 
 
 @dataclasses.dataclass
@@ -88,8 +99,25 @@ class StatsCalculator:
                     nf,
                     _as_float(lo),
                     _as_float(hi),
+                    unique=ndv is not None and ndv >= _UNIQUE_NDV_SHARE * rows,
+                )
+            if node.fields[i].type.is_string:
+                cols[i] = dataclasses.replace(
+                    cols.get(i, ColStats()),
+                    dictionary=self._dictionary_of(node, name),
                 )
         return PlanStats(rows, cols)
+
+    def _dictionary_of(self, node: P.ScanNode, name: str):
+        def dictionary():
+            try:
+                return self._catalogs.get(node.catalog).metadata.column_dictionary(
+                    node.handle, name
+                )
+            except Exception:
+                return None
+
+        return dictionary
 
     def _ValuesNode(self, node: P.ValuesNode) -> PlanStats:
         return PlanStats(float(len(node.rows)))
@@ -127,15 +155,22 @@ class StatsCalculator:
             ndv_prod *= ndv if ndv is not None else math.sqrt(child.row_count)
         rows = max(min(child.row_count, ndv_prod), 1.0)
         cols = {
-            i: child.col(c) for i, c in enumerate(node.group_channels)
+            i: dataclasses.replace(
+                child.col(c), unique=len(node.group_channels) == 1
+            )
+            for i, c in enumerate(node.group_channels)
         }
         return PlanStats(rows, cols)
 
     def _JoinNode(self, node: P.JoinNode) -> PlanStats:
         left = self.stats(node.left)
         right = self.stats(node.right)
+        width_l = len(node.left.fields)
         if node.kind == "cross":
-            return PlanStats(left.row_count * right.row_count, dict(left.columns))
+            cols = _repeated(left.columns)
+            for ch, cs in _repeated(right.columns).items():
+                cols[width_l + ch] = cs
+            return PlanStats(left.row_count * right.row_count, cols)
         if node.kind in ("semi", "anti"):
             return PlanStats(
                 max(left.row_count * 0.5, 1.0), dict(left.columns)
@@ -150,22 +185,37 @@ class StatsCalculator:
         # sqrt(rows) default overestimated join output ~25x on TPC-H Q3
         # through the memory connector, which flipped the reorderer into
         # building the lookup on the 6M-row side.
-        denom = 1.0
-        for lk, rk in zip(node.left_keys, node.right_keys):
-            ndv_l = left.col(lk).ndv
-            ndv_r = right.col(rk).ndv
-            key_ndv = max(
-                ndv_l if ndv_l is not None else left.row_count,
-                ndv_r if ndv_r is not None else right.row_count,
+        ndvs = [
+            (
+                max(_or(left.col(lk).ndv, left.row_count), 1.0),
+                max(_or(right.col(rk).ndv, right.row_count), 1.0),
             )
-            denom *= max(key_ndv, 1.0)
+            for lk, rk in zip(node.left_keys, node.right_keys)
+        ]
+        if len(ndvs) == 1:
+            denom = max(ndvs[0])
+        else:
+            denom = _composite_key_ndv(ndvs, left.row_count, right.row_count)
         rows = max(left.row_count * right.row_count / denom, 1.0)
         if node.kind == "left":
             rows = max(rows, left.row_count)
-        cols = dict(left.columns)
-        width_l = len(node.left.fields)
+        # a side's rows come out once each where the other side's key is
+        # unique, and its unique columns stay so
+        keeps_l = key_is_unique(right, node.right_keys)
+        keeps_r = key_is_unique(left, node.left_keys) and node.kind == "inner"
+        cols = dict(left.columns) if keeps_l else _repeated(left.columns)
         for ch, cs in right.columns.items():
-            cols[width_l + ch] = cs
+            cols[width_l + ch] = cs if keeps_r else dataclasses.replace(cs, unique=False)
+        if node.kind == "inner":
+            # what is left of a key column holds no more distinct values
+            # than its partner had
+            for (lk, rk), (ndv_l, ndv_r) in zip(
+                zip(node.left_keys, node.right_keys), ndvs
+            ):
+                for ch in (lk, width_l + rk):
+                    cols[ch] = dataclasses.replace(
+                        cols.get(ch, ColStats()), ndv=min(ndv_l, ndv_r)
+                    )
         return PlanStats(rows, cols)
 
     def _WindowNode(self, node: P.WindowNode) -> PlanStats:
@@ -206,6 +256,105 @@ def _as_float(v) -> Optional[float]:
         return None
 
 
+def key_is_unique(side: PlanStats, channels) -> bool:
+    """Whether no two rows of `side` share a value of the key: one of
+    its columns is unique already."""
+    return any(side.col(c).unique for c in channels)
+
+
+def _repeated(columns: Dict[int, ColStats]) -> Dict[int, ColStats]:
+    return {ch: dataclasses.replace(cs, unique=False) for ch, cs in columns.items()}
+
+
+def _or(value: Optional[float], default: float) -> float:
+    return value if value is not None else default
+
+
+def _composite_key_ndv(ndvs, left_rows: float, right_rows: float) -> float:
+    """Distinct values of a multi-column equi-join key, for the join's
+    denominator: max over the sides of each side's distinct tuples.
+
+    A side's columns are rarely independent (TPC-H's `(l_partkey,
+    l_suppkey)` has 8 M pairs at SF10 where the product of the columns'
+    NDVs is 2 x 10^11), so the product is only a bound, and so are the
+    side's rows. Where every column of one side has no more distinct
+    values than its partner, containment (the assumption the one-column
+    estimate already makes: the smaller value set lies inside the
+    larger) is taken for the tuples too: that side's tuples are among
+    the other's, so it has no more of them. A foreign key of several
+    columns then joins its primary key at the foreign side's row count
+    instead of at next to nothing."""
+    left_rows, right_rows = max(left_rows, 1.0), max(right_rows, 1.0)
+    # (a join's output keeps its inputs' column NDVs: no more than its rows)
+    ndvs = [(min(l, left_rows), min(r, right_rows)) for l, r in ndvs]
+    tuples_l = min(math.prod(l for l, _ in ndvs), left_rows)
+    tuples_r = min(math.prod(r for _, r in ndvs), right_rows)
+    if all(l <= r for l, r in ndvs):
+        tuples_l = min(tuples_l, tuples_r)
+    if all(r <= l for l, r in ndvs):
+        tuples_r = min(tuples_r, tuples_l)
+    return max(tuples_l, tuples_r, 1.0)
+
+
+def _first_input_ref(e: ir.Expr) -> Optional[ir.InputRef]:
+    if isinstance(e, ir.InputRef):
+        return e
+    for c in e.children():
+        found = _first_input_ref(c)
+        if found is not None:
+            return found
+    return None
+
+
+def _host_device():
+    """Plan-time arithmetic stays off the accelerator where the process
+    has a CPU backend beside it."""
+    import contextlib
+
+    import jax
+
+    try:
+        return jax.default_device(jax.devices("cpu")[0])
+    except RuntimeError:
+        return contextlib.nullcontext()
+
+
+def _dictionary_selectivity(e: ir.Expr, child: PlanStats) -> Optional[float]:
+    """The share of a dictionary's VALUES that pass `e`, for a predicate
+    over one dictionary-coded column (`p_name like '%green%'`): the
+    expression compiler evaluates it over the dictionary, one code a
+    value, on the host's CPU. Every value is taken to be as frequent as
+    any other. None where the predicate reads another number of columns,
+    the column has no dictionary in hand, or the compiler cannot bind
+    it."""
+    from trino_tpu.sql.optimizer import expr_refs, substitute
+
+    refs = expr_refs(e)
+    if len(refs) != 1:
+        return None
+    (channel,) = refs
+    thunk = child.col(channel).dictionary
+    dictionary = thunk() if thunk is not None else None
+    if dictionary is None or not len(dictionary):
+        return None
+    try:
+        from trino_tpu.expr.compile import bind_expr
+
+        column = _first_input_ref(e)
+        bound_to = substitute(e, {channel: ir.InputRef(0, column.type)})
+        with _host_device():
+            bound = bind_expr(bound_to, [column.type], [dictionary])
+            data, valid = bound.fn(
+                [np.arange(len(dictionary), dtype=np.int32)], [None]
+            )
+            passed = np.asarray(data, dtype=bool)
+            if valid is not None:
+                passed &= np.asarray(valid, dtype=bool)
+    except Exception:
+        return None
+    return float(passed.sum()) / len(dictionary)
+
+
 def _selectivity(e: ir.Expr, child: PlanStats) -> float:
     """FilterStatsCalculator-style predicate selectivity."""
     if isinstance(e, ir.Call):
@@ -237,8 +386,8 @@ def _selectivity(e: ir.Expr, child: PlanStats) -> float:
                     frac = (v - lo) / (hi - lo)
                     frac = min(max(frac, 0.0), 1.0)
                     return frac if op in ("lt", "le") else 1.0 - frac
-                return UNKNOWN_FILTER_COEFFICIENT
-    return UNKNOWN_FILTER_COEFFICIENT
+    share = _dictionary_selectivity(e, child)
+    return UNKNOWN_FILTER_COEFFICIENT if share is None else share
 
 
 def determine_partition_count(
