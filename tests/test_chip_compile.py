@@ -220,6 +220,23 @@ def test_join_build_lookup_compiles_for_v5e(one_chip, key_columns):
         assert "sort" in probe.as_text()
 
 
+@pytest.mark.parametrize("bits", [32, 38])
+def test_two_level_probe_bounds_compile_for_v5e(one_chip, bits):
+    """The bounds of 2^10 queries in a sorted array of 2^16 words (PR 37:
+    `sorted_run_bounds` against an array much larger than the batch):
+    the two packed sorts over the splitters and the queries only, and
+    two gathers of whole 128-word rows, u32 words and the u64 words of
+    several key columns."""
+    from trino_tpu.ops import join
+
+    dtype = jnp.uint32 if bits <= 32 else jnp.uint64
+    assert join.probe_path(1 << 16, 1 << 10, bits) == "blocked"
+    compiled = jax.jit(lambda t, q: join.sorted_run_bounds(t, q, bits)).lower(
+        _sds((1 << 16,), dtype, one_chip), _sds((1 << 10,), dtype, one_chip)).compile()
+    text = compiled.as_text()
+    assert "sort" in text and f"[{1 << 10},{join.PROBE_BLOCK}]" in text
+
+
 def test_packed_parts_are_placed_and_taken_on_v5e(one_chip):
     """`_pack_place` writes a packed part into the buffer of a batch and
     a quarter at a position the host gives, `_pack_take` cuts a batch

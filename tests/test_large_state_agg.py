@@ -341,6 +341,32 @@ def joined_keys(bridge, filtered):
     return sorted(np.concatenate(keys).tolist()) if keys else []
 
 
+@pytest.mark.parametrize("build_rows,path", [(64 * 128, "blocked"), (128 // 4, "sorted")])
+def test_join_probe_path_counts_each_probe_batch_by_the_form_its_bounds_took(build_rows, path):
+    """`join_probe_path.blocked` / `.sorted` (PR 37): one a probe batch,
+    by what `ops/join.probe_path` answers for the build side's slots,
+    the batch's and the word's bits: the two-level bounds against a build
+    of 64 times the batch, the two packed sorts against one of a
+    quarter of it. The matches are the same either way."""
+    from trino_tpu.ops import join as J
+
+    bridge = O.JoinBridge()
+    sink = O.HashBuildSink(bridge, [0], [(T.BIGINT, None), (T.BIGINT, None)])
+    sink.add_input(keyed_batch(np.arange(build_rows) * 3))
+    sink.finish()
+    ls = bridge.lookup_source
+    probes = [keyed_batch(np.arange(128) + at) for at in (0, 40, 90)]
+    assert J.probe_path(ls.build_capacity, 128, ls.hash_bits) == path
+    names = ("join_probe_path.blocked", "join_probe_path.sorted")
+    before = {n: METRICS.counter(n) for n in names}
+    keys = joined_keys(bridge, probes)
+    moved = {n: METRICS.counter(n) - before[n] for n in names}
+    assert moved == {"join_probe_path." + path: 3,
+                     "join_probe_path." + ("sorted" if path == "blocked" else "blocked"): 0}
+    assert keys == sorted(k for at in (0, 40, 90) for k in range(at, at + 128)
+                          if k % 3 == 0 and k < 3 * build_rows)
+
+
 @pytest.mark.parametrize("build_rows", [0, 1, O.DF_SET_MAX_SLOTS, O.DF_SET_MAX_SLOTS + 1])
 def test_the_set_filter_and_the_range_filter_hand_the_join_the_same_matches(build_rows):
     """Random keys, three probe batches with NULLs and dead rows: the

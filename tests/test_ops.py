@@ -2,6 +2,7 @@
 unit tests (TestGroupByHash, TestHashJoinOperator etc., SURVEY.md §4.1)."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -300,6 +301,167 @@ def test_exact_keys_of_several_columns_count_matches_and_never_candidates(case):
     # a build side of so many slots that they leave a word of 32 bits at most: the hash
     with pytest.raises(TypeError):
         join.probe_counts(ls, [jnp.asarray(pks[0])], [jnp.asarray(pvalid[0])], jnp.asarray(plive))
+
+
+def _parent_sorted_run_bounds(sorted_arr, q, value_bits=32):
+    """`ops/join.sorted_run_bounds` as it was before PR 37, letter for
+    letter: the one-level bounds the two-level ones must equal, and the
+    program a shape below the threshold must still trace."""
+    B = sorted_arr.shape[0]
+    N = q.shape[0]
+    if B == 0 or N == 0:
+        z = jnp.zeros(N, jnp.int32)
+        return z, z
+    id_bits = max(int(N - 1).bit_length(), 1)
+    if value_bits + 2 + id_bits > 64:
+        if value_bits <= 32:
+            raise ValueError("too many queries")
+        step = 1 << (62 - value_bits)
+        bounds = [
+            _parent_sorted_run_bounds(sorted_arr, q[at:at + step], value_bits)
+            for at in range(0, N, step)
+        ]
+        return (jnp.concatenate([lo for lo, _ in bounds]),
+                jnp.concatenate([hi for _, hi in bounds]))
+    vshift = jnp.uint64(2 + id_bits)
+    tshift = jnp.uint64(id_bits)
+    qv = q.astype(jnp.uint64)
+    tv = sorted_arr.astype(jnp.uint64)
+    iota = jnp.arange(N, dtype=jnp.uint64)
+    t0 = jnp.uint64(0) << tshift
+    t1 = jnp.uint64(1) << tshift
+    t2 = jnp.uint64(2) << tshift
+    words = jnp.concatenate(
+        [
+            (qv << vshift) | t0 | iota,
+            (tv << vshift) | t1,
+            (qv << vshift) | t2 | iota,
+        ]
+    )
+    ws = jnp.sort(words)
+    tag = (ws >> tshift) & jnp.uint64(3)
+    is_table = tag == jnp.uint64(1)
+    bp = jnp.cumsum(is_table.astype(jnp.int32)).astype(jnp.uint64)
+    qid = ws & jnp.uint64((1 << id_bits) - 1)
+    rid = jnp.where(is_table, jnp.uint64(N), qid)
+    is_hi = (tag == jnp.uint64(2)).astype(jnp.uint64)
+    res = jnp.sort(
+        (rid << jnp.uint64(33)) | (is_hi << jnp.uint64(32)) | bp
+    )
+    pair = (res[: 2 * N] & jnp.uint64(0xFFFFFFFF)).astype(jnp.int32)
+    pair = pair.reshape(N, 2)
+    return pair[:, 0], pair[:, 1]
+
+
+@pytest.mark.parametrize("case", [
+    "unique", "runs_cross_a_block_edge", "one_key_over_two_blocks", "ragged_last_block",
+    "below_the_least_and_above_the_greatest", "words_of_38_bits", "queries_in_chunks",
+    "one_query", "three_levels"])
+def test_two_level_bounds_equal_the_one_level_bounds_and_numpy(case):
+    """`sorted_run_bounds` against an array much larger than the batch
+    (PR 37): the queries are placed among every PROBE_BLOCK-th word by
+    the two packed sorts, then inside one block by comparison. The same
+    (lo, hi) as the one-level bounds and as `numpy.searchsorted`, for
+    runs that cross blocks and fill them, with no flag and no fallback."""
+    W = join.PROBE_BLOCK
+    rng = np.random.default_rng(37)
+    bits, dtype, nq = 32, np.uint32, 64
+    B = 40 * W
+    if case == "unique":
+        t = np.sort(rng.choice(1 << 20, B, replace=False))
+    elif case == "runs_cross_a_block_edge":
+        t = np.sort(rng.integers(0, B // 24, B))            # runs of about 24: most blocks end inside one
+    elif case == "one_key_over_two_blocks":
+        t = np.sort(np.concatenate([rng.integers(0, 1000, B - 3 * W - 9), np.full(3 * W + 9, 500)]))
+    elif case == "ragged_last_block":
+        B = 40 * W + 37
+        t = np.sort(rng.integers(0, 3000, B))
+    elif case == "below_the_least_and_above_the_greatest":
+        t = np.sort(rng.integers(1000, 2000, B))
+    elif case == "words_of_38_bits":
+        bits, dtype = 38, np.uint64
+        t = np.sort(rng.integers(0, 1 << 38, B))
+        t[W - 2:W + 3] = t[W]                               # a run over the first edge
+    elif case == "queries_in_chunks":
+        bits, dtype, nq = 58, np.uint64, 50                 # 16 queries a chunk: each chunk decides
+        t = np.sort(rng.integers(0, 1 << 58, B))
+    elif case == "one_query":
+        nq = 1
+        t = np.sort(rng.integers(0, 3000, B))
+    else:
+        B, nq = 4 * W * W * 4 + 5, 4                         # the splitters are blocked again
+        t = np.sort(rng.integers(0, 50_000, B))
+    t = t.astype(dtype)
+    q = np.concatenate([rng.choice(t, nq - nq // 2), rng.integers(0, int(t[-1]) + 2, nq // 2)])
+    if case == "below_the_least_and_above_the_greatest":
+        q[:4] = [0, 999, 2000, (1 << 32) - 1]
+    elif case != "one_query":
+        q[:4] = [t[0], t[-1], t[W], t[W - 1]]
+    q = q.astype(dtype)
+    assert join.probe_path(B, nq, bits) == "blocked"
+    lo, hi = join.sorted_run_bounds(jnp.asarray(t), jnp.asarray(q), bits)
+    one_lo, one_hi = _parent_sorted_run_bounds(jnp.asarray(t), jnp.asarray(q), bits)
+    assert lo.dtype == hi.dtype == jnp.int32
+    assert np.array_equal(np.asarray(lo), np.asarray(one_lo))
+    assert np.array_equal(np.asarray(hi), np.asarray(one_hi))
+    assert np.array_equal(np.asarray(lo), np.searchsorted(t, q, side="left"))
+    assert np.array_equal(np.asarray(hi), np.searchsorted(t, q, side="right"))
+    text = str(jax.make_jaxpr(lambda a, b: join.sorted_run_bounds(a, b, bits))(t, q))
+    assert text.count("gather") >= (4 if case == "three_levels" else 2)
+
+
+@pytest.mark.parametrize("exact_keys", [False, True], ids=["hashed", "exact"])
+def test_two_level_probe_counts_keep_the_sentinels_apart(exact_keys, monkeypatch):
+    """Through `build_lookup` and `probe_counts` at shapes that take the
+    two levels: a NULL or dead probe row (`_NO_MATCH_HASH`) finds
+    nothing, a dead or NULL-keyed build row (`_DEAD_BUILD_HASH`, which
+    is also what pads the last block) is never found, and (lo, counts,
+    total) equal the one-level path's."""
+    rng = np.random.default_rng(3737)
+    nb, npr = 64 * join.PROBE_BLOCK + 11, 256
+    bk = rng.integers(0, 3000, nb).astype(np.int64)
+    bvalid, blive = rng.random(nb) > 0.1, rng.random(nb) > 0.2
+    pk = rng.integers(-5, 3005, npr).astype(np.int64)
+    pvalid, plive = rng.random(npr) > 0.2, rng.random(npr) > 0.2
+    ls = join.build_lookup([jnp.asarray(bk)], [jnp.asarray(bvalid)], jnp.asarray(blive),
+                           exact_keys=exact_keys)
+    assert join.probe_path(ls.build_capacity, npr, ls.hash_bits) == "blocked"
+    probe = ([jnp.asarray(pk)], [jnp.asarray(pvalid)], jnp.asarray(plive))
+    lo, counts, total = join.probe_counts(ls, *probe)
+    usable = bk[bvalid & blive]
+    true_counts = np.array([(usable == k).sum() for k in pk]) * (pvalid & plive)
+    if exact_keys:
+        assert np.array_equal(np.asarray(counts), true_counts)
+    else:
+        assert (np.asarray(counts) >= true_counts).all()
+    assert (np.asarray(counts)[~(pvalid & plive)] == 0).all()
+    assert int(np.asarray(lo + counts).max()) <= int((bvalid & blive).sum())
+    monkeypatch.setattr(join, "probe_path", lambda *shape: "sorted")
+    one = join.probe_counts.__wrapped__(ls, *probe)
+    for got, want in zip((lo, counts, total), one):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("build,queries,bits", [(4096, 4096, 32), (3 * 4096 + 4095, 4096, 32),
+                                                (1 << 14, 1 << 13, 38), (300, 2, 32)])
+def test_a_shape_below_the_threshold_traces_the_program_it_always_did(build, queries, bits):
+    """Below PROBE_BLOCK_RATIO times the batch (and for an array of a
+    few blocks) `sorted_run_bounds` is the program it was before PR 37,
+    equation for equation: every compiled program of a cell that does
+    not qualify is found in the compile cache again. The expansion's
+    own use of it (offsets against output positions) stays so at every
+    shape."""
+    dtype = jnp.uint32 if bits <= 32 else jnp.uint64
+    t = jax.ShapeDtypeStruct((build,), dtype)
+    q = jax.ShapeDtypeStruct((queries,), dtype)
+    assert join.probe_path(build, queries, bits) == "sorted"
+    now = jax.make_jaxpr(lambda a, b: join.sorted_run_bounds(a, b, bits))(t, q)
+    then = jax.make_jaxpr(lambda a, b: _parent_sorted_run_bounds(a, b, bits))(t, q)
+    assert str(now) == str(then)
+    off = jax.ShapeDtypeStruct((1 << 14,), jnp.int32)
+    j = jax.ShapeDtypeStruct((64,), jnp.int32)
+    assert str(jax.make_jaxpr(lambda a, b: join._sorted_bounds(a, b, 32))(off, j)) == str(
+        jax.make_jaxpr(_parent_sorted_run_bounds)(off, j))
 
 
 def test_semi_and_outer_flags():

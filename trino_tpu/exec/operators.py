@@ -3405,6 +3405,11 @@ class LookupJoinOperator(Operator):
     evaluated on candidate pairs BEFORE match flags are computed, which
     is what makes filtered semi/anti joins (Q21-style `l2.suppkey <>
     l1.suppkey`) correct.
+
+    METRICS `join_probe_path.blocked` and `.sorted` count the probe
+    batches by the form their bounds took (`ops/join.probe_path`: a
+    function of the build side's slots, the batch's and the word's
+    bits).
     """
 
     def __init__(
@@ -3511,6 +3516,9 @@ class LookupJoinOperator(Operator):
                 keys.append(col.data)
                 valids.append(v)
         live = probe.live_mask()
+        METRICS.increment("join_probe_path." + J.probe_path(
+            ls.build_capacity, probe.capacity, ls.hash_bits
+        ))
         lo, counts, total = J.probe_counts(ls, keys, valids, live)
         fan1 = _fanout_le_one(counts)
         for scalar in (total, fan1):

@@ -10,9 +10,17 @@ what TPUs do well, instead of pointer-chasing:
   structure — duplicates are adjacent runs, playing the role of Trino's
   PositionLinks chains without linked lists.
 - Probe: `sorted_run_bounds` positions every probe hash among the
-  sorted build hashes with two single-operand packed sorts (r4
-  rewrite; see its docstring for why sorts beat every alternative on
-  this hardware).
+  sorted build hashes. Against a build side not much larger than the
+  batch, with two single-operand packed sorts over both (r4 rewrite;
+  `_sorted_bounds` says why sorts beat one-word gathers on this
+  hardware). Against one much larger (`probe_path`), in two levels:
+  the two sorts place the batch among every 128th build word only, and
+  two gathers of whole 128-word rows finish inside one block each, so
+  the build side, sorted once, is not sorted again with every batch.
+  On a v5e the two gathers of 2^20 rows of 512 B and their compares are
+  7 to 8 ms together, where ONE one-word gather of as many indices is
+  9.5 (PR 35): a gather is paid a row, and a 128-lane row is the cheap
+  one (PROBE_BLOCK's comment has the rates).
 - Fan-out (dynamic output size): two-phase — count matches, host picks
   a bucketed output capacity, then a dense expansion pass materializes
   (probe_row, build_row) pairs. 32-bit hash collisions are culled by an
@@ -47,6 +55,40 @@ _NO_MATCH_HASH = jnp.uint32(0xFFFFFFFE)  # probes that must find nothing
 _DEAD_BUILD_HASH = jnp.uint32(0xFFFFFFFF)  # dead build rows sort last
 
 
+# The two-level probe (`sorted_run_bounds`): the sorted array is cut into
+# blocks of PROBE_BLOCK words, and the queries are placed among the
+# blocks' first words before they are placed inside one block. A block
+# is one 128-lane row of the chip: narrower rows gather slower (2^20
+# queries against 15.7 M u32 words, ms: 22.7 at 128, 43.8 at 64, 105.7
+# at 32, 154.2 in one level). Taken where the array holds at least
+# PROBE_BLOCK_RATIO times the queries: at twice it reads what one level
+# reads (21.6 for 20.6), at eight times 22.0 for 78.2. 64-bit words (a
+# u64 array is two u32 arrays on the chip, and their row gathers cost
+# more than twice) gain from eight times on: 62.9 for 78.4, and 27.0
+# for 20.8 at twice (PERF.md section 6, PR 37, has the table).
+PROBE_BLOCK = 128
+PROBE_BLOCK_RATIO = 4
+PROBE_BLOCK_RATIO_WIDE = 8
+
+
+def probe_path(build_capacity: int, probe_capacity: int,
+               hash_bits: int = 32) -> str:
+    """Which form `sorted_run_bounds` takes for `probe_capacity` queries
+    against a sorted array of `build_capacity` words of `hash_bits`
+    bits: "blocked" (two levels) or "sorted" (all of it through two
+    packed sorts). A function of the shapes alone; the kernel and
+    `LookupJoinOperator`'s counter `join_probe_path.*` both ask it."""
+    # (queries too many for one packed word are bounded in chunks, and a
+    # chunk decides)
+    probe_capacity = min(probe_capacity, 1 << max(62 - hash_bits, 0))
+    ratio = PROBE_BLOCK_RATIO if hash_bits <= 32 else PROBE_BLOCK_RATIO_WIDE
+    if probe_capacity > 0 and build_capacity >= ratio * max(
+        probe_capacity, PROBE_BLOCK
+    ):
+        return "blocked"
+    return "sorted"
+
+
 def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray,
                       value_bits: int = 32):
     """For each query, the run [lo, hi) of equal values in a sorted
@@ -57,23 +99,13 @@ def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray,
     query's position share one 64-bit word: queries beyond what is left
     for the position are bounded in chunks.
 
-    TPU-native formulation (r4): on this hardware gathers run at
-    ~16.5ms/M, scatters at ~117ms/M, XLA searchsorted at ~135ms/M, and
-    the scan primitives lax.cummax/cummin hang XLA:TPU compiles the way
-    associative_scan does — while a single-operand lax.sort is ~2ms/M.
-    So the probe is exactly TWO single-operand packed sorts + cumsum:
-
-    1. Each query enters the combined array TWICE — tagged to sort
-       before any equal table value (where its table-prefix count = lo)
-       and after (= hi). The duplicate entry replaces the rightward
-       run-boundary propagation the previous design needed (a
-       scatter+gather pair measured at 15.9ms per 1M rows).
-    2. value(32b) | tag(2b) | query-id packs into one int64 word, so
-       the combined sort carries no payload operands; a second packed
-       sort on (query-id | is-hi | count) routes both bounds back to
-       query order, where each query's (lo, hi) land adjacent and
-       reshape to (N, 2) — no gather, no scatter anywhere.
-    """
+    One algorithm whose first level shrinks with the shapes
+    (`probe_path`). Where the array is not much larger than the batch,
+    `_sorted_bounds`: array and queries through two packed sorts. Where
+    it is, `_blocked_bounds`: the same two sorts place the queries among
+    every PROBE_BLOCK-th word only, and two row gathers and a compare
+    finish inside one block each; the array, in order already, is not
+    sorted again with every batch. Same (lo, hi) either way."""
     B = sorted_arr.shape[0]
     N = q.shape[0]
     if B == 0 or N == 0:
@@ -93,6 +125,36 @@ def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray,
         ]
         return (jnp.concatenate([lo for lo, _ in bounds]),
                 jnp.concatenate([hi for _, hi in bounds]))
+    if probe_path(B, N, value_bits) == "blocked":
+        return _blocked_bounds(sorted_arr, q, value_bits)
+    return _sorted_bounds(sorted_arr, q, value_bits)
+
+
+def _sorted_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray, value_bits: int):
+    """`sorted_run_bounds` in one level, for queries that fit one packed
+    word (its caller chunks them).
+
+    TPU-native formulation (r4). On this chip a gather of 2^20 one-word
+    indices takes 9.5 ms whatever the table's bytes, a scatter of 2^20
+    slots 8.5-10.1 ms (PERF.md section 6, PR 35), XLA's searchsorted is
+    a loop of such gathers, and the scan primitives lax.cummax/cummin
+    hang XLA:TPU compiles the way associative_scan does — while a
+    single-operand lax.sort of 64-bit words is 4.1 ns a word (PR 35).
+    So the bounds are exactly TWO single-operand packed sorts + cumsum:
+
+    1. Each query enters the combined array TWICE — tagged to sort
+       before any equal table value (where its table-prefix count = lo)
+       and after (= hi). The duplicate entry replaces the rightward
+       run-boundary propagation the previous design needed (a
+       scatter+gather pair measured at 15.9ms per 1M rows).
+    2. value(32b) | tag(2b) | query-id packs into one int64 word, so
+       the combined sort carries no payload operands; a second packed
+       sort on (query-id | is-hi | count) routes both bounds back to
+       query order, where each query's (lo, hi) land adjacent and
+       reshape to (N, 2) — no gather, no scatter anywhere.
+    """
+    N = q.shape[0]
+    id_bits = max(int(N - 1).bit_length(), 1)
     vshift = jnp.uint64(2 + id_bits)
     tshift = jnp.uint64(id_bits)
     qv = q.astype(jnp.uint64)
@@ -122,6 +184,44 @@ def sorted_run_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray,
     pair = (res[: 2 * N] & jnp.uint64(0xFFFFFFFF)).astype(jnp.int32)
     pair = pair.reshape(N, 2)
     return pair[:, 0], pair[:, 1]
+
+
+def _blocked_bounds(sorted_arr: jnp.ndarray, q: jnp.ndarray, value_bits: int):
+    """`sorted_run_bounds` in two levels, for an array much larger than
+    the batch of queries.
+
+    1. Splitters: every PROBE_BLOCK-th word of the array, a strided
+       slice. `sorted_run_bounds` places the queries among them (lo_s
+       splitters below a query, hi_s at or below it).
+    2. The array as rows of PROBE_BLOCK words (padded with the greatest
+       word): every block before row lo_s - 1 ends at or below a
+       splitter that is below the query, every block from lo_s on
+       starts at a splitter not below it, so lo is (lo_s - 1) * width
+       plus the words of that ONE row below the query; hi likewise from
+       row hi_s - 1 and the words at or below it. Exact for duplicate
+       runs, also where one crosses blocks: no flag, no fallback.
+    """
+    width = PROBE_BLOCK
+    B = sorted_arr.shape[0]
+    word = jnp.uint32 if value_bits <= 32 else jnp.uint64
+    t = sorted_arr.astype(word)
+    qw = q.astype(word)
+    blocks = -(-B // width)
+    lo_s, hi_s = sorted_run_bounds(t[::width], qw, value_bits)
+    if blocks * width > B:
+        t = jnp.concatenate(
+            [t, jnp.full(blocks * width - B, jnp.iinfo(word).max, word)]
+        )
+    rows = t.reshape(blocks, width)
+
+    def within(at_s, below):
+        row = jnp.maximum(at_s - 1, 0)
+        inside = jnp.sum(below(take_clip(rows, row, axis=0), qw[:, None]),
+                         axis=1, dtype=jnp.int32)
+        at = jnp.where(at_s > 0, row * width + inside, 0)
+        return jnp.minimum(at, B)       # (padding is never below a query)
+
+    return within(lo_s, jnp.less), within(hi_s, jnp.less_equal)
 
 
 def _one_integer_key(keys) -> bool:
@@ -372,8 +472,10 @@ def expand_matches(
     total = off[-1] if counts.shape[0] else jnp.int32(0)
     j = jnp.arange(out_capacity, dtype=jnp.int32)
     # which probe row produced output j: #offs <= j (hi-rank of j among
-    # the sorted offsets)
-    _, pi = sorted_run_bounds(off, j)
+    # the sorted offsets). One level at every shape: a sparse join's
+    # offsets qualify for two, which nobody has measured here, and Q3's
+    # programs stay the ones in the compile cache (ROADMAP S4)
+    _, pi = _sorted_bounds(off, j, 32)
     pi_c = jnp.clip(pi, 0, counts.shape[0] - 1)
     # lo and start ride one packed int64 gather instead of three
     packed = (

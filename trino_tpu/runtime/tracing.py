@@ -86,6 +86,14 @@ while a trace runs: a ``sync.*`` span adds itself to that call's
 ``operator`` span each (``calls``, ``batches``, ``host_syncs``,
 ``host_sync_ms``, ``busy_ms``) in the tree ``GET /v1/query/{id}/trace``
 serves. ``chipbench/spans.py`` reduces the events to per-layer metrics.
+
+Which of several device paths a batch took is not a span but a counter
+of ``runtime/metrics.METRICS``, one increment a batch, trace or no
+trace: ``agg_ingest_path.dense`` / ``.mxu`` / ``.sort`` (the bounded
+reduce a grouped batch got), ``df_filter_path.set`` / ``.bits`` /
+``.range`` (a dynamic filter's batches) and ``join_probe_path.blocked``
+/ ``.sorted`` (a join's probe batches by ``ops/join.probe_path``: the
+two-level bounds or the two packed sorts), all in ``exec/operators.py``.
 """
 
 from __future__ import annotations
