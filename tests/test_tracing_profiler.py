@@ -3,12 +3,17 @@ the profiler's host plane works there). `tiny` TPC-H in the memory
 connector, served by `CoordinatorServer`, asked by `Client`: what a
 traced statement writes (`tpusql.<kind>.<name>`, runtime/tracing.py),
 how the events nest, what the served span tree and the response's
-`stats` carry, and that nothing of it exists while no trace runs."""
+`stats` carry, and that nothing of it exists while no trace runs. And
+the statement's own account, which is kept trace or no trace: what it
+counts, that it is the statement's alone, and the one `tpusql.stmt.done`
+event a statement that carries it, whenever the statement began."""
 
 import glob
 import json
 import os
 import sys
+import threading
+import time
 import urllib.request
 
 import jax
@@ -291,3 +296,251 @@ def test_without_a_trace_a_statement_makes_no_more_spans_than_before(
     export = runner.query_trace_export()
     assert {s["kind"] for s in export["spans"]} == {"query", "phase"}
     assert tracing.check_span_invariants(export) == []
+
+
+# -- the statement's own account ------------------------------------------------
+
+
+def begun_before_the_trace(runner, sql, trace_dir):
+    """Start `sql` on a thread of its own and hold it inside its first
+    scan call, so that its pipeline HAS begun; start a profiler trace in
+    `trace_dir`; return the function that lets the statement go and
+    returns its result. The caller stops the trace."""
+    from trino_tpu.exec import operators
+
+    entered, go, out = threading.Event(), threading.Event(), {}
+    inner = operators.TableScanOperator.get_output
+
+    def held(self):
+        if not entered.is_set():
+            entered.set()
+            assert go.wait(60)
+        return inner(self)
+
+    def run():
+        try:
+            out["result"] = runner.execute(sql)
+        except BaseException as e:          # handed to the caller below
+            out["error"] = e
+
+    thread = threading.Thread(target=run)
+    operators.TableScanOperator.get_output = held
+    try:
+        thread.start()
+        assert entered.wait(60)
+        start_trace(trace_dir)
+    except BaseException:
+        operators.TableScanOperator.get_output = inner
+        go.set()
+        raise
+
+    def finish():
+        try:
+            go.set()
+            thread.join(120)
+            assert not thread.is_alive()
+        finally:
+            operators.TableScanOperator.get_output = inner
+        if "error" in out:
+            raise out["error"]
+        return out["result"]
+
+    return finish
+
+
+COUNTS = ("syncs", "sync_bytes", "plan_hit")
+
+
+def counts_of(account):
+    """What of an account repeats exactly from run to run."""
+    return {k: v for k, v in account.items()
+            if k in COUNTS or k.endswith(".n") or k.startswith("c.")}
+
+
+def test_the_account_is_kept_without_a_trace_and_served(served):
+    runner, server, client = served
+    assert not tracing.profiling()
+    result = runner.execute(STATEMENTS["q3"])
+    account = result.stats["account"]
+    assert {"wall_us", "parse_us", "plan_us", "plan_hit", "instantiate_us",
+            "execute_us", "release_us", "cpu_us", "syncs", "sync_us",
+            "sync_bytes"} <= set(account)
+    assert account["plan_hit"] == 1
+    phases = sum(account[k] for k in (
+        "parse_us", "plan_us", "instantiate_us", "execute_us", "release_us"))
+    assert 0 < account["execute_us"] < phases <= account["wall_us"]
+    assert 0 < account["cpu_us"] <= account["execute_us"]
+    assert 0 < account["sync_us"] <= account["execute_us"]
+    # every readback by its site, and they add up
+    sites = {k[2:-2] for k in account if k.startswith("s.") and k.endswith(".n")}
+    assert {"result", "join.match_total", "scan.rows_scanned"} <= sites
+    assert sum(account[f"s.{s}.n"] for s in sites) == account["syncs"] >= 4
+    assert sum(account[f"s.{s}.us"] for s in sites) == pytest.approx(
+        account["sync_us"])
+    # the counters its thread moved: the rows it scanned, the paths taken
+    assert account["c.rows_scanned"] > 0
+    assert account["c.plan_cache.hits"] == 1
+    assert account["c.join_probe_path.sorted"] >= 1
+    assert account["c.agg_ingest_path.sort"] == account["c.agg_ingest_batches"]
+    assert not [k for k in account if "by_query" in k]
+    # the same statement again counts the same
+    again = runner.execute(STATEMENTS["q3"]).stats["account"]
+    assert counts_of(again) == counts_of(account)
+    # the span tree's export and the endpoint serve it, with no profiler
+    assert runner.query_trace_export()["account"] == again
+    served_result = client.execute(STATEMENTS["q3"])
+    with urllib.request.urlopen(
+        f"{server.uri}/v1/query/{served_result.query_id}/trace", timeout=10,
+    ) as r:
+        tree = json.load(r)
+    assert counts_of(tree["account"]) == counts_of(account)
+    assert tree["account"]["wall_us"] > 0 and tree["traceEvents"]
+
+
+def test_a_thread_with_no_statement_gets_the_shared_noop():
+    assert tracing.running_statement() is None
+    assert tracing.host_sync("scan.rows_scanned", 8) is tracing.OFF
+    account = tracing.StmtAccount("by-hand")
+    seen = {}
+
+    def elsewhere():
+        seen["sync"] = tracing.host_sync("scan.rows_scanned", 8)
+        seen["phase"] = tracing.phase_span(None, "plan", hit=0)
+        seen["statement"] = tracing.running_statement()
+
+    with tracing.statement(account, time.perf_counter_ns(), 7):
+        assert tracing.running_statement() is account
+        with tracing.host_sync("a.site", 16) as sync:
+            sync.set_metadata(rows=1)           # accepted, dropped
+            assert sync is not tracing.OFF
+        with tracing.host_sync("a.site", 4):
+            pass
+        with tracing.phase_span(None, "plan", hit=0) as plan:
+            plan.set_metadata(hit=1)
+        assert tracing.phase_span(None, "finalize") is tracing.OFF
+        from trino_tpu.runtime.metrics import METRICS
+
+        METRICS.increment("test_tracing_profiler.by_hand", 3)
+        thread = threading.Thread(target=elsewhere)
+        thread.start()
+        thread.join(30)
+        assert not thread.is_alive()
+    # another thread, while the statement ran on this one: nothing
+    assert seen == {"sync": tracing.OFF, "phase": tracing.OFF, "statement": None}
+    assert tracing.running_statement() is None
+    assert tracing.host_sync("a.site", 16) is tracing.OFF
+    stats = account.stats()
+    assert (stats["syncs"], stats["sync_bytes"], stats["s.a.site.n"]) == (2, 20, 2)
+    assert stats["s.a.site.us"] == stats["sync_us"] > 0
+    assert stats["plan_hit"] == 1 and stats["plan_us"] > 0
+    assert stats["parse_us"] == 0.007 and stats["wall_us"] > stats["plan_us"]
+    assert stats["c.test_tracing_profiler.by_hand"] == 3
+    METRICS.remove("test_tracing_profiler.by_hand")
+
+
+def test_a_statement_begun_before_the_trace_leaves_its_whole_account(
+        served, tmp_path):
+    """Held inside its first scan call while the trace starts: its
+    pipeline began before the trace, its query and `execute` spans were
+    entered before it. It still leaves `op.*` and `sync.*` events from
+    the trace's start on and one `stmt.done` with the numbers of its
+    whole life; and no `stmt.begin`, no `query.query`."""
+    runner, _server, _client = served
+    alone = runner.execute(STATEMENTS["q3"]).stats["account"]
+    finish = begun_before_the_trace(runner, STATEMENTS["q3"], tmp_path)
+    try:
+        result = finish()
+        after = runner.execute(STATEMENTS["q1"])
+    finally:
+        jax.profiler.stop_trace()
+    lines = host_lines(tmp_path)
+    events = [e for line in lines for e in line]
+    done = {e[3]["query_id"]: e for e in events if e[0] == P + "stmt.done"}
+    assert set(done) == {result.stats["query_id"], after.stats["query_id"]}
+    assert [e[3]["query_id"] for e in events if e[0] == P + "stmt.begin"] == [
+        after.stats["query_id"]]
+    assert [e[3]["query_id"] for e in events if e[0] == P + "query.query"] == [
+        after.stats["query_id"]]
+    event = done[result.stats["query_id"]]
+    stats = dict(event[3])
+    del stats["query_id"]
+    # the event is the account the result carries, number for number
+    account = result.stats["account"]
+    assert set(stats) == set(account)
+    for key, value in account.items():
+        assert stats[key] == pytest.approx(value, rel=1e-6), key
+    # and what the same statement counts untraced: counts exactly
+    assert counts_of(stats) == counts_of(alone)
+    assert stats["syncs"] == alone["syncs"]
+    assert stats["c.rows_scanned"] == alone["c.rows_scanned"]
+    assert stats["execute_us"] > 0 and stats["wall_us"] > stats["execute_us"]
+    # it began before the trace did: its wall reaches back past the
+    # first event of the trace
+    first_ns = min(e[1] for e in events)
+    assert event[2] - 1e3 * stats["wall_us"] < first_ns
+    # operator calls and readbacks from the trace's start on, on its line
+    line = next(line for line in lines if event in line)
+    mine = [e for e in line if e[2] <= event[1]]
+    ops = [e for e in mine if e[0].startswith(P + "op.")]
+    syncs = [e for e in mine if e[0].startswith(P + "sync.")]
+    assert {e[0].rsplit(".", 1)[1] for e in ops} == {
+        "get_output", "add_input", "finish"}
+    assert any(e[0].startswith(P + "op.LookupJoinOperator.") for e in ops)
+    assert syncs and len(syncs) <= stats["syncs"]
+    # (a readback of the held call itself lies in no span: that call
+    # began before the trace)
+    first_op_ns = min(o[1] for o in ops)
+    for sync in syncs:
+        assert sync[1] < first_op_ns or any(
+            o[1] <= sync[1] and sync[2] <= o[2] for o in mine
+            if o[0].startswith((P + "op.", P + "result."))), sync
+    assert sum(sync[1] > first_op_ns for sync in syncs) >= stats["syncs"] - 2
+    # the served tree still has its operator spans, made at the first
+    # call that found the profiler on
+    export = runner.query_trace_export(result.stats["query_id"])
+    assert export is None or tracing.check_span_invariants(export) == []
+
+
+def test_two_statements_at_once_count_each_its_own(served):
+    """Two threads, one runner, both statements' accounts open before
+    either runs: each counts what it counts alone, and so does its
+    completion event (which was the process's count over its life)."""
+    runner, _server, _client = served
+    alone = {name: runner.execute(STATEMENTS[name]).stats["account"]
+             for name in ("q1", "q3")}
+    both_open = threading.Barrier(2, timeout=60)
+    completed, results, errors = {}, {}, []
+
+    class Listener:
+        def query_created(self, event):
+            both_open.wait()
+
+        def query_completed(self, event):
+            completed[event.query_id] = event
+
+    listener = Listener()
+    runner.event_listeners.add(listener)
+
+    def run(name):
+        try:
+            results[name] = runner.execute(STATEMENTS[name])
+        except BaseException as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(n,)) for n in ("q1", "q3")]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+            assert not t.is_alive()
+    finally:
+        runner.event_listeners._listeners.remove(listener)
+    assert not errors
+    for name in ("q1", "q3"):
+        account = results[name].stats["account"]
+        assert counts_of(account) == counts_of(alone[name]), name
+        event = completed[results[name].stats["query_id"]]
+        assert event.rows_scanned == alone[name]["c.rows_scanned"]
+    assert alone["q1"]["c.rows_scanned"] != alone["q3"]["c.rows_scanned"]
+    assert alone["q1"]["syncs"] != alone["q3"]["syncs"]

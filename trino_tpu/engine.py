@@ -357,7 +357,9 @@ class LocalQueryRunner:
         t_parse = time.perf_counter_ns()
         with host_span("phase.parse"):
             stmt = parse(sql)
-        # (parse, queued) nanoseconds before the query span opens
+        # when the statement entered, and (parse, queued) nanoseconds
+        # before the query span opens
+        self._stmt_txn.entered_ns = t_parse
         self._stmt_txn.before_ns = (
             time.perf_counter_ns() - t_parse, queued_ns or 0
         )
@@ -1219,24 +1221,37 @@ class LocalQueryRunner:
         return MaterializedResult([[writer.rows_written]], ["rows"], [T.BIGINT])
 
     def _run_tracked(self, sql: str, stmt: ast.Query) -> MaterializedResult:
-        """Query lifecycle: span tree + event listener dispatch around
-        the actual execution (SqlQueryExecution's tracing shape)."""
-        import time as _time
-
-        from trino_tpu.runtime.events import QueryCreatedEvent
-        from trino_tpu.runtime.metrics import METRICS
-        from trino_tpu.runtime.tracing import KIND_QUERY, QueryTrace
+        """Query lifecycle: span tree, the statement's own account and
+        event listener dispatch around the actual execution
+        (SqlQueryExecution's tracing shape)."""
+        from trino_tpu.runtime.tracing import QueryTrace, statement
 
         self._query_seq += 1
         query_id = f"local-{self._query_seq}"
         trace = QueryTrace(query_id)
+        parse_ns, queued_ns = getattr(self._stmt_txn, "before_ns", (0, 0))
+        entered_ns = getattr(
+            self._stmt_txn, "entered_ns", time.perf_counter_ns())
+        # opened and closed on this, the executing thread: what it reads
+        # back and counts below is this statement's
+        with statement(trace.account, entered_ns, parse_ns):
+            result = self._run_in_trace(
+                sql, stmt, query_id, trace, parse_ns, queued_ns)
+        result.stats["account"] = trace.account.stats()
+        return result
+
+    def _run_in_trace(self, sql, stmt, query_id, trace, parse_ns,
+                      queued_ns) -> MaterializedResult:
+        import time as _time
+
+        from trino_tpu.runtime.events import QueryCreatedEvent
+        from trino_tpu.runtime.tracing import KIND_QUERY
+
         qspan = trace.span(f"query {query_id}", KIND_QUERY, sql=sql[:500])
-        counters_before = METRICS.snapshot()
         self.event_listeners.query_created(
             QueryCreatedEvent(query_id, sql, _time.time())
         )
         status, failure, rows_n = "finished", None, 0
-        parse_ns, queued_ns = getattr(self._stmt_txn, "before_ns", (0, 0))
         qspan.set(queued_ms=queued_ns / 1e6, plan_ms=parse_ns / 1e6)
         # entered and left on this, the executing thread: in a profiler
         # trace the statement is one event with everything below inside
@@ -1263,17 +1278,15 @@ class LocalQueryRunner:
             finally:
                 with phase_span(qspan, "finalize"):
                     self._finalize_query(
-                        query_id, sql, trace, qspan, status, failure,
-                        rows_n, counters_before,
+                        query_id, sql, trace, qspan, status, failure, rows_n,
                     )
 
     def _finalize_query(self, query_id, sql, trace, qspan, status,
-                        failure, rows_n, counters_before):
+                        failure, rows_n):
         """Close the span tree, retire per-query compile counters, and
         fire the enriched completion event. Observability finalization
         must never mask the query's own verdict, so it swallows."""
         try:
-            from trino_tpu.exec.stats import engine_counters_delta
             from trino_tpu.runtime.events import QueryCompletedEvent
             from trino_tpu.runtime.metrics import (
                 METRICS,
@@ -1286,9 +1299,8 @@ class LocalQueryRunner:
             wall = qspan.duration_s
             METRICS.observe("query_wall_s", wall)
             compile_count = retire_query_compiles(query_id)
-            counters = engine_counters_delta(
-                counters_before, METRICS.snapshot()
-            )
+            # what this statement's own thread counted, not the process
+            account = trace.account
             peak = 0
             if self._last_pool is not None:
                 peaks = self._last_pool.query_peaks()
@@ -1299,9 +1311,9 @@ class LocalQueryRunner:
                     query_id, sql, status, wall,
                     rows=rows_n, failure=failure,
                     peak_memory_bytes=peak,
-                    rows_scanned=int(counters.get("rows_scanned", 0)),
-                    bytes_scanned=int(counters.get("bytes_scanned", 0)),
-                    rows_shuffled=int(counters.get("rows_shuffled", 0)),
+                    rows_scanned=int(account.counter("rows_scanned")),
+                    bytes_scanned=int(account.counter("bytes_scanned")),
+                    rows_shuffled=int(account.counter("rows_shuffled")),
                     compile_count=compile_count,
                 )
             )
@@ -1470,7 +1482,10 @@ class LocalQueryRunner:
         export = self.query_trace_export(query_id)
         if export is None:
             return None
-        return {"traceEvents": chrome_trace(export)}
+        # Perfetto reads `traceEvents`; the statement's own numbers ride
+        # beside it
+        return {"traceEvents": chrome_trace(export),
+                "account": export.get("account")}
 
     def _execute_query(
         self, q: ast.Query, sql_key: Optional[str] = None,
@@ -1510,6 +1525,8 @@ class LocalQueryRunner:
 
             exec_span = query_span.child("execute", KIND_PHASE)
         op_parent = exec_span if query_span is not None else None
+        account = trace.account if trace is not None else None
+        t_execute = time.perf_counter_ns()
         cpu0 = time.thread_time_ns()
         try:
             with exec_span:
@@ -1524,12 +1541,16 @@ class LocalQueryRunner:
                         if v:
                             raise RuntimeError(msg)
                 finally:
+                    # this thread's CPU time inside the phase: wall
+                    # minus it is waiting (the device, the GIL)
+                    cpu_ns = time.thread_time_ns() - cpu0
                     if query_span is not None:
-                        # this thread's CPU time inside the phase: wall
-                        # minus it is waiting (the device, the GIL)
-                        cpu_ns = time.thread_time_ns() - cpu0
                         exec_span.set(cpu_ns=cpu_ns)
                         query_span.set(cpu_ms=cpu_ns / 1e6)
+                    if account is not None:
+                        account.cpu_ns += cpu_ns
+                        account.execute_ns += (
+                            time.perf_counter_ns() - t_execute)
         finally:
             set_compile_attribution(prev_qid)
         with phase_span(query_span, "release"):

@@ -44,6 +44,8 @@ class Driver:
         # while a profiler trace runs, every operator call is a leaf
         # span of it, and `span` gets one operator span per operator
         self._span = span
+        # made by the first operator call that finds the profiler on
+        self._tallies = None
         self._finish_signalled = [False] * len(self.ops)
         self._should_stop = should_stop
         # observer(op_name, moved) fires after every batch move (moved=
@@ -54,15 +56,24 @@ class Driver:
         self._observer = observer
 
     def run(self) -> None:
-        if not tracing.profiling():
-            return self._run(None)
-        tallies = [tracing.OpTally(type(o).__name__) for o in self.ops]
         try:
-            return self._run(tallies)
+            return self._run()
         finally:
-            tracing.record_operators(self._span, tallies)
+            if self._tallies is not None:
+                tracing.record_operators(self._span, self._tallies)
 
-    def _run(self, tallies) -> None:
+    def _call(self, i: int, method: str):
+        """Operator `i`'s call of `method` as a span: asked per call, so
+        a pipeline that began before a trace is in it from its first
+        instant; `tracing.OFF` without a trace."""
+        if not tracing.profiling():
+            return tracing.OFF
+        if self._tallies is None:
+            self._tallies = [tracing.OpTally(type(o).__name__)
+                             for o in self.ops]
+        return self._tallies[i].call(method)
+
+    def _run(self) -> None:
         ops = self.ops
         n = len(ops)
         while not ops[-1].is_finished():
@@ -81,31 +92,23 @@ class Driver:
                     # a long batch train, not after it
                     if self._should_stop is not None and self._should_stop():
                         raise TaskAbortedError("task aborted")
-                    if tallies is None:
+                    with self._call(i, "get_output"):
                         out = cur.get_output()
-                        if out is None:
-                            break
-                        nxt.add_input(out)
-                    else:
-                        with tallies[i].call("get_output"):
-                            out = cur.get_output()
-                        if out is None:
-                            break
+                    if out is None:
+                        break
+                    if self._tallies is not None:
                         # batches an operator put out; the sink's: took in
-                        tallies[i].batches += 1
-                        tallies[i + 1].batches += i + 2 == n
-                        with tallies[i + 1].call("add_input"):
-                            nxt.add_input(out)
+                        self._tallies[i].batches += 1
+                        self._tallies[i + 1].batches += i + 2 == n
+                    with self._call(i + 1, "add_input"):
+                        nxt.add_input(out)
                     progressed = True
                     if self._observer is not None:
                         self._observer(type(cur).__name__, True)
                 # finish cascade (Driver.java:417)
                 if cur.is_finished() and not self._finish_signalled[i + 1]:
-                    if tallies is None:
+                    with self._call(i + 1, "finish"):
                         nxt.finish()
-                    else:
-                        with tallies[i + 1].call("finish"):
-                            nxt.finish()
                     self._finish_signalled[i + 1] = True
                     progressed = True
             if not progressed and not ops[-1].is_finished():

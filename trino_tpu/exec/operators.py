@@ -43,7 +43,7 @@ from trino_tpu.ops.gather import take_clip
 from trino_tpu.ops import join as J
 from trino_tpu.ops.sort import SortKey, sort_order
 from trino_tpu.runtime.metrics import METRICS
-from trino_tpu.runtime.tracing import host_span, host_sync, profiling
+from trino_tpu.runtime.tracing import host_span, host_sync
 
 
 class Operator:
@@ -4653,9 +4653,10 @@ class CollectorSink(Operator):
         so the whole tree costs about one read-back, while a
         device-side pack-into-one-buffer program costs a dispatch plus
         a fetch. Don't 'optimize' this into a packing kernel."""
+        # the statement's account has the bytes with or without a trace
         nbytes = sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.batches)
-        ) if profiling() else 0
+        )
         with host_span("result.fetch", batches=len(self.batches)):
             with host_sync("result", nbytes):
                 host_batches, host_extra = jax.device_get(

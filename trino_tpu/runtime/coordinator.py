@@ -288,6 +288,14 @@ class QueryScheduler:
                     pass
 
 
+def _engine_counters_now() -> Dict[str, float]:
+    """The process's engine counters, read one by one: no gauge runs."""
+    from trino_tpu.exec.stats import ENGINE_COUNTERS
+    from trino_tpu.runtime.metrics import METRICS
+
+    return {k: METRICS.counter(k) for k in ENGINE_COUNTERS}
+
+
 class DistributedQueryRunner:
     """Multi-worker engine in one process (DistributedQueryRunner.java:84
     analogue): same SQL surface as LocalQueryRunner, but every query runs
@@ -558,10 +566,12 @@ class DistributedQueryRunner:
 
         from trino_tpu.runtime.tracing import host_span
 
+        entered_ns = _time.perf_counter_ns()
         t_parse0 = _time.time()
         with host_span("phase.parse"):
             stmt = parse(sql)
         t_parse1 = _time.time()
+        parse_ns = _time.perf_counter_ns() - entered_ns
         if isinstance(stmt, ast.ExplainStatement):
             output = self._analyze(stmt.query)
             self._check_access(output, identity)
@@ -662,11 +672,11 @@ class DistributedQueryRunner:
         # (query/phases/stages/tasks — a handful of spans); worker
         # OPERATOR spans and row counting only under query_trace=on
         from trino_tpu.runtime.events import QueryCreatedEvent
-        from trino_tpu.runtime.metrics import METRICS
         from trino_tpu.runtime.tracing import (
             KIND_PHASE,
             KIND_QUERY,
             QueryTrace,
+            statement,
         )
 
         trace = QueryTrace(base_qid)
@@ -677,33 +687,46 @@ class DistributedQueryRunner:
         pspan.end(t_parse1)
         with self._lock:
             self._active_traces[base_qid] = trace
-        counters_before = METRICS.snapshot()
+        counters_before = _engine_counters_now()
         self.event_listeners.query_created(
             QueryCreatedEvent(base_qid, sql, _time.time())
         )
         self._last_stage_infos = None
         self._last_data_plane = "http"
-        status, failure_txt, rows_n = "finished", None, 0
-        try:
-            result = self._execute_query(
-                stmt, identity, base_qid, tq, limits, cancel,
-                trace=trace, query_span=qspan, param_dtypes=param_dtypes,
-            )
-            rows_n = len(result.rows)
-            return result
-        except BaseException as e:
-            status, failure_txt = "failed", repr(e)
-            if not qspan.ended:
-                qspan.event("exception", type=type(e).__name__,
-                            message=str(e)[:500])
-                qspan.set(error=True)
-            raise
-        finally:
-            tracker.complete(base_qid)
-            self._finalize_query(
-                base_qid, sql, trace, qspan, status, failure_txt,
-                rows_n, counters_before,
-            )
+        status, failure_txt, rows_n, plane = "finished", None, 0, None
+        account = trace.account
+        # the account of what THIS thread does for the statement: all
+        # of the mesh plane (its programs, readbacks and counters), the
+        # coordinator's part of the page and spooled planes
+        with statement(account, entered_ns, parse_ns):
+            t_query = _time.perf_counter_ns()
+            try:
+                result = self._execute_query(
+                    stmt, identity, base_qid, tq, limits, cancel,
+                    trace=trace, query_span=qspan,
+                    param_dtypes=param_dtypes,
+                )
+                rows_n = len(result.rows)
+                plane = result.data_plane
+            except BaseException as e:
+                status, failure_txt = "failed", repr(e)
+                if not qspan.ended:
+                    qspan.event("exception", type=type(e).__name__,
+                                message=str(e)[:500])
+                    qspan.set(error=True)
+                raise
+            finally:
+                # everything after the plan was made or found
+                account.execute_ns = (
+                    _time.perf_counter_ns() - t_query - account.plan_ns)
+                tracker.complete(base_qid)
+                self._finalize_query(
+                    base_qid, sql, trace, qspan, status, failure_txt,
+                    rows_n, counters_before, plane,
+                )
+        result.stats = {"query_id": base_qid, "cpu_ms": account.cpu_ns / 1e6,
+                        "account": account.stats()}
+        return result
 
     def _execute_query(
         self, stmt, identity, base_qid, tq, limits, cancel,
@@ -725,6 +748,8 @@ class DistributedQueryRunner:
             return query_span.child(name, KIND_PHASE)
 
         tracker = self.query_tracker
+        account = trace.account if trace is not None else None
+        t_plan = time.perf_counter_ns()
         # reset BEFORE any plane decision: a stale reason from an earlier
         # query must not read as applying to this one
         self.last_mesh_fallback = None
@@ -807,6 +832,9 @@ class DistributedQueryRunner:
         tracker.enforce_now(base_qid)
         tracker.check(base_qid)
         tracker.transition(base_qid, EXECUTING)
+        if account is not None:
+            account.plan_ns = time.perf_counter_ns() - t_plan
+            account.plan_hit = int(cached is not None)
         # worker-local deadline: translate the query's remaining wall
         # budget into the epoch-seconds deadline every TaskSpec carries,
         # so workers self-terminate between batches instead of waiting
@@ -883,9 +911,10 @@ class DistributedQueryRunner:
                             fast=fast_lane, query_id=base_qid,
                         )
                     finally:
-                        executing.set_metadata(
-                            cpu_ns=_time.thread_time_ns() - cpu0
-                        )
+                        cpu_ns = _time.thread_time_ns() - cpu0
+                        executing.set_metadata(cpu_ns=cpu_ns)
+                        if account is not None:
+                            account.cpu_ns += cpu_ns
                 self._last_data_plane = "mesh"
                 return MaterializedResult(
                     rows, *result_meta, data_plane="mesh"
@@ -1972,7 +2001,7 @@ class DistributedQueryRunner:
 
     def _finalize_query(
         self, base_qid, sql, trace, qspan, status, failure_txt,
-        rows_n, counters_before,
+        rows_n, counters_before, plane,
     ) -> None:
         """Close out the observability plane for one query (success OR
         failure): end the span tree, record histograms, retire per-query
@@ -1981,7 +2010,10 @@ class DistributedQueryRunner:
         QueryCompletedEvent. Never raises — observability must not mask
         the query verdict."""
         try:
-            from trino_tpu.exec.stats import engine_counters_delta
+            from trino_tpu.exec.stats import (
+                ENGINE_COUNTERS,
+                engine_counters_delta,
+            )
             from trino_tpu.runtime.events import QueryCompletedEvent
             from trino_tpu.runtime.metrics import (
                 METRICS,
@@ -2004,9 +2036,17 @@ class DistributedQueryRunner:
             RECORDER.purge(base_qid)
             compile_count = int(retire_query_compiles(base_qid))
             peak = self._drain_query_peaks(base_qid)
-            counters = engine_counters_delta(
-                counters_before, METRICS.snapshot()
-            )
+            if plane == "mesh":
+                # the statement's own thread ran all of it: its account
+                counters = {
+                    k: trace.account.counter(k) for k in ENGINE_COUNTERS
+                }
+            else:
+                # worker tasks ran it on threads (or hosts) of their
+                # own: what the process counted meanwhile
+                counters = engine_counters_delta(
+                    counters_before, _engine_counters_now()
+                )
             err_code = None
             if failure_txt:
                 err_code = deadline_code(failure_txt)
@@ -2092,7 +2132,10 @@ class DistributedQueryRunner:
         export = self.query_trace_export(query_id)
         if export is None:
             return None
-        return {"traceEvents": chrome_trace(export)}
+        # Perfetto reads `traceEvents`; the statement's own numbers ride
+        # beside it
+        return {"traceEvents": chrome_trace(export),
+                "account": export.get("account")}
 
     @staticmethod
     def _raise_if_failed(scheduler: QueryScheduler) -> None:

@@ -16,6 +16,8 @@ import threading
 from trino_tpu.analysis.witness import named_condition, named_lock, named_rlock
 from typing import Callable, Dict, List, Optional
 
+from trino_tpu.runtime.tracing import running_statement
+
 
 class Distribution:
     """Fixed-bucket histogram (DistributionStat/TimeStat analogue).
@@ -91,6 +93,11 @@ class MetricsRegistry:
     def increment(self, name: str, delta: float = 1.0) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0.0) + delta
+        # the statement this thread is executing counts what it moved
+        account = running_statement()
+        if account is not None:
+            mine = account.counters
+            mine[name] = mine.get(name, 0.0) + delta
 
     def counter(self, name: str) -> float:
         with self._lock:

@@ -43,7 +43,9 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   numeric attribute of the span (``phase.execute``: ``cpu_ns``, the
   executing thread's CPU time inside it);
 - leaf spans, ``host_span(name, **stats)``: no ``Span``, no id, no lock;
-  with no trace running the one shared no-op ``OFF``. Their names:
+  with no trace running the one shared no-op ``OFF`` (``host_sync`` and
+  ``phase_span`` then still time into the running statement's account,
+  below). Their names:
   ``phase.parse``, ``phase.plan`` (stat ``hit``: 1 when the plan cache
   answered), ``phase.instantiate``, ``phase.release`` (the operators'
   state and its device buffers are dropped), ``phase.finalize`` (all
@@ -79,13 +81,54 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   and ``phase.execute`` (``cpu_ns``) around a distributed statement.
 
 Leaf spans know their statement by lying inside its ``tpusql.query.*``
-event on the same thread line. The one piece of per-thread state here is
-the operator call that is running (``_RUNNING``), which exists only
-while a trace runs: a ``sync.*`` span adds itself to that call's
+event on the same thread line. An operator call is a span whenever the
+profiler is on as the call begins (``exec/driver.Driver`` asks per call,
+not once a pipeline), so a pipeline that began before the trace has
+``op.*`` events, and ``sync.*`` inside them, from the trace's first
+instant. A ``sync.*`` span adds itself to the running call's
 ``OpTally``, and ``record_operators`` turns the tallies into one
 ``operator`` span each (``calls``, ``batches``, ``host_syncs``,
 ``host_sync_ms``, ``busy_ms``) in the tree ``GET /v1/query/{id}/trace``
 serves. ``chipbench/spans.py`` reduces the events to per-layer metrics.
+
+A statement's own account
+-------------------------
+
+Every statement's ``QueryTrace`` carries one ``StmtAccount``, kept
+whether or not a profiler runs, from the runner's ``execute`` entered to
+the result handed back. The one piece of per-thread state here is the
+statement running on this thread (``running_statement()``; both runners
+open and close it with ``statement(...)``), and the operator call that
+is running hangs off it. The account is fed where the work happens:
+``phase_span`` times ``plan`` (and ``plan_hit``), ``instantiate`` and
+``release`` into it, the runners ``parse``, ``execute`` and the
+executing thread's CPU time inside ``execute``; ``host_sync`` times
+every readback on the thread into it (count, wall and bytes, in all and
+by site) and is the shared ``OFF`` only where no statement runs on the
+thread; ``runtime/metrics.MetricsRegistry.increment`` adds to it every
+delta the statement's own thread adds to a counter. Work that other
+threads do for a statement (the page plane's worker tasks) is not in
+it. ``StmtAccount.stats()`` is the account as flat numbers:
+
+- ``wall_us``, ``parse_us``, ``plan_us``, ``plan_hit``,
+  ``instantiate_us``, ``execute_us``, ``release_us`` (walls; a phase a
+  runner does not have reads 0), ``cpu_us``;
+- ``syncs``, ``sync_us``, ``sync_bytes``, and ``s.<site>.n`` /
+  ``s.<site>.us`` for every ``sync.<site>`` the statement reached;
+- ``c.<counter>`` for every counter it moved (``c.rows_scanned``,
+  ``c.agg_ingest_path.sort``, ``c.join_probe_path.blocked``,
+  ``c.mesh.chunk_steps``, ...).
+
+``result.stats["account"]``, ``QueryTrace.export()["account"]`` and the
+``account`` key of ``GET /v1/query/{id}/trace`` carry it, and the
+completion event takes ``rows_scanned``, ``bytes_scanned`` and
+``rows_shuffled`` from it. While a profiler trace runs at the
+statement's END, whenever it began, the executing thread writes one
+event ``tpusql.stmt.done`` whose stats are ``query_id`` and all of the
+above: the statement's numbers over its whole life, at the instant it
+ended, on the clock the device's events are on. A statement that starts
+inside a trace writes one ``tpusql.stmt.begin`` (``query_id``) as its
+account opens. ``chipbench/stmt_account.py`` reads both (STMT.md).
 
 Which of several device paths a batch took is not a span but a counter
 of ``runtime/metrics.METRICS``, one increment a batch, trace or no
@@ -259,13 +302,170 @@ def host_span(name: str, **stats):
     return OFF
 
 
+# -- a statement's own account ------------------------------------------
+
+
+# counters that carry a statement's id in their name
+PER_QUERY_COUNTERS = "xla_compiles_by_query."
+
+
+class StmtAccount:
+    """What one statement did, counted on the thread that executes it
+    whether or not a profiler runs (the module docstring has the
+    vocabulary). Slots and no lock: only that thread writes."""
+
+    __slots__ = (
+        "query_id", "entered_ns", "wall_ns", "parse_ns", "plan_ns",
+        "plan_hit", "instantiate_ns", "execute_ns", "release_ns", "cpu_ns",
+        "syncs", "sync_ns", "sync_bytes", "sites", "counters", "tally",
+    )
+
+    def __init__(self, query_id: str):
+        self.query_id = query_id
+        self.entered_ns = time.perf_counter_ns()
+        self.wall_ns = self.parse_ns = self.plan_ns = self.plan_hit = 0
+        self.instantiate_ns = self.execute_ns = self.release_ns = 0
+        self.cpu_ns = self.syncs = self.sync_ns = self.sync_bytes = 0
+        self.sites: Dict[str, List[int]] = {}    # site -> [count, ns]
+        self.counters: Dict[str, float] = {}
+        # the operator call running on the thread, while a trace runs
+        self.tally: Optional[OpTally] = None
+
+    def sync(self, site: str, ns: int, nbytes: int) -> None:
+        self.syncs += 1
+        self.sync_ns += ns
+        self.sync_bytes += nbytes
+        row = self.sites.get(site)
+        if row is None:
+            self.sites[site] = [1, ns]
+        else:
+            row[0] += 1
+            row[1] += ns
+
+    def counter(self, name: str) -> float:
+        return self.counters.get(name, 0.0)
+
+    def stats(self) -> Dict[str, Union[int, float]]:
+        """The account as flat numbers: `tpusql.stmt.done`'s stats."""
+        out: Dict[str, Union[int, float]] = {
+            "wall_us": self.wall_ns / 1e3, "parse_us": self.parse_ns / 1e3,
+            "plan_us": self.plan_ns / 1e3, "plan_hit": self.plan_hit,
+            "instantiate_us": self.instantiate_ns / 1e3,
+            "execute_us": self.execute_ns / 1e3,
+            "release_us": self.release_ns / 1e3, "cpu_us": self.cpu_ns / 1e3,
+            "syncs": self.syncs, "sync_us": self.sync_ns / 1e3,
+            "sync_bytes": self.sync_bytes,
+        }
+        for site, (n, ns) in self.sites.items():
+            out[f"s.{site}.n"] = n
+            out[f"s.{site}.us"] = ns / 1e3
+        for name, v in self.counters.items():
+            # a counter named after one statement would be a new stat
+            # name with every statement
+            if not name.startswith(PER_QUERY_COUNTERS):
+                out[f"c.{name}"] = int(v) if v == int(v) else v
+        return out
+
+
+class _Thread(threading.local):
+    """The one piece of per-thread state: the account of the statement
+    this thread is executing. (A class default, because a thread-local's
+    missing attribute costs 600 ns to ask for.)"""
+
+    stmt: Optional[StmtAccount] = None
+
+
+_THREAD = _Thread()
+
+
+def running_statement() -> Optional[StmtAccount]:
+    return _THREAD.stmt
+
+
+class statement:
+    """The runners' one way to open and close a statement's account on
+    the thread that executes it: `with statement(trace.account, t):`
+    from the trace made to the result handed back, `t` the
+    `perf_counter_ns` at which `execute` was entered and the parse
+    began. Writes `stmt.begin` and `stmt.done` into a running profiler
+    trace."""
+
+    __slots__ = ("_account", "_outer")
+
+    def __init__(self, account: StmtAccount, entered_ns: int,
+                 parse_ns: int = 0):
+        account.entered_ns = entered_ns
+        account.parse_ns = parse_ns
+        self._account = account
+
+    def __enter__(self) -> StmtAccount:
+        self._outer = running_statement()
+        _THREAD.stmt = self._account
+        if TraceAnnotation.is_enabled():
+            _instant("stmt.begin", query_id=self._account.query_id)
+        return self._account
+
+    def __exit__(self, *exc) -> None:
+        account = self._account
+        _THREAD.stmt = self._outer
+        account.wall_ns = time.perf_counter_ns() - account.entered_ns
+        if TraceAnnotation.is_enabled():
+            _instant("stmt.done", query_id=account.query_id,
+                     **account.stats())
+
+
+def _instant(name: str, **stats) -> None:
+    with TraceAnnotation(PROFILE_PREFIX + name, **stats):
+        pass
+
+
+# the phases whose wall `phase_span` adds to the running account
+_PHASE_WALLS = {"plan": "plan_ns", "instantiate": "instantiate_ns",
+                "release": "release_ns"}
+
+
+class _Phase:
+    """`phase.<name>` timed into the running statement's account, and an
+    event of the profiler's trace where `annotation` is one."""
+
+    __slots__ = ("_account", "_slot", "_annotation", "_t0")
+
+    def __init__(self, account: StmtAccount, slot: str, annotation):
+        self._account, self._slot = account, slot
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        account = self._account
+        setattr(account, self._slot, getattr(account, self._slot)
+                + time.perf_counter_ns() - self._t0)
+
+    def set_metadata(self, **stats) -> None:
+        if "hit" in stats:
+            self._account.plan_hit = int(stats["hit"])
+        self._annotation.set_metadata(**stats)
+
+
 def phase_span(query_span: Optional["Span"], name: str, **stats):
-    """`host_span("phase.<name>")` carrying the statement's id."""
-    if not TraceAnnotation.is_enabled():
-        return OFF
-    if query_span is not None:
-        stats["query_id"] = query_span.query_id
-    return host_span("phase." + name, **stats)
+    """`host_span("phase.<name>")` carrying the statement's id, and the
+    phase's wall in the account of the statement running on the thread
+    (`plan`, `instantiate`, `release`); `OFF` where there is neither a
+    trace nor such a statement."""
+    span = OFF
+    if TraceAnnotation.is_enabled():
+        if query_span is not None:
+            stats["query_id"] = query_span.query_id
+        span = host_span("phase." + name, **stats)
+    account = running_statement()
+    slot = _PHASE_WALLS.get(name)
+    if account is None or slot is None:
+        return span
+    return _Phase(account, slot, span)
 
 
 class OpTally:
@@ -286,21 +486,20 @@ class OpTally:
         return _OpCall(self, method)
 
 
-# the operator call running on this thread, while a trace runs
-_RUNNING = threading.local()
-
-
 class _OpCall(TraceAnnotation):
     """`op.<OperatorClass>.<method>`: one operator call, timed into its
-    operator's tally; `sync.*` spans inside it find the tally here."""
+    operator's tally; `sync.*` spans inside it find the tally on the
+    running statement's account."""
 
     def __init__(self, tally: OpTally, method: str):
         super().__init__(f"{PROFILE_PREFIX}op.{tally.name}.{method}")
         self._tally = tally
 
     def __enter__(self):
-        self._outer = getattr(_RUNNING, "tally", None)
-        _RUNNING.tally = self._tally
+        self._account = account = running_statement()
+        if account is not None:
+            self._outer = account.tally
+            account.tally = self._tally
         if self._tally.first_s is None:
             self._tally.first_s = time.time()
         self._t0 = time.perf_counter_ns()
@@ -312,12 +511,39 @@ class _OpCall(TraceAnnotation):
         tally.calls += 1
         tally.busy_ns += time.perf_counter_ns() - self._t0
         tally.last_s = time.time()
-        _RUNNING.tally = self._outer
+        if self._account is not None:
+            self._account.tally = self._outer
+
+
+class _Readback:
+    """One device-to-host readback of the statement running on this
+    thread, timed into its account; no profiler trace runs."""
+
+    __slots__ = ("_account", "_site", "_nbytes", "_t0")
+
+    def __init__(self, account: StmtAccount, site: str, nbytes: int):
+        self._account, self._site, self._nbytes = account, site, nbytes
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._account.sync(
+            self._site, time.perf_counter_ns() - self._t0, self._nbytes)
+        return False
+
+    def set_metadata(self, **stats) -> None:
+        pass
 
 
 class _Sync(TraceAnnotation):
-    """`sync.<site>`: one device-to-host readback, counted into the
-    operator call it happens in."""
+    """`sync.<site>`: the same readback while a trace runs, an event of
+    it, counted into the account and the operator call it happens in."""
+
+    def __init__(self, site: str, nbytes: int):
+        super().__init__(f"{PROFILE_PREFIX}sync.{site}", nbytes=nbytes)
+        self._site, self._nbytes = site, nbytes
 
     def __enter__(self):
         self._t0 = time.perf_counter_ns()
@@ -325,19 +551,29 @@ class _Sync(TraceAnnotation):
 
     def __exit__(self, *exc):
         super().__exit__(*exc)
-        tally = getattr(_RUNNING, "tally", None)
+        account = running_statement()
+        if account is None:
+            return
+        ns = time.perf_counter_ns() - self._t0
+        account.sync(self._site, ns, self._nbytes)
+        tally = account.tally
         if tally is not None:
             tally.host_syncs += 1
-            tally.host_sync_ns += time.perf_counter_ns() - self._t0
+            tally.host_sync_ns += ns
 
 
 def host_sync(site: str, nbytes: int = 0):
     """Around a device-to-host readback (`int()`, `bool()`,
     `np.asarray`, `device_get` of a device value): where the host waits
-    for the device. `nbytes` is what comes back."""
+    for the device. `nbytes` is what comes back. Timed into the account
+    of the statement running on this thread, trace or no trace; `OFF`
+    where there is neither."""
     if TraceAnnotation.is_enabled():
-        return _Sync(f"{PROFILE_PREFIX}sync.{site}", nbytes=nbytes)
-    return OFF
+        return _Sync(site, nbytes)
+    account = running_statement()
+    if account is None:
+        return OFF
+    return _Readback(account, site, nbytes)
 
 
 def record_operators(parent: Optional[Span], tallies: List[OpTally]) -> None:
@@ -375,6 +611,8 @@ class QueryTrace:
         self._lock = named_lock("QueryTrace._lock")
         self._spans: List[Span] = []
         self._grafted: List[dict] = []
+        # the statement's own numbers, fed by the thread that runs it
+        self.account = StmtAccount(query_id)
 
     @classmethod
     def remote(cls, ctx: dict, query_id: str = "") -> "QueryTrace":
@@ -440,6 +678,7 @@ class QueryTrace:
             "trace_id": self.trace_id,
             "query_id": self.query_id,
             "spans": dicts,
+            "account": self.account.stats(),
         }
 
 
