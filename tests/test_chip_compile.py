@@ -105,7 +105,7 @@ def test_grouped_sum_mxu_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("path", ["mxu", "dense", "q1"])
+@pytest.mark.parametrize("path", ["mxu", "dense", "q1", "q9"])
 def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     """The train of issue 26 at the engine's batch: eight batches of 2^20
     rows, the per-batch body once inside a loop (a `while`) that picks
@@ -115,13 +115,16 @@ def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     `jax.default_backend()`, which is the CPU here. `q1` is Q1's (issue
     31): 12 slots, 14 value slots of which eight are two long decimals'
     limb slots behind one validity mask each, through the same kernel
-    at `w8` = 24."""
+    at `w8` = 24. `q9` is Q9's (issue 39): a dictionary of 25 and a
+    BIGINT year counted from 1992, (25 + 1) x (7 + 1) = 208 slots, one
+    long decimal's sum."""
     from trino_tpu import types as T
     from trino_tpu.block import Column, Dictionary, RelBatch
     from trino_tpu.exec import operators as O
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    dims = (7, 4, 3) if path == "mxu" else (3, 2)
+    dims = {"mxu": (7, 4, 3), "q9": (25, 7)}.get(path, (3, 2))
+    lows = (0, 1992) if path == "q9" else None
     dicts = [Dictionary([f"{i}.{j}" for j in range(d)]) for i, d in enumerate(dims)]
     k = len(dims)
     short, longs = T.decimal(12, 2), (T.decimal(34, 4), T.decimal(38, 6))
@@ -129,6 +132,10 @@ def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     def batch():
         cols = [Column(T.VARCHAR, _sds((BATCH,), jnp.int32, one_chip), None, d)
                 for d in dicts]
+        if path == "q9":
+            cols[1] = Column(T.BIGINT, _sds((BATCH,), jnp.int64, one_chip))
+            cols.append(Column(longs[0], _sds((BATCH, 2), jnp.int64, one_chip)))
+            return RelBatch(cols, _sds((BATCH,), jnp.bool_, one_chip))
         cols.append(Column(short, _sds((BATCH,), jnp.int64, one_chip)))
         if path == "q1":
             cols.extend(Column(short, _sds((BATCH,), jnp.int64, one_chip))
@@ -147,11 +154,14 @@ def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
                 O.AggSpec("sum", k + 4, T.decimal(38, 6)),
                 O.AggSpec("avg", k, short), O.AggSpec("avg", k + 1, short),
                 O.AggSpec("avg", k + 2, short), aggs[0]]
+    if path == "q9":
+        aggs = [O.AggSpec("sum", k, T.decimal(38, 4))]
     compiled = O._agg_ingest_train.lower(
         tuple(batch() for _ in range(O.TRAIN_BATCHES)),
         _sds((), jnp.int32, one_chip),
-        tuple(range(k)), tuple(aggs), 256 if path == "mxu" else 16, None,
+        tuple(range(k)), tuple(aggs), 256 if path in ("mxu", "q9") else 16, None,
         dims if path == "dense" else None, dims if path != "dense" else None,
+        lows,
     ).compile()
     text = compiled.as_text()
     assert "while" in text and "conditional" in text

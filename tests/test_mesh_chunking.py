@@ -151,6 +151,12 @@ def test_warmup_registration():
     shape classes warm for the compile regime (PR 6)."""
     from trino_tpu.compile.warmup import WARM_CLASSES
 
+    # the registry is the process's: what another file's statements left
+    # in it on this worker may have had its classes forgotten since
+    # (test_compile_regime and test_fabric reset WARM_CLASSES), so look
+    # at what THIS statement's programs register
+    with mesh_chunk._warmup_entries_lock:
+        del mesh_chunk.MESH_WARMUP_ENTRIES[:]
     r = mk_runner(mesh_chunk_rows=512)
     r.execute(Q_GROUP)
     entries = mesh_chunk.mesh_warmup_entries()

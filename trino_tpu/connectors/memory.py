@@ -111,14 +111,16 @@ class MemoryMetadata(ConnectorMetadata):
         the lookup on the BIG side (measured: TPC-H Q3 built on lineitem
         instead of orders x customer). We hold the actual arrays, so
         estimate honestly: stride-sample up to 256k rows, Duj1-estimate
-        NDV from sample singletons, exact min/max. Cached per table
-        version (writes invalidate)."""
+        NDV from sample singletons, exact min/max (an integer column's
+        are listed in `exact_ranges`: taken over every row, not the
+        sample). Cached per table version (writes invalidate)."""
         t = self.store.tables[(handle.schema, handle.table)]
         key = (handle.schema, handle.table)
         cached = self._stats_cache.get(key)
         if cached is not None and cached[0] is t and cached[1] == t.version:
             return cached[2]
         cols: Dict[str, tuple] = {}
+        exact = set()
         n = t.row_count
         for name, sc in t.data.items():
             if n == 0 or isinstance(sc.data, list):  # empty or ARRAY column
@@ -152,9 +154,16 @@ class MemoryMetadata(ConnectorMetadata):
                 ndv = min(ndv, float(np.count_nonzero(arr[1:] != arr[:-1]) + 1))
             lo = hi = None
             if not sc.type.is_string and arr.dtype.kind in "iuf":
-                lo, hi = float(arr.min()), float(arr.max())
+                least, most = arr.min(), arr.max()
+                lo, hi = float(least), float(most)
+                # (a float holds an integer exactly up to 2^53)
+                if arr.ndim == 1 and arr.dtype.kind in "iu" \
+                        and lo == int(least) and hi == int(most):
+                    exact.add(name)
             cols[name] = (ndv, nf, lo, hi)
-        ts = TableStatistics(row_count=float(n), columns=cols)
+        ts = TableStatistics(
+            row_count=float(n), columns=cols, exact_ranges=frozenset(exact)
+        )
         self._stats_cache[key] = (t, t.version, ts)
         return ts
 

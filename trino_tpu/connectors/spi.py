@@ -91,6 +91,12 @@ class TableStatistics:
     columns: Dict[str, Tuple[Optional[float], Optional[float], Any, Any]] = dataclasses.field(
         default_factory=dict
     )
+    # columns whose (min, max) were computed over every row of the table
+    # at the version these statistics describe: every non-NULL value lies
+    # inside them. An estimate, a sample's extremes or a declared range
+    # is not listed: only an exact range may bound a group table
+    # (sql/stats.group_key_ranges).
+    exact_ranges: frozenset = frozenset()
 
 
 class ConnectorMetadata:

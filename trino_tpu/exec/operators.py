@@ -1460,7 +1460,7 @@ def _overflow_bit(word):
 
 
 def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
-                  dense_dims, mxu_dims):
+                  dense_dims, mxu_dims, key_lows):
     """The per-batch body both ingest programs trace: the fused upstream
     filter/project, then one batch's group-reduce. Returns the reduce's
     7-tuple and the per-slot reducers it ran with."""
@@ -1498,11 +1498,12 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
     if dense_dims is not None:
         out = G.dense_group_reduce(
             keys, valids, live, values, tuple(vvalids), reds, dense_dims, cap,
+            lows=key_lows,
         )
     elif mxu_dims is not None:
         out = G.mxu_group_reduce(
             keys, valids, live, values, tuple(vvalids), reds, mxu_dims, cap,
-            **_mxu_word_layout(aggs, batch, vvalids),
+            lows=key_lows, **_mxu_word_layout(aggs, batch, vvalids),
         )
     else:
         out = G.sort_group_reduce(
@@ -1512,12 +1513,13 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
     return out, reds
 
 
-_INGEST_STATICS = ("groups", "aggs", "cap", "pre_fn", "dense_dims", "mxu_dims")
+_INGEST_STATICS = ("groups", "aggs", "cap", "pre_fn", "dense_dims", "mxu_dims",
+                   "key_lows")
 
 
 @partial(jax.jit, static_argnames=_INGEST_STATICS)
 def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
-                dense_dims=None, mxu_dims=None):
+                dense_dims=None, mxu_dims=None, key_lows=None):
     """Fused upstream filter/project + ONE batch's group-reduce in one
     device program (scan->filter->project->partial-aggregate is the Q1
     hot path; separate launches pay a host round trip each on
@@ -1526,9 +1528,17 @@ def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
     trains of batches go through _agg_ingest_train and only a train of
     one comes here."""
     return _ingest_batch(
-        batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims
+        batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims, key_lows
     )[0]
 
+
+# Group key types a plan-time value range can bound (a digit a value):
+# not a decimal, not a float, not a long decimal's limb pair. The one
+# list: sql/stats.group_key_ranges gives a range to no other key.
+RANGE_KEY_KINDS = frozenset((
+    T.TypeKind.TINYINT, T.TypeKind.SMALLINT, T.TypeKind.INTEGER,
+    T.TypeKind.BIGINT, T.TypeKind.DATE,
+))
 
 # States one fold of the sort path merges (HashAggregationOperator.
 # _fold_settled_locked). A scan of N batches leaves N group states; held
@@ -1551,7 +1561,7 @@ TRAIN_BATCHES = 8
 
 @partial(jax.jit, static_argnames=_INGEST_STATICS)
 def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
-                      pre_fn, dense_dims=None, mxu_dims=None):
+                      pre_fn, dense_dims=None, mxu_dims=None, key_lows=None):
     """The first `n` of `batches` (equal in layout; `n` is an operand, so
     a short train is this same program) through _agg_ingest's body, ONE
     launch and ONE group state for all of them. The body is traced once,
@@ -1574,7 +1584,7 @@ def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
         leaves = list(jax.lax.switch(i, picks))
         out, r = _ingest_batch(
             jax.tree_util.tree_unflatten(treedef, leaves),
-            groups, aggs, cap, pre_fn, dense_dims, mxu_dims,
+            groups, aggs, cap, pre_fn, dense_dims, mxu_dims, key_lows,
         )
         reds[:] = r
         gk, gv, used, vals, cnts, _ngroups, ovf = out
@@ -1716,12 +1726,15 @@ class HashAggregationOperator(Operator):
     Launches: one per batch on the sort, global, holistic and `final`
     paths, whose batches may overflow a table or need the raw rows.
     Where the plan bounds the table and addresses it by slot (dictionary
-    and boolean keys: `_dense_dims`, `_mxu_dims`), batches are held and
+    and boolean keys, integer keys of a known exact range: `_dense_dims`,
+    `_mxu_dims`, `_key_lows`), batches are held and
     go TRAIN_BATCHES at a time through one launch of _agg_ingest_train,
     which leaves one state; finish and revocation flush what is held.
     METRICS `agg_ingest_batches` over `agg_ingest_launches` is the train
     length achieved; `agg_ingest_path.dense`, `.mxu` and `.sort` count
-    the same batches by the reduce the plan got (`_path`)."""
+    the same batches by the reduce the plan got (`_path`), and
+    `agg_key_bound.range`, `.dictionary` and `.none` the operators by
+    what bounded their table."""
 
     def __init__(
         self,
@@ -1733,6 +1746,7 @@ class HashAggregationOperator(Operator):
         memory_context=None,
         deferred_checks: Optional[List] = None,
         pre_fn=None,
+        key_ranges: Optional[Sequence[Optional[Tuple[int, int]]]] = None,
     ):
         """step: "single" (raw rows in, results out), "partial" (raw rows
         in, serialized accumulator state out) or "final" (accumulator
@@ -1740,7 +1754,9 @@ class HashAggregationOperator(Operator):
         mode the input layout is partial_output_schema's, whose state
         value columns carry each aggregate's original argument
         representation (decimal scale, dictionary) — finalization reads
-        it straight from the input schema."""
+        it straight from the input schema. key_ranges: per group
+        channel, the EXACT (low, high) of its values where the plan
+        knows one (sql/stats.group_key_ranges), else None."""
         assert step in ("single", "partial", "final"), step
         self._step = step
         self._pre = pre_fn  # fused upstream stage (plan-time jit)
@@ -1811,25 +1827,39 @@ class HashAggregationOperator(Operator):
             for a, m in zip(self._aggs, self._arg_meta)
         )
         # Static group-cardinality bound: dictionary-coded and boolean
-        # keys bound the distinct-group count at PLAN time, so the table
+        # keys, and integer keys whose exact value range the plan knows
+        # (`key_ranges`: a digit a value, counted from the range's low
+        # end), bound the distinct-group count at PLAN time, so the table
         # can never overflow and the per-batch host sync on the overflow
         # flag disappears (a host read-back is a synchronisation point
         # — the reason Trino precomputes hash channels is the same
         # "decide statically, not per row" discipline).
         bound = 1
-        dims = []
-        for c in self._group_channels:
+        dims, lows = [], []
+        ranged = False
+        for i, c in enumerate(self._group_channels):
             t, d = self._schema[c]
+            span = key_ranges[i] if key_ranges is not None else None
+            low = 0
             if t.is_string and d is not None and len(d) > 0:
                 dims.append(len(d))
-                bound *= len(d) + 1  # +1: the NULL group
             elif t.kind == T.TypeKind.BOOLEAN:
-                dims.append(2)
-                bound *= 3  # true/false/null
+                dims.append(2)  # true/false
+            elif span is not None and t.kind in RANGE_KEY_KINDS:
+                low = span[0]
+                dims.append(span[1] - low + 1)
+                ranged = True
             else:
                 bound = 0
                 break
-        self._static_bound = bound if 0 < bound <= (1 << 16) else None
+            lows.append(low)
+            bound *= dims[-1] + 1  # +1: the NULL group
+        # a range bounds a table only where the table is addressed by
+        # slot: the whole product within what the MXU reduce takes and a
+        # chooser that answers `dense` or `mxu`; otherwise the operator
+        # is built as it is without the range (no bound, the sort path)
+        limit = G.MXU_MAX_SLOTS if ranged else 1 << 16
+        self._static_bound = bound if 0 < bound <= limit else None
         # Which reduce the bounded domain gets is the kernels' layer's
         # rule (ops/groupby.choose_bounded_reduce): the dense slot
         # reduce (per-group masked reductions unrolled into one fused
@@ -1845,9 +1875,23 @@ class HashAggregationOperator(Operator):
                 mxu=jax.default_backend() == "tpu"
                 or _os.environ.get("TRINO_TPU_FORCE_MXU") == "1",
             )
+        if ranged and self._path == "sort":
+            self._static_bound = None
+        # METRICS `agg_key_bound.*`: what bounded the table, counted
+        # once an operator, with its first batch
+        self._key_bound_counter = "agg_key_bound." + (
+            "none" if self._static_bound is None
+            else "range" if ranged else "dictionary"
+        )
         self._path_counter = "agg_ingest_path." + self._path
         self._dense_dims = tuple(dims) if self._path == "dense" else None
         self._mxu_dims = tuple(dims) if self._path == "mxu" else None
+        # per key, the value of digit 0; None where every key counts
+        # from 0, so that a table bounded by dictionaries alone keeps the
+        # programs it had
+        self._key_lows = (
+            tuple(lows) if self._path != "sort" and any(lows) else None
+        )
         self._deferred_ovf: List = []
         # Trains: where the plan bounds the table AND addresses it by
         # slot, a batch needs no readback and no replay and every batch's
@@ -1907,6 +1951,9 @@ class HashAggregationOperator(Operator):
             return
         METRICS.increment("agg_ingest_batches")
         METRICS.increment(self._path_counter)
+        if self._key_bound_counter is not None:
+            METRICS.increment(self._key_bound_counter)
+            self._key_bound_counter = None
         if self._trains:
             layout = self._train_layout(batch)
             with self._state_lock:
@@ -1930,7 +1977,7 @@ class HashAggregationOperator(Operator):
         METRICS.increment("agg_ingest_launches")
         gk, gv, used, vals, cnts, ngroups, ovf = _agg_ingest(
             batch, tuple(self._group_channels), tuple(self._aggs),
-            cap, self._pre, self._dense_dims, self._mxu_dims,
+            cap, self._pre, self._dense_dims, self._mxu_dims, self._key_lows,
         )
         new = (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
         if self._static_bound is not None:
@@ -2000,7 +2047,7 @@ class HashAggregationOperator(Operator):
         # slot-addressed tables hold the FULL domain whatever the batch
         statics = (
             tuple(self._group_channels), tuple(self._aggs), self._cap,
-            self._pre, self._dense_dims, self._mxu_dims,
+            self._pre, self._dense_dims, self._mxu_dims, self._key_lows,
         )
         self._launched += 1
         if len(held) == 1:
@@ -2013,7 +2060,8 @@ class HashAggregationOperator(Operator):
         gk, gv, used, vals, cnts, _ngroups, ovf = out
         # overflow impossible by the plan-time bound: defer the flag and
         # verify ONCE at finish (fail-loud guard against a runtime
-        # dictionary outgrowing the plan-time one)
+        # dictionary outgrowing the plan-time one, or a value outside
+        # the plan-time range)
         self._deferred_ovf.append(ovf)
         self._push_pending_locked(
             (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
@@ -2052,6 +2100,7 @@ class HashAggregationOperator(Operator):
             gk, gv, used, vals, cnts, ngroups, ovf = _agg_ingest(
                 batch, tuple(self._group_channels), tuple(self._aggs),
                 cap, self._pre, self._dense_dims, self._mxu_dims,
+                self._key_lows,
             )
             self._pending[idx] = (
                 tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts)
@@ -2797,8 +2846,9 @@ class HashAggregationOperator(Operator):
                 for f in self._deferred_ovf
             ))
             msg = (
-                "group table overflowed its plan-time bound "
-                "(runtime dictionary larger than planned)"
+                "group table overflowed its plan-time bound (runtime "
+                "dictionary larger than planned, or a key outside the "
+                "value range the plan was made for)"
             )
             if self._checks is not None:
                 # deferred to the end-of-query sync point

@@ -434,24 +434,55 @@ def _seg_reduce(red, contrib, boundary, num_segments: int):
     return out.astype(jnp.bool_) if as_bool else out
 
 
-def _dense_gid(keys, valids, mask, dims, radices):
+def _dense_gid(keys, valids, mask, dims, radices, lows=None):
     """Mixed-radix dense group id for plan-time-bounded key domains;
-    NULL takes the extra digit d. Returns (gid, out_of_domain) where
-    out_of_domain flags a live valid code outside [0, d) — the runtime
-    dictionary outgrew the plan-time bound (fail-loud, same contract as
-    sort_group_reduce's overflow flag)."""
+    NULL takes the extra digit d. A key's digit is its dictionary or
+    boolean code, or, for an integer key whose exact range the plan
+    knows, its distance from the range's low end (`lows`, one a key;
+    None: every key counts from 0). Returns (gid, out_of_domain) where
+    out_of_domain flags a live valid digit outside [0, d): the runtime
+    dictionary outgrew the plan-time bound, or a row holds a value the
+    plan's range does not (fail-loud, same contract as
+    sort_group_reduce's overflow flag). A key wider than 32 bits is
+    tested before it is narrowed."""
     n = mask.shape[0]
     gid = jnp.zeros(n, dtype=jnp.int32)
     out_of_domain = jnp.asarray(False)
-    for k, v, d, r in zip(keys, valids, dims, radices):
-        raw = k.astype(jnp.int32)
+    for k, v, d, r, low in zip(
+            keys, valids, dims, radices, lows or (0,) * len(dims)):
+        raw = k if k.dtype.itemsize > 4 else k.astype(jnp.int32)
+        if low:
+            raw = raw - jnp.asarray(low, raw.dtype)
         out_of_domain = out_of_domain | jnp.any(
             mask & v & ((raw < 0) | (raw >= d))
         )
-        code = jnp.clip(raw, 0, d - 1)
+        code = jnp.clip(raw, 0, d - 1).astype(jnp.int32)
         code = jnp.where(v, code, d)
         gid = gid * r + code
     return gid, out_of_domain
+
+
+def _slot_keys(keys, dims, radices, lows, pad):
+    """Slot -> (key values, key valids) of a mixed-radix table (last
+    key fastest): the digit, counted from the key's low end, in the
+    key's own dtype; the NULL digit and unused slots hold any value."""
+    total = 1
+    for r in radices:
+        total *= r
+    digits = []
+    rem = jnp.arange(total, dtype=jnp.int32)
+    for r in reversed(radices):
+        digits.append(rem % r)
+        rem = rem // r
+    digits.reverse()
+    group_keys, group_valids = [], []
+    for k, d, digit, low in zip(keys, dims, digits, lows or (0,) * len(dims)):
+        value = jnp.clip(digit, 0, d - 1).astype(k.dtype)
+        if low:
+            value = value + jnp.asarray(low, k.dtype)
+        group_keys.append(pad(value))
+        group_valids.append(pad(digit < d, False))
+    return group_keys, group_valids
 
 
 # Which bounded-domain reduce a plan gets (choose_bounded_reduce). The
@@ -511,7 +542,7 @@ def shared_valids(value_valids: Sequence) -> tuple:
 
 
 @partial(jax.jit, static_argnames=(
-    "dims", "reducers", "out_capacity", "value_limbs", "valid_of"))
+    "dims", "reducers", "out_capacity", "value_limbs", "valid_of", "lows"))
 def mxu_group_reduce(
     keys: Sequence[jnp.ndarray],
     valids: Sequence[jnp.ndarray],
@@ -523,6 +554,7 @@ def mxu_group_reduce(
     out_capacity: int,
     value_limbs: Optional[tuple] = None,
     valid_of: Optional[tuple] = None,
+    lows: Optional[tuple] = None,
 ):
     """dense_group_reduce contract, executed by the Pallas MXU one-hot
     contraction kernel (ops/mxu_groupby.py) in one pass over rows of
@@ -534,7 +566,7 @@ def mxu_group_reduce(
     knows 0 <= value < 2^(8k): a long decimal's three low limb slots
     are 4 and have no high word). `valid_of` (shared_valids) names, per
     slot, the first slot with the same validity array: they share one
-    indicator column."""
+    indicator column. `lows` as in dense_group_reduce."""
     from trino_tpu.ops.mxu_groupby import MAX_ROWS, grouped_sum_mxu
 
     assert all(r in ("sum", "count") for r in reducers), reducers
@@ -550,7 +582,7 @@ def mxu_group_reduce(
     for r in radices:
         total *= r
     assert total <= out_capacity
-    gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices)
+    gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices, lows)
     if value_limbs is None:
         value_limbs = (8,) * len(values)
     if valid_of is None:
@@ -591,19 +623,7 @@ def mxu_group_reduce(
     def pad(x, fill=0):
         return jnp.pad(x, (0, out_capacity - total), constant_values=fill)
 
-    # decode slot -> key codes/valids (mixed radix, last key fastest)
-    slots = jnp.arange(total, dtype=jnp.int32)
-    digits = []
-    rem = slots
-    for r in reversed(radices):
-        digits.append(rem % r)
-        rem = rem // r
-    digits.reverse()
-    group_keys = []
-    group_valids = []
-    for (k, d), digit in zip(zip(keys, dims), digits):
-        group_keys.append(pad(jnp.clip(digit, 0, d - 1).astype(k.dtype)))
-        group_valids.append(pad(digit < d, False))
+    group_keys, group_valids = _slot_keys(keys, dims, radices, lows, pad)
 
     results = [pad(sums[i]) for i in col_of_value]
     counts = [pad(sums[i]) for i in col_of_count]
@@ -620,7 +640,7 @@ def mxu_group_reduce(
     )
 
 
-@partial(jax.jit, static_argnames=("dims", "reducers", "out_capacity"))
+@partial(jax.jit, static_argnames=("dims", "reducers", "out_capacity", "lows"))
 def dense_group_reduce(
     keys: Sequence[jnp.ndarray],
     valids: Sequence[jnp.ndarray],
@@ -628,12 +648,15 @@ def dense_group_reduce(
     values: Sequence[jnp.ndarray],
     value_valids: Sequence[Optional[jnp.ndarray]],
     reducers: tuple,
-    dims: tuple,  # per key: dictionary size (codes in [0, d)); NULL -> d
+    dims: tuple,  # per key: digits (codes or value - low in [0, d)); NULL -> d
     out_capacity: int,
+    lows: Optional[tuple] = None,  # per key: the value of digit 0 (None: 0)
 ):
-    """Group-reduce for PLAN-TIME-BOUNDED key domains (dictionary/bool
-    codes): the group id is the dense mixed-radix composition of the
-    codes — no sort, no hash table, no scatter. Each group reduces with
+    """Group-reduce for PLAN-TIME-BOUNDED key domains (dictionary and
+    boolean codes; integer keys whose exact range the plan knows, by
+    their distance from `lows`): the group id is the dense mixed-radix
+    composition of the digits — no sort, no hash table, no scatter.
+    Each group reduces with
     a masked whole-column reduction; the per-group loop unrolls into one
     fused XLA program (total domain is capped small by the caller).
     Same output contract as sort_group_reduce; group ids are slot
@@ -645,24 +668,12 @@ def dense_group_reduce(
     for r in radices:
         total *= r
     assert total <= out_capacity
-    gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices)
+    gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices, lows)
 
     def pad(x, fill=0):
         return jnp.pad(x, (0, out_capacity - total), constant_values=fill)
 
-    # decode slot -> key codes/valids (mixed radix, last key fastest)
-    slots = jnp.arange(total, dtype=jnp.int32)
-    digits = []
-    rem = slots
-    for r in reversed(radices):
-        digits.append(rem % r)
-        rem = rem // r
-    digits.reverse()
-    group_keys = []
-    group_valids = []
-    for (k, d), digit in zip(zip(keys, dims), digits):
-        group_keys.append(pad(jnp.clip(digit, 0, d - 1).astype(k.dtype)))
-        group_valids.append(pad(digit < d, False))
+    group_keys, group_valids = _slot_keys(keys, dims, radices, lows, pad)
 
     results = []
     counts = []

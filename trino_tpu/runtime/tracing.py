@@ -137,6 +137,10 @@ reduce a grouped batch got), ``df_filter_path.set`` / ``.bits`` /
 ``.range`` (a dynamic filter's batches) and ``join_probe_path.blocked``
 / ``.sorted`` (a join's probe batches by ``ops/join.probe_path``: the
 two-level bounds or the two packed sorts), all in ``exec/operators.py``.
+Beside them, one increment an OPERATOR (with its first batch):
+``agg_key_bound.range`` / ``.dictionary`` / ``.none``, what bounded a
+grouped aggregation's table at plan time: an integer key's exact value
+range among its keys, dictionaries and booleans alone, or nothing.
 """
 
 from __future__ import annotations

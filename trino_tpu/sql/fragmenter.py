@@ -439,7 +439,8 @@ def push_partial_aggregation_through_exchange(
                 partial, "repartition", tuple(range(k)), partial_fields
             )
         return P.AggregateNode(
-            new_ex, tuple(range(k)), final_aggs, n.fields, step="final"
+            new_ex, tuple(range(k)), final_aggs, n.fields, step="final",
+            key_ranges=n.key_ranges,
         )
 
     return walk(root)

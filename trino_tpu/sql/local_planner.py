@@ -106,15 +106,17 @@ class _AggWarmer:
     static config, so driving a throwaway operator instance over the
     dead batch seeds the very dispatch cache the real query hits."""
 
-    def __init__(self, groups, specs, schema, step):
+    def __init__(self, groups, specs, schema, step, key_ranges=None):
         self.groups = list(groups)
         self.specs = list(specs)
         self.schema = list(schema)
         self.step = step
+        self.key_ranges = key_ranges
 
     def __call__(self, batch):
         op = HashAggregationOperator(
-            self.groups, self.specs, self.schema, step=self.step
+            self.groups, self.specs, self.schema, step=self.step,
+            key_ranges=self.key_ranges,
         )
         op.add_input(batch)
         op.finish()
@@ -522,6 +524,7 @@ class LocalPlanner:
         ]
         groups = list(node.group_channels)
         step = node.step
+        key_ranges = node.key_ranges
         # input capacity classes before the fused stage is absorbed
         # (filter/project preserves capacity, so they flow through)
         src_caps = getattr(chain[-1], "out_caps", None) if chain else None
@@ -530,7 +533,7 @@ class LocalPlanner:
             lambda ctx: HashAggregationOperator(
                 groups, specs, schema, step=step, memory_context=_mem_ctx(ctx),
                 deferred_checks=ctx.setdefault("deferred_checks", []),
-                pre_fn=pre,
+                pre_fn=pre, key_ranges=key_ranges,
             )
         )
         if step == "partial":
@@ -539,7 +542,7 @@ class LocalPlanner:
             out_schema = partial_output_schema(specs, groups, schema)
             self._record_kernel_warmup(
                 "HashAggregationOperator",
-                _AggWarmer(groups, specs, schema, step),
+                _AggWarmer(groups, specs, schema, step, key_ranges),
                 schema, out_schema, src_caps,
             )
             return chain, out_schema
@@ -573,7 +576,7 @@ class LocalPlanner:
             ]
         self._record_kernel_warmup(
             "HashAggregationOperator",
-            _AggWarmer(groups, specs, schema, step),
+            _AggWarmer(groups, specs, schema, step, key_ranges),
             schema, out_schema, src_caps,
         )
         return chain, out_schema

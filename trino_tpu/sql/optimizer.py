@@ -1956,7 +1956,25 @@ def optimize(
         root = ReorderJoins(stats, cost).rewrite(root)
         root = it.optimize(root, stats, validator=per_rule)
         checkpoint(root, "join_reordering")
-    return root
+    return _with_group_key_ranges(root, stats)
+
+
+def _with_group_key_ranges(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNode:
+    """The last pass: every AggregateNode learns the exact value ranges
+    of its integer group keys where the statistics have them
+    (sql/stats.group_key_ranges), so that the operator can bound its
+    group table by them as it does by a dictionary."""
+    from trino_tpu.sql.stats import group_key_ranges
+
+    node = with_children(
+        node, [_with_group_key_ranges(c, stats) for c in node.children()]
+    )
+    if not isinstance(node, P.AggregateNode) or not node.group_channels:
+        return node
+    ranges = group_key_ranges(node, stats.stats(node.child))
+    if ranges == node.key_ranges:
+        return node
+    return dataclasses.replace(node, key_ranges=ranges)
 
 
 # -- timestamptz key canonicalization (correctness, not optimization) --------

@@ -97,13 +97,18 @@ class AggregateNode(PlanNode):
     (AggregationNode analogue). `step` is the AggregationNode.Step:
     single | partial (emits serialized accumulator state) | final
     (consumes state from the exchange). In partial/final steps the
-    output/input layout follows operators.partial_output_schema."""
+    output/input layout follows operators.partial_output_schema.
+    `key_ranges`: per group channel the exact (low, high) of its values,
+    or None for a key that has none to give; None where no group table
+    can be bounded by them (sql/stats.group_key_ranges, set by the
+    optimizer's last pass; a partial and its final step carry the same)."""
 
     child: PlanNode
     group_channels: Tuple[int, ...]
     aggs: Tuple[AggCall, ...]
     fields: Tuple[Field, ...]
     step: str = "single"
+    key_ranges: Optional[Tuple[Optional[Tuple[int, int]], ...]] = None
 
     def children(self):
         return (self.child,)
@@ -353,6 +358,8 @@ def explain_text(node: PlanNode, indent: int = 0) -> str:
         detail = f" keys={list(node.group_channels)} aggs={[a.kind for a in node.aggs]}"
         if node.step != "single":
             detail += f" step={node.step}"
+        if node.key_ranges is not None:
+            detail += f" key_ranges={list(node.key_ranges)}"
     elif isinstance(node, ExchangeNode):
         detail = f" {node.kind}"
         if node.hash_channels:

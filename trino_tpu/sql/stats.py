@@ -10,11 +10,13 @@ broadcast-vs-partitioned join choice and adaptive partition counts
 from __future__ import annotations
 
 import dataclasses
+import datetime
 import math
 from typing import Callable, Dict, Optional
 
 import numpy as np
 
+from trino_tpu import types as T
 from trino_tpu.expr import ir
 from trino_tpu.sql import plan as P
 
@@ -36,6 +38,14 @@ class ColStats:
     # no two rows share a value (a key of its table, and what filters and
     # joins that repeat no row of its side have left of one)
     unique: bool = False
+    # every non-NULL value lies inside [low, high]: a connector computed
+    # them over the whole column at this table version
+    # (TableStatistics.exact_ranges), or they follow from such a range
+    # (_projected). Filters, joins and aggregations keep it: the
+    # survivors' range lies inside. An estimate or a declared range
+    # never sets it; only an exact range may bound a group table
+    # (group_key_ranges).
+    exact: bool = False
 
 
 @dataclasses.dataclass
@@ -78,7 +88,14 @@ class StatsCalculator:
         kids = node.children()
         if not kids:
             return PlanStats(1e6)
-        return self.stats(kids[0])
+        # a node without a rule of its own may lay its channels out
+        # otherwise than its child: an estimate survives that, a bound
+        # must not
+        child = self.stats(kids[0])
+        return PlanStats(child.row_count, {
+            ch: dataclasses.replace(cs, exact=False) if cs.exact else cs
+            for ch, cs in child.columns.items()
+        })
 
     # -- leaves --
     def _ScanNode(self, node: P.ScanNode) -> PlanStats:
@@ -100,6 +117,8 @@ class StatsCalculator:
                     _as_float(lo),
                     _as_float(hi),
                     unique=ndv is not None and ndv >= _UNIQUE_NDV_SHARE * rows,
+                    exact=name in ts.exact_ranges
+                    and lo is not None and hi is not None,
                 )
             if node.fields[i].type.is_string:
                 cols[i] = dataclasses.replace(
@@ -139,10 +158,9 @@ class StatsCalculator:
         child = self.stats(node.child)
         cols: Dict[int, ColStats] = {}
         for i, e in enumerate(node.exprs):
-            if isinstance(e, ir.InputRef):
-                cs = child.columns.get(e.index)
-                if cs is not None:
-                    cols[i] = cs
+            cs = _projected(e, child)
+            if cs is not None:
+                cols[i] = cs
         return PlanStats(child.row_count, cols)
 
     def _AggregateNode(self, node: P.AggregateNode) -> PlanStats:
@@ -247,6 +265,98 @@ class StatsCalculator:
 
     def _RemoteSourceNode(self, node: P.RemoteSourceNode) -> PlanStats:
         return PlanStats(1e6)
+
+
+_INTEGER_KINDS = (
+    T.TypeKind.TINYINT, T.TypeKind.SMALLINT, T.TypeKind.INTEGER,
+    T.TypeKind.BIGINT,
+)
+# a date part's values, whatever the date
+_DATE_PART_RANGE = {
+    "extract_month": (1, 12), "extract_day": (1, 31), "quarter": (1, 4),
+}
+
+
+def _year_of(days: float) -> Optional[int]:
+    """The civil year of a day number (days since 1970-01-01), None
+    outside the calendar `datetime` knows."""
+    try:
+        return (datetime.date(1970, 1, 1) + datetime.timedelta(days=int(days))).year
+    except (OverflowError, ValueError):
+        return None
+
+
+def _projected(e: ir.Expr, child: PlanStats) -> Optional[ColStats]:
+    """What a projection hands on of its input's statistics. A column
+    keeps all of its own. A RANGE goes through a closed list of
+    expressions and nothing else, each either monotone in its one
+    argument or bounded whatever it is: the year of a date whose exact
+    range is known; the month, the day of the month and the quarter of
+    any date or timestamp; a cast between integer kinds of a column
+    whose exact range is known. These carry no NDV and no null
+    fraction: the estimates above them stay what they were."""
+    if isinstance(e, ir.InputRef):
+        return child.columns.get(e.index)
+    if isinstance(e, ir.Call) and len(e.args) == 1:
+        arg = e.args[0]
+        if e.name in _DATE_PART_RANGE and arg.type.kind in (
+                T.TypeKind.DATE, T.TypeKind.TIMESTAMP):
+            low, high = _DATE_PART_RANGE[e.name]
+            return ColStats(low=float(low), high=float(high), exact=True)
+        if e.name == "extract_year" and arg.type.kind == T.TypeKind.DATE:
+            inner = _projected(arg, child)
+            if inner is None or not inner.exact:
+                return None
+            low, high = _year_of(inner.low), _year_of(inner.high)
+            if low is None or high is None:
+                return None
+            return ColStats(low=float(low), high=float(high), exact=True)
+        return None
+    if isinstance(e, ir.Cast) and e.type.kind in _INTEGER_KINDS \
+            and e.arg.type.kind in _INTEGER_KINDS:
+        inner = _projected(e.arg, child)
+        if inner is None or not inner.exact:
+            return None
+        info = np.iinfo(e.type.dtype)
+        if inner.low < info.min or inner.high > info.max:
+            return None  # the cast fails on some row: no bound to state
+        return ColStats(low=inner.low, high=inner.high, exact=True)
+    return None
+
+
+def group_key_ranges(node: P.AggregateNode, child: PlanStats):
+    """Per group channel of `node`, the exact (low, high) of its values
+    or None; None for the node where no group table can be bounded by
+    them. A key of integer kind (exec/operators.RANGE_KEY_KINDS: TINYINT
+    to BIGINT, DATE) whose range is exact gives its range; a string or a
+    boolean gives None (the operator bounds those itself, by their
+    dictionary); any other key, or ranges whose digits alone (a NULL
+    digit a key) outgrow what a slot-addressed reduce takes
+    (ops/groupby.MXU_MAX_SLOTS), leaves the node without any."""
+    from trino_tpu.exec.operators import RANGE_KEY_KINDS
+    from trino_tpu.ops.groupby import MXU_MAX_SLOTS
+
+    ranges, slots = [], 1
+    for c in node.group_channels:
+        t = node.child.fields[c].type
+        if t.is_string or t.kind == T.TypeKind.BOOLEAN:
+            ranges.append(None)
+            continue
+        cs = child.col(c)
+        if t.kind not in RANGE_KEY_KINDS \
+                or not cs.exact or cs.low is None or cs.high is None \
+                or not cs.low <= cs.high:
+            return None
+        low, high = int(cs.low), int(cs.high)
+        if low != cs.low or high != cs.high:
+            return None
+        slots *= high - low + 2
+        if slots > MXU_MAX_SLOTS:
+            return None
+        ranges.append((low, high))
+    if all(r is None for r in ranges):
+        return None
+    return tuple(ranges)
 
 
 def _as_float(v) -> Optional[float]:
