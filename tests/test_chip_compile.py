@@ -320,6 +320,59 @@ def test_key_bits_dynamic_filter_compiles_for_v5e(one_chip):
     assert "scatter" in compiled.as_text()
 
 
+def test_flag_build_rows_first_candidates_compile_for_v5e(one_chip):
+    """`_flag_build_rows` without `out_cap` (PR 40: a semi- or anti-join
+    that builds the side it preserves): a probe batch of 2^20 rows of
+    `lineitem`'s two keys against Q21's preserved side (2^20 slots of
+    s_name, l_orderkey, l_suppkey), the residual on the pairs, the
+    flags scattered: gathers and one scatter, about 8 s. With
+    `out_cap` (the candidates after the first) it sorts the offsets and
+    takes the compiler 45 to 51 s at this size (PERF.md section 6, PR
+    40): not here. And the key-bits table of a build side of 2^21 slots
+    over `l_orderkey`'s 60 M values (`DF_BITS_PLANNED_MAX_SLOTS`)."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+    from trino_tpu.expr import ir
+    from trino_tpu.expr.compile import ExprBinder
+    from trino_tpu.ops.join import build_lookup
+
+    def column(t, n):
+        return Column(t, _sds((n,), jnp.int32 if t.is_string else jnp.int64, one_chip),
+                      None, None)
+
+    n = slots = BATCH
+    probe = RelBatch([column(T.BIGINT, n), column(T.BIGINT, n)],
+                     _sds((n,), jnp.bool_, one_chip))
+    build = RelBatch([column(T.VARCHAR, slots), column(T.BIGINT, slots),
+                      column(T.BIGINT, slots)], _sds((slots,), jnp.bool_, one_chip))
+    lowered = build_lookup.lower(
+        (_sds((slots,), jnp.int64, one_chip),), (_sds((slots,), jnp.bool_, one_chip),),
+        _sds((slots,), jnp.bool_, one_chip), exact_keys=True)
+    ls = jax.tree_util.tree_map(lambda x: _sds(x.shape, x.dtype, one_chip), lowered.out_info)
+    differs = ir.Call("ne", (ir.InputRef(2, T.BIGINT), ir.InputRef(4, T.BIGINT)), T.BOOLEAN)
+    residual = O.make_residual_fn(ExprBinder(
+        [T.VARCHAR, T.BIGINT, T.BIGINT, T.BIGINT, T.BIGINT], [None] * 5).bind(differs))
+    run = _sds((n,), jnp.int32, one_chip)
+    compiled = O._flag_build_rows.lower(
+        ls, probe, build, (_sds((n,), jnp.int64, one_chip),),
+        (_sds((n,), jnp.bool_, one_chip),), run, run, _sds((slots,), jnp.bool_, one_chip),
+        _sds((2,), jnp.int64, one_chip), pkc=(0,), bkc=(1,), unread=(0, 2),
+        residual_fn=residual,
+    ).compile()
+    text = compiled.as_text()
+    # (the chip's compiler makes the flags' scatter of a sort of its
+    # own; the program's pair expansion has none)
+    assert "scatter" in text and "gather" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert O.DF_BITS_PLANNED_MAX_SLOTS >= 1 << 21
+    table = O._df_bit_table.lower(
+        _sds((1 << 21,), jnp.int64, one_chip), _sds((1 << 21,), jnp.bool_, one_chip),
+        _sds((), jnp.int64, one_chip), n_words=1 << 21,
+    ).compile()
+    assert "scatter" in table.as_text()
+
+
 def test_distributed_groupby_step_compiles_for_four_v5e(topo):
     """The partial -> all_to_all -> final aggregation step as one SPMD
     program over the four described chips."""

@@ -278,8 +278,13 @@ def test_without_a_trace_a_statement_makes_no_more_spans_than_before(
     made, tallies, annotations = [], [], []
     init = tracing.Span.__init__
 
+    here = threading.get_ident()
+
     def counting(self, *args, **kwargs):
-        made.append(args[1])
+        # (this statement's thread alone: a worker that ran another
+        # file's statements before may still be ending one)
+        if threading.get_ident() == here:
+            made.append(args[1])
         init(self, *args, **kwargs)
 
     monkeypatch.setattr(tracing.Span, "__init__", counting)

@@ -69,8 +69,10 @@ class Driver:
         if not tracing.profiling():
             return tracing.OFF
         if self._tallies is None:
-            self._tallies = [tracing.OpTally(type(o).__name__)
-                             for o in self.ops]
+            self._tallies = [
+                tracing.OpTally(type(o).__name__, getattr(o, "span_stats", None))
+                for o in self.ops
+            ]
         return self._tallies[i].call(method)
 
     def _run(self) -> None:

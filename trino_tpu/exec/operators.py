@@ -289,17 +289,27 @@ class FilterProjectOperator(Operator):
         filter_bound: Optional[Bound],
         projections: Sequence[Bound],
         fn=None,
+        read_row_bytes: Optional[int] = None,
     ):
         self._out: Optional[RelBatch] = None
         self._done = False
         self._fn = fn if fn is not None else make_filter_project_fn(
             filter_bound, projections
         )
+        # bytes a row of what the stage's predicate and computed columns
+        # READ (the planner's count; columns handed on untouched are not
+        # in it), for METRICS `filter_read_bytes`: with a byte of mask a
+        # slot, the least a launch has to move
+        self._read_row_bytes = read_row_bytes
 
     def needs_input(self) -> bool:
         return self._out is None and not self._finishing
 
     def add_input(self, batch: RelBatch) -> None:
+        if self._read_row_bytes is not None:
+            METRICS.increment(
+                "filter_read_bytes", batch.capacity * (self._read_row_bytes + 1)
+            )
         self._out = self._fn(batch)
 
     def get_output(self) -> Optional[RelBatch]:
@@ -3400,6 +3410,61 @@ def _segment_any(counts, pi, ok, probe_capacity):
     return (counts > 0) & (seg > 0)
 
 
+@partial(jax.jit, static_argnames=("out_cap", "pkc", "bkc", "unread",
+                                   "residual_fn"))
+def _flag_build_rows(ls, probe: RelBatch, build: RelBatch, keys, valids,
+                     lo, counts, flags, totals, out_cap=None,
+                     pkc=None, bkc=None, unread=(), residual_fn=None):
+    """One probe batch of a semi- or anti-join whose PRESERVED side is
+    the build: `flags` (a build slot: some pair held) with this batch's
+    pairs added, and `totals` (pairs the residual saw, pairs it kept).
+
+    Without `out_cap`: every probe row's FIRST candidate, the fanout-one
+    expansion whatever the counts (the probe batch as it stands, one
+    build row gathered beside it: no offsets, no sorts). With it: the
+    candidates AFTER the first, expanded the general way into `out_cap`
+    slots. The two are every pair; a join key that is nearly unique on
+    the build side (TPC-H Q21: 1.04 late lines of one nation's suppliers
+    an order) then pays the general expansion for a few rows in a
+    hundred and not for a batch and a bit, which a power of two of
+    slots would double. Of the pairs' columns only what the keys'
+    compare and the residual read is gathered (`unread`); the residual
+    is typed build side first."""
+    if out_cap is None:
+        _, bi, ok, pairs = _expand_pairs_fanout1(
+            ls, probe, build, keys, valids, lo, counts, pkc=pkc, bkc=bkc,
+            unread=unread,
+        )
+    else:
+        _, bi, ok, pairs = _expand_pairs(
+            ls, probe, build, keys, valids, lo + 1,
+            jnp.maximum(counts - 1, 0), out_cap, pkc=pkc, bkc=bkc,
+            unread=unread,
+        )
+    seen = jnp.sum(ok.astype(jnp.int64))
+    if residual_fn is not None:
+        n = len(probe.columns)
+        cols = list(pairs.columns[n:]) + list(pairs.columns[:n])
+        ok = ok & residual_fn(RelBatch(cols, ok))
+    flags = J.build_matched_flags(build.capacity, bi, ok, prior=flags)
+    return flags, totals + jnp.stack([seen, jnp.sum(ok.astype(jnp.int64))])
+
+
+@jax.jit
+def _probe_row_counts(counts):
+    """(candidate pairs, probe rows with a candidate) of a probe batch:
+    the second says how many pairs are somebody's first."""
+    return jnp.stack([jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))])
+
+
+@jax.jit
+def _flagged_rows(build: RelBatch, flags):
+    """(live build rows, those of them flagged)."""
+    live = build.live_mask()
+    return jnp.stack([jnp.sum(live.astype(jnp.int64)),
+                      jnp.sum((live & flags).astype(jnp.int64))])
+
+
 @jax.jit
 def _left_unmatched(probe: RelBatch, build: RelBatch, matched):
     """Unmatched probe rows with NULL build columns (LEFT outer arm).
@@ -3456,6 +3521,20 @@ class LookupJoinOperator(Operator):
     is what makes filtered semi/anti joins (Q21-style `l2.suppkey <>
     l1.suppkey`) correct.
 
+    `build_preserved` (semi/anti; the plan's `JoinNode.build_left`): the
+    side the join preserves is the BUILD and the filtering side probes.
+    Nothing leaves while batches arrive: each adds its pairs to a flag a
+    build row (`_flag_build_rows`, the residual on the pairs first), and
+    at finish the flagged build rows (semi) or the others (anti) go out
+    as one batch. `residual_fn` is then typed build side first, and
+    `unread` names the pair channels (probe first) it does not read.
+    METRICS `join_semi_side.source` / `.filtering` count the semi- and
+    anti-joins by the side they built, `join_expand_launches.first` /
+    `.general` / `.fanout1` every expansion by its form, and
+    `semi_pairs_seen` / `semi_pairs_kept` / `semi_build_rows` /
+    `semi_build_flagged` what the span `sync.join.semi_flags` read back
+    at finish.
+
     METRICS `join_probe_path.blocked` and `.sorted` count the probe
     batches by the form their bounds took (`ops/join.probe_path`: a
     function of the build side's slots, the batch's and the word's
@@ -3471,7 +3550,13 @@ class LookupJoinOperator(Operator):
         residual: Optional[Bound] = None,
         residual_fn=None,
         unread: Sequence[int] = (),
+        build_preserved: bool = False,
     ):
+        if build_preserved and join_type not in ("semi", "anti"):
+            raise ValueError("build_preserved is a semi- or anti-join's")
+        self._build_preserved = build_preserved
+        if build_preserved:
+            self.span_stats = {"preserved": 1}
         self._bridge = bridge
         self._keys = list(key_channels)
         self._type = join_type
@@ -3487,8 +3572,12 @@ class LookupJoinOperator(Operator):
         # as zeros, and its expansion gathers nothing for them
         self._unread = (
             tuple(unread)
-            if join_type == "inner" and self._residual_fn is None else ()
+            if build_preserved
+            or join_type == "inner" and self._residual_fn is None else ()
         )
+        # build_preserved: (pairs the residual saw, pairs it kept), on
+        # the device until finish
+        self._pair_totals = None
         self._outputs: List[RelBatch] = []
         self._remap_cache: Dict[tuple, jnp.ndarray] = {}
         # grace mode: probe rows hash-partition to disk alongside the
@@ -3570,16 +3659,20 @@ class LookupJoinOperator(Operator):
             ls.build_capacity, probe.capacity, ls.hash_bits
         ))
         lo, counts, total = J.probe_counts(ls, keys, valids, live)
-        fan1 = _fanout_le_one(counts)
-        for scalar in (total, fan1):
+        if self._build_preserved:
+            # (both forms of its expansion run whatever the fanout)
+            scalars = {"total": _probe_row_counts(counts)}
+        else:
+            scalars = {"total": total, "fan1": _fanout_le_one(counts)}
+        for scalar in scalars.values():
             try:
                 scalar.copy_to_host_async()
             except AttributeError:
                 pass
         self._probe_pending.append({
             "ls": ls, "build": build, "probe": probe, "keys": keys,
-            "valids": valids, "lo": lo, "counts": counts, "total": total,
-            "fan1": fan1, "remapped": remapped,
+            "valids": valids, "lo": lo, "counts": counts,
+            "remapped": remapped, **scalars,
         })
         # depth-1 pipeline: settle the PREVIOUS batch — its total has
         # been in flight while this batch's upstream ran on device
@@ -3596,6 +3689,9 @@ class LookupJoinOperator(Operator):
         if not rec.get("remapped") and self._bridge.build_key_channels:
             pkc = tuple(self._keys)
             bkc = tuple(self._bridge.build_key_channels)
+        if self._build_preserved:
+            self._flag_oldest(rec, pkc, bkc)
+            return
         with host_sync("join.match_total", 8) as span:
             total = int(rec["total"])
             span.set_metadata(rows=total, probe_slots=probe.capacity)
@@ -3608,6 +3704,7 @@ class LookupJoinOperator(Operator):
             # expansion below: reusing the 4M-padded probe batch for a
             # 30k-match join would drag the FULL padding through every
             # downstream operator (measured 4x on TPC-H Q3)
+            METRICS.increment("join_expand_launches.fanout1")
             pi, bi, ok, pairs = _expand_pairs_fanout1(
                 ls, probe, build, rec["keys"], rec["valids"],
                 rec["lo"], rec["counts"], pkc=pkc, bkc=bkc,
@@ -3628,6 +3725,7 @@ class LookupJoinOperator(Operator):
                 # 466 s of compiling for each of them; PERF.md section
                 # 6, PR 35)
                 out_cap = max(out_cap, probe.capacity)
+            METRICS.increment("join_expand_launches.general")
             pi, bi, ok, pairs = _expand_pairs(
                 ls, probe, build, rec["keys"], rec["valids"],
                 rec["lo"], rec["counts"], out_cap, pkc=pkc, bkc=bkc,
@@ -3695,6 +3793,65 @@ class LookupJoinOperator(Operator):
             return
         raise NotImplementedError(self._type)
 
+    def _flag_oldest(self, rec: dict, pkc, bkc) -> None:
+        """build_preserved: the oldest pending batch's pairs into the
+        build rows' flags: every row's first candidate through the
+        fanout-one form at the batch's own capacity, and the candidates
+        after the first, where there are any, the general way into the
+        power of two that holds them."""
+        ls, build, probe = rec["ls"], rec["build"], rec["probe"]
+        with host_sync("join.match_total", 8) as span:
+            total, firsts = (int(x) for x in jax.device_get(rec["total"]))
+            span.set_metadata(rows=total, probe_slots=probe.capacity,
+                              first_candidates=firsts)
+        if not total:
+            return
+        if self._build_matched is None:
+            self._build_matched = jnp.zeros(build.capacity, dtype=jnp.bool_)
+            self._pair_totals = jnp.zeros(2, dtype=jnp.int64)
+        out_caps = [None]
+        if total > firsts:
+            # (an eighth of the batch's slots at least: the offsets' two
+            # sorts are over the batch's rows whatever the pairs, and
+            # every smaller power of two would be one more program of 45
+            # to 50 s of compiling, PERF.md section 6, PR 40)
+            out_caps.append(max(bucket_capacity(total - firsts),
+                                probe.capacity // 8))
+        for out_cap in out_caps:
+            METRICS.increment("join_expand_launches." + (
+                "first" if out_cap is None else "general"
+            ))
+            self._build_matched, self._pair_totals = _flag_build_rows(
+                ls, probe, build, rec["keys"], rec["valids"], rec["lo"],
+                rec["counts"], self._build_matched, self._pair_totals,
+                out_cap=out_cap, pkc=pkc, bkc=bkc,
+                unread=self._unread, residual_fn=self._residual_fn,
+            )
+
+    def _emit_preserved(self, build: RelBatch) -> None:
+        """build_preserved, the input's end: the flagged build rows
+        (semi) or the others (anti), and what the pairs came to."""
+        flags = self._build_matched
+        if flags is None:
+            flags = jnp.zeros(build.capacity, dtype=jnp.bool_)
+            self._pair_totals = jnp.zeros(2, dtype=jnp.int64)
+        with host_sync("join.semi_flags", 32) as span:
+            (seen, kept), (rows, flagged) = (
+                [int(x) for x in v] for v in jax.device_get(
+                    (self._pair_totals, _flagged_rows(build, flags))
+                )
+            )
+            span.set_metadata(pairs_seen=seen, pairs_kept=kept,
+                              build_rows=rows, build_flagged=flagged,
+                              kind=self._type)
+        for name, n in (("pairs_seen", seen), ("pairs_kept", kept),
+                        ("build_rows", rows), ("build_flagged", flagged)):
+            METRICS.increment("semi_" + name, n)
+        self._outputs.append(
+            build.mask(flags if self._type == "semi" else ~flags)
+        )
+        self._build_matched = self._pair_totals = None
+
     def _resolve_spec(self) -> None:
         """Drain every pending probe batch (finish / partition end)."""
         while self._probe_pending:
@@ -3704,8 +3861,14 @@ class LookupJoinOperator(Operator):
         if self._finishing:
             return
         self._finishing = True
+        if self._type in ("semi", "anti"):
+            METRICS.increment("join_semi_side." + (
+                "source" if self._build_preserved else "filtering"
+            ))
         self._resolve_spec()
         if self._bridge.grace is None:
+            if self._build_preserved:
+                self._emit_preserved(self._bridge.build_batch)
             if self._type == "full":
                 build = self._bridge.build_batch
                 mb = (
@@ -3728,7 +3891,9 @@ class LookupJoinOperator(Operator):
                 if self._probe_spill is not None
                 else []
             )
-            if not probe_pages and self._type != "full":
+            if not probe_pages and self._type != "full" and not (
+                self._build_preserved and self._type == "anti"
+            ):
                 continue  # before touching the build spill: no probe rows
             build_pages = grace.partition_pages(p)
             parts = tuple(
@@ -3749,6 +3914,8 @@ class LookupJoinOperator(Operator):
             for pg in probe_pages:
                 self._probe_one(ls, merged, key_dicts, pg.to_batch())
             self._resolve_spec()
+            if self._build_preserved:
+                self._emit_preserved(merged)
             if self._type == "full":
                 mb = (
                     self._build_matched
@@ -3848,7 +4015,20 @@ DF_SET_MIN_SLOTS = 1 << 7
 # words, 10.1 for 2^20 into 2^22), which is what bounds them
 # (DF_BITS_MAX_SLOTS); a wider domain (DF_BITS_MAX_DOMAIN: 16 MB of
 # bits) was not measured. Whatever fits none of these gets the range.
+# A build side of more slots, up to DF_BITS_PLANNED_MAX_SLOTS (four such
+# scatters once a join), has its keys' domain read back and takes the
+# bits only where the PLAN expects it to fill at most DF_BITS_MAX_FILL of
+# its key's value range (`key_fill`: the build side's estimated rows
+# over the statistics' high - low + 1). TPC-H Q21's 0.8 to 1.5 M late
+# lines of one nation's suppliers, in 2^20 to 3,407,872 slots, are a
+# fortieth of l_orderkey's 60 M values: the range of their keys keeps
+# every row of `orders` and of the 60 M-row scans they stand in front
+# of. Where the plan says the build fills its range, or cannot say, such
+# a build side keeps the range and reads nothing back (TPC-H Q18's 1.5 M
+# customers in 2^21 slots: reading the domain to learn that they fill it
+# cost its p50 0.3 %, PERF.md section 6, PR 40).
 DF_BITS_MAX_SLOTS = 1 << 20
+DF_BITS_PLANNED_MAX_SLOTS = 1 << 22
 DF_BITS_MAX_DOMAIN = 1 << 27
 DF_BITS_MAX_FILL = 0.25
 # The slots of the ONE batch the set filter gathers a scan's survivors
@@ -4086,8 +4266,9 @@ class DynamicFilterOperator(Operator):
     One of three filters, by what the build side is (`_prepare`): on one
     integer key a small build side (DF_SET_MAX_SLOTS) filters by its key
     SET, a larger one whose keys lie scattered over a narrow domain by
-    its key BITS, and everything else by the RANGE of each key. Behind
-    the set and the bits the probe sees only rows that will match, so
+    its key BITS (past DF_BITS_MAX_SLOTS only where the plan's `key_fill`
+    expects the domain sparse), and everything else by the RANGE of each
+    key. Behind the set and the bits the probe sees only rows that will match, so
     their batches are mostly dead slots, and the operator packs them
     before the join sorts anything. A batch's count of survivors is read
     back one batch late (the next batch's filter is on the device by
@@ -4105,9 +4286,21 @@ class DynamicFilterOperator(Operator):
     reading: the rest of the scan leaves masked and unread, by the range
     where the set would cost more than it drops."""
 
-    def __init__(self, bridge: JoinBridge, key_channels: Sequence[int]):
+    def __init__(self, bridge: JoinBridge, key_channels: Sequence[int],
+                 reverse: bool = False, key_fill: Optional[float] = None):
         self._bridge = bridge
         self._keys = list(key_channels)
+        # the plan's estimate of the share of its ONE key's value range
+        # that the build side's rows fill (None: it cannot say); consulted
+        # for a build side of over DF_BITS_MAX_SLOTS slots only
+        self._key_fill = key_fill
+        # in front of the FILTERING side of a semi- or anti-join whose
+        # preserved side is the build (a filtering row whose key no
+        # preserved row has decides nothing): the same filters, counted
+        # apart (`df_reverse_rows_in` / `_kept`, stat `reverse`)
+        self._reverse = reverse
+        if reverse:
+            self.span_stats = {"reverse": 1}
         self._domains = None
         self._key_set = None
         self._bits = None
@@ -4180,7 +4373,11 @@ class DynamicFilterOperator(Operator):
                 span.set_metadata(path="set", slots=slots)
                 return
             self._use_range()
-            if key is None or build.capacity > DF_BITS_MAX_SLOTS:
+            if key is None or build.capacity > (
+                DF_BITS_PLANNED_MAX_SLOTS
+                if self._key_fill is not None
+                and self._key_fill <= DF_BITS_MAX_FILL else DF_BITS_MAX_SLOTS
+            ):
                 span.set_metadata(path="range")
                 return
             usable = build.live_mask() & key.valid_mask()
@@ -4361,10 +4558,13 @@ class DynamicFilterOperator(Operator):
                 span.set_metadata(
                     rows_in=rows_in, rows_kept=kept, batches=self._batches,
                     slots=self._slots, path=self._path,
-                    key_bytes=self._key_bytes,
+                    key_bytes=self._key_bytes, reverse=int(self._reverse),
                 )
             METRICS.increment("df_rows_in", rows_in)
             METRICS.increment("df_rows_kept", kept)
+            if self._reverse:
+                METRICS.increment("df_reverse_rows_in", rows_in)
+                METRICS.increment("df_reverse_rows_kept", kept)
 
     def get_output(self) -> Optional[RelBatch]:
         return self._outs.pop(0) if self._outs else None

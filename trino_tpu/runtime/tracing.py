@@ -51,7 +51,10 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   state and its device buffers are dropped), ``phase.finalize`` (all
   with ``query_id`` where a query span exists);
   ``op.<OperatorClass>.<get_output|add_input|finish>`` around every
-  operator call of ``exec/driver.Driver.run``;
+  operator call of ``exec/driver.Driver.run`` (with the operator's
+  ``span_stats`` where it has any: ``preserved`` 1 on a semi- or
+  anti-join that built the side it preserves, ``reverse`` 1 on the
+  dynamic filter in front of its probe);
   ``sync.<site>`` (``host_sync``, stat ``nbytes``) around every
   device-to-host readback; ``df.prepare`` around a
   ``DynamicFilterOperator``'s choice of its filter (stats ``path``:
@@ -62,7 +65,15 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   finish, ``sync.join.dynamic_filter_totals``
   (stats ``rows_in``, ``rows_kept``, ``batches``, ``slots``, ``path``,
   ``key_bytes``: what the filter saw and kept over the scan, counted on
-  the device and read back once); ``scan.batches`` (stat ``cached``),
+  the device and read back once; ``reverse``: 1 in front of the
+  FILTERING side of a semi- or anti-join that built the side it
+  preserves); ``sync.join.match_total`` around the read of a probe
+  batch's candidate pairs (stats ``rows``, ``probe_slots`` and, under
+  such a join, ``first_candidates``: the probe rows that have one);
+  ``sync.join.semi_flags``, once at the finish of such a join (stats
+  ``pairs_seen`` and ``pairs_kept``: what its residual was shown and
+  what it let through; ``build_rows`` and ``build_flagged``; ``kind``);
+  ``scan.batches`` (stat ``cached``),
   ``scan.host_filter``, ``scan.to_device`` in the memory connector;
   ``result.fetch`` and ``result.to_rows`` around the result's readback
   and its conversion to rows; ``server.queued`` (stat ``handoff_us`` on
@@ -140,7 +151,19 @@ two-level bounds or the two packed sorts), all in ``exec/operators.py``.
 Beside them, one increment an OPERATOR (with its first batch):
 ``agg_key_bound.range`` / ``.dictionary`` / ``.none``, what bounded a
 grouped aggregation's table at plan time: an integer key's exact value
-range among its keys, dictionaries and booleans alone, or nothing.
+range among its keys, dictionaries and booleans alone, or nothing;
+``join_semi_side.source`` / ``.filtering``, which side a semi- or
+anti-join built (its finish): the side it preserves, as the plan's
+``build_left`` says, or the one that filters it. One increment a LAUNCH:
+``join_expand_launches.first`` / ``.general`` / ``.fanout1``, the form a
+probe batch's pairs took (every row's first candidate beside the batch
+as it stands and, of a join that preserves its build side, nothing else
+of it; offsets, sorts and gathers of both sides; the fanout-one form of
+a batch no row of which has two). Read back once at such a join's finish
+and added as counters too: ``semi_pairs_seen``, ``semi_pairs_kept``,
+``semi_build_rows``, ``semi_build_flagged``; and ``df_reverse_rows_in``
+/ ``df_reverse_rows_kept`` beside ``df_rows_in`` / ``df_rows_kept`` for
+the filter in front of its probe.
 """
 
 from __future__ import annotations
@@ -476,11 +499,14 @@ class OpTally:
     """What one operator of one `Driver.run` did, counted only while a
     profiler trace runs; `record_operators` makes a span of it."""
 
-    __slots__ = ("name", "calls", "batches", "host_syncs", "host_sync_ns",
-                 "busy_ns", "first_s", "last_s")
+    __slots__ = ("name", "stats", "calls", "batches", "host_syncs",
+                 "host_sync_ns", "busy_ns", "first_s", "last_s")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, stats: Optional[dict] = None):
         self.name = name
+        # stats every `op.*` span of this operator carries: what the
+        # class name does not say (an operator's `span_stats`)
+        self.stats = stats or {}
         self.calls = self.batches = self.host_syncs = 0
         self.host_sync_ns = self.busy_ns = 0
         self.first_s: Optional[float] = None
@@ -496,7 +522,9 @@ class _OpCall(TraceAnnotation):
     running statement's account."""
 
     def __init__(self, tally: OpTally, method: str):
-        super().__init__(f"{PROFILE_PREFIX}op.{tally.name}.{method}")
+        super().__init__(
+            f"{PROFILE_PREFIX}op.{tally.name}.{method}", **tally.stats
+        )
         self._tally = tally
 
     def __enter__(self):

@@ -143,7 +143,7 @@ def unread_join_outputs(root: P.PlanNode) -> Dict[int, frozenset]:
         if isinstance(node, P.JoinNode):
             width_l = len(node.left.fields)
             pairs = node.kind in ("inner", "left", "full")
-            if read is None or not pairs and node.kind != "semi":
+            if read is None or not pairs and node.kind not in ("semi", "anti"):
                 walk(node.left, None)
                 walk(node.right, None)
                 return
@@ -154,11 +154,10 @@ def unread_join_outputs(root: P.PlanNode) -> Dict[int, frozenset]:
             refs = expr_refs(node.residual) if node.residual is not None else set()
             left = {c for c in read | refs if c < width_l} | set(node.left_keys)
             walk(node.left, left)
-            if pairs:
-                walk(node.right, {c - width_l for c in read | refs
-                                  if c >= width_l} | set(node.right_keys))
-            else:   # a semi-join hands on the probe's columns only
-                walk(node.right, None)
+            # (a semi- or anti-join hands on the left's columns only: of
+            # the right it reads its keys and what the residual names)
+            walk(node.right, {c - width_l for c in (read if pairs else set()) | refs
+                              if c >= width_l} | set(node.right_keys))
             return
         if isinstance(node, P.FilterNode):
             walk(node.child,
@@ -375,9 +374,25 @@ class LocalPlanner:
             ),
         )
 
+    @staticmethod
+    def _read_row_bytes(schema: Schema, exprs) -> int:
+        """Bytes a row of the columns `exprs` read (a column handed on
+        as it is reads nothing): what a filter/project stage cannot
+        avoid moving, for `filter_read_bytes`."""
+        from trino_tpu.sql.optimizer import expr_refs
+
+        read = set()
+        for e in exprs:
+            if e is not None and not isinstance(e, InputRef):
+                read |= expr_refs(e)
+        return sum(
+            schema[c][0].dtype.itemsize * schema[c][0].lanes
+            for c in read if not schema[c][0].is_nested
+        )
+
     def _append_fp(self, chain: List[Factory], fn,
                    in_schema: Optional[Schema],
-                   out_schema: Optional[Schema]) -> None:
+                   out_schema: Optional[Schema], read_row_bytes: int = 0) -> None:
         """Append a filter/project stage, folding it into a directly
         preceding one so adjacent stages share a device program. Also
         records the stage's warmup entry: the (possibly composed) jit,
@@ -391,6 +406,7 @@ class LocalPlanner:
         caps = getattr(prev, "out_caps", None)
         if pf is not None:
             chain.pop()
+            read_row_bytes += getattr(prev, "read_row_bytes", 0)
             prev_entry = getattr(prev, "warmup_entry", None)
             if prev_entry is not None:
                 # the folded stage dispatches as one program; its parts
@@ -410,9 +426,12 @@ class LocalPlanner:
                 fn = compose_batch_fns(pf, inner, name="FilterProjectOperator")
 
         def factory(ctx, fn=fn):
-            return FilterProjectOperator(None, (), fn=fn)
+            return FilterProjectOperator(
+                None, (), fn=fn, read_row_bytes=read_row_bytes
+            )
 
         factory.fused_fn = fn
+        factory.read_row_bytes = read_row_bytes
         # filter/project preserves capacity, so the source classes flow
         # through for any further folding above this stage
         factory.out_caps = caps
@@ -485,7 +504,8 @@ class LocalPlanner:
         fn = self._cached_fp(
             flt, self._identity(schema), schema, ("flt", repr(node.predicate))
         )
-        self._append_fp(chain, fn, schema, schema)
+        self._append_fp(chain, fn, schema, schema,
+                        self._read_row_bytes(schema, [node.predicate]))
         return chain, schema
 
     def _visit_ProjectNode(self, node: P.ProjectNode):
@@ -505,7 +525,12 @@ class LocalPlanner:
         )
         fn = self._cached_fp(flt, bounds, schema, fingerprint)
         out_schema: Schema = [(b.type, b.dictionary) for b in bounds]
-        self._append_fp(chain, fn, schema, out_schema)
+        self._append_fp(
+            chain, fn, schema, out_schema, self._read_row_bytes(
+                schema,
+                [child.predicate if flt is not None else None, *node.exprs],
+            ),
+        )
         return chain, out_schema
 
     def _visit_AggregateNode(self, node: P.AggregateNode):
@@ -757,17 +782,13 @@ class LocalPlanner:
             self.pipelines.append(build_chain)
             probe_chain.append(lambda ctx: CrossJoinOperator(bridge_of(ctx)))
             return probe_chain, probe_schema + build_schema
-        rkeys = list(node.right_keys)
-        # adaptive spill-mode annotation (skewed/oversized build side):
-        # grace partitions open before the first batch arrives
-        force_spill = bool(getattr(node, "spill_build", False))
-        build_chain.append(
-            lambda ctx: HashBuildSink(
-                bridge_of(ctx), rkeys, build_schema,
-                memory_context=_mem_ctx(ctx), force_spill=force_spill,
+        if node.kind in ("semi", "anti") and node.build_left:
+            return self._semi_join_built_left(
+                node, probe_chain, probe_schema, build_chain, build_schema,
+                bridge_of,
             )
-        )
-        self.pipelines.append(build_chain)
+        rkeys = list(node.right_keys)
+        self._end_in_build(node, build_chain, rkeys, build_schema, bridge_of)
         residual_fn = None
         if node.residual is not None:
             residual_fn = make_residual_fn(
@@ -809,8 +830,11 @@ class LocalPlanner:
                 if caps is not None:
                     df_scan_factory.out_caps = caps
                 probe_chain[0] = df_scan_factory
+            key_fill = self._build_key_fill(node.right, rkeys)
             probe_chain.append(
-                lambda ctx: DynamicFilterOperator(bridge_of(ctx), lkeys)
+                lambda ctx: DynamicFilterOperator(
+                    bridge_of(ctx), lkeys, key_fill=key_fill
+                )
             )
         unread = tuple(sorted(self._unread.get(id(node), ())))
         probe_chain.append(
@@ -835,6 +859,86 @@ class LocalPlanner:
                 probe_schema, out_schema, probe_caps,
             )
         return probe_chain, out_schema
+
+    def _end_in_build(self, node: P.JoinNode, chain, keys, schema,
+                      bridge_of) -> None:
+        """`chain` as the pipeline that builds `node`'s lookup side."""
+        # adaptive spill-mode annotation (skewed/oversized build side):
+        # grace partitions open before the first batch arrives
+        force_spill = bool(getattr(node, "spill_build", False))
+        chain.append(
+            lambda ctx: HashBuildSink(
+                bridge_of(ctx), keys, schema,
+                memory_context=_mem_ctx(ctx), force_spill=force_spill,
+            )
+        )
+        self.pipelines.append(chain)
+
+    def _build_key_fill(self, build: P.PlanNode, keys) -> Optional[float]:
+        """The share of its ONE key's value range that a build side's
+        rows are estimated to fill (rows over high - low + 1 of the
+        statistics), for `DynamicFilterOperator(key_fill=)`; None where
+        the statistics cannot say."""
+        if len(keys) != 1:
+            return None
+        try:
+            if self._stats_calc is None:
+                from trino_tpu.sql.stats import StatsCalculator
+
+                self._stats_calc = StatsCalculator(self.catalogs)
+            stats = self._stats_calc.stats(build)
+            key = stats.col(keys[0])
+            width = float(key.high) - float(key.low) + 1.0
+            fill = float(stats.row_count) / width
+        except Exception:
+            return None
+        return fill if width >= 1.0 and fill == fill else None
+
+    def _semi_join_built_left(self, node: P.JoinNode, left_chain, left_schema,
+                              right_chain, right_schema, bridge_of):
+        """A semi- or anti-join whose PRESERVED side is the lookup
+        (`JoinNode.build_left`): the left's pipeline ends in the build,
+        the filtering side's scan is filtered by the left's keys (a
+        filtering row whose key no left row has decides nothing, for
+        EXISTS and NOT EXISTS alike) and probes, and the join puts out
+        the left rows some pair flagged (semi) or none did (anti) when
+        its input ends. Of the pairs only what the residual reads is
+        gathered."""
+        from trino_tpu.exec.operators import DynamicFilterOperator
+        from trino_tpu.sql.optimizer import expr_refs
+
+        lkeys, rkeys = list(node.left_keys), list(node.right_keys)
+        kind = node.kind
+        self._end_in_build(node, left_chain, lkeys, left_schema, bridge_of)
+        residual_fn = None
+        read = frozenset()
+        if node.residual is not None:
+            residual_fn = make_residual_fn(
+                self._bind(node.residual, left_schema + right_schema)
+            )
+            # pairs are laid out probe (right) first, then build (left)
+            width_l = len(left_schema)
+            read = frozenset(
+                c + len(right_schema) if c < width_l else c - width_l
+                for c in expr_refs(node.residual)
+            )
+        unread = tuple(sorted(
+            frozenset(range(len(left_schema) + len(right_schema))) - read
+        ))
+        if self.dynamic_filtering:
+            key_fill = self._build_key_fill(node.left, lkeys)
+            right_chain.append(
+                lambda ctx: DynamicFilterOperator(
+                    bridge_of(ctx), rkeys, reverse=True, key_fill=key_fill
+                )
+            )
+        right_chain.append(
+            lambda ctx: LookupJoinOperator(
+                bridge_of(ctx), rkeys, kind, right_schema,
+                residual_fn=residual_fn, unread=unread, build_preserved=True,
+            )
+        )
+        return right_chain, left_schema
 
     def _visit_WindowNode(self, node: P.WindowNode):
         from trino_tpu.exec.operators import WindowOperator

@@ -981,9 +981,14 @@ class PushSemiJoinDown(Rule):
 
     name = "push_semi_join_down"
 
+    @staticmethod
+    def moves(node) -> bool:
+        """Whether `node` is a join this rule carries down."""
+        return (isinstance(node, P.JoinNode) and node.kind == "semi"
+                and node.residual is None and len(node.left_keys) == 1)
+
     def apply(self, node, ctx):
-        if (not isinstance(node, P.JoinNode) or node.kind != "semi"
-                or node.residual is not None or len(node.left_keys) != 1):
+        if not self.moves(node):
             return None
         key = node.left_keys[0]
         left = ctx.resolve(node.left)
@@ -1012,6 +1017,80 @@ class PushSemiJoinDown(Rule):
         return None
 
 
+def _narrowed(child: P.PlanNode, keep: Sequence[int]):
+    """(a projection of `child` onto the channels `keep`, in that order;
+    old channel -> its InputRef over the projection)."""
+    fields = tuple(child.fields[c] for c in keep)
+    project = P.ProjectNode(
+        child, tuple(ir.InputRef(c, child.fields[c].type) for c in keep), fields
+    )
+    return project, {
+        old: ir.InputRef(new, child.fields[old].type)
+        for new, old in enumerate(keep)
+    }
+
+
+class PruneSemiJoinInputs(Rule):
+    """A semi- or anti-join reads of its filtering side the keys and
+    what the residual names, and hands nothing of it on; of the side it
+    preserves, those and what the projection above it reads. Either
+    side wider than that is projected down first (PruneSemiJoinColumns /
+    PruneSemiJoinFilteringSourceColumns.java), so that a scan under it
+    loads the narrow columns only (push_projection_into_table_scan) and
+    the join's lookup side carries a few columns, not the whole row of
+    every table joined below it (TPC-H Q21: `select *` from lineitem
+    twice, and 3 of 11 columns of the joins under the two
+    subqueries)."""
+
+    name = "prune_semi_join_inputs"
+
+    def apply(self, node, ctx):
+        project = None
+        join = node
+        if isinstance(node, P.ProjectNode):
+            project, join = node, ctx.resolve(node.child)
+        if not isinstance(join, P.JoinNode) or join.kind not in ("semi", "anti"):
+            return None
+        left, right = ctx.resolve(join.left), ctx.resolve(join.right)
+        width_l, width_r = len(left.fields), len(right.fields)
+        refs = expr_refs(join.residual) if join.residual is not None else set()
+        if project is None:
+            keep = sorted(set(join.right_keys) | {c - width_l for c in refs
+                                                 if c >= width_l})
+            if len(keep) >= width_r:
+                return None
+            narrow, at = _narrowed(join.right, keep)
+            mapping = {c: ir.InputRef(c, left.fields[c].type) for c in range(width_l)}
+            mapping.update({width_l + old: ir.InputRef(width_l + ref.index, ref.type)
+                            for old, ref in at.items()})
+            return dataclasses.replace(
+                join, right=narrow,
+                right_keys=tuple(at[c].index for c in join.right_keys),
+                residual=None if join.residual is None
+                else substitute(join.residual, mapping),
+            )
+        if PushSemiJoinDown.moves(join):
+            return None     # that rule puts it under this projection
+        above = set().union(*map(expr_refs, project.exprs)) if project.exprs else set()
+        keep = sorted(above | set(join.left_keys) | {c for c in refs if c < width_l})
+        if len(keep) >= width_l:
+            return None
+        narrow, at = _narrowed(join.left, keep)
+        mapping = dict(at)
+        mapping.update({width_l + c: ir.InputRef(len(keep) + c, right.fields[c].type)
+                        for c in range(width_r)})
+        new_join = dataclasses.replace(
+            join, left=narrow, fields=narrow.fields,
+            left_keys=tuple(at[c].index for c in join.left_keys),
+            residual=None if join.residual is None
+            else substitute(join.residual, mapping),
+            skew_hot_keys=(),
+        )
+        return P.ProjectNode(
+            new_join, tuple(substitute(e, at) for e in project.exprs), project.fields
+        )
+
+
 SIMPLIFICATION_RULES: Tuple[Rule, ...] = (
     MergeFilters(),
     InlineProjections(),
@@ -1033,6 +1112,7 @@ SIMPLIFICATION_RULES: Tuple[Rule, ...] = (
     RemoveRedundantDistinct(),
     PushAggregationThroughOuterJoin(),
     PushSemiJoinDown(),
+    PruneSemiJoinInputs(),
 )
 
 
@@ -1956,7 +2036,31 @@ def optimize(
         root = ReorderJoins(stats, cost).rewrite(root)
         root = it.optimize(root, stats, validator=per_rule)
         checkpoint(root, "join_reordering")
-    return _with_group_key_ranges(root, stats)
+    return _with_semi_join_sides(_with_group_key_ranges(root, stats), stats)
+
+
+def _with_semi_join_sides(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNode:
+    """With the group key ranges, the last pass: which side of a semi-
+    or anti-join is built. A filtering row whose key no row of the
+    preserved side has decides nothing, for EXISTS and NOT EXISTS
+    alike, so where the filtering side is estimated the larger the
+    preserved side is the lookup (`build_left`): its keys filter the
+    other side's scan, the filtering side probes, and a flag a build
+    row says whether any pair held (TPC-H Q21: 0.8 M late lines of one
+    nation's suppliers against lineitem's 60 M, twice). Decided from
+    the estimates alone; a join without an equality key keeps its side."""
+    node = with_children(
+        node, [_with_semi_join_sides(c, stats) for c in node.children()]
+    )
+    if (not isinstance(node, P.JoinNode) or node.kind not in ("semi", "anti")
+            or not node.left_keys):
+        return node
+    build_left = (
+        stats.stats(node.right).row_count > stats.stats(node.left).row_count
+    )
+    if build_left == node.build_left:
+        return node
+    return dataclasses.replace(node, build_left=build_left)
 
 
 def _with_group_key_ranges(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNode:

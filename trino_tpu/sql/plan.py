@@ -120,7 +120,13 @@ class JoinNode(PlanNode):
     Output schema: left fields + right fields (inner/left/cross);
     left fields only (semi/anti). `residual` is typed over the
     concatenated left+right schema and runs inside the join, before
-    match flags (JoinNode.filter analogue)."""
+    match flags (JoinNode.filter analogue).
+
+    `build_left` (semi/anti only; the optimizer's last pass sets it from
+    the two sides' estimated rows): the side the join PRESERVES, the
+    left, is the one built, the filtering side probes it, and a flag a
+    build row says whether any pair held. Shown in EXPLAIN as
+    ` build=left`, only where set."""
 
     kind: str
     left: PlanNode
@@ -142,6 +148,7 @@ class JoinNode(PlanNode):
     # grace partitions (hybrid hash) instead of thrashing revocation.
     skew_hot_keys: Tuple = ()
     spill_build: bool = False
+    build_left: bool = False
 
     def children(self):
         return (self.left, self.right)
@@ -372,6 +379,7 @@ def explain_text(node: PlanNode, indent: int = 0) -> str:
         detail = (
             f" {node.kind} L{list(node.left_keys)}=R{list(node.right_keys)}"
             + (" +residual" if node.residual is not None else "")
+            + (" build=left" if node.build_left else "")
         )
         # skew annotations render only when present, so plans with no
         # skew stay byte-identical to the unannotated output

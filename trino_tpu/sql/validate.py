@@ -860,17 +860,23 @@ def shape_census(
             visit(node.child, fused_into_consumer=True)
             return
         if isinstance(node, P.JoinNode):
-            probe_rows = rows(node.left)
+            # (a semi- or anti-join that builds the side it preserves is
+            # probed by the other, behind a filter of the build's keys
+            # whatever its kind: LocalPlanner._semi_join_built_left)
+            built_left = node.kind in ("semi", "anti") and node.build_left
+            probe = node.right if built_left else node.left
+            probe_rows = rows(probe)
             if node.kind == "cross":
                 add("CrossJoinOperator", rows(node), node.fields)
             else:
-                if node.kind in ("inner", "semi") and dynamic_filtering:
+                if (built_left or node.kind in ("inner", "semi")
+                        ) and dynamic_filtering:
                     # the filter compacts probe batches to a DATA-
                     # DEPENDENT capacity; which capacity depends on which
                     # retry attempt's build side survives, so every
                     # pruned class is a fresh lowering no warm run covers
                     add("DynamicFilterOperator", probe_rows,
-                        node.left.fields, retry_variant=True)
+                        probe.fields, retry_variant=True)
                 # an equi-join's output rides at the bucketed MATCH
                 # capacity, which is data-dependent: selective keys land
                 # near the output-row estimate, FK-ish multiplicity
