@@ -320,6 +320,35 @@ def test_key_bits_dynamic_filter_compiles_for_v5e(one_chip):
     assert "scatter" in compiled.as_text()
 
 
+@pytest.mark.parametrize("n_words", [128, 1 << 16, 1 << 21],
+                         ids=["one_table_row", "q9s_part_keys", "q21s_order_keys"])
+def test_key_bits_window_compiles_for_v5e(one_chip, n_words):
+    """The key bits looked up a window a block (`_df_filter_bits_window`,
+    PR 42) at the engine's batch, `lineitem`'s two keys, against the
+    table of Q21's order keys (60 M values, 2^21 words), of Q9's part
+    keys (2 M values, 2^16 words: a shape this program meets only on a
+    wrong word of the plan's) and against the least table there is (one row of 128
+    words): two row gathers a block
+    and the pick in one fusion that never lays out its blocks x rows x
+    lanes (1 GB as u32), the gather a row kept as the other branch of
+    the `cond`. No sort: seconds."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+
+    key = _sds((BATCH,), jnp.int64, one_chip)
+    batch = RelBatch([Column(T.BIGINT, key, None, None) for _ in range(2)],
+                     _sds((BATCH,), jnp.bool_, one_chip))
+    scalar = _sds((), jnp.int64, one_chip)
+    compiled = O._df_filter_bits_window.lower(
+        batch, (key, None), _sds((n_words,), jnp.uint32, one_chip),
+        scalar, scalar, _sds((2,), jnp.int64, one_chip), _sds((), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "conditional" in text and "gather" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_flag_build_rows_first_candidates_compile_for_v5e(one_chip):
     """`_flag_build_rows` without `out_cap` (PR 40: a semi- or anti-join
     that builds the side it preserves): a probe batch of 2^20 rows of

@@ -34,6 +34,17 @@ from trino_tpu.connectors.spi import (
 )
 
 
+def _never_descends(arr: np.ndarray) -> bool:
+    """Whether no value of `arr` is below the one before it, a stretch
+    of rows at a time: a column in no order says so in its first."""
+    step = 1 << 20
+    for at in range(0, len(arr) - 1, step):
+        part = arr[at:at + step + 1]
+        if np.any(part[1:] < part[:-1]):
+            return False
+    return True
+
+
 @dataclasses.dataclass
 class _StoredColumn:
     type: T.DataType
@@ -113,14 +124,15 @@ class MemoryMetadata(ConnectorMetadata):
         estimate honestly: stride-sample up to 256k rows, Duj1-estimate
         NDV from sample singletons, exact min/max (an integer column's
         are listed in `exact_ranges`: taken over every row, not the
-        sample). Cached per table version (writes invalidate)."""
+        sample; one whose values never descend from a row to the next
+        in `ordered`). Cached per table version (writes invalidate)."""
         t = self.store.tables[(handle.schema, handle.table)]
         key = (handle.schema, handle.table)
         cached = self._stats_cache.get(key)
         if cached is not None and cached[0] is t and cached[1] == t.version:
             return cached[2]
         cols: Dict[str, tuple] = {}
-        exact = set()
+        exact, ordered = set(), set()
         n = t.row_count
         for name, sc in t.data.items():
             if n == 0 or isinstance(sc.data, list):  # empty or ARRAY column
@@ -157,12 +169,15 @@ class MemoryMetadata(ConnectorMetadata):
                 least, most = arr.min(), arr.max()
                 lo, hi = float(least), float(most)
                 # (a float holds an integer exactly up to 2^53)
-                if arr.ndim == 1 and arr.dtype.kind in "iu" \
-                        and lo == int(least) and hi == int(most):
-                    exact.add(name)
+                if arr.ndim == 1 and arr.dtype.kind in "iu":
+                    if lo == int(least) and hi == int(most):
+                        exact.add(name)
+                    if _never_descends(arr):
+                        ordered.add(name)
             cols[name] = (ndv, nf, lo, hi)
         ts = TableStatistics(
-            row_count=float(n), columns=cols, exact_ranges=frozenset(exact)
+            row_count=float(n), columns=cols, exact_ranges=frozenset(exact),
+            ordered=frozenset(ordered),
         )
         self._stats_cache[key] = (t, t.version, ts)
         return ts

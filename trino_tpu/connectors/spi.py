@@ -97,6 +97,14 @@ class TableStatistics:
     # is not listed: only an exact range may bound a group table
     # (sql/stats.group_key_ranges).
     exact_ranges: frozenset = frozenset()
+    # integer columns whose stored values never descend from one row to
+    # the next at this version (NULLs apart): a scan's batch of such a
+    # column asks for neighbouring values in neighbouring rows, and so
+    # does every subsequence of it (a pushed-down predicate's filtered
+    # copy, a row-range or a bucket split). What reads it may only go
+    # faster for it, never answer otherwise
+    # (exec/operators.DynamicFilterOperator, `key_ordered`).
+    ordered: frozenset = frozenset()
 
 
 class ConnectorMetadata:

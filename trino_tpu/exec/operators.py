@@ -4015,6 +4015,7 @@ DF_SET_MIN_SLOTS = 1 << 7
 # words, 10.1 for 2^20 into 2^22), which is what bounds them
 # (DF_BITS_MAX_SLOTS); a wider domain (DF_BITS_MAX_DOMAIN: 16 MB of
 # bits) was not measured. Whatever fits none of these gets the range.
+# (On a scan in key order the word is not gathered: DF_WINDOW_ROWS.)
 # A build side of more slots, up to DF_BITS_PLANNED_MAX_SLOTS (four such
 # scatters once a join), has its keys' domain read back and takes the
 # bits only where the PLAN expects it to fill at most DF_BITS_MAX_FILL of
@@ -4031,6 +4032,32 @@ DF_BITS_MAX_SLOTS = 1 << 20
 DF_BITS_PLANNED_MAX_SLOTS = 1 << 22
 DF_BITS_MAX_DOMAIN = 1 << 27
 DF_BITS_MAX_FILL = 0.25
+# The gather is not paid where the scan hands the key on in stored order
+# (the plan's `key_ordered`: a column the connector lists in
+# `TableStatistics.ordered`, `lineitem` and `orders` by their order key,
+# with nothing between the scan and the filter that moves rows): the
+# batch's words are looked up by WINDOW (_df_filter_bits_window), a block
+# of DF_WINDOW_ROWS rows sharing two adjacent table rows of
+# DF_WINDOW_WORDS words (8,192 key values; 128 rows of `orders` span 16
+# words, of `lineitem` 4 or 5) and each row picking its word among them.
+# Measured on a v5e (PERF.md section 6, PR 42; a 2^20-row batch of SF10's
+# l_orderkey against 2^21 words, device time a launch): the gather 7.76
+# ms, the window 0.85 (0.84-0.95 at 128 to 512 rows a block and 128- or
+# 256-word windows; 0.99 inside TPC-H Q21, where the gather is 7.35); a
+# batch that does not fit its windows (the same keys shuffled) 7.77: the
+# guard inside the program sends the whole batch to the gather, for 0.02
+# ms, so the window is exact whatever the plan said and a wrong word
+# costs time, never a row. A batch of no whole number of blocks takes
+# the gather. The guard does not make the plan's word redundant: on keys
+# that ask all over the table (l_partkey, l_suppkey; not a batch's own
+# order keys shuffled, as above) the gather inside the `cond` costs 9.30
+# ms for _df_filter_bits' 7.76 alone, at 2^12, 2^16 and 2^21 words
+# alike (8.2 for 6.4 inside TPC-H Q9), so launching this program for
+# EVERY bits batch cost Q9 4.5-5.3 % of its p50 (58 such batches a
+# statement) and Q21 4 %: measured and taken back (PERF.md section 6,
+# PR 42, after the review).
+DF_WINDOW_ROWS = 128
+DF_WINDOW_WORDS = 128
 # The slots of the ONE batch the set filter gathers a scan's survivors
 # into, and the most rows a batch may keep and still be gathered: below
 # it a sort is no faster, and every smaller power of two would be one
@@ -4116,6 +4143,66 @@ def _df_filter_bits(batch: RelBatch, key, words, lo, hi, totals):
         keep = keep & c_valid
     return (batch.mask(keep), jnp.sum(keep.astype(jnp.int32)),
             _df_count(totals, batch, keep))
+
+
+@jax.jit
+def _df_filter_bits_window(batch: RelBatch, key, words, lo, hi, totals,
+                           fallbacks):
+    """`_df_filter_bits` for a batch whose key column is in order: the
+    word of a row is not gathered, it is picked out of a WINDOW of the
+    table that its block of DF_WINDOW_ROWS rows shares. The table is
+    read as rows of DF_WINDOW_WORDS words; a block's window is the two
+    adjacent rows from the one that holds the least word any of its
+    rows that can match (live, not NULL, inside [lo, hi]) asks for, one
+    gather of two table rows a block; a row's word is the window's lane
+    at its distance from there (compare, select, reduce over the
+    lanes). Exact whatever the batch: where some row that can match
+    asks past its block's window (keys in no order, a sparse stretch)
+    the whole batch takes `_df_filter_bits`' gather, and `fallbacks`
+    counts it. A row that cannot match anchors no window and is kept by
+    none. Returns (batch, rows kept, totals, fallbacks)."""
+    c_data, c_valid = key
+    k = c_data.astype(jnp.int64)
+    slot = k - lo
+    can = batch.live_mask() & (k >= lo) & (k <= hi)
+    if c_valid is not None:
+        can = can & c_valid
+    n_words = words.shape[0]
+    at = jnp.clip(slot >> 5, 0, n_words - 1).astype(jnp.int32)
+    shift = (slot & 31).astype(jnp.uint32)
+    width = DF_WINDOW_WORDS
+    rows = n_words // width
+    blocks = k.shape[0] // DF_WINDOW_ROWS
+    at_b = at.reshape(blocks, DF_WINDOW_ROWS)
+    can_b = can.reshape(blocks, DF_WINDOW_ROWS)
+    first = jnp.min(jnp.where(can_b, at_b, jnp.int32(n_words - 1)), axis=1)
+    row = first // width
+    # (not below 0 for a row that can match: its block's window starts
+    # at or before the least of them)
+    rel = at_b - (row * width)[:, None]
+    held = jnp.all(~can_b | (rel < 2 * width))
+
+    def by_window():
+        table = words.reshape(rows, width)
+        window = jnp.concatenate([
+            take_clip(table, row, axis=0),
+            take_clip(table, jnp.minimum(row + 1, rows - 1), axis=0),
+        ], axis=1)
+        lane = jnp.arange(2 * width, dtype=jnp.int32)
+        word = jnp.sum(
+            jnp.where(rel[:, :, None] == lane[None, None, :],
+                      window[:, None, :], jnp.uint32(0)),
+            axis=2, dtype=jnp.uint32,
+        )
+        return (word.reshape(-1) >> shift) & jnp.uint32(1)
+
+    def by_gather():
+        return (take_clip(words, at) >> shift) & jnp.uint32(1)
+
+    keep = can & (jax.lax.cond(held, by_window, by_gather) == 1)
+    return (batch.mask(keep), jnp.sum(keep.astype(jnp.int32)),
+            _df_count(totals, batch, keep),
+            fallbacks + (~held).astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("capacity",))
@@ -4267,8 +4354,11 @@ class DynamicFilterOperator(Operator):
     integer key a small build side (DF_SET_MAX_SLOTS) filters by its key
     SET, a larger one whose keys lie scattered over a narrow domain by
     its key BITS (past DF_BITS_MAX_SLOTS only where the plan's `key_fill`
-    expects the domain sparse), and everything else by the RANGE of each
-    key. Behind the set and the bits the probe sees only rows that will match, so
+    expects the domain sparse; a batch's words are picked out of a window
+    a block where the plan's `key_ordered` says the scan hands the key
+    on in stored order, gathered a row otherwise), and everything else
+    by the RANGE of each key. Behind the set and the bits the probe sees
+    only rows that will match, so
     their batches are mostly dead slots, and the operator packs them
     before the join sorts anything. A batch's count of survivors is read
     back one batch late (the next batch's filter is on the device by
@@ -4287,9 +4377,19 @@ class DynamicFilterOperator(Operator):
     where the set would cost more than it drops."""
 
     def __init__(self, bridge: JoinBridge, key_channels: Sequence[int],
-                 reverse: bool = False, key_fill: Optional[float] = None):
+                 reverse: bool = False, key_fill: Optional[float] = None,
+                 key_ordered: bool = False):
         self._bridge = bridge
         self._keys = list(key_channels)
+        # whether the plan found the ONE key to be a column its connector
+        # stores in order, scanned with nothing in between that moves
+        # rows: the bits of such a batch are looked up a window a block
+        # (_df_filter_bits_window), which stays exact if the plan is wrong
+        self._key_ordered = key_ordered
+        # batches of the window program that took its gather after all,
+        # counted on the device and read with `totals` at finish (None:
+        # no bits, or not in order)
+        self._window_fallbacks = None
         # the plan's estimate of the share of its ONE key's value range
         # that the build side's rows fill (None: it cannot say); consulted
         # for a build side of over DF_BITS_MAX_SLOTS slots only
@@ -4400,6 +4500,8 @@ class DynamicFilterOperator(Operator):
                 jnp.asarray(int(hi), dtype=jnp.int64),
             )
             self._domains, self._path = None, "bits"
+            if self._key_ordered:
+                self._window_fallbacks = jnp.zeros((), dtype=jnp.int32)
             span.set_metadata(path="bits", table_bytes=4 * n_words)
 
     def _use_range(self) -> None:
@@ -4433,9 +4535,19 @@ class DynamicFilterOperator(Operator):
             )
         elif self._bits is not None:
             METRICS.increment("df_filter_path.bits")
-            out, kept, self._totals = _df_filter_bits(
-                batch, keys[0], *self._bits, self._totals
-            )
+            if self._key_ordered and batch.capacity % DF_WINDOW_ROWS == 0:
+                METRICS.increment("df_bits_lookup.window")
+                out, kept, self._totals, self._window_fallbacks = (
+                    _df_filter_bits_window(
+                        batch, keys[0], *self._bits, self._totals,
+                        self._window_fallbacks,
+                    )
+                )
+            else:
+                METRICS.increment("df_bits_lookup.gather")
+                out, kept, self._totals = _df_filter_bits(
+                    batch, keys[0], *self._bits, self._totals
+                )
         else:
             METRICS.increment("df_filter_path.range")
             out, self._totals = _df_filter(
@@ -4554,7 +4666,10 @@ class DynamicFilterOperator(Operator):
         self._emit_parts(last=True)
         if self._totals is not None:
             with host_sync("join.dynamic_filter_totals", 16) as span:
-                rows_in, kept = (int(x) for x in jax.device_get(self._totals))
+                totals, fallbacks = jax.device_get(
+                    (self._totals, self._window_fallbacks)
+                )
+                rows_in, kept = (int(x) for x in totals)
                 span.set_metadata(
                     rows_in=rows_in, rows_kept=kept, batches=self._batches,
                     slots=self._slots, path=self._path,
@@ -4562,6 +4677,8 @@ class DynamicFilterOperator(Operator):
                 )
             METRICS.increment("df_rows_in", rows_in)
             METRICS.increment("df_rows_kept", kept)
+            if fallbacks is not None:
+                METRICS.increment("df_bits_window_fallbacks", int(fallbacks))
             if self._reverse:
                 METRICS.increment("df_reverse_rows_in", rows_in)
                 METRICS.increment("df_reverse_rows_kept", kept)

@@ -145,7 +145,11 @@ Which of several device paths a batch took is not a span but a counter
 of ``runtime/metrics.METRICS``, one increment a batch, trace or no
 trace: ``agg_ingest_path.dense`` / ``.mxu`` / ``.sort`` (the bounded
 reduce a grouped batch got), ``df_filter_path.set`` / ``.bits`` /
-``.range`` (a dynamic filter's batches) and ``join_probe_path.blocked``
+``.range`` (a dynamic filter's batches; of the bits' batches,
+``df_bits_lookup.window`` / ``.gather``: whether the words were picked
+out of a window a block, on a scan in key order, or gathered a row, and
+``df_bits_window_fallbacks``, added at the filter's finish: the window
+program's batches that took its gather after all) and ``join_probe_path.blocked``
 / ``.sorted`` (a join's probe batches by ``ops/join.probe_path``: the
 two-level bounds or the two packed sorts), all in ``exec/operators.py``.
 Beside them, one increment an OPERATOR (with its first batch):

@@ -831,9 +831,11 @@ class LocalPlanner:
                     df_scan_factory.out_caps = caps
                 probe_chain[0] = df_scan_factory
             key_fill = self._build_key_fill(node.right, rkeys)
+            key_ordered = self._scan_key_ordered(node.left, lkeys)
             probe_chain.append(
                 lambda ctx: DynamicFilterOperator(
-                    bridge_of(ctx), lkeys, key_fill=key_fill
+                    bridge_of(ctx), lkeys, key_fill=key_fill,
+                    key_ordered=key_ordered,
                 )
             )
         unread = tuple(sorted(self._unread.get(id(node), ())))
@@ -894,6 +896,33 @@ class LocalPlanner:
             return None
         return fill if width >= 1.0 and fill == fill else None
 
+    def _scan_key_ordered(self, side: P.PlanNode, keys) -> bool:
+        """Whether the ONE key of a dynamic filter in front of `side` is a
+        column its connector lists as stored in order
+        (`TableStatistics.ordered`) of a scan with nothing between the
+        scan and the filter that moves rows: filters (they mask, or the
+        connector scans a filtered copy: a subsequence) and projections
+        that hand the column on as it is. Anything else, a join among
+        them, is not asked."""
+        if len(keys) != 1:
+            return False
+        ch = keys[0]
+        while not isinstance(side, P.ScanNode):
+            if isinstance(side, P.ProjectNode):
+                e = side.exprs[ch]
+                if not isinstance(e, InputRef):
+                    return False
+                ch = e.index
+            elif not isinstance(side, P.FilterNode):
+                return False
+            side = side.child
+        # (a connector with no statistics lists nothing: connectors/spi;
+        # a scan's `columns` are the connector's names, one a field)
+        stats = self.catalogs.get(side.catalog).metadata.get_table_statistics(
+            side.handle
+        )
+        return side.columns[ch] in stats.ordered
+
     def _semi_join_built_left(self, node: P.JoinNode, left_chain, left_schema,
                               right_chain, right_schema, bridge_of):
         """A semi- or anti-join whose PRESERVED side is the lookup
@@ -927,9 +956,11 @@ class LocalPlanner:
         ))
         if self.dynamic_filtering:
             key_fill = self._build_key_fill(node.left, lkeys)
+            key_ordered = self._scan_key_ordered(node.right, rkeys)
             right_chain.append(
                 lambda ctx: DynamicFilterOperator(
-                    bridge_of(ctx), rkeys, reverse=True, key_fill=key_fill
+                    bridge_of(ctx), rkeys, reverse=True, key_fill=key_fill,
+                    key_ordered=key_ordered,
                 )
             )
         right_chain.append(
