@@ -402,6 +402,42 @@ def test_flag_build_rows_first_candidates_compile_for_v5e(one_chip):
     assert "scatter" in table.as_text()
 
 
+def test_mark_build_rows_compiles_for_v5e(one_chip):
+    """`_mark_build_rows` (PR 43: a LEFT join that builds the side it
+    preserves): a probe batch of 2^20 orders' pairs into the flags of
+    Q13's 1.5 M customers (2^21 slots), one scatter; and the fanout-one
+    expansion that hands it `bi` and `ok`, the orders' three columns
+    passed through and `c_custkey` gathered beside them."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+    from trino_tpu.ops.join import build_lookup
+
+    def column(t, n):
+        return Column(t, _sds((n,), jnp.int32 if t.is_string else jnp.int64, one_chip),
+                      None, None)
+
+    n, slots = BATCH, 1 << 21
+    compiled = O._mark_build_rows.lower(
+        _sds((slots,), jnp.bool_, one_chip), _sds((n,), jnp.int32, one_chip),
+        _sds((n,), jnp.bool_, one_chip)).compile()
+    assert "scatter" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    probe = RelBatch([column(T.BIGINT, n), column(T.BIGINT, n), column(T.VARCHAR, n)],
+                     _sds((n,), jnp.bool_, one_chip))
+    build = RelBatch([column(T.BIGINT, slots)], _sds((slots,), jnp.bool_, one_chip))
+    lowered = build_lookup.lower(
+        (_sds((slots,), jnp.int64, one_chip),), (_sds((slots,), jnp.bool_, one_chip),),
+        _sds((slots,), jnp.bool_, one_chip), exact_keys=True)
+    ls = jax.tree_util.tree_map(lambda x: _sds(x.shape, x.dtype, one_chip), lowered.out_info)
+    run = _sds((n,), jnp.int32, one_chip)
+    pairs = O._expand_pairs_fanout1.lower(
+        ls, probe, build, (_sds((n,), jnp.int64, one_chip),),
+        (_sds((n,), jnp.bool_, one_chip),), run, run, pkc=(1,), bkc=(0,),
+    ).compile()
+    assert "gather" in pairs.as_text()
+
+
 def test_distributed_groupby_step_compiles_for_four_v5e(topo):
     """The partial -> all_to_all -> final aggregation step as one SPMD
     program over the four described chips."""

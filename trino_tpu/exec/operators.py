@@ -1728,10 +1728,10 @@ class HashAggregationOperator(Operator):
     ranges (a scan of a table clustered on the key), by laying them end
     to end. A batch that arrives in key order skips its key sort too.
     Both are read off the data on the device, and METRICS
-    `agg_ordered_input.batches` (of `agg_ingest_path.sort`) and
-    `agg_ordered_merge.launches` (of `agg_merge_launches`) count how
-    often. Output schema = [group keys..., aggregate results...]; group
-    rows come out dense.
+    `agg_ordered_input.batches` (of `agg_ingest_path.sort`; the others
+    `agg_unordered_input.batches`) and `agg_ordered_merge.launches` (of
+    `agg_merge_launches`) count how often. Output schema = [group
+    keys..., aggregate results...]; group rows come out dense.
 
     Launches: one per batch on the sort, global, holistic and `final`
     paths, whose batches may overflow a table or need the raw rows.
@@ -1792,8 +1792,9 @@ class HashAggregationOperator(Operator):
         # merges launched / retried under _state_lock, not yet in METRICS
         self._merges = [0, 0]
         # likewise the batches and the merges whose reduce found its
-        # input in key order already (G.flag_word)
-        self._ordered = [0, 0]
+        # input in key order already (G.flag_word), and the batches
+        # whose reduce did not (they paid their key sort)
+        self._ordered = [0, 0, 0]
         # a state ingested off the wire (_add_state_input) may carry
         # DUPLICATE group keys within one batch (a spooled-stage replay
         # concatenates several producer pages into one values batch), so
@@ -2083,11 +2084,12 @@ class HashAggregationOperator(Operator):
         with self._state_lock:
             n, self._launched = self._launched, 0
             (merges, retries), self._merges = self._merges, [0, 0]
-            (batches, laid), self._ordered = self._ordered, [0, 0]
+            (batches, laid, unordered), self._ordered = self._ordered, [0, 0, 0]
         for name, moved in (("agg_ingest_launches", n),
                             ("agg_merge_launches", merges),
                             ("agg_merge_retries", retries),
                             ("agg_ordered_input.batches", batches),
+                            ("agg_unordered_input.batches", unordered),
                             ("agg_ordered_merge.launches", laid)):
             if moved:
                 METRICS.increment(name, moved)
@@ -2103,7 +2105,7 @@ class HashAggregationOperator(Operator):
         while True:
             overflowed, ordered = _flag_word("agg.ingest_overflow", ovf)
             if not overflowed:
-                self._ordered[0] += ordered
+                self._ordered[0 if ordered else 2] += 1
                 break
             cap = max(cap * 2, bucket_capacity(int(ngroups)))
             self._cap = max(self._cap, cap)
@@ -3493,6 +3495,39 @@ def _right_unmatched(probe_schema, build: RelBatch, matched_b):
     )
 
 
+@jax.jit
+def _preserved_probe_rows(live, unmatched):
+    """(probe rows, those of them nothing matched) of one batch of a
+    LEFT join that preserves its probe side."""
+    return jnp.stack([jnp.sum(live.astype(jnp.int64)),
+                      jnp.sum(unmatched.astype(jnp.int64))])
+
+
+@jax.jit
+def _mark_build_rows(flags, bi, ok):
+    """`flags` (a build slot: some pair held) with one probe batch's
+    pairs added: the build rows `bi` of the pairs that are `ok`. The
+    one program a LEFT join that builds the side it preserves runs a
+    batch beyond an inner join's."""
+    return J.build_matched_flags(flags.shape[0], bi, ok, prior=flags)
+
+
+def _unmatched_build_rows(probe_schema, build: RelBatch, flags, unmatched: int):
+    """The build rows no pair flagged, build columns first, NULLs for
+    the probe's: the rows a LEFT join that built its preserved side
+    owes at its input's end. Packed to the power of two that holds them
+    where that is at most half the build side's slots (what follows
+    then runs at their size, not the lookup's)."""
+    from trino_tpu.block import null_column
+
+    rows = build.mask(~flags)
+    target = max(bucket_capacity(unmatched), 16)
+    if target * 2 <= rows.capacity and _sortable(rows):
+        rows = _pack_rows(rows, target)
+    nulls = [null_column(t, rows.capacity, d) for t, d in probe_schema]
+    return RelBatch(list(rows.columns) + nulls, rows.live_mask())
+
+
 def make_residual_fn(residual: Bound):
     """Plan-time compiled residual evaluator over pair batches."""
 
@@ -3521,13 +3556,26 @@ class LookupJoinOperator(Operator):
     is what makes filtered semi/anti joins (Q21-style `l2.suppkey <>
     l1.suppkey`) correct.
 
-    `build_preserved` (semi/anti; the plan's `JoinNode.build_left`): the
-    side the join preserves is the BUILD and the filtering side probes.
-    Nothing leaves while batches arrive: each adds its pairs to a flag a
-    build row (`_flag_build_rows`, the residual on the pairs first), and
-    at finish the flagged build rows (semi) or the others (anti) go out
-    as one batch. `residual_fn` is then typed build side first, and
-    `unread` names the pair channels (probe first) it does not read.
+    `build_preserved` (semi/anti/left; the plan's `JoinNode.build_left`):
+    the side the join preserves is the BUILD and the other side probes.
+    Of a semi- or anti-join nothing leaves while batches arrive: each
+    adds its pairs to a flag a build row (`_flag_build_rows`, the
+    residual on the pairs first), and at finish the flagged build rows
+    (semi) or the others (anti) go out as one batch. `residual_fn` is
+    then typed build side first, and `unread` names the pair channels
+    (probe first) it does not read. A LEFT join's batches are expanded
+    as an inner join's are (the fanout-one form where no probe row has
+    two candidates) and leave as they come, BUILD columns first (the
+    plan's left), each adding its pairs to the build rows' flags
+    (`_mark_build_rows`); at finish, and at the end of every grace
+    partition, the build rows no pair flagged go out once with NULLs for
+    the probe's columns (`_unmatched_build_rows`). METRICS
+    `join_outer_side.build` / `.probe` count the LEFT and FULL joins by
+    the side they preserved (a FULL join preserves both and counts
+    under `.probe`), `join_outer_build_rows` / `join_outer_unmatched_rows`
+    what the span `sync.join.outer_flags` read back at a LEFT join's
+    finish, whichever side it built (the live build rows, and the
+    preserved side's rows that went out with NULLs).
     METRICS `join_semi_side.source` / `.filtering` count the semi- and
     anti-joins by the side they built, `join_expand_launches.first` /
     `.general` / `.fanout1` every expansion by its form, and
@@ -3552,11 +3600,17 @@ class LookupJoinOperator(Operator):
         unread: Sequence[int] = (),
         build_preserved: bool = False,
     ):
-        if build_preserved and join_type not in ("semi", "anti"):
-            raise ValueError("build_preserved is a semi- or anti-join's")
-        self._build_preserved = build_preserved
-        if build_preserved:
+        if build_preserved and join_type not in ("semi", "anti", "left"):
+            raise ValueError("build_preserved is a semi-, anti- or left join's")
+        # a semi- or anti-join that flags its build rows and puts out
+        # nothing else / a LEFT join that puts out pairs and owes the
+        # unflagged build rows
+        self._build_preserved = build_preserved and join_type != "left"
+        self._outer_build = build_preserved and join_type == "left"
+        if self._build_preserved:
             self.span_stats = {"preserved": 1}
+        elif join_type in ("left", "full"):
+            self.span_stats = {"outer": 1}
         self._bridge = bridge
         self._keys = list(key_channels)
         self._type = join_type
@@ -3572,7 +3626,7 @@ class LookupJoinOperator(Operator):
         # as zeros, and its expansion gathers nothing for them
         self._unread = (
             tuple(unread)
-            if build_preserved
+            if self._build_preserved
             or join_type == "inner" and self._residual_fn is None else ()
         )
         # build_preserved: (pairs the residual saw, pairs it kept), on
@@ -3586,6 +3640,9 @@ class LookupJoinOperator(Operator):
         # FULL outer: build-side matched bitmap accumulated across probe
         # batches; unmatched build rows emit at finish (LookupOuter)
         self._build_matched = None
+        # LEFT outer, probe side preserved: (probe rows, those that went
+        # out with NULLs) so far, on the device until finish
+        self._probe_unmatched = None
         # Pipelined expansion (the per-batch `int(total)` host read
         # is a synchronisation point that drains the device): batch i's
         # match total starts copying to the host the moment its count
@@ -3710,10 +3767,7 @@ class LookupJoinOperator(Operator):
                 rec["lo"], rec["counts"], pkc=pkc, bkc=bkc,
                 unread=self._unread,
             )
-            if self._residual_fn is not None:
-                ok = ok & self._residual_fn(pairs)
-                pairs = RelBatch(pairs.columns, ok)
-            matched = ok
+            fanout1 = True
         else:
             out_cap = bucket_capacity(max(total, 1))
             if dense:
@@ -3731,11 +3785,25 @@ class LookupJoinOperator(Operator):
                 rec["lo"], rec["counts"], out_cap, pkc=pkc, bkc=bkc,
                 unread=self._unread,
             )
-            if self._residual_fn is not None:
-                ok = ok & self._residual_fn(pairs)
-                pairs = RelBatch(pairs.columns, ok)
-            matched = None
+            fanout1 = False
+        if self._outer_build:
+            # (the plan's left is the build: its columns first; a list
+            # reordered on the host, no program)
+            cols = list(pairs.columns)
+            n = len(probe.columns)
+            pairs = RelBatch(cols[n:] + cols[:n], pairs.live)
+        if self._residual_fn is not None:
+            ok = ok & self._residual_fn(pairs)
+            pairs = RelBatch(pairs.columns, ok)
+        # (a fanout-one batch's pairs are its probe rows)
+        matched = ok if fanout1 else None
         if self._type == "inner":
+            self._outputs.append(pairs)
+            return
+        if self._outer_build:
+            if self._build_matched is None:
+                self._build_matched = jnp.zeros(build.capacity, dtype=jnp.bool_)
+            self._build_matched = _mark_build_rows(self._build_matched, bi, ok)
             self._outputs.append(pairs)
             return
         if matched is None:
@@ -3782,7 +3850,12 @@ class LookupJoinOperator(Operator):
             return
         if self._type == "left":
             self._outputs.append(pairs)
-            self._outputs.append(_left_unmatched(probe, build, matched))
+            unmatched = _left_unmatched(probe, build, matched)
+            self._outputs.append(unmatched)
+            n = _preserved_probe_rows(probe.live_mask(), unmatched.live)
+            self._probe_unmatched = (
+                n if self._probe_unmatched is None else self._probe_unmatched + n
+            )
             return
         if self._type == "full":
             self._outputs.append(pairs)
@@ -3792,6 +3865,37 @@ class LookupJoinOperator(Operator):
             )
             return
         raise NotImplementedError(self._type)
+
+    def _emit_unmatched(self, build: RelBatch) -> None:
+        """A LEFT join, the input's end (or a grace partition's). One
+        that built its preserved side: the build rows no pair flagged,
+        once. Either way what the join came to is read back and counted:
+        the live build rows and the preserved rows that went out with
+        NULLs (the build's here, else the probe's, which left with their
+        batches)."""
+        flags = self._build_matched
+        if flags is None:
+            flags = jnp.zeros(build.capacity, dtype=jnp.bool_)
+        probe = self._probe_unmatched
+        if probe is None:
+            probe = np.zeros(2, dtype=np.int64)
+        with host_sync("join.outer_flags", 32) as span:
+            (rows, flagged), (preserved, unmatched) = (
+                [int(x) for x in v]
+                for v in jax.device_get((_flagged_rows(build, flags), probe))
+            )
+            if self._outer_build:
+                preserved, unmatched = rows, rows - flagged
+            span.set_metadata(build_rows=rows, unmatched=unmatched,
+                              preserved_rows=preserved, build_slots=build.capacity,
+                              preserved="build" if self._outer_build else "probe")
+        METRICS.increment("join_outer_build_rows", rows)
+        METRICS.increment("join_outer_unmatched_rows", unmatched)
+        if self._outer_build and unmatched:
+            self._outputs.append(_unmatched_build_rows(
+                self._probe_schema, build, flags, unmatched
+            ))
+        self._build_matched = self._probe_unmatched = None
 
     def _flag_oldest(self, rec: dict, pkc, bkc) -> None:
         """build_preserved: the oldest pending batch's pairs into the
@@ -3865,10 +3969,16 @@ class LookupJoinOperator(Operator):
             METRICS.increment("join_semi_side." + (
                 "source" if self._build_preserved else "filtering"
             ))
+        elif self._type in ("left", "full"):
+            METRICS.increment("join_outer_side." + (
+                "build" if self._outer_build else "probe"
+            ))
         self._resolve_spec()
         if self._bridge.grace is None:
             if self._build_preserved:
                 self._emit_preserved(self._bridge.build_batch)
+            if self._type == "left":
+                self._emit_unmatched(self._bridge.build_batch)
             if self._type == "full":
                 build = self._bridge.build_batch
                 mb = (
@@ -3893,7 +4003,7 @@ class LookupJoinOperator(Operator):
             )
             if not probe_pages and self._type != "full" and not (
                 self._build_preserved and self._type == "anti"
-            ):
+            ) and not self._outer_build:
                 continue  # before touching the build spill: no probe rows
             build_pages = grace.partition_pages(p)
             parts = tuple(
@@ -3916,6 +4026,8 @@ class LookupJoinOperator(Operator):
             self._resolve_spec()
             if self._build_preserved:
                 self._emit_preserved(merged)
+            if self._type == "left":
+                self._emit_unmatched(merged)
             if self._type == "full":
                 mb = (
                     self._build_matched

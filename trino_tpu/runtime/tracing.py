@@ -53,8 +53,9 @@ device's events are on. Every event is named ``tpusql.<kind>.<name>``:
   ``op.<OperatorClass>.<get_output|add_input|finish>`` around every
   operator call of ``exec/driver.Driver.run`` (with the operator's
   ``span_stats`` where it has any: ``preserved`` 1 on a semi- or
-  anti-join that built the side it preserves, ``reverse`` 1 on the
-  dynamic filter in front of its probe);
+  anti-join that built the side it preserves, ``outer`` 1 on a LEFT or
+  FULL join whichever side it built, ``reverse`` 1 on the dynamic
+  filter in front of the probe of a join that built its preserved side);
   ``sync.<site>`` (``host_sync``, stat ``nbytes``) around every
   device-to-host readback; ``df.prepare`` around a
   ``DynamicFilterOperator``'s choice of its filter (stats ``path``:
@@ -167,7 +168,20 @@ a batch no row of which has two). Read back once at such a join's finish
 and added as counters too: ``semi_pairs_seen``, ``semi_pairs_kept``,
 ``semi_build_rows``, ``semi_build_flagged``; and ``df_reverse_rows_in``
 / ``df_reverse_rows_kept`` beside ``df_rows_in`` / ``df_rows_kept`` for
-the filter in front of its probe.
+the filter in front of its probe. Outer joins: one increment an operator
+(its finish), ``join_outer_side.build`` / ``.probe``, the side a LEFT
+join preserved (the lookup, as the plan's ``build_left`` says, or the
+batches that probe it; a FULL join counts under ``.probe``); read back
+once at a LEFT join's finish (span ``sync.join.outer_flags``, stats
+``build_rows``, ``unmatched``, ``preserved_rows``, ``build_slots``,
+``preserved``: build or probe) and added as counters: ``join_outer_build_rows``, the live build
+rows, and ``join_outer_unmatched_rows``, the preserved side's rows that
+went out with NULLs (build rows no pair flagged, or probe rows nothing
+matched). Of the sort path's batches
+(``agg_ingest_path.sort``), ``agg_ordered_input.batches`` counts those
+whose reduce found them in key order and skipped the key sort,
+``agg_unordered_input.batches`` the others (both as the batch's flag is
+read, one batch late).
 """
 
 from __future__ import annotations

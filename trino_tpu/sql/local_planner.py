@@ -782,8 +782,8 @@ class LocalPlanner:
             self.pipelines.append(build_chain)
             probe_chain.append(lambda ctx: CrossJoinOperator(bridge_of(ctx)))
             return probe_chain, probe_schema + build_schema
-        if node.kind in ("semi", "anti") and node.build_left:
-            return self._semi_join_built_left(
+        if node.kind in ("semi", "anti", "left") and node.build_left:
+            return self._join_built_left(
                 node, probe_chain, probe_schema, build_chain, build_schema,
                 bridge_of,
             )
@@ -923,16 +923,18 @@ class LocalPlanner:
         )
         return side.columns[ch] in stats.ordered
 
-    def _semi_join_built_left(self, node: P.JoinNode, left_chain, left_schema,
-                              right_chain, right_schema, bridge_of):
-        """A semi- or anti-join whose PRESERVED side is the lookup
+    def _join_built_left(self, node: P.JoinNode, left_chain, left_schema,
+                         right_chain, right_schema, bridge_of):
+        """A semi-, anti- or LEFT join whose PRESERVED side is the lookup
         (`JoinNode.build_left`): the left's pipeline ends in the build,
-        the filtering side's scan is filtered by the left's keys (a
-        filtering row whose key no left row has decides nothing, for
-        EXISTS and NOT EXISTS alike) and probes, and the join puts out
-        the left rows some pair flagged (semi) or none did (anti) when
-        its input ends. Of the pairs only what the residual reads is
-        gathered."""
+        the other side's scan is filtered by the left's keys (a row
+        whose key no left row has decides nothing, for EXISTS and NOT
+        EXISTS alike, and pairs with nothing under a LEFT join) and
+        probes. A semi- or anti-join puts out the left rows some pair
+        flagged (semi) or none did (anti) when its input ends, and of
+        the pairs only what the residual reads is gathered; a LEFT join
+        puts out its pairs as they come, left columns first, and the
+        left rows no pair flagged, with NULLs, when its input ends."""
         from trino_tpu.exec.operators import DynamicFilterOperator
         from trino_tpu.sql.optimizer import expr_refs
 
@@ -951,7 +953,7 @@ class LocalPlanner:
                 c + len(right_schema) if c < width_l else c - width_l
                 for c in expr_refs(node.residual)
             )
-        unread = tuple(sorted(
+        unread = () if kind == "left" else tuple(sorted(
             frozenset(range(len(left_schema) + len(right_schema))) - read
         ))
         if self.dynamic_filtering:
@@ -969,6 +971,8 @@ class LocalPlanner:
                 residual_fn=residual_fn, unread=unread, build_preserved=True,
             )
         )
+        if kind == "left":
+            return right_chain, left_schema + right_schema
         return right_chain, left_schema
 
     def _visit_WindowNode(self, node: P.WindowNode):

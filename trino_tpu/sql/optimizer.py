@@ -451,6 +451,45 @@ class PushFilterIntoJoin(Rule):
         return out
 
 
+class PushOuterJoinConditionToNullSide(Rule):
+    """An `ON` conjunct of a LEFT join that reads the null-supplying
+    (right) side alone filters that side UNDER the join: a right row it
+    refuses pairs with no left row either way, and the left rows it
+    would have paired with come out with NULLs as they did
+    (PredicatePushDown.java's outer-join case; a RIGHT join is a LEFT
+    join by now, the analyzer swaps its sides). A conjunct that reads
+    the preserved side stays on the pairs: applied under the join it
+    would drop preserved rows. What the right side's scan then gets is
+    a filter the connector can take (TPC-H Q13: `o_comment not like
+    ...` over 15 M orders and not over 15 M pairs)."""
+
+    name = "push_outer_join_condition_to_null_side"
+
+    def apply(self, node, ctx):
+        if (not isinstance(node, P.JoinNode) or node.kind != "left"
+                or node.residual is None):
+            return None
+        width_l = len(ctx.resolve(node.left).fields)
+        under: List[ir.Expr] = []
+        keep: List[ir.Expr] = []
+        for c in split_conjuncts(node.residual):
+            refs = expr_refs(c)
+            if refs and min(refs) >= width_l and _is_deterministic(c):
+                under.append(c)
+            else:
+                keep.append(c)
+        if not under:
+            return None
+        right = P.FilterNode(
+            node.right,
+            ir.and_(*[shift_refs(c, -width_l) for c in under]),
+            ctx.resolve(node.right).fields,
+        )
+        return dataclasses.replace(
+            node, right=right, residual=ir.and_(*keep) if keep else None
+        )
+
+
 class PushPredicateIntoTableScan(Rule):
     """Filter(Scan) -> Scan' [+ residual Filter] through the connector's
     apply_filter SPI hook (rule/PushPredicateIntoTableScan.java:141 +
@@ -1098,6 +1137,7 @@ SIMPLIFICATION_RULES: Tuple[Rule, ...] = (
     PushFilterThroughProject(),
     InferTransitivePredicates(),
     PushFilterIntoJoin(),
+    PushOuterJoinConditionToNullSide(),
     LimitOverSortToTopN(),
     EvaluateEmptyJoin(),
     MergeLimits(),
@@ -2036,23 +2076,122 @@ def optimize(
         root = ReorderJoins(stats, cost).rewrite(root)
         root = it.optimize(root, stats, validator=per_rule)
         checkpoint(root, "join_reordering")
+    root = _with_aggregates_under_left_joins(root, stats)
+    checkpoint(root, "aggregates_under_left_joins")
     return _with_semi_join_sides(_with_group_key_ranges(root, stats), stats)
 
 
+# a LEFT join's null-supplying side is aggregated under the join where
+# that leaves it at most this share of its estimated rows
+_UNDER_JOIN_MAX_SHARE = 0.5
+# count(x) / sum / min / max of the null-supplying side's columns: what a
+# partial under the join and a final over it compute exactly (the partial
+# of a count is summed, with 0 for a preserved row nothing matched; the
+# others are taken again as they are, NULL where nothing matched)
+_UNDER_JOIN_FINAL = {"count": "sum", "sum": "sum", "min": "min", "max": "max"}
+
+
+def _with_aggregates_under_left_joins(
+    node: P.PlanNode, stats: StatsCalculator
+) -> P.PlanNode:
+    """Before the last two passes: an aggregation over a LEFT join (seen
+    through a projection that only picks columns) that groups by the
+    preserved side's columns and aggregates the null-supplying side's
+    alone is split in two where the estimates say the null-supplying
+    side has several rows a join key: that side is aggregated by its
+    JOIN KEYS under the join, every preserved row then meets at most one
+    row of it, and the aggregation over the join combines the partials
+    (eager aggregation; nothing is assumed of the preserved side's keys,
+    so rows that share a group are combined there as before). TPC-H Q13:
+    15 M orders are counted by `o_custkey` into 1 M rows before 1.5 M
+    customers meet them, not 15 M pairs made and then counted. Exact for
+    `count(x)`, `sum` over integers, `min` and `max`; a join with a
+    residual, any other aggregate, DISTINCT or a grouping or argument
+    that mixes the sides keeps its plan."""
+    node = with_children(
+        node, [_with_aggregates_under_left_joins(c, stats) for c in node.children()]
+    )
+    if (not isinstance(node, P.AggregateNode) or node.step != "single"
+            or not node.aggs or not node.group_channels):
+        return node
+    below = node.child
+    picks: Optional[Tuple[int, ...]] = None
+    if isinstance(below, P.ProjectNode):
+        if not all(isinstance(e, ir.InputRef) for e in below.exprs):
+            return node
+        picks, below = tuple(e.index for e in below.exprs), below.child
+    join = below
+    if (not isinstance(join, P.JoinNode) or join.kind != "left"
+            or join.residual is not None or not join.left_keys):
+        return node
+    at = (lambda c: picks[c]) if picks is not None else (lambda c: c)
+    wl = len(join.left.fields)
+    if any(at(g) >= wl for g in node.group_channels):
+        return node
+    for a in node.aggs:
+        if (a.kind not in _UNDER_JOIN_FINAL or a.distinct or a.arg_channel is None
+                or at(a.arg_channel) < wl or a.arg2_channel is not None
+                or a.arg3_channel is not None or a.post is not None
+                or a.kind == "sum" and a.out_type != T.BIGINT):
+            return node
+    rk = tuple(join.right_keys)
+    partial_fields = tuple(join.right.fields[c] for c in rk) + tuple(
+        P.Field(None, a.out_type) for a in node.aggs
+    )
+    partial = P.AggregateNode(
+        join.right, rk,
+        tuple(dataclasses.replace(a, arg_channel=at(a.arg_channel) - wl)
+              for a in node.aggs),
+        partial_fields,
+    )
+    try:
+        rows = float(stats.stats(join.right).row_count)
+        groups = float(stats.stats(partial).row_count)
+    except Exception:
+        return node
+    if not (rows == rows and groups == groups) or groups > rows * _UNDER_JOIN_MAX_SHARE:
+        return node
+    new_join = P.JoinNode(
+        "left", join.left, partial, tuple(join.left_keys),
+        tuple(range(len(rk))), None, join.left.fields + partial_fields,
+    )
+    # [group keys..., partials (a count's NULL is 0)...] for the final step
+    g = len(node.group_channels)
+    exprs: List[ir.Expr] = [
+        ir.InputRef(at(c), join.left.fields[at(c)].type) for c in node.group_channels
+    ]
+    for i, a in enumerate(node.aggs):
+        ref: ir.Expr = ir.InputRef(wl + len(rk) + i, a.out_type)
+        if a.kind == "count":
+            ref = ir.Call("coalesce", (ref, ir.Literal(0, a.out_type)), a.out_type)
+        exprs.append(ref)
+    project = P.ProjectNode(new_join, tuple(exprs), node.fields)
+    return P.AggregateNode(
+        project, tuple(range(g)),
+        tuple(P.AggCall(_UNDER_JOIN_FINAL[a.kind], g + i, a.out_type)
+              for i, a in enumerate(node.aggs)),
+        node.fields,
+    )
+
+
 def _with_semi_join_sides(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNode:
-    """With the group key ranges, the last pass: which side of a semi-
-    or anti-join is built. A filtering row whose key no row of the
-    preserved side has decides nothing, for EXISTS and NOT EXISTS
-    alike, so where the filtering side is estimated the larger the
-    preserved side is the lookup (`build_left`): its keys filter the
-    other side's scan, the filtering side probes, and a flag a build
-    row says whether any pair held (TPC-H Q21: 0.8 M late lines of one
-    nation's suppliers against lineitem's 60 M, twice). Decided from
-    the estimates alone; a join without an equality key keeps its side."""
+    """With the group key ranges, the last pass: which side of a semi-,
+    anti- or LEFT join is built. A row of the other side whose key no
+    row of the preserved side has decides nothing (EXISTS, NOT EXISTS)
+    and pairs with nothing (LEFT), so where the other side is estimated
+    the larger the preserved side is the lookup (`build_left`): its
+    keys filter the other side's scan, the other side probes, and a
+    flag a build row says whether any pair held (TPC-H Q21: 0.8 M late
+    lines of one nation's suppliers against lineitem's 60 M, twice;
+    Q13: 1.5 M customers, each key once, against 15 M orders with ten
+    or more a key, which as the lookup would send every customer
+    through the general expansion). Decided from the estimates alone; a
+    join without an equality key keeps its side."""
     node = with_children(
         node, [_with_semi_join_sides(c, stats) for c in node.children()]
     )
-    if (not isinstance(node, P.JoinNode) or node.kind not in ("semi", "anti")
+    if (not isinstance(node, P.JoinNode)
+            or node.kind not in ("semi", "anti", "left")
             or not node.left_keys):
         return node
     build_left = (

@@ -122,11 +122,14 @@ class JoinNode(PlanNode):
     concatenated left+right schema and runs inside the join, before
     match flags (JoinNode.filter analogue).
 
-    `build_left` (semi/anti only; the optimizer's last pass sets it from
-    the two sides' estimated rows): the side the join PRESERVES, the
-    left, is the one built, the filtering side probes it, and a flag a
-    build row says whether any pair held. Shown in EXPLAIN as
-    ` build=left`, only where set."""
+    `build_left` (semi, anti and left only; the optimizer's last pass
+    sets it from the two sides' estimated rows): the side the join
+    PRESERVES, the left, is the one built, the other side probes it,
+    and a flag a build row says whether any pair held: a semi- or
+    anti-join puts out the flagged rows or the others at its input's
+    end, a LEFT join its pairs as they come and the rows no pair
+    flagged, with NULLs, at the end. Shown in EXPLAIN as ` build=left`,
+    only where set."""
 
     kind: str
     left: PlanNode

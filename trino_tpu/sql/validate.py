@@ -860,10 +860,11 @@ def shape_census(
             visit(node.child, fused_into_consumer=True)
             return
         if isinstance(node, P.JoinNode):
-            # (a semi- or anti-join that builds the side it preserves is
-            # probed by the other, behind a filter of the build's keys
-            # whatever its kind: LocalPlanner._semi_join_built_left)
-            built_left = node.kind in ("semi", "anti") and node.build_left
+            # (a semi-, anti- or left join that builds the side it
+            # preserves is probed by the other, behind a filter of the
+            # build's keys whatever its kind: LocalPlanner._join_built_left)
+            built_left = (node.kind in ("semi", "anti", "left")
+                          and node.build_left)
             probe = node.right if built_left else node.left
             probe_rows = rows(probe)
             if node.kind == "cross":
