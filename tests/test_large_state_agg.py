@@ -15,10 +15,11 @@ import pytest
 
 from tests.oracle import assert_rows_match, oracle_rows
 from trino_tpu import types as T
-from trino_tpu.block import Column, RelBatch
+from trino_tpu.block import MIN_CAPACITY, Column, RelBatch
 from trino_tpu.exec import operators as O
 from trino_tpu.exec.operators import AggSpec, HashAggregationOperator
 from trino_tpu.ops.int128 import from_python, to_python
+from trino_tpu.runtime import tracing
 from trino_tpu.runtime.metrics import METRICS
 from trino_tpu.sql import plan as P
 
@@ -134,9 +135,9 @@ def test_the_last_merge_is_sized_by_the_group_counts_and_runs_once():
     seen = []
     inner = O._merge_group_states
 
-    def spy(states, reducers, out_capacity):
+    def spy(states, reducers, out_capacity, takes=None):
         seen.append((len(states), out_capacity))
-        return inner(states, reducers, out_capacity)
+        return inner(states, reducers, out_capacity, takes)
 
     try:
         O._merge_group_states = spy
@@ -155,9 +156,9 @@ def test_a_fold_takes_its_states_slots_whatever_they_hold():
     seen = []
     inner = O._merge_group_states
 
-    def spy(states, reducers, out_capacity):
+    def spy(states, reducers, out_capacity, takes=None):
         seen.append((tuple(int(s[2].shape[0]) for s in states), out_capacity))
-        return inner(states, reducers, out_capacity)
+        return inner(states, reducers, out_capacity, takes)
 
     try:
         O._merge_group_states = spy
@@ -182,9 +183,9 @@ def test_the_last_merge_of_a_folded_scan_has_one_shape_whatever_is_left(n_batche
     seen = []
     inner = O._merge_group_states
 
-    def spy(states, reducers, out_capacity):
+    def spy(states, reducers, out_capacity, takes=None):
         seen.append((tuple(int(s[2].shape[0]) for s in states), out_capacity))
-        return inner(states, reducers, out_capacity)
+        return inner(states, reducers, out_capacity, takes)
 
     try:
         O._merge_group_states = spy
@@ -196,6 +197,84 @@ def test_the_last_merge_of_a_folded_scan_has_one_shape_whatever_is_left(n_batche
     (fold_in, fold_out), (caps, out) = seen[0], seen[-1]
     assert caps == (fold_out,) * 2 + (fold_in[0],) * O.FOLD_STATES
     assert out < sum(caps)                                  # sized by the counts
+
+
+class MergeSpans:
+    """Stands where `host_span` stands: the stats of every `agg.merge`."""
+
+    def __init__(self):
+        self.merges = []
+
+    def __call__(self, name, **stats):
+        if name == "agg.merge":
+            self.merges.append(stats)
+        return tracing.OFF
+
+
+SHORT_READS = ("agg_merge_short_reads", "agg_merge_slots_spared", "agg_ordered_merge.launches")
+
+
+@pytest.mark.parametrize("in_key_order", [False, True], ids=["keys-in-no-order", "keys-in-order"])
+def test_the_last_merge_reads_a_fold_that_resorted_up_to_its_groups(in_key_order, monkeypatch):
+    """17 batches, two folds. Keys in no order: the folds re-sort, hold
+    30 groups in 2,048 slots, and the last merge reads 32 of them; the 8
+    states left are read whole. Every batch a key range of its own: the
+    folds lay their states end to end and the last merge reads them
+    whole, under today's program."""
+    rows = make_rows(17, 30, seed=3, overlap=not in_key_order)
+    seen, inner = [], O._merge_group_states
+    spans = MergeSpans()
+
+    def spy(states, reducers, out_capacity, takes=None):
+        seen.append((tuple(int(s[2].shape[0]) for s in states), out_capacity, takes))
+        return inner(states, reducers, out_capacity, takes)
+
+    monkeypatch.setattr(O, "_merge_group_states", spy)
+    monkeypatch.setattr(O, "host_span", spans)
+    before = {k: METRICS.counter(k) for k in SHORT_READS}
+    got, counts = aggregate(rows)
+    moved = {k: METRICS.counter(k) - v for k, v in before.items()}
+    assert got == want_rows(rows)
+    assert counts["agg_merge_launches"] == 3 and counts["agg_merge_retries"] == 0
+    (fold_in, fold_out, fold_takes), (caps, out, takes) = seen[0], seen[-1]
+    assert fold_takes is None and seen[1][2] is None          # a fold reads its states whole
+    assert caps == (fold_out,) * 2 + (fold_in[0],) * O.FOLD_STATES
+    assert [m["slots_in"] for m in spans.merges[:2]] == [sum(fold_in)] * 2
+    if in_key_order:
+        assert takes is None
+        assert spans.merges[2]["slots_in"] == sum(caps)
+        assert moved == {"agg_merge_short_reads": 0, "agg_merge_slots_spared": 0,
+                         "agg_ordered_merge.launches": 3}
+    else:
+        held = O.bucket_capacity(30)
+        assert takes == (held,) * 2 + (None,) * O.FOLD_STATES
+        assert spans.merges[2]["slots_in"] == 2 * held + O.FOLD_STATES * fold_in[0]
+        assert moved == {"agg_merge_short_reads": 2,
+                         "agg_merge_slots_spared": 2 * (fold_out - held),
+                         "agg_ordered_merge.launches": 0}
+
+
+def test_a_cut_that_drops_a_used_slot_is_retried_on_the_states_whole(monkeypatch):
+    """The guard against a state that is not a dense prefix of its
+    count: the last merge's cut made too short drops groups, the program
+    says so in its overflow bit, and the operator merges again, whole,
+    into the same table."""
+    rows = make_rows(17, 30, seed=3, overlap=True)
+    seen, inner = [], O._merge_group_states
+
+    def spy(states, reducers, out_capacity, takes=None):
+        if takes is not None:
+            takes = tuple(t and MIN_CAPACITY for t in takes)     # 16 slots of 30 groups
+        seen.append((out_capacity, takes))
+        return inner(states, reducers, out_capacity, takes)
+
+    monkeypatch.setattr(O, "_merge_group_states", spy)
+    got, counts = aggregate(rows)
+    assert got == want_rows(rows)
+    assert counts["agg_merge_launches"] == 4 and counts["agg_merge_retries"] == 1
+    (cap, cut), (again_cap, whole) = seen[-2:]
+    assert cut == (MIN_CAPACITY,) * 2 + (None,) * O.FOLD_STATES
+    assert whole is None and again_cap == cap
 
 
 def test_revocation_between_folds_loses_nothing():
@@ -1060,6 +1139,70 @@ def assert_same_state(got, want, states, laid):
         among = firsts[int(gk[i]) if gv[i] else None]
         assert int(w_vals[-1][i]) in among
         assert int(vals[-1][i]) in (among[:1] if laid else among)
+
+
+WIDE = 64
+
+
+def states_with_a_wide_one(rng, null_group, slot=None):
+    """A state of WIDE slots holding 20 groups at its front (21 with the
+    NULL group), as a fold that re-sorted leaves it, and two of
+    STATE_CAP slots whose keys fall among its own; `slot`: that one
+    value slot of SEAM_REDUCERS alone."""
+    key_lists = [list(range(0, 40, 2)) + ([None] if null_group else []),
+                 [7, 8, 9, 10] + ([None] if null_group else []), [1, 2, 3]]
+    states = [one_state(keys, rng, cap=WIDE if i == 0 else STATE_CAP)
+              for i, keys in enumerate(key_lists)]
+    if slot is not None:
+        states = [(k, v, used, vals[slot:slot + 1], cnts[slot:slot + 1])
+                  for k, v, used, vals, cnts in states]
+    return tuple(states), len(key_lists[0])
+
+
+@pytest.mark.parametrize("null_group", [False, True], ids=["no-null", "null-group"])
+@pytest.mark.parametrize("slot", range(len(SEAM_REDUCERS)),
+                         ids=["sum", "count", "min", "max", "first"])
+def test_a_merge_that_reads_a_state_short_equals_the_merge_that_reads_it_whole(slot, null_group):
+    """Slot for slot and flag for flag; `first` is any of the group's
+    values that counted a row (the sort is unstable, and the two sorts
+    differ in length)."""
+    rng = np.random.default_rng(44 + slot)
+    states, held = states_with_a_wide_one(rng, null_group, slot)
+    reducers = SEAM_REDUCERS[slot:slot + 1]
+    takes = (O.bucket_capacity(held), None, None)
+    assert takes[0] < WIDE
+    want, want_groups, want_word = O._merge_group_states(states, reducers, TABLE)
+    got, groups, word = O._merge_group_states(states, reducers, TABLE, takes)
+    assert int(word) == int(want_word) == 0 and int(groups) == int(want_groups) == 24 + null_group
+    (gk,), (gv,), used, (v,), (c,) = jax.device_get(got)
+    (wk,), (wv,), w_used, (w_v,), (w_c,) = jax.device_get(want)
+    for a, b in ((gk, wk), (gv, wv), (used, w_used), (c, w_c)):
+        np.testing.assert_array_equal(a, b)
+    if reducers != ("first",):
+        np.testing.assert_array_equal(v, w_v)
+        return
+    firsts = collections.defaultdict(list)
+    for (k,), (kv,), s_used, (s_v,), (s_c,) in jax.device_get(states):
+        for i in np.nonzero(s_used & (s_c > 0))[0]:
+            firsts[int(k[i]) if kv[i] else None].append(int(s_v[i]))
+    for i in np.nonzero(used & (c > 0))[0]:
+        assert int(v[i]) in firsts[int(gk[i]) if gv[i] else None]
+
+
+def test_a_used_slot_behind_a_cut_raises_the_overflow_bit():
+    """Single key (the flag is a word, and keeps which way the merge
+    went) and several keys (a plain flag); a cut behind every used slot
+    raises nothing."""
+    rng = np.random.default_rng(44)
+    states, held = states_with_a_wide_one(rng, null_group=False)
+    for takes, dropped in (((held, None, None), False), ((held - 1, None, None), True),
+                           ((None, None, 2), True), ((None, 4, 3), False)):
+        _, _, word = O._merge_group_states(states, SEAM_REDUCERS, TABLE, takes)
+        assert bool(int(word) & 1) == dropped and not int(word) & O.G.ORDERED
+    two_keys = tuple((k * 2, v * 2, *rest) for k, v, *rest in states)
+    for takes, dropped in (((held, None, None), False), ((held - 1, None, None), True)):
+        _, _, flag = O._merge_group_states(two_keys, SEAM_REDUCERS, TABLE, takes)
+        assert flag.dtype == jnp.bool_ and bool(flag) == dropped
 
 
 @pytest.mark.parametrize("name", ["ranges_of_their_own", "two_states_swapped"])

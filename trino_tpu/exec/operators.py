@@ -1405,20 +1405,8 @@ _MERGE_COMPILER_OPTIONS = tpu_compiler_options(
 )
 
 
-@partial(jax.jit, static_argnames=("reducers", "out_capacity"),
-         compiler_options=_MERGE_COMPILER_OPTIONS)
-def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
-    """N (keys, valids, used, vals, cnts) group-state sets merged into
-    one — the whole N-way merge is ONE device program (per-batch
-    pairwise merges would cost a program launch each). States come out
-    of the sort path dense and ascending in their key, and a scan of a
-    table clustered on that key hands them over in ascending ranges
-    that meet in at most one group a seam: single-key states are looked
-    at first (_states_ascend), and where that is what they are, laid
-    end to end (_lay_end_to_end); otherwise, and for several keys
-    (hash order) always, concatenated and group-reduced again
-    (_resort_states). The flag of a single-key merge is a G.flag_word:
-    overflow, and which of the two it did."""
+def _merge_read_states(states: tuple, reducers: tuple, out_capacity: int):
+    """_merge_group_states' body over the states as they are read."""
     if len(states[0][0]) != 1:
         return _resort_states(states, reducers, out_capacity)
     ordered, counts, seams = _states_ascend(states)
@@ -1432,6 +1420,41 @@ def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int):
         lambda: _resort_states(states, reducers, out_capacity),
     )
     return merged, ngroups, G.flag_word(ovf, ordered)
+
+
+@partial(jax.jit, static_argnames=("reducers", "out_capacity", "takes"),
+         compiler_options=_MERGE_COMPILER_OPTIONS)
+def _merge_group_states(states: tuple, reducers: tuple, out_capacity: int,
+                        takes: Optional[tuple] = None):
+    """N (keys, valids, used, vals, cnts) group-state sets merged into
+    one — the whole N-way merge is ONE device program (per-batch
+    pairwise merges would cost a program launch each). States come out
+    of the sort path dense and ascending in their key, and a scan of a
+    table clustered on that key hands them over in ascending ranges
+    that meet in at most one group a seam: single-key states are looked
+    at first (_states_ascend), and where that is what they are, laid
+    end to end (_lay_end_to_end); otherwise, and for several keys
+    (hash order) always, concatenated and group-reduced again
+    (_resort_states). The flag of a single-key merge is a G.flag_word:
+    overflow, and which of the two it did.
+
+    `takes`, one entry a state: the slots of it to read, from slot 0
+    (None: all of them). The caller knows the state dense from slot 0
+    and how many groups it holds, so what lies behind is empty and is
+    kept out of the sorts. A used slot behind a cut raises the overflow
+    bit: the caller then merges the states whole."""
+    if takes is None:
+        return _merge_read_states(states, reducers, out_capacity)
+    dropped = jnp.bool_(False)
+    read = []
+    for state, take in zip(states, takes):
+        if take is not None:
+            dropped |= jnp.any(state[2][take:])
+            state = jax.tree_util.tree_map(lambda a: a[:take], state)
+        read.append(state)
+    merged, ngroups, flag = _merge_read_states(
+        tuple(read), reducers, out_capacity)
+    return merged, ngroups, flag | dropped
 
 
 @partial(jax.jit, static_argnames=("capacity",))
@@ -1558,7 +1581,16 @@ RANGE_KEY_KINDS = frozenset((
 # whatever the scan's length, and costs one more pass over the states
 # per tier (a copy where their key ranges ascend, a sort where they do
 # not: _merge_group_states): 58 batches are 7 folds and one last merge
-# of 7 folded states and 8 of what is left.
+# of 7 folded states and 8 of what is left. A fold hands on its state,
+# as wide as its operands' slots together and dense from slot 0, the
+# group count it left on the device, and which of the two it did. The
+# last merge, which reads the counts to size its table, reads a state
+# that a fold RE-SORTED only up to the power of two that holds its
+# groups: such states will be sorted again, slot for slot, and what
+# lies behind their groups is empty. A fold that laid its states end to
+# end is read whole: it will be laid end to end again, a copy, and a
+# read that followed its count would give the merge a shape of its own
+# for every count near a power of two.
 FOLD_STATES = 8
 
 # Batches one launch of _agg_ingest_train takes. A launch costs the host
@@ -1730,8 +1762,11 @@ class HashAggregationOperator(Operator):
     Both are read off the data on the device, and METRICS
     `agg_ordered_input.batches` (of `agg_ingest_path.sort`; the others
     `agg_unordered_input.batches`) and `agg_ordered_merge.launches` (of
-    `agg_merge_launches`) count how often. Output schema = [group
-    keys..., aggregate results...]; group rows come out dense.
+    `agg_merge_launches`) count how often; `agg_merge_short_reads` and
+    `agg_merge_slots_spared` count the states a last merge read only up
+    to their groups and the slots it left unread (FOLD_STATES). Output
+    schema = [group keys..., aggregate results...]; group rows come out
+    dense.
 
     Launches: one per batch on the sort, global, holistic and `final`
     paths, whose batches may overflow a table or need the raw rows.
@@ -1786,11 +1821,13 @@ class HashAggregationOperator(Operator):
         # None where only the state's capacity does (trains, wire input)
         self._pending_groups: List = []
         self._acc_groups = None
-        # sort path: tier t holds (state, group count) pairs that t + 1
-        # folds of FOLD_STATES states produced (_fold_settled_locked)
+        # sort path: tier t holds (state, group count, whether the fold
+        # re-sorted) triples that t + 1 folds of FOLD_STATES states
+        # produced (_fold_settled_locked)
         self._folded: List[List[tuple]] = []
-        # merges launched / retried under _state_lock, not yet in METRICS
-        self._merges = [0, 0]
+        # merges launched / retried, states read short and the slots
+        # that spared, under _state_lock, not yet in METRICS
+        self._merges = [0, 0, 0, 0]
         # likewise the batches and the merges whose reduce found its
         # input in key order already (G.flag_word), and the batches
         # whose reduce did not (they paid their key sort)
@@ -2083,11 +2120,14 @@ class HashAggregationOperator(Operator):
         _state_lock: METRICS takes a lock of its own, so outside it."""
         with self._state_lock:
             n, self._launched = self._launched, 0
-            (merges, retries), self._merges = self._merges, [0, 0]
+            (merges, retries, short, spared), self._merges = (
+                self._merges, [0, 0, 0, 0])
             (batches, laid, unordered), self._ordered = self._ordered, [0, 0, 0]
         for name, moved in (("agg_ingest_launches", n),
                             ("agg_merge_launches", merges),
                             ("agg_merge_retries", retries),
+                            ("agg_merge_short_reads", short),
+                            ("agg_merge_slots_spared", spared),
                             ("agg_ordered_input.batches", batches),
                             ("agg_unordered_input.batches", unordered),
                             ("agg_ordered_merge.launches", laid)):
@@ -2148,21 +2188,30 @@ class HashAggregationOperator(Operator):
             if len(self._folded[tier]) < FOLD_STATES:
                 return
             full, self._folded[tier] = self._folded[tier], []
-            states = _common_capacity([s for s, _ in full])
+            states = _common_capacity([s for s, _, _ in full])
             tier += 1
 
-    def _merge_states_locked(self, states: list, groups=None):
+    def _merge_states_locked(self, states: list, groups=None, resorted=None):
         """ONE device program merges `states` into one; returns (state,
-        its group count on the device). The table is sized from what
-        the states can hold between them, so the merge does not
-        overflow on a count and runs once: a fold (`groups` None) takes
-        the states' slots (its shape then follows from theirs alone,
-        and one program serves every fold of a scan); the last merge
-        takes the group counts the launches left (`groups`, one a
-        state; None: the state's capacity), which is what the output is
-        sized by. The flag still covers sort_group_reduce's
-        hash-collision detector, whose retry doubles the table to
-        reseed."""
+        its group count on the device, whether the merge re-sorted: read
+        off its flag word, False where that is not read). The table is
+        sized from what the states can hold between them, so the merge
+        does not overflow on a count and runs once: a fold (`groups`
+        None) takes the states' slots (its shape then follows from
+        theirs alone, and one program serves every fold of a scan); the
+        last merge takes the group counts the launches left (`groups`,
+        one a state; None: the state's capacity), which is what the
+        output is sized by. With the counts in hand it also reads a
+        state that a fold re-sorted (`resorted`, one a state) only up to
+        the power of two that holds its count (`takes` of
+        _merge_group_states): the sort path leaves a state dense from
+        slot 0, a fold as wide as all its operands, and this merge would
+        sort the empty slots again. No other state is read short: an
+        ingest's count sits near its capacity, and a fold that laid its
+        states end to end is copied, not sorted (FOLD_STATES). The flag
+        still covers sort_group_reduce's hash-collision detector, whose
+        retry doubles the table to reseed, and a used slot behind a cut,
+        whose retry reads the states whole."""
         reducers = []
         for i, x in enumerate(self._aggs):
             reducers.extend(_slot_merge_reducers(x, self._arg_meta[i][0]))
@@ -2172,6 +2221,7 @@ class HashAggregationOperator(Operator):
         # arrays by the data, not by a possibly-overgrown _cap)
         caps = [int(s[2].shape[0]) for s in states]
         concat_len = sum(caps)
+        takes = None
         if self._static_bound is not None:
             cap = min(
                 max(self._cap, 16), bucket_capacity(max(concat_len, 16))
@@ -2189,30 +2239,47 @@ class HashAggregationOperator(Operator):
                 )
                 span.set_metadata(groups=bound)
             cap = bucket_capacity(max(bound, 16))
+            holds = [bucket_capacity(int(n)) for n in counts]
+            takes = tuple(
+                h if cut and h < c else None
+                for h, c, cut in zip(holds, caps, resorted)
+            )
+            if takes.count(None) == len(takes):
+                takes = None
         retry = 0
+        re_sorted = False
         while True:
+            slots_in = concat_len if takes is None else sum(
+                t or c for t, c in zip(takes, caps))
             # the span ends once the flag is read (`sync.agg.
             # merge_overflow`, inside it): which way the merge went is
             # known no sooner
             with host_span("agg.merge", states=len(states),
-                           slots_in=concat_len, cap=cap, retry=retry) as span:
+                           slots_in=slots_in, cap=cap, retry=retry) as span:
                 merged, ngroups, ovf = _merge_group_states(
-                    tuple(states), reducers, cap
+                    tuple(states), reducers, cap, takes
                 )
                 self._merges[0] += 1
+                if takes is not None:
+                    self._merges[2] += len(takes) - takes.count(None)
+                    self._merges[3] += concat_len - slots_in
                 if self._static_bound is not None:
                     self._deferred_ovf.append(ovf)
                     break
                 overflowed, ordered = _flag_word("agg.merge_overflow", ovf)
                 span.set_metadata(ordered=int(ordered))
             self._ordered[1] += ordered
+            re_sorted = not ordered
             if not overflowed:
                 break
             retry += 1
             self._merges[1] += 1
+            if takes is not None:
+                takes = None
+                continue
             cap = max(cap * 2, bucket_capacity(int(ngroups)))
             self._cap = max(self._cap, cap)
-        return merged, ngroups
+        return merged, ngroups, re_sorted
 
     def _merge_pending_locked(self) -> None:
         """Fold the current acc, the folded tiers and _pending into ONE
@@ -2222,10 +2289,12 @@ class HashAggregationOperator(Operator):
         self._resolve_pending_locked()
         states = [] if self._acc is None else [self._acc]
         groups = [] if self._acc is None else [self._acc_groups]
+        resorted = [False] * len(states)
         for tier in reversed(self._folded):  # oldest rows first
             if tier:
-                states.extend(_common_capacity([s for s, _ in tier]))
-                groups.extend(g for _, g in tier)
+                states.extend(_common_capacity([s for s, _, _ in tier]))
+                groups.extend(g for _, g, _ in tier)
+                resorted.extend(r for _, _, r in tier)
         pending, left = self._pending, self._pending_groups
         if self._folded and pending:
             # a scan long enough to fold brings what is left to one
@@ -2239,13 +2308,15 @@ class HashAggregationOperator(Operator):
             left = left + [0] * spare
         states.extend(pending)
         groups.extend(left)
+        resorted.extend([False] * len(pending))
         self._pending, self._pending_groups, self._folded = [], [], []
         if not states:
             return
         if len(states) == 1 and not self._unreduced_state:
             self._acc, self._acc_groups = states[0], groups[0]
             return
-        self._acc, self._acc_groups = self._merge_states_locked(states, groups)
+        self._acc, self._acc_groups, _ = self._merge_states_locked(
+            states, groups, resorted)
         self._unreduced_state = False
 
     # -- final step: consume serialized accumulator state --
@@ -2764,7 +2835,7 @@ class HashAggregationOperator(Operator):
         from trino_tpu.runtime.memory import batch_bytes
 
         total = 0
-        folded = [st for tier in list(self._folded) for st, _ in tier]
+        folded = [st for tier in list(self._folded) for st, _, _ in tier]
         for st in ([self._acc] if self._acc is not None else []) \
                 + folded + list(self._pending):
             gk, gv, used, vals, cnts = st
