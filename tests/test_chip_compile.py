@@ -170,6 +170,37 @@ def test_agg_ingest_train_compiles_for_v5e(one_chip, path, monkeypatch):
     assert "s32[24,1048576]" not in text and "s32[8,1048576]" not in text
 
 
+def test_slot_count_train_compiles_for_v5e(one_chip):
+    """Q13's count under its join at SF10 (issue 45): eight batches of
+    2^20 orders, `count(o_orderkey)` by `o_custkey` counted from 1 into
+    1,500,000 + 1 slots of a 2^21-slot table, one scatter-add a batch
+    inside the train's loop, and the two tables' addition."""
+    from trino_tpu import types as T
+    from trino_tpu.block import Column, RelBatch
+    from trino_tpu.exec import operators as O
+
+    def batch():
+        return RelBatch([Column(T.BIGINT, _sds((BATCH,), jnp.int64, one_chip)),
+                         Column(T.BIGINT, _sds((BATCH,), jnp.int64, one_chip))],
+                        _sds((BATCH,), jnp.bool_, one_chip))
+
+    aggs = (O.AggSpec("count", 0, T.BIGINT),)
+    cap = 1 << 21
+    lowered = O._agg_ingest_train.lower(
+        tuple(batch() for _ in range(O.TRAIN_BATCHES)), _sds((), jnp.int32, one_chip),
+        (1,), aggs, cap, None, None, None, (1,), (1_500_000,))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "while" in text and "scatter" in text and "sort(" not in text
+    table = ((_sds((cap,), jnp.int64, one_chip),), (_sds((cap,), jnp.bool_, one_chip),),
+             _sds((cap,), jnp.bool_, one_chip), (_sds((cap,), jnp.int64, one_chip),),
+             (_sds((cap,), jnp.int64, one_chip),))
+    added = O._add_slot_states.lower(table, table).compile()
+    assert "sort(" not in added.as_text()
+    # two states, the sum and a batch in flight fit the chip many times over
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_mxu_join_probe_page_sums_compiles_for_v5e(one_chip):
     """The MXU join-project contraction at its largest key domain. It is
     behind mxu_join_enabled=False today; a refusal here is recorded, not

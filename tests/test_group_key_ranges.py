@@ -2,7 +2,9 @@
 the group table (issue 39): the range goes from the connector's
 statistics through a closed list of projections to the AggregateNode,
 and the operator addresses its table by `value - low` on the dense and
-MXU reduces, as it does by a dictionary's codes. CPU counts and answers
+MXU reduces, as it does by a dictionary's codes; past the MXU reduce's
+2,048 slots counts alone keep the range, for the slot path (issue 45).
+CPU counts and answers
 only; what any of it costs is a chip reading (PERF.md section 6)."""
 
 import collections
@@ -36,7 +38,8 @@ Q9_TABLES = {
     "nation": ["n_nationkey", "n_name"],
 }
 COUNTERS = ("agg_ingest_batches", "agg_ingest_launches", "agg_merge_launches",
-            "agg_ingest_path.dense", "agg_ingest_path.mxu", "agg_ingest_path.sort",
+            "agg_ingest_path.dense", "agg_ingest_path.mxu", "agg_ingest_path.slot",
+            "agg_ingest_path.sort",
             "agg_key_bound.range", "agg_key_bound.dictionary", "agg_key_bound.none")
 
 
@@ -96,6 +99,13 @@ PLANNED = {
     "aggregate-below": (
         "select y, max(n) from (select extract(year from o_orderdate) y, o_shippriority p, "
         "count(*) n from orders group by 1, 2) group by y", "[(1992, 1998)]"),
+    # past the MXU reduce's 2,048 slots a COUNT keeps its range (the slot
+    # path's): some 2,400 dates
+    "wide-date": ("select o_orderdate, count(*) from orders group by 1", "[(8035, 10440)]"),
+    # three keys' digits, 32 x 13 x 8 = 3,328 slots, counted
+    "product-counted": (
+        "select extract(day from l_shipdate), month(l_shipdate), l_linenumber, count(*) "
+        "from lineitem group by 1, 2, 3", "[(1, 31), (1, 12), (1, 7)]"),
 }
 NOT_PLANNED = {
     # an estimate or a declared range never bounds a table
@@ -107,14 +117,16 @@ NOT_PLANNED = {
     "week": "select week(o_orderdate), count(*) from orders group by 1",
     "case": "select case when l_linenumber > 3 then 1 else 0 end, count(*) "
             "from lineitem group by 1",
-    # a range whose digits alone outgrow a slot-addressed table
-    "wide-date": "select o_orderdate, count(*) from orders group by 1",
+    # past 2,048 slots only counts address a table by slot: a SUM by
+    # 60,000 order keys has no range to use, a sum beside the count neither
     "wide-key": "select l_orderkey, sum(l_quantity) from lineitem group by 1",
+    "wide-date-sum": "select o_orderdate, count(*), sum(o_totalprice) from orders group by 1",
     # all or none: one key without a range leaves the node without any
     "half": "select extract(year from o_orderdate), o_orderkey % 7, count(*) "
             "from orders group by 1, 2",
-    "product": "select extract(day from l_shipdate), month(l_shipdate), l_linenumber, count(*) "
-               "from lineitem group by 1, 2, 3",
+    # 32 x 13 x 8 = 3,328 slots and a sum: over the limit its reduce has
+    "product": "select extract(day from l_shipdate), month(l_shipdate), l_linenumber, "
+               "sum(l_quantity) from lineitem group by 1, 2, 3",
     # a decimal is no integer kind
     "decimal": "select l_discount, count(*) from lineitem group by 1",
     # strings alone are the operator's own business, as before
@@ -341,8 +353,12 @@ def recorded_syncs(monkeypatch):
 
 
 UNBOUNDED = {
-    # over 2,048 slots with the NULL digits: (3 + 1) x (600 + 1)
+    # over 2,048 slots with the NULL digits, (3 + 1) x (600 + 1), and
+    # sums among the aggregates: past the MXU limit only counts keep a range
     "product-over-2048": (SUMS, (None, (1900, 2499)), {}),
+    # a SUM by a key of 60,000 values (the plan hands such a node no
+    # range; an operator handed one drops it)
+    "wide-key": (SUMS[2:3], ((-30000, 29999),), {"groups": (3,)}),
     # the chooser answers `sort`: a minimum is no MXU sum, and (3 + 1) x
     # (30 + 1) = 124 slots are more than the dense reduce takes
     "chooser-says-sort": (EXTREMES, (None, (1990, 2019)), {}),
@@ -366,11 +382,46 @@ def test_a_range_that_cannot_bound_the_table_leaves_the_operator_as_it_was(name,
     (plain, want), plain_counts = moved(lambda: aggregate(rows, aggs, None, **kwargs))
     assert agg._static_bound is None and agg._path == "sort" and not agg._trains
     assert agg._key_lows is None and agg._dense_dims is None and agg._mxu_dims is None
+    assert agg._slot_dims is None and agg._slot_acc is None
     assert agg._cap == plain._cap
     assert counts == plain_counts and counts["agg_key_bound.none"] == 1
     assert counts["agg_ingest_launches"] == counts["agg_ingest_path.sort"] == 11
     assert with_range == sites and sites.count("agg.ingest_overflow") == 11
     assert exact_rows(out) == exact_rows(want)
+
+
+def test_a_count_only_twin_of_the_product_over_2048_takes_the_slot_path():
+    """The same (3 + 1) x (600 + 1) = 2,404 slots with counts alone:
+    eleven batches in two trains, their states added, no merge, no
+    readback; row for row the sort path's answer."""
+    counts_only = [AggSpec("count_star", None, T.BIGINT), AggSpec("count", 3, T.BIGINT)]
+    ranges = (None, (1900, 2499))
+    rows = make_rows(11, years=(1900, 2499))
+    (agg, out), counts = moved(lambda: aggregate(rows, counts_only, ranges))
+    assert agg._path == "slot" and agg._static_bound == 2404 and agg._trains
+    assert agg._slot_dims == (3, 600) and agg._key_lows == (0, 1900)
+    assert counts == {"agg_ingest_batches": 11, "agg_ingest_launches": 2,
+                      "agg_ingest_path.slot": 11, "agg_key_bound.range": 1}
+    assert out.capacity == 4096
+    (plain, want), counts = moved(lambda: aggregate(rows, counts_only, None))
+    assert plain._path == "sort" and counts["agg_merge_launches"] >= 1
+    assert exact_rows(out) == exact_rows(want)
+    assert len(exact_rows(out)) > 1000
+
+
+def test_a_count_by_2400_dates_equals_the_sort_path(runner):
+    """`wide-date` through SQL: planned since the slot path (it was
+    NOT_PLANNED while 2,048 slots were the only limit), counted as
+    `range` and `slot`, and what the tpch connector's own table (no
+    exact range, the sort path) answers."""
+    sql = "select o_orderdate, count(*) from {}orders group by 1 order by 1"
+    result, counts = moved(lambda: runner.execute(sql.format("")))
+    assert counts["agg_key_bound.range"] == 1
+    assert counts["agg_ingest_path.slot"] == counts["agg_ingest_batches"] >= 1
+    assert "agg_ingest_path.sort" not in counts and "agg_merge_launches" not in counts
+    plain, counts = moved(lambda: runner.execute(sql.format("tpch.tiny.")))
+    assert counts["agg_ingest_path.sort"] >= 1 and counts["agg_key_bound.none"] == 1
+    assert result.rows == plain.rows and len(result.rows) > 2000
 
 
 def test_dictionaries_alone_keep_their_bound_and_their_programs(monkeypatch):

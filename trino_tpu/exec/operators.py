@@ -1493,7 +1493,7 @@ def _overflow_bit(word):
 
 
 def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
-                  dense_dims, mxu_dims, key_lows):
+                  dense_dims, mxu_dims, key_lows, slot_dims=None):
     """The per-batch body both ingest programs trace: the fused upstream
     filter/project, then one batch's group-reduce. Returns the reduce's
     7-tuple and the per-slot reducers it ran with."""
@@ -1538,6 +1538,11 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
             keys, valids, live, values, tuple(vvalids), reds, mxu_dims, cap,
             lows=key_lows, **_mxu_word_layout(aggs, batch, vvalids),
         )
+    elif slot_dims is not None:
+        out = G.slot_group_reduce(
+            keys, valids, live, values, tuple(vvalids), reds, slot_dims, cap,
+            valid_of=G.shared_valids(vvalids), lows=key_lows,
+        )
     else:
         out = G.sort_group_reduce(
             keys, valids, live, values, tuple(vvalids), reds, cap,
@@ -1547,12 +1552,12 @@ def _ingest_batch(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
 
 
 _INGEST_STATICS = ("groups", "aggs", "cap", "pre_fn", "dense_dims", "mxu_dims",
-                   "key_lows")
+                   "key_lows", "slot_dims")
 
 
 @partial(jax.jit, static_argnames=_INGEST_STATICS)
 def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
-                dense_dims=None, mxu_dims=None, key_lows=None):
+                dense_dims=None, mxu_dims=None, key_lows=None, slot_dims=None):
     """Fused upstream filter/project + ONE batch's group-reduce in one
     device program (scan->filter->project->partial-aggregate is the Q1
     hot path; separate launches pay a host round trip each on
@@ -1561,7 +1566,8 @@ def _agg_ingest(batch: RelBatch, groups: tuple, aggs: tuple, cap: int, pre_fn,
     trains of batches go through _agg_ingest_train and only a train of
     one comes here."""
     return _ingest_batch(
-        batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims, key_lows
+        batch, groups, aggs, cap, pre_fn, dense_dims, mxu_dims, key_lows,
+        slot_dims,
     )[0]
 
 
@@ -1603,7 +1609,8 @@ TRAIN_BATCHES = 8
 
 @partial(jax.jit, static_argnames=_INGEST_STATICS)
 def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
-                      pre_fn, dense_dims=None, mxu_dims=None, key_lows=None):
+                      pre_fn, dense_dims=None, mxu_dims=None, key_lows=None,
+                      slot_dims=None):
     """The first `n` of `batches` (equal in layout; `n` is an operand, so
     a short train is this same program) through _agg_ingest's body, ONE
     launch and ONE group state for all of them. The body is traced once,
@@ -1612,11 +1619,11 @@ def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
     reduces it and folds the result into the running state. Laying the
     batches side by side and slicing the turn's out costs the device
     more (PERF.md section 6, PR 26). Only the slot-addressed tables
-    (dense_dims, mxu_dims) come here: slot g is the same group in every
+    (dense_dims, mxu_dims, slot_dims) come here: slot g is the same group in every
     batch, so the fold is elementwise (counts and sums add, min/max keep
     the extreme of the batches that had a row) and the keys are any
     batch's."""
-    assert dense_dims is not None or mxu_dims is not None
+    assert (dense_dims, mxu_dims, slot_dims) != (None, None, None)
     flat = [jax.tree_util.tree_flatten(b) for b in batches]
     treedef = flat[0][1]
     picks = [lambda leaves=tuple(leaves): leaves for leaves, _ in flat]
@@ -1627,6 +1634,7 @@ def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
         out, r = _ingest_batch(
             jax.tree_util.tree_unflatten(treedef, leaves),
             groups, aggs, cap, pre_fn, dense_dims, mxu_dims, key_lows,
+            slot_dims,
         )
         reds[:] = r
         gk, gv, used, vals, cnts, _ngroups, ovf = out
@@ -1653,6 +1661,20 @@ def _agg_ingest_train(batches: tuple, n, groups: tuple, aggs: tuple, cap: int,
     # the reduce's 7-tuple without its group count: a bounded table is
     # never grown, so nobody reads one (and an output costs the host)
     return gk, gv, used, vals, cnts, None, ovf
+
+
+@jax.jit
+def _add_slot_states(a: tuple, b: tuple):
+    """Two states of ONE operator's slot-addressed count table
+    (G.slot_group_reduce) as one: slot g is the same group in both, so
+    `used` ORs and the counts add, slot for slot, as _agg_ingest_train's
+    fold does inside a train, and the keys are either's. No sort: what
+    _merge_group_states would do to them is sort every slot again."""
+    gk, gv, a_used, a_vals, a_cnts = a
+    _, _, b_used, b_vals, b_cnts = b
+    return (gk, gv, a_used | b_used,
+            tuple(x + y for x, y in zip(a_vals, b_vals)),
+            tuple(x + y for x, y in zip(a_cnts, b_cnts)))
 
 
 @partial(jax.jit, static_argnames=("aggs", "arg_types"))
@@ -1772,14 +1794,24 @@ class HashAggregationOperator(Operator):
     paths, whose batches may overflow a table or need the raw rows.
     Where the plan bounds the table and addresses it by slot (dictionary
     and boolean keys, integer keys of a known exact range: `_dense_dims`,
-    `_mxu_dims`, `_key_lows`), batches are held and
+    `_mxu_dims`, `_slot_dims`, `_key_lows`), batches are held and
     go TRAIN_BATCHES at a time through one launch of _agg_ingest_train,
     which leaves one state; finish and revocation flush what is held.
-    METRICS `agg_ingest_batches` over `agg_ingest_launches` is the train
-    length achieved; `agg_ingest_path.dense`, `.mxu` and `.sort` count
-    the same batches by the reduce the plan got (`_path`), and
-    `agg_key_bound.range`, `.dictionary` and `.none` the operators by
-    what bounded their table."""
+    Four paths (`_path`, G.choose_bounded_reduce) and two limits: the
+    dense and the MXU reduce up to G.MXU_MAX_SLOTS, for COUNTS the
+    scatter-add (`slot`, G.slot_group_reduce) from there to
+    G.SLOT_MAX_SLOTS where an integer range is among what bounds the
+    table and to 2^16 where dictionaries and booleans alone do, the sort
+    path for everything else. The slot path's trains leave states that
+    are ADDED slot for slot (_add_slot_states, into `_slot_acc`): no
+    merge program, no sort, whatever the scan's length; only a state
+    that came off the wire or out of a spill, which is not addressed by
+    slot, takes the sort merge there. METRICS `agg_ingest_batches` over
+    `agg_ingest_launches` is the train length achieved;
+    `agg_ingest_path.dense`, `.mxu`, `.slot` and `.sort` count the same
+    batches by the reduce the plan got, and `agg_key_bound.range`,
+    `.dictionary` and `.none` the operators by what bounded their
+    table."""
 
     def __init__(
         self,
@@ -1903,16 +1935,23 @@ class HashAggregationOperator(Operator):
             lows.append(low)
             bound *= dims[-1] + 1  # +1: the NULL group
         # a range bounds a table only where the table is addressed by
-        # slot: the whole product within what the MXU reduce takes and a
-        # chooser that answers `dense` or `mxu`; otherwise the operator
-        # is built as it is without the range (no bound, the sort path)
-        limit = G.MXU_MAX_SLOTS if ranged else 1 << 16
+        # slot: the whole product within what the largest slot-addressed
+        # reduce takes and a chooser that answers `dense`, `mxu` or
+        # `slot`; otherwise the operator is built as it is without the
+        # range (no bound, the sort path). Dictionaries and booleans
+        # alone keep their limit of 2^16, whatever the reducers: a
+        # dictionary is as large as its column's distinct values, not as
+        # its rows' groups, and a count of a few thousand rows by a
+        # dictionary of 100,000 names (TPC-H Q21's) is better sorted
+        # than spread over a table it leaves empty
+        limit = G.SLOT_MAX_SLOTS if ranged else 1 << 16
         self._static_bound = bound if 0 < bound <= limit else None
         # Which reduce the bounded domain gets is the kernels' layer's
         # rule (ops/groupby.choose_bounded_reduce): the dense slot
         # reduce (per-group masked reductions unrolled into one fused
         # program), the MXU one-hot contraction (ops/mxu_groupby.py
-        # Pallas kernel) on a TPU, or the sort path
+        # Pallas kernel) on a TPU, for counts over a larger domain one
+        # scatter-add a batch, or the sort path
         self._path = "sort"
         if self._static_bound is not None and self._group_channels:
             reds, dtypes, _ = _value_slot_layout(
@@ -1934,6 +1973,9 @@ class HashAggregationOperator(Operator):
         self._path_counter = "agg_ingest_path." + self._path
         self._dense_dims = tuple(dims) if self._path == "dense" else None
         self._mxu_dims = tuple(dims) if self._path == "mxu" else None
+        self._slot_dims = tuple(dims) if self._path == "slot" else None
+        # the slot path's states so far, added up (_add_slot_states)
+        self._slot_acc = None
         # per key, the value of digit 0; None where every key counts
         # from 0, so that a table bounded by dictionaries alone keeps the
         # programs it had
@@ -1948,7 +1990,7 @@ class HashAggregationOperator(Operator):
         # TRAIN_BATCHES of one layout are there, and one launch of
         # _agg_ingest_train leaves one state for all of them. Every
         # other path launches once per batch.
-        self._trains = self._dense_dims is not None or self._mxu_dims is not None
+        self._trains = self._path != "sort"
         self._held: List[RelBatch] = []
         self._held_layout = None
         self._launched = 0  # trains launched and not yet in METRICS
@@ -2096,6 +2138,7 @@ class HashAggregationOperator(Operator):
         statics = (
             tuple(self._group_channels), tuple(self._aggs), self._cap,
             self._pre, self._dense_dims, self._mxu_dims, self._key_lows,
+            self._slot_dims,
         )
         self._launched += 1
         if len(held) == 1:
@@ -2111,9 +2154,15 @@ class HashAggregationOperator(Operator):
         # dictionary outgrowing the plan-time one, or a value outside
         # the plan-time range)
         self._deferred_ovf.append(ovf)
-        self._push_pending_locked(
-            (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
-        )
+        state = (tuple(gk), tuple(gv), used, tuple(vals), tuple(cnts))
+        if self._slot_dims is None:
+            self._push_pending_locked(state)
+        elif self._slot_acc is None:
+            self._slot_acc = state
+        else:
+            # slot g is the same group in every train of this operator:
+            # the states add, and a scan of any length holds one
+            self._slot_acc = _add_slot_states(self._slot_acc, state)
 
     def _report_launches(self) -> None:
         """`agg_ingest_launches` for the trains launched under
@@ -2286,6 +2335,12 @@ class HashAggregationOperator(Operator):
         merged state with a single N-way device program (caller holds
         _state_lock)."""
         self._flush_held_locked()
+        if self._slot_acc is not None:
+            # alone it becomes the result as it is; beside a state off
+            # the wire or out of a spill (keys in any slot, a key
+            # perhaps twice) it is one more operand of the sort merge
+            self._push_pending_locked(self._slot_acc)
+            self._slot_acc = None
         self._resolve_pending_locked()
         states = [] if self._acc is None else [self._acc]
         groups = [] if self._acc is None else [self._acc_groups]
@@ -2479,8 +2534,7 @@ class HashAggregationOperator(Operator):
             self._out = RelBatch(cols, jnp.ones(1, dtype=jnp.bool_))
             return
         out = self._partial_state_batch()
-        if out.capacity >= _SHRINK_MIN_CAPACITY and self._dense_dims is None \
-                and self._mxu_dims is None:
+        if out.capacity >= _SHRINK_MIN_CAPACITY and not self._trains:
             out = _shrink_prefix(out, _count("agg.partial_rows", out.live_mask()))
         self._out = out
 
@@ -2836,7 +2890,7 @@ class HashAggregationOperator(Operator):
 
         total = 0
         folded = [st for tier in list(self._folded) for st, _, _ in tier]
-        for st in ([self._acc] if self._acc is not None else []) \
+        for st in [s for s in (self._acc, self._slot_acc) if s is not None] \
                 + folded + list(self._pending):
             gk, gv, used, vals, cnts = st
             for arr in [*gk, *gv, used, *vals, *cnts]:
@@ -2852,7 +2906,8 @@ class HashAggregationOperator(Operator):
         except Exception:
             # pool exhausted even after revoking others: spill our own
             # state (self-revocation) and account the reset footprint
-            if self._acc is None and not self._pending and not self._held \
+            if self._acc is None and self._slot_acc is None \
+                    and not self._pending and not self._held \
                     and not any(self._folded):
                 raise
             self._revoke_memory()
@@ -2997,8 +3052,7 @@ class HashAggregationOperator(Operator):
             d = arg_d if a.kind in ("min", "max", "any") else None
             cols.append(Column(a.out_type, data, valid, d))
         out = RelBatch(cols, used)
-        if out.capacity >= _SHRINK_MIN_CAPACITY and self._dense_dims is None \
-                and self._mxu_dims is None:
+        if out.capacity >= _SHRINK_MIN_CAPACITY and not self._trains:
             # sort-path group rows are prefix-dense: hand downstream
             # operators the live size, not the table capacity
             groups = _count("agg.group_rows", used)

@@ -500,22 +500,44 @@ def _slot_keys(keys, dims, radices, lows, pad):
 DENSE_MAX_SLOTS = 64
 MXU_MAX_SLOTS = 2048
 MXU_MIN_WORK = 12  # slots x value slots from which the MXU reduce is taken
+# Past MXU_MAX_SLOTS a table is addressed by slot only for COUNTS
+# (slot_group_reduce: one scatter-add of 32-bit ones a row mask). The
+# scatter is paid a row, not a slot; what grows with the table is the
+# rest of a batch's turn, which zeroes it, widens it and folds it into
+# the running state, and the state every later operator is handed. On a
+# v5e, device ms a batch of 2^20 rows of TPC-H SF10's `o_custkey` by the
+# table's slots, the reduce alone / a batch of a train of eight with a
+# dictionary look-up fused in (PERF.md section 6, PR 45, step 0):
+#   2^21  7.10 / 15.67     2^23  10.51 / 19.28     2^25  16.43 / 22.16
+# (up to 2^21 slots the compiler keeps the 32-bit table in fast memory;
+# past it the scatter sorts its indices first). The sort path beside it
+# is 14.4 ms a batch and as much again in merges, so the scatter wins
+# at every size read; the limit is where a state stops being small: 26
+# bytes a slot and key, 218 MB at 2^23 and 872 MB at 2^25 of the chip's
+# 16 GB, three of them alive inside a train, and a join or a sort above
+# handed a batch that wide.
+SLOT_MAX_SLOTS = 1 << 23
 
 
 def choose_bounded_reduce(bound: int, reducers: Sequence[str],
                           dtypes: Sequence, mxu: bool,
                           dense_sums_only: bool = False) -> str:
-    """"mxu", "dense" or "sort": the group reduce for a key domain the
-    plan bounds at `bound` slots (NULL digits included), by what the
-    callers can observe: the bound, the per-value-slot reducers, the
-    value dtypes and whether the backend has the MXU kernel (`mxu`: a
-    TPU, or the tests' hook). Sums and counts of integer-kind values go
-    to the MXU reduce up to MXU_MAX_SLOTS, unless the domain is one the
-    dense reduce takes and the work (slots x value slots) is under
-    MXU_MIN_WORK. The dense reduce takes up to DENSE_MAX_SLOTS: sums,
-    counts, minima and maxima of any dtype, or, where the caller's folds
-    only add (`dense_sums_only`: the mesh plane's), integer sums and
-    counts alone. Everything else sorts."""
+    """"mxu", "dense", "slot" or "sort": the group reduce for a key
+    domain the plan bounds at `bound` slots (NULL digits included), by
+    what the callers can observe: the bound, the per-value-slot
+    reducers, the value dtypes and whether the backend has the MXU
+    kernel (`mxu`: a TPU, or the tests' hook). Four paths, two limits.
+    Sums and counts of integer-kind values go to the MXU reduce up to
+    MXU_MAX_SLOTS, unless the domain is one the dense reduce takes and
+    the work (slots x value slots) is under MXU_MIN_WORK. The dense
+    reduce takes up to DENSE_MAX_SLOTS: sums, counts, minima and maxima
+    of any dtype, or, where the caller's folds only add
+    (`dense_sums_only`: the mesh plane's, which has no other
+    slot-addressed reduce either), integer sums and counts alone. Past
+    MXU_MAX_SLOTS and up to SLOT_MAX_SLOTS counts alone take the
+    scatter-add (slot_group_reduce), on any backend: a 64-bit sum
+    scattered costs twenty times a count (PERF.md section 6, PR 44),
+    and a minimum does not add. Everything else sorts."""
     adds = all(r in ("sum", "count") for r in reducers)
     ints = not any(jnp.issubdtype(d, jnp.floating) for d in dtypes)
     dense = bound <= DENSE_MAX_SLOTS and (
@@ -526,7 +548,13 @@ def choose_bounded_reduce(bound: int, reducers: Sequence[str],
         dense and bound * len(reducers) < MXU_MIN_WORK
     ):
         return "mxu"
-    return "dense" if dense else "sort"
+    if dense:
+        return "dense"
+    counts = bool(reducers) and all(r == "count" for r in reducers)
+    if counts and not dense_sums_only \
+            and MXU_MAX_SLOTS < bound <= SLOT_MAX_SLOTS:
+        return "slot"
+    return "sort"
 
 
 def shared_valids(value_valids: Sequence) -> tuple:
@@ -635,6 +663,74 @@ def mxu_group_reduce(
         used,
         results,
         counts,
+        n_groups,
+        out_of_domain,
+    )
+
+
+@partial(jax.jit, static_argnames=(
+    "dims", "reducers", "out_capacity", "valid_of", "lows"))
+def slot_group_reduce(
+    keys: Sequence[jnp.ndarray],
+    valids: Sequence[jnp.ndarray],
+    mask: jnp.ndarray,
+    values: Sequence[jnp.ndarray],
+    value_valids: Sequence[Optional[jnp.ndarray]],
+    reducers: tuple,
+    dims: tuple,
+    out_capacity: int,
+    valid_of: Optional[tuple] = None,
+    lows: Optional[tuple] = None,
+):
+    """dense_group_reduce contract for COUNTS over a key domain of
+    millions of slots (caller gates, choose_bounded_reduce): the table
+    is as wide as the domain, slot g the same group in every batch, and
+    a batch is one scatter-add of 32-bit ones a DISTINCT row mask into
+    a zeroed table: the live rows, and the live rows whose counted
+    column is not NULL, once for all the value slots that share the
+    validity array (`valid_of`: shared_valids; a slot without one
+    counts the live rows). No sort, no merge. A batch holds fewer rows
+    than 32 bits count; the tables are widened to the state's 64 bits
+    on the way out, and it is the caller's states that add up. `used`
+    is "a live row had this key", not "the count is above 0": a group
+    whose counted values are all NULL exists, with count 0. `values`
+    are not read. `lows` as in dense_group_reduce."""
+    assert reducers and all(r == "count" for r in reducers), reducers
+    radices = tuple(d + 1 for d in dims)
+    total = 1
+    for r in radices:
+        total *= r
+    assert total <= out_capacity
+    gid, out_of_domain = _dense_gid(keys, valids, mask, dims, radices, lows)
+    if valid_of is None:
+        valid_of = tuple(range(len(values)))
+
+    def count_rows(w):
+        return jnp.zeros(out_capacity, jnp.int32).at[gid].add(w.astype(jnp.int32))
+
+    rows = count_rows(mask)
+    used = rows > 0
+    rows = rows.astype(jnp.int64)
+    counts = []
+    for i, vv in enumerate(value_valids):
+        if vv is None:
+            counts.append(rows)
+        elif valid_of[i] != i:
+            counts.append(counts[valid_of[i]])
+        else:
+            counts.append(count_rows(mask & vv).astype(jnp.int64))
+
+    def pad(x, fill=0):
+        return jnp.pad(x, (0, out_capacity - total), constant_values=fill)
+
+    group_keys, group_valids = _slot_keys(keys, dims, radices, lows, pad)
+    n_groups = jnp.sum(used.astype(jnp.int32))
+    return (
+        group_keys,
+        group_valids,
+        used,
+        list(counts),
+        list(counts),
         n_groups,
         out_of_domain,
     )

@@ -144,8 +144,9 @@ account opens. ``chipbench/stmt_account.py`` reads both (STMT.md).
 
 Which of several device paths a batch took is not a span but a counter
 of ``runtime/metrics.METRICS``, one increment a batch, trace or no
-trace: ``agg_ingest_path.dense`` / ``.mxu`` / ``.sort`` (the bounded
-reduce a grouped batch got), ``df_filter_path.set`` / ``.bits`` /
+trace: ``agg_ingest_path.dense`` / ``.mxu`` / ``.slot`` / ``.sort`` (the
+bounded reduce a grouped batch got; ``.slot`` the scatter-add of counts
+by a key domain past the MXU reduce's), ``df_filter_path.set`` / ``.bits`` /
 ``.range`` (a dynamic filter's batches; of the bits' batches,
 ``df_bits_lookup.window`` / ``.gather``: whether the words were picked
 out of a window a block, on a scan in key order, or gathered a row, and

@@ -331,11 +331,30 @@ def group_key_ranges(node: P.AggregateNode, child: PlanStats):
     to BIGINT, DATE) whose range is exact gives its range; a string or a
     boolean gives None (the operator bounds those itself, by their
     dictionary); any other key, or ranges whose digits alone (a NULL
-    digit a key) outgrow what a slot-addressed reduce takes
-    (ops/groupby.MXU_MAX_SLOTS), leaves the node without any."""
+    digit a key) outgrow what a slot-addressed reduce takes, leaves the
+    node without any. Two limits (ops/groupby.choose_bounded_reduce's):
+    MXU_MAX_SLOTS for any aggregates, and SLOT_MAX_SLOTS where every
+    aggregate is a plain count, the one reduce that addresses a table
+    that large. This looks at the node's aggregates so that a SUM by a
+    key of a million values (TPC-H Q13's over its join, Q18's by
+    `l_orderkey`) carries no range its operator would drop: the plan
+    says `key_ranges` only where a table can be bounded by them. Past
+    MXU_MAX_SLOTS it also looks at the rows the node is estimated to
+    read: a table of millions of slots is zeroed, folded and handed on
+    slot by slot whatever it holds, so a count of a few rows (a
+    selective filter under it) keeps the sort path, whose cost follows
+    its rows; the table is taken where the estimate gives at least a
+    row to four slots (what HashBuildSink takes for a build side worth
+    packing). The operator asks the chooser again with what it alone
+    knows (the dictionaries' sizes, the backend) and still drops a
+    range whose answer is `sort`."""
     from trino_tpu.exec.operators import RANGE_KEY_KINDS
-    from trino_tpu.ops.groupby import MXU_MAX_SLOTS
+    from trino_tpu.ops.groupby import MXU_MAX_SLOTS, SLOT_MAX_SLOTS
 
+    counts = bool(node.aggs) and all(
+        a.kind in ("count", "count_star") and not a.distinct for a in node.aggs
+    )
+    limit = SLOT_MAX_SLOTS if counts else MXU_MAX_SLOTS
     ranges, slots = [], 1
     for c in node.group_channels:
         t = node.child.fields[c].type
@@ -351,10 +370,12 @@ def group_key_ranges(node: P.AggregateNode, child: PlanStats):
         if low != cs.low or high != cs.high:
             return None
         slots *= high - low + 2
-        if slots > MXU_MAX_SLOTS:
+        if slots > limit:
             return None
         ranges.append((low, high))
     if all(r is None for r in ranges):
+        return None
+    if slots > MXU_MAX_SLOTS and child.row_count * 4 < slots:
         return None
     return tuple(ranges)
 
