@@ -284,7 +284,7 @@ def corpus_05_plan_validation():
         "where s_nationkey = n_nationkey group by n_name"
     )
     output = Analyzer(c, "tpch", "tiny").plan(parse(sql))
-    census = census_text(shape_census(output, c), warn_threshold=32)
+    census = census_text(shape_census(output, c))
     emit(
         "05_plan_validation.txt",
         ("corrupted plan: Project ref outside input width\n"
@@ -341,7 +341,6 @@ def corpus_06_compile_regime():
         shape_census(
             output, c, batch_rows=49152, ladder=CapacityLadder(base=2)
         ),
-        warn_threshold=32,
     )
 
     # 3. the census-driven warmup plan: the fused filter/project stages

@@ -100,17 +100,132 @@ def test_removed_mesh_scheduler_property_is_unknown():
 
 
 def test_registry_and_session_declare_the_same_properties():
-    # every property is declared twice (a registry row and a Session
-    # field); until one declaration derives the other this holds them
-    # to the same names and defaults
-    fields = {f.name for f in dataclasses.fields(Session)}
+    # a property is declared once, as a field of Session made by
+    # config._prop; the registry is read off those fields at import
+    plain = {"catalog", "schema", "user", "timezone"}
+    fields = {f.name: f for f in dataclasses.fields(Session)}
     props = SYSTEM_PROPERTIES.all()
-    assert fields - {m.name for m in props} == {
-        "catalog", "schema", "user", "timezone"
-    }
+    assert set(fields) - {m.name for m in props} == plain
+    assert len(props) == 48
+    for m in props:
+        f = fields[m.name]
+        assert m.description == f.metadata["description"]
+        assert m.allowed == f.metadata["allowed"]
+        assert m.default == f.metadata.get("registered_default", f.default)
+        assert m.type is type(m.default)
+    assert not any(fields[name].metadata for name in plain)
+    # every default round-trips through bind_session
     bound = Session()
     bind_session(bound, {m.name: m.default for m in props})
     assert bound == Session()
+    bind_session(bound, {m.name: str(m.default) for m in props})
+    assert bound == Session()
+
+
+# the twenty-one properties nothing ever set (PR 47): four switches
+# that went with their off-branches, seventeen thresholds that are now
+# constants of the module that owns the mechanism
+REMOVED_PROPERTIES = {
+    "preemption_enabled": False,
+    "replica_failover_enabled": False,
+    "mesh_steal_enabled": False,
+    "low_memory_killer_enabled": False,
+    "speculation_quantile": 3.0,
+    "request_max_error_duration_s": 10.0,
+    "node_breaker_threshold": 5,
+    "node_breaker_cooldown_s": 2.0,
+    "replica_breaker_threshold": 5,
+    "replica_breaker_cooldown_s": 2.0,
+    "compile_churn_warn_threshold": 8,
+    "plan_cache_entries": 16,
+    "micro_batch_max": 4,
+    "admission_fast_depth": 8,
+    "admission_general_depth": 8,
+    "admission_retry_after_s": 2.0,
+    "mesh_scheduler_min_slice_chunks": 2,
+    "mesh_park_max_bytes": 1024,
+    "fabric_queue_depth": 2,
+    "fabric_max_error_duration_s": 1.0,
+    "hash_partition_count": 2,
+}
+
+
+@pytest.mark.parametrize("name", sorted(REMOVED_PROPERTIES))
+def test_removed_property_is_refused_as_unknown(name):
+    value = REMOVED_PROPERTIES[name]
+    with pytest.raises(ValueError, match="unknown session property"):
+        SYSTEM_PROPERTIES.validate(name, value)
+    lq = LocalQueryRunner(Session(catalog="tpch", schema="tiny"))
+    before = dataclasses.replace(lq.session)
+    literal = str(value).lower() if isinstance(value, bool) else value
+    with pytest.raises(Exception, match="unknown session property"):
+        lq.execute(f"SET SESSION {name} = {literal}")
+    assert lq.session == before and not hasattr(lq.session, name)
+    with pytest.raises(TypeError):
+        Session(**{name: value})
+
+
+def test_no_reader_carries_a_default_of_its_own():
+    # `getattr(<expr>, "<property>", <default>)` was the third place a
+    # property's default was written, and two had drifted; a reader
+    # reads `session.<name>`
+    import ast
+    import pathlib
+
+    import trino_tpu
+
+    names = {m.name for m in SYSTEM_PROPERTIES.all()}
+    root = pathlib.Path(trino_tpu.__file__).parent
+    found = []
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)
+                and node.func.id == "getattr"
+                and len(node.args) == 3
+                and isinstance(node.args[1], ast.Constant)
+                and node.args[1].value in names
+            ):
+                found.append(f"{path.relative_to(root)}:{node.lineno}")
+    assert found == []
+
+
+def test_show_session_lists_the_registry_with_a_fresh_sessions_defaults():
+    lq = LocalQueryRunner(Session(catalog="tpch", schema="tiny"))
+    result = lq.execute("SHOW SESSION")
+    assert result.column_names == ["Name", "Value", "Default", "Description"]
+    props = SYSTEM_PROPERTIES.all()
+    assert [r[0] for r in result.rows] == [m.name for m in props]
+    assert len(result.rows) == 48
+    fresh = Session()
+    for (name, value, default, description), m in zip(result.rows, props):
+        assert value == default == str(m.default)
+        assert description == m.description
+        # what the session holds is what a properties file would bind
+        bound = Session()
+        bind_session(bound, {name: value})
+        assert getattr(bound, name) == getattr(fresh, name)
+    lq.execute("SET SESSION memory_pool_bytes = 4096")
+    shown = {r[0]: r[1:3] for r in lq.execute("SHOW SESSION").rows}
+    assert shown["memory_pool_bytes"] == ["4096", "0"]
+
+
+def test_a_property_is_a_plain_attribute_of_a_plain_dataclass():
+    # no __getattr__ fallback and no per-read lookup: a session is read
+    # once a statement on the host-bound path
+    assert "__getattr__" not in vars(Session)
+    assert "__getattribute__" not in vars(Session)
+    assert not any(
+        isinstance(v, property) for v in vars(Session).values()
+    )
+    s = Session()
+    with pytest.raises(AttributeError):
+        s.no_such_property
+    with pytest.raises(AttributeError):
+        s.plan_cache_entries
+    assert vars(s)["batch_rows"] == s.batch_rows == 1 << 20
+    assert set(vars(s)) == {f.name for f in dataclasses.fields(Session)}
 
 
 def test_load_properties_file(tmp_path):

@@ -157,35 +157,27 @@ class AdaptiveController:
     # -- config ------------------------------------------------------
     @property
     def _adaptive_on(self) -> bool:
-        return bool(getattr(self.session, "adaptive_execution", False))
+        return bool(self.session.adaptive_execution)
 
     @property
     def _shared_on(self) -> bool:
-        return bool(
-            getattr(self.session, "shared_subtree_materialization", False)
-        )
+        return bool(self.session.shared_subtree_materialization)
 
     @property
     def _threshold(self) -> float:
-        return float(
-            getattr(self.session, "adaptive_replan_threshold", 4.0) or 4.0
-        )
+        return float(self.session.adaptive_replan_threshold or 4.0)
 
     @property
     def _salting_on(self) -> bool:
-        return bool(getattr(self.session, "skewed_join_salting", False))
+        return bool(self.session.skewed_join_salting)
 
     @property
     def _hot_threshold(self) -> float:
-        return float(
-            getattr(self.session, "skew_hot_key_threshold", 0.2) or 0.2
-        )
+        return float(self.session.skew_hot_key_threshold or 0.2)
 
     @property
     def _spill_min_rows(self) -> int:
-        return int(
-            getattr(self.session, "skew_spill_min_rows", 1 << 18) or 1 << 18
-        )
+        return int(self.session.skew_spill_min_rows or 1 << 18)
 
     def enabled(self) -> bool:
         return self._adaptive_on or self._shared_on
@@ -296,7 +288,7 @@ class AdaptiveController:
         return found[0] if found else None
 
     def _validate(self, root: P.PlanNode) -> None:
-        if getattr(self.session, "plan_validation", "passes") == "off":
+        if self.session.plan_validation == "off":
             return
         from trino_tpu.sql.validate import validate_logical
 

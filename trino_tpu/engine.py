@@ -14,6 +14,8 @@ import time
 from typing import Dict, List, Optional, Sequence
 
 from trino_tpu import types as T
+# Session is declared in config.py and imported from here by its users
+from trino_tpu.config import SYSTEM_PROPERTIES, Session
 from trino_tpu.connectors.spi import CatalogManager, Connector
 from trino_tpu.exec import CollectorSink, Driver, Pipeline
 from trino_tpu.runtime.tracing import host_span, phase_span
@@ -22,196 +24,6 @@ from trino_tpu.sql.analyzer import AnalysisError, Analyzer
 from trino_tpu.sql.local_planner import LocalPlanner
 from trino_tpu.sql.parser import parse
 from trino_tpu.sql.plan import OutputNode, explain_text
-
-
-@dataclasses.dataclass
-class Session:
-    """Per-query context (main/Session.java analogue; properties grow
-    with the session-property system). retry_policy mirrors Trino's
-    `retry_policy` session property: "none" (pipelined), "query"
-    (whole-query retry inside the pipelined scheduler,
-    PipelinedQueryScheduler.scheduleRetryWithDelay:394) or "task"
-    (FTE over spooled exchange, SURVEY.md §3.5)."""
-
-    catalog: str = "tpch"
-    schema: str = "tiny"
-    user: str = "user"
-    # session time zone (Session.java getTimeZoneKey): fixes literal
-    # parsing, timestamp<->tstz casts, now()/current_date
-    timezone: str = "UTC"
-    batch_rows: int = 1 << 20
-    target_splits: int = 1
-    retry_policy: str = "none"
-    query_retry_count: int = 2
-    task_retries: int = 3
-    # per-query memory budget (None = unlimited); exceeding it triggers
-    # revocation/spill, then ExceededMemoryLimitError
-    memory_pool_bytes: Optional[int] = None
-    hash_partition_count: int = 4
-    enable_dynamic_filtering: bool = True
-    broadcast_join_threshold: int = 1_000_000
-    # distributed data plane: run mesh-colocated fragments as ONE
-    # shard_map program with all_to_all/all_gather exchanges over ICI
-    # (parallel/mesh_plan.py); ineligible plans and cross-host/FTE
-    # topologies fall back to the HTTP page exchange
-    mesh_execution: bool = True
-    # rows per mesh chunk-step per shard: >0 splits the driver scan into
-    # ceil(rows/chunk) jit steps with host preemption checks at every
-    # chunk boundary; 0 compiles the plan as one program
-    mesh_chunk_rows: int = 0
-    # optimizer (sql/optimizer.py): the iterative rule pipeline and the
-    # cost-based join reorderer (JOIN_REORDERING_STRATEGY analogue)
-    enable_optimizer: bool = True
-    join_reordering_strategy: str = "automatic"
-    # connector scan pushdown (sql/optimizer.py PushPredicateIntoTableScan
-    # / PushProjectionIntoTableScan via the apply_filter/apply_projection
-    # SPI hooks)
-    enable_pushdown: bool = True
-    # FTE straggler mitigation: duplicate slow tasks, first wins
-    # (retry-policy=TASK speculative execution). A task speculates once
-    # it runs `speculation_quantile`x beyond the stage's median
-    # committed-attempt wall time AND a spare schedulable worker exists.
-    speculation_enabled: bool = True
-    speculation_quantile: float = 2.0
-    # intra-task pipeline parallelism (LocalExchange): parallel build
-    # pipelines + host IO overlapped with device compute; 1 = off
-    task_concurrency: int = 2
-    # cluster resiliency (PR 2): per-destination transient-error budget
-    # for inter-node requests (runtime/error_tracker.py), circuit
-    # breaker graylisting thresholds (runtime/discovery.py), and the
-    # last-resort low-memory killer (runtime/memory.py)
-    request_max_error_duration_s: float = 30.0
-    node_breaker_threshold: int = 3
-    node_breaker_cooldown_s: float = 1.0
-    low_memory_killer_enabled: bool = True
-    # deadline hierarchy (PR 4, runtime/query_tracker.py): per-query
-    # time budgets (0 = unlimited). Breaches are typed NON-RETRYABLE
-    # errors (EXCEEDED_TIME_LIMIT / EXCEEDED_CPU_LIMIT) — the budget is
-    # a property of the query, so neither QUERY retry nor FTE task
-    # retry may resubmit past one
-    query_max_planning_time_s: float = 0.0
-    query_max_execution_time_s: float = 0.0
-    query_max_run_time_s: float = 0.0
-    query_max_cpu_time_s: float = 0.0
-    # client-abandonment reaping (CoordinatorServer): a query whose
-    # results page went unpolled this long is cancelled and its
-    # resource-group slot + memory reservation released
-    client_timeout_s: float = 300.0
-    # worker stuck-task watchdog: interrupt a task making no batch
-    # progress for this long (RETRYABLE, unlike deadline kills — a hung
-    # split may succeed on another worker); 0 disables
-    stuck_task_interrupt_s: float = 0.0
-    # FTE speculation duration estimate: quantile of committed attempt
-    # wall times per fragment (the reference's p75-based model)
-    speculation_percentile: float = 0.75
-    # plan sanity checking (sql/validate.py, PlanSanityChecker
-    # analogue): "off" | "passes" (after each optimizer pass and after
-    # fragmentation) | "rules" (also after every rule application +
-    # plan-determinism double-planning — debug mode)
-    plan_validation: str = "passes"
-    # EXPLAIN (ANALYZE) warns when the shape census predicts more
-    # distinct XLA lowerings than this per plan/fragment
-    compile_churn_warn_threshold: int = 32
-    # shape stabilization (compile/shapes.py): pad scan chunks to the
-    # capacity class of their pre-pruning span so pushdown/dynamic-
-    # filter pruning and FTE retries re-land on census-predicted
-    # lowerings instead of minting data-dependent ones
-    shape_stabilization: bool = True
-    # geometric ratio between capacity-ladder rungs (power of two);
-    # 2 = the native bucket_capacity grid, larger = fewer classes
-    capacity_ladder_base: int = 2
-    # census-driven AOT warmup (compile/warmup.py): "off" | "background"
-    # (precompile predicted lowerings while the query runs) | "block"
-    # (wait for warmup before execution — deterministic cold starts)
-    warmup_mode: str = "off"
-    # aggressive watchdog threshold once a task's predicted shape
-    # classes are all warm (warmup/cache hits or a prior completed
-    # run); 0 falls back to stuck_task_interrupt_s
-    stuck_task_interrupt_warm_s: float = 0.0
-    # query tracing (runtime/tracing.py): "on" records the full span
-    # tree (phases/stages/task attempts/operators; worker spans grafted
-    # into the coordinator's) for GET /v1/query/{id}/trace
-    query_trace: str = "off"
-    # serving tier (trino_tpu/serving/): plan-cache LRU bound,
-    # micro-batch coalescing window (0 = batching off) + per-flush cap,
-    # and the admission lanes' queue depths / shed Retry-After hint
-    plan_cache_entries: int = 256
-    micro_batch_window_ms: float = 0.0
-    micro_batch_max: int = 16
-    admission_fast_depth: int = 64
-    admission_general_depth: int = 256
-    admission_retry_after_s: float = 1.0
-    # resident state tier (trino_tpu/resident/): tables whose point
-    # lookups serve from pinned device-resident hash tables, the
-    # device-memory pin budget (0 disables pinning), and the delta-side
-    # capacity before background compaction folds it into the base
-    resident_tables: str = ""
-    resident_pin_budget_mb: int = 64
-    resident_delta_max_rows: int = 4096
-    # adaptive execution tier (trino_tpu/adaptive/): mid-query
-    # re-planning from observed barrier stats, the divergence ratio
-    # that triggers it, and shared-subtree (NOT IN / CTE) spooling
-    adaptive_execution: bool = False
-    adaptive_replan_threshold: float = 4.0
-    shared_subtree_materialization: bool = False
-    # skew-aware join plane (ISSUE 16): heavy-hitter classification at
-    # build-side barriers, salted repartition on the mesh plane, the
-    # DHHJ spill-mode re-plan floor, and the MXU matmul join-project
-    # kernel with its profitability threshold
-    skewed_join_salting: bool = False
-    skew_hot_key_threshold: float = 0.2
-    skew_spill_min_rows: int = 1 << 18
-    mxu_join_enabled: bool = False
-    mxu_join_min_work: float = 16.0
-    # recovery tier (trino_tpu/recovery/): checkpoint the mesh step
-    # loop's carries every N chunk boundaries (0 = off) so mesh faults
-    # resume from the last checkpoint; bound in-run resume attempts;
-    # tee completed fragment outputs into the subtree spool so QUERY
-    # retry substitutes finished stages instead of recomputing them
-    mesh_checkpoint_interval_chunks: int = 0
-    mesh_resume_attempts: int = 2
-    recovery_spool_stages: bool = False
-    # replicated serving meshes (trino_tpu/runtime/replicas.py): carve
-    # the device set into N identical sub-meshes, each running the same
-    # prelude/step/flush programs; the coordinator load-balances across
-    # healthy replicas and, with failover on, re-places an in-flight
-    # chunked query onto a sibling when its replica dies or drains
-    # (resuming from the host-portable checkpoint). Breaker thresholds
-    # mirror the worker graylist (node_breaker_*), per replica.
-    mesh_replicas: int = 1
-    replica_failover_enabled: bool = True
-    replica_breaker_threshold: int = 3
-    replica_breaker_cooldown_s: float = 1.0
-    # preemptive multi-tenancy (runtime/scheduler.py): chunk-granular
-    # weighted-fair run queue per mesh with a fast lane for point
-    # lookups; a fast arrival parks the running analytic (carries
-    # snapshot to the host checkpoint store within park_max_bytes,
-    # resume from chunk k warm); drain failover may split the
-    # unstarted chunk range across siblings (work stealing)
-    preemption_enabled: bool = True
-    park_max_bytes: int = 256 << 20
-    mesh_scheduler_weights: str = ""
-    mesh_scheduler_min_slice_chunks: int = 1
-    mesh_scheduler_group: str = ""
-    mesh_steal_enabled: bool = True
-    # multi-host replica fabric (runtime/fabric.py): park budgets are
-    # apportioned across resource groups by scheduler weight out of
-    # mesh_park_max_bytes (0 = unscoped, fall back to park_max_bytes);
-    # fabric_peers names sibling coordinators whose checkpoint stores
-    # receive async pushes at checkpoint boundaries and serve pulls at
-    # failover, with fabric_max_error_duration_s bounding the retry
-    # budget per peer request
-    mesh_park_max_bytes: int = 0
-    fabric_peers: str = ""
-    fabric_queue_depth: int = 8
-    fabric_max_error_duration_s: float = 5.0
-
-    def set_property(self, name: str, value) -> None:
-        """SET SESSION entry point — validated through the typed
-        registry (config.SYSTEM_PROPERTIES)."""
-        from trino_tpu.config import bind_session
-
-        bind_session(self, {name: value})
 
 
 @dataclasses.dataclass
@@ -272,9 +84,7 @@ class LocalQueryRunner:
         # §2.9); serving/plan_cache.py owns keying/LRU/counters
         from trino_tpu.serving.plan_cache import PlanCache
 
-        self._plan_cache = PlanCache(
-            max_entries=getattr(self.session, "plan_cache_entries", 256)
-        )
+        self._plan_cache = PlanCache()
         # dtype vector of the current EXECUTE's bound parameters (part
         # of the plan-cache key; set around the re-dispatch). Thread-
         # local: the HTTP server runs concurrent statements on one
@@ -552,13 +362,11 @@ class LocalQueryRunner:
             self.session.set_property(stmt.name, stmt.value)
             return MaterializedResult([[True]], ["result"], [T.BOOLEAN])
         if isinstance(stmt, ast.ShowSession):
-            from trino_tpu.config import SYSTEM_PROPERTIES
-
             rows = []
             for meta in SYSTEM_PROPERTIES.all():
-                current = getattr(self.session, meta.name, None)
+                current = getattr(self.session, meta.name)
                 if meta.name == "memory_pool_bytes":
-                    current = self.session.memory_pool_bytes or 0
+                    current = current or 0
                 rows.append(
                     [meta.name, str(current), str(meta.default), meta.description]
                 )
@@ -642,7 +450,7 @@ class LocalQueryRunner:
         root = optimize(analyzer.plan(q), self.catalogs, self.session)
         # correctness pass: runs regardless of enable_optimizer
         root = canonicalize_tstz_keys(root)
-        mode = getattr(self.session, "plan_validation", "passes")
+        mode = self.session.plan_validation
         if mode != "off":
             from trino_tpu.sql.validate import validate_logical
 
@@ -1435,14 +1243,12 @@ class LocalQueryRunner:
     def _make_stabilizer(self):
         """Session's capacity policy (compile/shapes.py); None when
         shape stabilization is off."""
-        if not getattr(self.session, "shape_stabilization", True):
+        if not self.session.shape_stabilization:
             return None
         from trino_tpu.compile.shapes import CapacityLadder, ShapeStabilizer
 
         return ShapeStabilizer(
-            CapacityLadder(
-                base=getattr(self.session, "capacity_ladder_base", 2)
-            ),
+            CapacityLadder(base=self.session.capacity_ladder_base),
             batch_rows=self.session.batch_rows,
         )
 
@@ -1450,7 +1256,7 @@ class LocalQueryRunner:
         """Kick off census-driven AOT warmup per warmup_mode; returns
         the (started) WarmupService or None. mode=block waits here, so
         execution starts with every predicted program compiled."""
-        mode = getattr(self.session, "warmup_mode", "off")
+        mode = self.session.warmup_mode
         entries = getattr(physical, "warmup_entries", ())
         if mode == "off" or not entries:
             return None
@@ -1623,13 +1429,7 @@ class LocalQueryRunner:
             op.flush_counts()
         after = METRICS.snapshot()
         counters = engine_counters_delta(before, after)
-        census = census_text(
-            classes,
-            warn_threshold=getattr(
-                self.session, "compile_churn_warn_threshold", 0
-            ),
-            observed=len(ledger),
-        )
+        census = census_text(classes, observed=len(ledger))
         # compile-regime lines ride directly under the census: per-query
         # attributed compile count (satellite of the process-wide
         # xla_compiles engine counter), warmup hit/miss, cache stats

@@ -326,7 +326,7 @@ def build_chunk_plan(mesh_sps, root_child_ids, feeds, shard_caps, session):
     chunk_rows = chunk_rows_for(session, max(shard_caps, default=0))
     if chunk_rows > 0 and feeds:
         ladder = CapacityLadder(
-            base=int(getattr(session, "capacity_ladder_base", 2) or 2)
+            base=int(session.capacity_ladder_base or 2)
         )
         by_pos: Dict[int, List[int]] = {}
         for key, pos in feeds.items():
@@ -1422,7 +1422,7 @@ class ChunkedMeshRunner:
                 "steals": 0,
             }
             resume_budget = int(
-                getattr(self.session, "mesh_resume_attempts", 2) or 0
+                self.session.mesh_resume_attempts or 0
             )
             overflows = 0
             attempt = 0
@@ -1555,9 +1555,7 @@ class ChunkedMeshRunner:
 
         n = self.ex.n
         K = record.n_chunks
-        watchdog_s = float(
-            getattr(self.session, "stuck_task_interrupt_s", 0.0) or 0.0
-        )
+        watchdog_s = float(self.session.stuck_task_interrupt_s or 0.0)
         outs: Dict[int, Tuple[object, bool]] = {}
 
         if preempt is not None:
@@ -1570,10 +1568,7 @@ class ChunkedMeshRunner:
             for (fid, rep), b in zip(record.prelude_out_meta, p_outs):
                 outs[fid] = (b, rep)
 
-        interval = int(
-            getattr(self.session, "mesh_checkpoint_interval_chunks", 0)
-            or 0
-        )
+        interval = int(self.session.mesh_checkpoint_interval_chunks or 0)
         # park_key: program identity for scheduler parks (and for the
         # resume-on-entry lookup — a parked query failed over by a
         # drain resumes here on the sibling even with periodic
@@ -1764,10 +1759,7 @@ class ChunkedMeshRunner:
             from trino_tpu.adaptive.observer import record_observation
             from trino_tpu.sql.stats import StatsCalculator
 
-            threshold = float(
-                getattr(self.session, "adaptive_replan_threshold", 4.0)
-                or 4.0
-            )
+            threshold = float(self.session.adaptive_replan_threshold or 4.0)
             from trino_tpu.sql.stats import PlanStats
 
             frag_rows: Dict[int, float] = {}
@@ -1819,9 +1811,7 @@ class ChunkedMeshRunner:
         record key, so uncacheable plans (repr-identity leaks) never
         pin."""
         rkey = None
-        budget_mb = int(
-            getattr(self.session, "resident_pin_budget_mb", 64) or 0
-        )
+        budget_mb = int(self.session.resident_pin_budget_mb or 0)
         if self._last_record_key is not None and budget_mb > 0:
             from trino_tpu.resident import GENERATIONS, RESIDENT
 
@@ -1942,18 +1932,7 @@ class ChunkedMeshRunner:
             tables=self.feed_tables,
             generations=GENERATIONS.snapshot(self.feed_tables),
         )
-        budget = int(
-            getattr(self.session, "park_max_bytes", 256 << 20)
-        )
-        group = None
-        # admission-weighted park pool: mesh_park_max_bytes apportioned
-        # across resource groups by scheduler weight — a group past its
-        # share gets refused (in-place yield), never failed
-        pool = int(getattr(self.session, "mesh_park_max_bytes", 0) or 0)
-        if pool > 0:
-            budget = job.scheduler.park_budget_for(job, pool)
-            group = job.group
-        if not CHECKPOINTS.park(key, ckpt, budget, group=group):
+        if not CHECKPOINTS.park(key, ckpt, int(self.session.park_max_bytes)):
             job.park_refused()
             if task_span is not None:
                 task_span.event("park_refused", chunk=next_chunk, of=K)

@@ -57,7 +57,7 @@ _place_lock = named_lock("mesh_feed._place_lock")
 def chunk_rows_for(session, shard_rows: int) -> int:
     """Rows per chunk of a driver scan whose largest shard has
     `shard_rows` rows; 0 means one program."""
-    explicit = int(getattr(session, "mesh_chunk_rows", 0) or 0)
+    explicit = int(session.mesh_chunk_rows or 0)
     if explicit > 0:
         return explicit
     return AUTO_CHUNK_ROWS if shard_rows > AUTO_CHUNK_ROWS else 0

@@ -112,9 +112,6 @@ class MeshCheckpointStore:
         # keys are pinned (immune to LRU eviction) and their host
         # bytes are accounted against the session park budget.
         self._parked: Dict[tuple, int] = {}  # guarded_by: _lock — key -> accounted bytes
-        # resource group a parked entry is accounted to (admission-
-        # weighted park budgets: runtime/scheduler.py park_budget_for)
-        self._park_groups: Dict[tuple, str] = {}  # guarded_by: _lock
         self.parked_refused = 0
 
     def _generations(self, tables) -> Tuple[int, ...]:
@@ -170,7 +167,6 @@ class MeshCheckpointStore:
         with self._lock:
             self._entries.pop(key, None)
             self._parked.pop(key, None)
-            self._park_groups.pop(key, None)
 
     # -- park lifecycle (preemptive scheduler) ------------------------
     @staticmethod
@@ -185,36 +181,23 @@ class MeshCheckpointStore:
             total += int(arr.nbytes)
         return total
 
-    def park(self, key: tuple, ckpt: MeshCheckpoint,
-             max_bytes: int, group: Optional[str] = None) -> bool:
+    def park(self, key: tuple, ckpt: MeshCheckpoint, max_bytes: int) -> bool:
         """Install a parked query's snapshot, accounting its host bytes
-        against `max_bytes`. With `group=None` the budget is shared by
-        every parked entry (the park_max_bytes pool); with a group the
-        budget is that GROUP's share of the admission-weighted pool
-        (mesh_park_max_bytes apportioned by scheduler weight) and only
-        same-group entries count against it — one group past its share
-        cannot starve another's parks. Returns False (store untouched)
-        when the budget refuses — the caller keeps its device carries
-        and runs to completion."""
+        against `max_bytes`, the budget every parked entry shares (the
+        park_max_bytes pool). Returns False (store untouched) when the
+        budget refuses — the caller keeps its device carries and runs
+        to completion."""
         from trino_tpu.runtime.metrics import METRICS
 
         nbytes = self._ckpt_nbytes(ckpt)
         with self._lock:
-            in_use = sum(
-                b for k, b in self._parked.items()
-                if k != key
-                and (group is None or self._park_groups.get(k) == group)
-            )
+            in_use = sum(b for k, b in self._parked.items() if k != key)
             if max_bytes >= 0 and in_use + nbytes > max_bytes:
                 self.parked_refused += 1
                 return False
             self._entries[key] = ckpt
             self._entries.move_to_end(key)
             self._parked[key] = nbytes
-            if group is not None:
-                self._park_groups[key] = group
-            else:
-                self._park_groups.pop(key, None)
             self.taken += 1
         METRICS.increment(CHECKPOINTS_TAKEN)
         return True
@@ -227,7 +210,6 @@ class MeshCheckpointStore:
         (typed kills: a dead query must never resume)."""
         with self._lock:
             self._parked.pop(key, None)
-            self._park_groups.pop(key, None)
             if not keep:
                 self._entries.pop(key, None)
 
@@ -302,7 +284,6 @@ class MeshCheckpointStore:
         with self._lock:
             self._entries.clear()
             self._parked.clear()
-            self._park_groups.clear()
 
     def reset_stats(self) -> None:
         """Zero the lifetime counters (corpus generation and tests pin

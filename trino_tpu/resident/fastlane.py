@@ -50,7 +50,7 @@ def _resolve_table(table_sql: str, session) -> Tuple[str, str, str]:
 def _configured(tkey: Tuple[str, str, str], session) -> bool:
     names = [
         t.strip().lower()
-        for t in str(getattr(session, "resident_tables", "") or "").split(",")
+        for t in str(session.resident_tables or "").split(",")
         if t.strip()
     ]
     cat, schema, table = tkey
@@ -76,7 +76,7 @@ def try_resident_lookup(runner, sql: str, identity=None, prepared=None,
     from trino_tpu.runtime.metrics import METRICS
 
     session = getattr(runner, "session", None)
-    if session is None or not getattr(session, "resident_tables", ""):
+    if session is None or not session.resident_tables:
         return None
     from trino_tpu.serving.batcher import classify
 
@@ -154,13 +154,9 @@ def _build_and_probe(runner, session, look, tkey, ikey, gen, dkind,
         [r[0] for r in result.rows],
         [r[1:] for r in result.rows],
         string_key=(dkind == "s"),
-        delta_max_rows=int(
-            getattr(session, "resident_delta_max_rows", 4096)
-        ),
+        delta_max_rows=int(session.resident_delta_max_rows),
     )
-    RESIDENT.configure(
-        int(getattr(session, "resident_pin_budget_mb", 64)) << 20
-    )
+    RESIDENT.configure(int(session.resident_pin_budget_mb) << 20)
     key = _full_key(
         tkey, look.key_col, look.select_sql, dkind,
         table.dtype_sig, table.base_cap, gen,

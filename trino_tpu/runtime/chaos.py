@@ -28,10 +28,7 @@ drains racing a live query and a straggler that speculation must beat
 (run_drain_case, run_speculation_case: tests/test_chaos.py), a hung
 operator and a vanished client (TIMEBOUND_CLASSES:
 tests/test_deadlines.py), and the seeded faults inside the mesh chunk
-loop (PREEMPT_CLASSES, FABRIC_CLASSES: tests/test_chaos.py). The three
-population cases (run_loaded_cluster_case and the two built on it)
-sleep and race a drain against live client threads, so no test calls
-them (ROADMAP.md, D9).
+loop (PREEMPT_CLASSES, FABRIC_CLASSES: tests/test_chaos.py).
 """
 
 from __future__ import annotations
@@ -785,7 +782,6 @@ class ChaosHarness:
         memory_pool_bytes: Optional[int] = None,
         stuck_task_interrupt_s: Optional[float] = None,
         stuck_task_interrupt_warm_s: Optional[float] = None,
-        in_process: bool = False,
     ):
         from trino_tpu.engine import Session
         from trino_tpu.runtime.coordinator import DistributedQueryRunner
@@ -799,23 +795,6 @@ class ChaosHarness:
         from trino_tpu.connectors.spi import CatalogManager
 
         self._catalogs = CatalogManager()
-        self.in_process = in_process
-        if in_process:
-            # the mesh plane only engages on COLOCATED (engine-owned)
-            # workers, so the recovery drain case builds the runner on
-            # the n_workers path and exposes its Workers for the drain
-            # bookkeeping. Injector schedules do not land here — mesh
-            # faults arrive through MESH_FAULT_HOOK instead.
-            self.stuck_task_interrupt_s = stuck_task_interrupt_s
-            self.runner = DistributedQueryRunner(
-                self.session,
-                n_workers=n_workers,
-                hash_partitions=hash_partitions,
-            )
-            self.workers = list(self.runner.workers)
-            for name, conn in (catalogs or {}).items():
-                self.register_catalog(name, conn)
-            return
         # every worker sits behind a DownableWorker proxy so lifecycle
         # cases can count ACCEPTED launches (drain assertions) and take
         # nodes dark (graylist assertions) without touching the engine
@@ -945,7 +924,7 @@ class ChaosHarness:
         speculation_wins/losses and attempts_per_partition).
 
         stall_s must comfortably exceed the query's REAL per-task wall
-        time: the trigger is `age > speculation_quantile * median`, and
+        time: the trigger is `age > STRAGGLER_WALL_MULTIPLE * median`, and
         a stalled attempt's age only reaches `stall + wall`, so a stall
         close to the task wall never crosses 2x median and the scenario
         silently degrades to a plain wait. The duplicate wins and
@@ -1010,7 +989,7 @@ class ChaosHarness:
         # speculation would race the watchdog to the rescue (a duplicate
         # attempt commits and cancels the wedged loser) — turn it off so
         # THIS case proves the watchdog path alone unhangs the query
-        was_spec = getattr(self.session, "speculation_enabled", True)
+        was_spec = self.session.speculation_enabled
         self.session.speculation_enabled = False
         for w in self.workers:
             w.start_watchdog()
@@ -1099,185 +1078,3 @@ class ChaosHarness:
         finally:
             self.injector.clear()
             server.stop()
-
-    def run_loaded_cluster_case(
-        self, queries: Dict[str, str], seed: int = 0,
-        n_clients: int = 6, duration_s: float = 3.0,
-        join_timeout_s: float = 45.0,
-    ) -> Tuple[None, dict]:
-        """Faults under LIVE concurrent load, through the HTTP serving
-        path end to end (admission lanes, plan cache, statement
-        protocol). N client threads drive the query mix closed-loop
-        while the fault schedule lands mid-traffic; every completion is
-        checked against the clean-run oracle. Acceptable per-query
-        outcomes: oracle-equal rows, an overload shed (HTTP 429), or a
-        TYPED failure (a bracketed error code the client can act on).
-        An untyped error or a client thread that never returns is a
-        violation — under concurrency, a silent hang is the failure
-        mode this case exists to catch."""
-        import re
-        import urllib.error
-
-        from trino_tpu.client import Client, QueryError
-        from trino_tpu.runtime.server import CoordinatorServer
-
-        rng = random.Random(seed)
-        self.injector.clear()
-        oracle = {n: self.run_clean(sql) for n, sql in queries.items()}
-        ordered = {
-            n: "order by" in sql.lower() for n, sql in queries.items()
-        }
-        server = CoordinatorServer(self.runner, max_concurrent=n_clients)
-        lock = threading.Lock()
-        stats = {
-            "completed": 0, "ok": 0, "mismatches": 0, "sheds": 0,
-            "typed_failures": 0, "untyped_errors": [], "hung_threads": 0,
-        }
-        typed = re.compile(r"\[[A-Z][A-Z_]+\]")
-        stop_at = time.monotonic() + duration_s
-
-        def client_loop(i: int):
-            r = random.Random(seed * 997 + i)
-            c = Client(server.uri, timeout=30.0, poll_interval=0.005)
-            names = list(queries)
-            while time.monotonic() < stop_at:
-                name = r.choice(names)
-                try:
-                    rows = c.execute(queries[name]).rows
-                    with lock:
-                        stats["completed"] += 1
-                        if rows_equal(rows, oracle[name],
-                                      ordered=ordered[name]):
-                            stats["ok"] += 1
-                        else:
-                            stats["mismatches"] += 1
-                except urllib.error.HTTPError as e:
-                    with lock:
-                        stats["completed"] += 1
-                        if e.code == 429:
-                            stats["sheds"] += 1
-                        else:
-                            stats["untyped_errors"].append(
-                                f"{name}: HTTP {e.code}"
-                            )
-                except QueryError as e:
-                    with lock:
-                        stats["completed"] += 1
-                        if typed.search(str(e)):
-                            stats["typed_failures"] += 1
-                        else:
-                            stats["untyped_errors"].append(
-                                f"{name}: {e}"
-                            )
-                except Exception as e:
-                    with lock:
-                        stats["completed"] += 1
-                        stats["untyped_errors"].append(
-                            f"{name}: {type(e).__name__}: {e}"
-                        )
-
-        threads = [
-            threadreg.spawn(f"chaos-client-{i}", client_loop, args=(i,),
-                            owner="chaos", start=False)
-            for i in range(n_clients)
-        ]
-        try:
-            for t in threads:
-                t.start()
-            # let traffic establish, then land a burst of every injector
-            # fault class MID-FLIGHT; clear before the phase ends so the
-            # tail of the run proves the cluster comes back clean
-            time.sleep(min(0.4, duration_s / 4))
-            for fc in ("task_crash_start", "task_crash_mid",
-                       "fetch_loss", "oom"):
-                for rule in generate_schedule(rng.randrange(1 << 20), fc):
-                    self.injector.inject(**rule)
-            # and a lifecycle maneuver on top: gracefully drain one
-            # worker out from under the live population (one-way, so
-            # the remaining nodes carry the tail of the run)
-            drain_ok = self.runner.drain(
-                self.workers[rng.randrange(len(self.workers))].worker_id,
-                timeout_s=30.0,
-            )
-            time.sleep(min(1.0, duration_s / 2))
-            self.injector.clear()
-            deadline = time.monotonic() + duration_s + join_timeout_s
-            for t in threads:
-                t.join(max(0.1, deadline - time.monotonic()))
-            stats["hung_threads"] = sum(t.is_alive() for t in threads)
-        finally:
-            self.injector.clear()
-            server.stop()
-        stats["drained"] = bool(drain_ok)
-        stats["untyped_error_count"] = len(stats["untyped_errors"])
-        stats["untyped_errors"] = stats["untyped_errors"][:5]
-        return None, stats
-
-    def run_adaptive_drain_case(
-        self, queries: Dict[str, str], seed: int = 0, **kw,
-    ) -> Tuple[None, dict]:
-        """PR 13: loaded-cluster faults + mid-traffic drain against an
-        ADAPTIVE session (construct the harness with adaptive_execution
-        on and a permissive re-plan threshold). Delegates the population
-        mechanics to run_loaded_cluster_case and adds the adaptive
-        counters observed during the phase, so the caller can assert
-        the drain actually landed on a cluster that was re-planning."""
-        from trino_tpu.runtime.metrics import METRICS
-
-        before = METRICS.snapshot()
-        _, report = self.run_loaded_cluster_case(queries, seed, **kw)
-        after = METRICS.snapshot()
-        for counter in ("adaptive.replans", "adaptive.divergences",
-                        "adaptive.spool_hits"):
-            report[counter] = int(
-                after.get(counter, 0) - before.get(counter, 0)
-            )
-        return None, report
-
-    def run_recovery_drain_case(
-        self, queries: Dict[str, str], seed: int = 0,
-        n_faults: int = 3, **kw,
-    ) -> Tuple[None, dict]:
-        """PR 8 carry-forward, re-aimed (PR 14): the drain_mid_query /
-        drain_all_but_one maneuvers now land on the loaded_cluster
-        POPULATION instead of one isolated query — construct the
-        harness with in_process=True and mesh checkpointing on, and
-        mesh faults raise mid-chunk (MESH_FAULT_HOOK at the middle
-        boundary, first n_faults hits) while run_loaded_cluster_case
-        drains a worker out from under the live traffic. Faulted
-        queries must RESUME from checkpoint on the surviving capacity
-        (report carries checkpoint_resumes from the store's counters),
-        and every completion still checks against the clean oracle."""
-        from trino_tpu.parallel import mesh_chunk
-        from trino_tpu.recovery import CHECKPOINTS
-
-        if not self.in_process:
-            raise ValueError(
-                "run_recovery_drain_case needs in_process=True (the "
-                "mesh plane only engages on colocated workers)"
-            )
-        lock = threading.Lock()
-        state = {"fired": 0}
-
-        def hook(k: int, K: int) -> None:
-            # deterministic allowance, not a coin flip: the first
-            # n_faults arrivals at a mid-run boundary fault; everything
-            # after runs clean so the tail proves the cluster recovered
-            with lock:
-                if K >= 2 and k == max(1, K // 2) \
-                        and state["fired"] < n_faults:
-                    state["fired"] += 1
-                    raise mesh_chunk.MeshDeviceLost(
-                        f"chaos[recovery_drain]: injected device loss "
-                        f"at chunk {k}/{K}"
-                    )
-
-        resumed0 = CHECKPOINTS.resumed
-        mesh_chunk.MESH_FAULT_HOOK = hook
-        try:
-            _, report = self.run_loaded_cluster_case(queries, seed, **kw)
-        finally:
-            mesh_chunk.MESH_FAULT_HOOK = None
-        report["mesh_faults_fired"] = state["fired"]
-        report["checkpoint_resumes"] = CHECKPOINTS.resumed - resumed0
-        return None, report

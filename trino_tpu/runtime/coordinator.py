@@ -20,6 +20,7 @@ from typing import Callable, Dict, List, Optional
 from trino_tpu import types as T
 from trino_tpu.connectors.spi import CatalogManager, Connector
 from trino_tpu.engine import MaterializedResult, Session
+from trino_tpu.runtime.stages import DEFAULT_HASH_PARTITIONS
 from trino_tpu.runtime.task import TaskId, TaskSpec
 from trino_tpu.runtime.worker import Worker
 from trino_tpu.sql import ast
@@ -57,7 +58,7 @@ class QueryScheduler:
         self.collect_stats = collect_stats
         self.deadline_epoch_s = deadline_epoch_s
         self.hash_partitions = hash_partitions or min(
-            len(workers), session.hash_partition_count
+            len(workers), DEFAULT_HASH_PARTITIONS
         )
         # fragment id -> [(worker handle, task id string)]
         self.tasks: Dict[int, List] = {}
@@ -122,9 +123,7 @@ class QueryScheduler:
                 KIND_TASK,
                 wire_context,
             )
-        record_stages = bool(
-            getattr(self.session, "recovery_spool_stages", False)
-        )
+        record_stages = bool(self.session.recovery_spool_stages)
         if record_stages:
             from trino_tpu.recovery import RECORDER, fragment_recordable
         root_fid = self.subplan.fragment.id
@@ -171,12 +170,8 @@ class QueryScheduler:
                     dynamic_filtering=self.session.enable_dynamic_filtering,
                     collect_stats=self.collect_stats,
                     task_concurrency=self.session.task_concurrency,
-                    shape_stabilization=getattr(
-                        self.session, "shape_stabilization", True
-                    ),
-                    capacity_ladder_base=getattr(
-                        self.session, "capacity_ladder_base", 2
-                    ),
+                    shape_stabilization=self.session.shape_stabilization,
+                    capacity_ladder_base=self.session.capacity_ladder_base,
                     deadline_epoch_s=self.deadline_epoch_s,
                     record_output=record_this,
                 )
@@ -329,12 +324,12 @@ class DistributedQueryRunner:
                 Worker(
                     f"worker-{i}", self.catalogs,
                     memory_pool_bytes=self.session.memory_pool_bytes,
-                    stuck_task_interrupt_s=getattr(
-                        self.session, "stuck_task_interrupt_s", 0.0
-                    ) or None,
-                    stuck_task_interrupt_warm_s=getattr(
-                        self.session, "stuck_task_interrupt_warm_s", 0.0
-                    ) or None,
+                    stuck_task_interrupt_s=(
+                        self.session.stuck_task_interrupt_s or None
+                    ),
+                    stuck_task_interrupt_warm_s=(
+                        self.session.stuck_task_interrupt_warm_s or None
+                    ),
                 )
                 for i in range(n_workers)
             ]
@@ -360,23 +355,11 @@ class DistributedQueryRunner:
         # deterministic tests)
         from trino_tpu.runtime.discovery import NodeManager
 
-        self.node_manager = NodeManager(
-            breaker_threshold=self.session.node_breaker_threshold,
-            breaker_cooldown_s=self.session.node_breaker_cooldown_s,
-        )
+        self.node_manager = NodeManager()
         for w in self.workers:
             self.node_manager.register(w)
-            # remote handles (HttpWorkerClient): bind the session's
-            # retry budget and the breaker listener unless the caller
-            # already chose them explicitly
-            if getattr(w, "retry_policy", False) is None:
-                from trino_tpu.runtime.error_tracker import RetryPolicy
-
-                w.retry_policy = RetryPolicy(
-                    max_error_duration_s=(
-                        self.session.request_max_error_duration_s
-                    ),
-                )
+            # remote handles (HttpWorkerClient): bind the breaker
+            # listener unless the caller already chose one explicitly
             if (
                 hasattr(w, "failure_listener")
                 and w.failure_listener is None
@@ -390,11 +373,7 @@ class DistributedQueryRunner:
         # cluster memory arbiter over the in-process workers' SHARED
         # pools: on exhaustion kill the largest query, not the worker
         self.memory_manager = None
-        if (
-            self._in_process_workers
-            and self.session.memory_pool_bytes
-            and self.session.low_memory_killer_enabled
-        ):
+        if self._in_process_workers and self.session.memory_pool_bytes:
             from trino_tpu.runtime.memory import ClusterMemoryManager
 
             self.memory_manager = ClusterMemoryManager(
@@ -439,9 +418,7 @@ class DistributedQueryRunner:
         # split listings describe a data snapshot.
         from trino_tpu.serving.plan_cache import PlanCache
 
-        self._plan_cache = PlanCache(
-            max_entries=getattr(self.session, "plan_cache_entries", 256)
-        )
+        self._plan_cache = PlanCache()
         # replicated serving meshes (runtime/replicas.py): carved
         # lazily on the first mesh dispatch with mesh_replicas >= 2
         # (device carving needs jax initialized, which query execution
@@ -589,7 +566,7 @@ class DistributedQueryRunner:
                 output, self.catalogs,
                 broadcast_threshold=self.session.broadcast_join_threshold,
                 target_splits=self.session.target_splits,
-                validation=getattr(self.session, "plan_validation", "passes"),
+                validation=self.session.plan_validation,
             )
             if stmt.analyze:
                 return self._explain_analyze(subplan)
@@ -806,9 +783,7 @@ class DistributedQueryRunner:
                     self.catalogs,
                     broadcast_threshold=self.session.broadcast_join_threshold,
                     target_splits=self.session.target_splits,
-                    validation=getattr(
-                        self.session, "plan_validation", "passes"
-                    ),
+                    validation=self.session.plan_validation,
                 )
             if (
                 cache_key is not None
@@ -962,7 +937,7 @@ class DistributedQueryRunner:
         # the subtree spool and the NEXT attempt substitutes them as
         # literal sources (only the work that failed is recomputed)
         spool_stages = attempts > 1 and bool(
-            getattr(self.session, "recovery_spool_stages", False)
+            self.session.recovery_spool_stages
         )
         last_error: Optional[BaseException] = None
         accrued_cpu = 0.0  # CPU spent by completed attempts
@@ -1023,9 +998,7 @@ class DistributedQueryRunner:
                 self.catalogs,
                 self.session,
                 self.hash_partitions,
-                collect_stats=(
-                    getattr(self.session, "query_trace", "off") == "on"
-                ),
+                collect_stats=self.session.query_trace == "on",
                 trace=trace,
                 query_span=query_span,
                 deadline_epoch_s=deadline_epoch_s,
@@ -1086,7 +1059,7 @@ class DistributedQueryRunner:
         identical sub-meshes (runtime/replicas.py). None — the single
         full-width mesh — when replication is off or the device set is
         too small to carve."""
-        n = int(getattr(self.session, "mesh_replicas", 1) or 1)
+        n = int(self.session.mesh_replicas or 1)
         if n < 2:
             return None
         rm = self._replicas
@@ -1095,16 +1068,7 @@ class DistributedQueryRunner:
         from trino_tpu.runtime.replicas import ReplicaManager
 
         try:
-            rm = ReplicaManager(
-                n,
-                breaker_threshold=int(getattr(
-                    self.session, "replica_breaker_threshold", 3
-                )),
-                breaker_cooldown_s=float(getattr(
-                    self.session, "replica_breaker_cooldown_s", 1.0
-                )),
-                scheduler_kw=self._scheduler_kw(),
-            )
+            rm = ReplicaManager(n, scheduler_kw=self._scheduler_kw())
         except ValueError:
             rm = None  # fewer devices than replicas: keep one mesh
         self._replicas = rm
@@ -1114,25 +1078,16 @@ class DistributedQueryRunner:
         from trino_tpu.runtime.scheduler import parse_group_weights
 
         return {
-            "min_slice_chunks": int(getattr(
-                self.session, "mesh_scheduler_min_slice_chunks", 1
-            ) or 1),
-            "preemption_enabled": bool(getattr(
-                self.session, "preemption_enabled", True
-            )),
-            "weights": parse_group_weights(str(getattr(
-                self.session, "mesh_scheduler_weights", ""
-            ) or "")),
+            "weights": parse_group_weights(
+                str(self.session.mesh_scheduler_weights or "")
+            ),
         }
 
     def _tune_scheduler(self, sched) -> None:
         """Refresh a live scheduler's knobs from the current session —
         SET SESSION between queries must take effect without rebuilding
         the run queue (waiting jobs keep their seats)."""
-        kw = self._scheduler_kw()
-        sched.min_slice_chunks = max(1, int(kw["min_slice_chunks"]))
-        sched.preemption_enabled = bool(kw["preemption_enabled"])
-        sched.weights = dict(kw["weights"])
+        sched.weights = self._scheduler_kw()["weights"]
 
     def _mesh_scheduler_for(self):
         if self._mesh_scheduler is None:
@@ -1146,9 +1101,7 @@ class DistributedQueryRunner:
         return self._mesh_scheduler
 
     def _sched_group(self) -> str:
-        return str(getattr(
-            self.session, "mesh_scheduler_group", ""
-        ) or "") or "default"
+        return str(self.session.mesh_scheduler_group or "") or "default"
 
     def _execute_mesh(self, subplan, preempt, query_span, fast=False,
                       query_id=""):
@@ -1159,8 +1112,8 @@ class DistributedQueryRunner:
         mid-query, re-place onto a sibling — the sibling's chunk runner
         finds the host-portable checkpoint under the device-independent
         key and continues from chunk k on its own warm programs. Only
-        when no sibling remains (or failover is off) does the fault
-        re-raise into the caller's page-plane fallback.
+        when no sibling remains does the fault re-raise into the
+        caller's page-plane fallback.
 
         The serialization point is the seat of the weighted-fair run
         queue (runtime/scheduler.py): the holder's chunk loop consults
@@ -1211,10 +1164,6 @@ class DistributedQueryRunner:
                 )
             finally:
                 sched.finish(job)
-        failover_on = bool(
-            getattr(self.session, "replica_failover_enabled", True)
-        )
-        steal_on = bool(getattr(self.session, "mesh_steal_enabled", True))
         tried: set = set()
         # membership-epoch fencing: a failover remembers the epoch it
         # faulted under; a resume target whose join_epoch moved past it
@@ -1305,7 +1254,7 @@ class DistributedQueryRunner:
                     r.state == "active" and r.replica_id not in tried
                     for r in rm.replicas
                 )
-                if not failover_on or not have_sibling:
+                if not have_sibling:
                     raise
                 rm.note_failover(rep)
                 if query_span is not None:
@@ -1316,8 +1265,7 @@ class DistributedQueryRunner:
                         reason=str(e)[:300],
                     )
                 if (
-                    steal_on
-                    and isinstance(e, MeshReplicaDraining)
+                    isinstance(e, MeshReplicaDraining)
                     and getattr(e, "steal_ok", False)
                     and getattr(e, "ckpt_key", None) is not None
                 ):
@@ -1459,7 +1407,7 @@ class DistributedQueryRunner:
         would pick for this plan, decided STATICALLY (structural
         eligibility + collective census, no second execution) so the
         output is deterministic under program-cache hits."""
-        if getattr(self.session, "retry_policy", "none") == "task":
+        if self.session.retry_policy == "task":
             return "data_plane=fte"
         if not (self.session.mesh_execution and self._mesh_colocated()):
             return "data_plane=http"
@@ -1473,7 +1421,7 @@ class DistributedQueryRunner:
         except MeshUnsupported as ex:
             self._record_mesh_fallback(str(ex))
             return f"data_plane=http (mesh fallback: {ex})"
-        chunk_rows = int(getattr(self.session, "mesh_chunk_rows", 0) or 0)
+        chunk_rows = int(self.session.mesh_chunk_rows or 0)
         chunking = (
             f"chunk_rows={chunk_rows}" if chunk_rows > 0 else "unchunked"
         )
@@ -1547,7 +1495,7 @@ class DistributedQueryRunner:
         corpus output stays deterministic across process reuse."""
         rm = self._replicas
         if rm is None:
-            n = int(getattr(self.session, "mesh_replicas", 1) or 1)
+            n = int(self.session.mesh_replicas or 1)
             return f"replicas= n={n} (single mesh)"
         return rm.stats_line()
 
@@ -1609,9 +1557,6 @@ class DistributedQueryRunner:
             catalogs=self.catalogs,
             batch_rows=self.session.batch_rows,
             dynamic_filtering=self.session.enable_dynamic_filtering,
-            warn_threshold=getattr(
-                self.session, "compile_churn_warn_threshold", 0
-            ),
         )
 
     def _explain_analyze(self, subplan) -> MaterializedResult:
@@ -1685,9 +1630,7 @@ class DistributedQueryRunner:
                 node_manager=self.node_manager,
                 trace=trace,
                 query_span=query_span,
-                collect_stats=(
-                    getattr(self.session, "query_trace", "off") == "on"
-                ),
+                collect_stats=self.session.query_trace == "on",
                 deadline_epoch_s=deadline_epoch_s,
             )
             if tq is not None:
@@ -1790,7 +1733,7 @@ class DistributedQueryRunner:
             # packed zone bits still set, splitting equal instants
             # across tasks)
             root = canonicalize_tstz_keys(root)
-        if getattr(self.session, "plan_validation", "passes") != "off":
+        if self.session.plan_validation != "off":
             from trino_tpu.sql.validate import validate_logical
 
             with phase("validate"):
@@ -1948,10 +1891,7 @@ class DistributedQueryRunner:
             )
 
             estimates = self._fragment_estimates(subplan)
-            threshold = float(
-                getattr(self.session, "adaptive_replan_threshold", 4.0)
-                or 4.0
-            )
+            threshold = float(self.session.adaptive_replan_threshold or 4.0)
             for stage in stages:
                 fid = stage.get("fragment_id")
                 est = estimates.get(fid)
@@ -2057,9 +1997,7 @@ class DistributedQueryRunner:
                     err_code = "EXCEEDED_MEMORY_LIMIT"
             retry_count = max(0, self.last_query_attempts - 1)
             attempt_count = 1
-            is_fte = (
-                getattr(self.session, "retry_policy", "none") == "task"
-            )
+            is_fte = self.session.retry_policy == "task"
             if is_fte and self.last_fte_stats:
                 app = (
                     self.last_fte_stats.get("attempts_per_partition")

@@ -357,7 +357,7 @@ def maybe_start_fabric(session, store=None) -> Optional[Fabric]:
     global ACTIVE_FABRIC
     peers = [
         p.strip()
-        for p in str(getattr(session, "fabric_peers", "") or "").split(",")
+        for p in str(session.fabric_peers or "").split(",")
         if p.strip()
     ]
     if not peers:
@@ -367,15 +367,7 @@ def maybe_start_fabric(session, store=None) -> Optional[Fabric]:
             return ACTIVE_FABRIC
         if ACTIVE_FABRIC is not None:
             ACTIVE_FABRIC.stop()
-        fab = Fabric(
-            peers, store=store,
-            queue_depth=int(
-                getattr(session, "fabric_queue_depth", 8) or 8
-            ),
-            max_error_duration_s=float(
-                getattr(session, "fabric_max_error_duration_s", 5.0) or 5.0
-            ),
-        )
+        fab = Fabric(peers, store=store)
         from trino_tpu.parallel import mesh_chunk
 
         mesh_chunk.CHECKPOINT_PUSH_HOOK = fab.push_hook()

@@ -18,6 +18,7 @@ import itertools
 import time
 from typing import Dict, List, Optional, Tuple
 
+from trino_tpu.runtime.stages import DEFAULT_HASH_PARTITIONS
 from trino_tpu.runtime.task import TaskId, TaskSpec
 from trino_tpu.sql.fragmenter import SubPlan
 
@@ -37,6 +38,12 @@ def _quantile(sorted_vals: List[float], q: float) -> float:
     hi = min(lo + 1, len(sorted_vals) - 1)
     frac = idx - lo
     return sorted_vals[lo] * (1.0 - frac) + sorted_vals[hi] * frac
+
+
+# a task speculates once it has run this multiple of its fragment's
+# estimated attempt wall time (the speculation_percentile quantile of
+# the committed attempts')
+STRAGGLER_WALL_MULTIPLE = 2.0
 
 
 class _LaunchFailed(Exception):
@@ -70,7 +77,9 @@ class FaultTolerantQueryScheduler:
         self.catalogs = catalogs
         self.session = session
         self.spool_dir = spool_dir
-        self.hash_partitions = hash_partitions or min(len(workers), 4)
+        self.hash_partitions = hash_partitions or min(
+            len(workers), DEFAULT_HASH_PARTITIONS
+        )
         self.max_task_retries = max_task_retries
         self.node_manager = node_manager
         if active_workers_fn is not None:
@@ -100,7 +109,7 @@ class FaultTolerantQueryScheduler:
         self.allocator = BinPackingNodeAllocator(node_manager=node_manager)
         self.estimator = PartitionMemoryEstimator()
         # straggler mitigation: duplicate attempts for tasks running
-        # `speculation_quantile`x beyond the stage's PER-FRAGMENT p75
+        # STRAGGLER_WALL_MULTIPLE x beyond the stage's PER-FRAGMENT p75
         # (speculation_percentile) of committed-attempt wall times,
         # provided a spare schedulable worker exists; first attempt to
         # commit wins (the one-committed-attempt-per-partition
@@ -108,13 +117,8 @@ class FaultTolerantQueryScheduler:
         # quantile beats the old median on skewed stages: half the tasks
         # being "slow-ish" no longer drags the threshold down and
         # triggers duplicate storms.
-        self.enable_speculation = getattr(session, "speculation_enabled", True)
-        self.speculation_quantile = float(
-            getattr(session, "speculation_quantile", 2.0)
-        )
-        self.speculation_percentile = float(
-            getattr(session, "speculation_percentile", 0.75)
-        )
+        self.enable_speculation = session.speculation_enabled
+        self.speculation_percentile = float(session.speculation_percentile)
         # fragment id -> the quantile wall-time estimate last used to
         # size its straggler threshold (surfaced in last_fte_stats)
         self.speculation_estimates: Dict[int, float] = {}
@@ -224,9 +228,7 @@ class FaultTolerantQueryScheduler:
         # records its committed spool files back into the spool so the
         # NEXT attempt after a failure starts further along.
         spooled_ids: set = set()
-        record_stages = bool(
-            getattr(self.session, "recovery_spool_stages", False)
-        )
+        record_stages = bool(self.session.recovery_spool_stages)
         if record_stages:
             from trino_tpu.recovery import substitute_spooled_fragments
 
@@ -358,12 +360,8 @@ class FaultTolerantQueryScheduler:
                 spool_dir=self.spool_dir,
                 dynamic_filtering=self.session.enable_dynamic_filtering,
                 task_concurrency=self.session.task_concurrency,
-                shape_stabilization=getattr(
-                    self.session, "shape_stabilization", True
-                ),
-                capacity_ladder_base=getattr(
-                    self.session, "capacity_ladder_base", 2
-                ),
+                shape_stabilization=self.session.shape_stabilization,
+                capacity_ladder_base=self.session.capacity_ladder_base,
                 collect_stats=self.collect_stats,
                 deadline_epoch_s=self.deadline_epoch_s,
             )
@@ -554,7 +552,7 @@ class FaultTolerantQueryScheduler:
                     and len(next_entries) == 1
                     and est_wall is not None
                     and now - next_entries[0][3]
-                    > max(self.speculation_quantile * est_wall, 0.25)
+                    > max(STRAGGLER_WALL_MULTIPLE * est_wall, 0.25)
                     and attempt_hwm[p] < self.max_task_retries
                 ):
                     handle = next_entries[0][0]

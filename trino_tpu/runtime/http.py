@@ -309,8 +309,8 @@ class HttpWorkerClient:
         self.uri = uri.rstrip("/")
         self.timeout = timeout
         self.worker_id = uri
-        # None = "not explicitly chosen": the coordinator may bind the
-        # session's request_max_error_duration_s onto it at registration
+        # None = "not explicitly chosen": every request then runs under
+        # a default RetryPolicy() (its 30 s error budget)
         self.retry_policy = retry_policy
         self.failure_listener = failure_listener
         self._auth = None

@@ -171,12 +171,11 @@ class DeadlineLimits:
 
     @classmethod
     def from_session(cls, session) -> "DeadlineLimits":
-        g = lambda n: float(getattr(session, n, 0.0) or 0.0)
         return cls(
-            max_planning_time_s=g("query_max_planning_time_s"),
-            max_execution_time_s=g("query_max_execution_time_s"),
-            max_run_time_s=g("query_max_run_time_s"),
-            max_cpu_time_s=g("query_max_cpu_time_s"),
+            max_planning_time_s=float(session.query_max_planning_time_s or 0.0),
+            max_execution_time_s=float(session.query_max_execution_time_s or 0.0),
+            max_run_time_s=float(session.query_max_run_time_s or 0.0),
+            max_cpu_time_s=float(session.query_max_cpu_time_s or 0.0),
         )
 
     def any(self) -> bool:

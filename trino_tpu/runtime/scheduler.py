@@ -147,11 +147,9 @@ class MeshScheduler:
     METRICS registry for /v1/metrics."""
 
     def __init__(self, name: str = "mesh", min_slice_chunks: int = 1,
-                 preemption_enabled: bool = True,
                  weights: Optional[Dict[str, float]] = None):
         self.name = name
         self.min_slice_chunks = max(1, int(min_slice_chunks))
-        self.preemption_enabled = bool(preemption_enabled)
         self.weights = dict(weights or {})
         self._lock = named_lock("MeshScheduler._lock")
         self._cond = threading.Condition(self._lock)
@@ -260,12 +258,7 @@ class MeshScheduler:
                 if not fast_waiter:
                     return "run"
             self.preemptions += 1
-            if (
-                fast_waiter
-                and self.preemption_enabled
-                and parkable
-                and not job.no_park
-            ):
+            if fast_waiter and parkable and not job.no_park:
                 METRICS.increment(PREEMPTIONS)
                 return "park"
             # in-place yield: rotate the grant, carries stay resident
@@ -301,28 +294,6 @@ class MeshScheduler:
         with self._lock:
             self.resumes += 1
         METRICS.increment(RESUMES)
-
-    def park_budget_for(self, job: MeshJob, total_bytes: int) -> int:
-        """Admission-weighted park budget: `total_bytes` (the
-        mesh_park_max_bytes pool) apportioned across the groups this
-        scheduler has seen by their scheduling weight — the park-store
-        analogue of the vtime share. A group over its share gets its
-        park refused (the chunk loop degrades to an in-place yield via
-        the latched no_park, never to failure). A single-group
-        scheduler keeps the whole pool; an unbounded pool (< 0) passes
-        through."""
-        if total_bytes < 0:
-            return int(total_bytes)
-        with self._lock:
-            groups = set(self._vtime) | set(self.weights) | {job.group}
-            if len(groups) <= 1:
-                return int(total_bytes)
-            wsum = sum(self.weights.get(g, 1.0) for g in groups)
-            share = (
-                self.weights.get(job.group, 1.0) / wsum
-                if wsum > 0 else 1.0
-            )
-        return int(total_bytes * share)
 
     def park_refused(self, job: MeshJob) -> None:
         """The park budget refused the snapshot: latch no_park so the

@@ -27,7 +27,10 @@ from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
 
+from trino_tpu.config import Session
 from trino_tpu.runtime.tracing import OFF, host_span
+
+_DEFAULT_SESSION = Session()
 
 ROWS_PER_PAGE = 4096
 
@@ -149,48 +152,28 @@ class CoordinatorServer:
         self.authenticator = authenticator or InsecureAuthenticator()
         # serving tier: lane-based admission (shed with 429 instead of
         # queueing without bound) and optional point-lookup coalescing
-        _sess = getattr(runner, "session", None)
         if admission is None:
             from trino_tpu.serving.admission import AdmissionPipeline
 
-            admission = AdmissionPipeline(
-                resource_groups,
-                fast_depth=int(
-                    getattr(_sess, "admission_fast_depth", 64) or 64
-                ),
-                general_depth=int(
-                    getattr(_sess, "admission_general_depth", 256) or 256
-                ),
-                retry_after_s=float(
-                    getattr(_sess, "admission_retry_after_s", 1.0) or 1.0
-                ),
-            )
+            admission = AdmissionPipeline(resource_groups)
         self.admission = admission
         # replica-plane visibility in admission stats (the manager is
         # carved lazily by the runner, hence a supplier, not a value)
         self.admission.attach_replicas(
             lambda: getattr(runner, "_replicas", None)
         )
-        _window_ms = float(
-            getattr(_sess, "micro_batch_window_ms", 0.0) or 0.0
-        )
+        _window_ms = float(self._session().micro_batch_window_ms or 0.0)
         if batcher is None and _window_ms > 0:
             from trino_tpu.serving.batcher import MicroBatcher
 
-            batcher = MicroBatcher(
-                runner,
-                window_s=_window_ms / 1000.0,
-                max_batch=int(getattr(_sess, "micro_batch_max", 16) or 16),
-            )
+            batcher = MicroBatcher(runner, window_s=_window_ms / 1000.0)
         self.batcher = batcher
         self._jobs: Dict[str, _QueryJob] = {}
         self._pool = ThreadPoolExecutor(max_workers=max_concurrent)
         # client-abandonment TTL: explicit arg wins, else the runner
         # session's client_timeout_s, else the class default
         if client_timeout_s is None:
-            client_timeout_s = getattr(
-                getattr(runner, "session", None), "client_timeout_s", None
-            )
+            client_timeout_s = self._session().client_timeout_s
         if client_timeout_s:
             self.CLIENT_TTL_S = float(client_timeout_s)
         outer = self
@@ -461,6 +444,11 @@ class CoordinatorServer:
     # cannot pin results/resources forever
     CLIENT_TTL_S = 300.0
 
+    def _session(self) -> Session:
+        """The runner's session as it stands now; a front that carries
+        none (a test's stub runner) is served under the defaults."""
+        return getattr(self.runner, "session", None) or _DEFAULT_SESSION
+
     def _evict_completed(self) -> None:
         now = time.monotonic()
         for qid, j in list(self._jobs.items()):
@@ -574,10 +562,7 @@ class CoordinatorServer:
                 # query that burned its whole wall budget waiting for an
                 # admission slot fails typed, before launching anything
                 run_limit = float(
-                    getattr(
-                        getattr(self.runner, "session", None),
-                        "query_max_run_time_s", 0.0,
-                    ) or 0.0
+                    self._session().query_max_run_time_s or 0.0
                 )
                 if run_limit and (
                     time.monotonic() - job.created_at > run_limit

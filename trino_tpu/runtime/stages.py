@@ -9,6 +9,10 @@ from typing import Dict, List
 
 from trino_tpu.sql.fragmenter import SubPlan
 
+# tasks per hash-distributed stage where the runner was given no
+# `hash_partitions` (capped by the worker count)
+DEFAULT_HASH_PARTITIONS = 4
+
 
 def topo_order(subplan: SubPlan) -> List[SubPlan]:
     """Children before parents (producers schedule before consumers)."""
@@ -26,7 +30,7 @@ def topo_order(subplan: SubPlan) -> List[SubPlan]:
 def stage_task_count(sp: SubPlan, n_workers: int, hash_partitions: int) -> int:
     """Task-count policy per fragment partitioning; hash stages take the
     stats-driven suggestion (DeterminePartitionCount.java:90) capped by
-    the session's hash_partition_count."""
+    the scheduler's `hash_partitions`."""
     p = sp.fragment.partitioning
     if p == "single":
         return 1

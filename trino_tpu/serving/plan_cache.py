@@ -61,7 +61,7 @@ PLAN_AFFECTING_PROPERTIES = (
 def plan_properties(session) -> Tuple:
     """The plan-shaping slice of a Session, as a hashable tuple."""
     return tuple(
-        getattr(session, name, None) for name in PLAN_AFFECTING_PROPERTIES
+        getattr(session, name) for name in PLAN_AFFECTING_PROPERTIES
     )
 
 

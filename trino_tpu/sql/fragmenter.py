@@ -672,7 +672,6 @@ def explain_distributed(
     catalogs=None,
     batch_rows: int = 1 << 20,
     dynamic_filtering: bool = True,
-    warn_threshold: int = 0,
 ) -> str:
     """EXPLAIN (TYPE DISTRIBUTED) rendering: one section per fragment.
     With `catalogs` each fragment also carries its compile-churn census
@@ -690,7 +689,7 @@ def explain_distributed(
                 f.root, catalogs, batch_rows=batch_rows,
                 dynamic_filtering=dynamic_filtering,
             )
-            header += " " + census_line(classes, warn_threshold)
+            header += " " + census_line(classes)
         lines.append(header)
         lines.append(P.explain_text(f.root, indent=1))
     return "\n".join(lines)

@@ -2027,17 +2027,17 @@ class ReorderJoins:
 def optimize(
     root: P.PlanNode,
     catalogs,
-    session=None,
+    session,
 ) -> P.PlanNode:
     """The PlanOptimizers pipeline: iterative simplification, cost-based
     join reordering, cleanup. `session.enable_optimizer` gates the whole
     pass; `session.join_reordering_strategy` gates the CBO step
     ("automatic" | "none" — SystemSessionProperties
     JOIN_REORDERING_STRATEGY)."""
-    if session is not None and not getattr(session, "enable_optimizer", True):
+    if not session.enable_optimizer:
         return root
-    strategy = getattr(session, "join_reordering_strategy", "automatic")
-    validation = getattr(session, "plan_validation", "passes")
+    strategy = session.join_reordering_strategy
+    validation = session.plan_validation
     if validation != "off":
         from trino_tpu.sql.validate import validate_logical
     else:
@@ -2056,7 +2056,7 @@ def optimize(
 
     stats = StatsCalculator(catalogs)
     rules: Tuple[Rule, ...] = SIMPLIFICATION_RULES
-    if getattr(session, "enable_pushdown", True) and catalogs is not None:
+    if session.enable_pushdown and catalogs is not None:
         rules = rules + (
             PushPredicateIntoTableScan(catalogs),
             PushProjectionIntoTableScan(catalogs),

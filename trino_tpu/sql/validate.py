@@ -917,7 +917,15 @@ def shape_census(
     return out
 
 
-def census_line(classes: List[Lowering], warn_threshold: int = 0) -> str:
+# EXPLAIN (ANALYZE) warns when the shape census predicts more distinct
+# (operator, capacity, dtype) XLA lowerings than this per plan/fragment
+COMPILE_CHURN_WARN_THRESHOLD = 32
+
+
+def census_line(
+    classes: List[Lowering],
+    warn_threshold: int = COMPILE_CHURN_WARN_THRESHOLD,
+) -> str:
     """One summary line for EXPLAIN (ANALYZE) output."""
     n = len(classes)
     variants = sum(1 for c in classes if c.retry_variant)
@@ -938,11 +946,10 @@ def census_line(classes: List[Lowering], warn_threshold: int = 0) -> str:
 
 def census_text(
     classes: List[Lowering],
-    warn_threshold: int = 0,
     observed: Optional[int] = None,
 ) -> str:
     """Multi-line census block: summary + one line per class."""
-    lines = ["Compile-churn census: " + census_line(classes, warn_threshold)]
+    lines = ["Compile-churn census: " + census_line(classes)]
     if observed is not None:
         lines[0] += f" observed_shape_classes={observed}"
     for c in sorted(classes, key=lambda c: (c.operator, c.capacity)):
