@@ -1174,14 +1174,14 @@ class LocalQueryRunner:
             plan_span.set_metadata(hit=1)
             return cached
         from trino_tpu.sql.analyzer import (
-            plan_is_volatile,
-            reset_volatile_plan,
+            decorrelated_scalar_aggregates, plan_is_volatile,
+            reset_plan_marks,
         )
 
         # snapshot the generation BEFORE planning: a DDL landing while
         # we plan must win over our store below
         cache_generation = self._plan_cache.generation
-        reset_volatile_plan()
+        reset_plan_marks()
         with phase("analyze"):
             output = self._analyze(q)
         self._check_scans(output)
@@ -1210,7 +1210,7 @@ class LocalQueryRunner:
                 mxu_join=self.session.mxu_join_enabled,
                 mxu_join_min_work=self.session.mxu_join_min_work,
             )
-            physical = planner.plan(output)
+            physical = planner.plan(output, decorrelated_scalar_aggregates())
         # plans with analysis-time-folded volatile values (now(),
         # current_date, uuid()) re-analyze every execution
         if (

@@ -695,10 +695,10 @@ def _window_compute(
             if kind == "avg":
                 q = v.astype(jnp.float64) / jnp.maximum(cnt, 1) / arg_sf
                 if out_sf is not None:
-                    # decimal avg: rescale into the output's scaled-int64
-                    # domain, rounding half away (same as _agg_output)
-                    q = q * out_sf
-                    q = jnp.sign(q) * jnp.floor(jnp.abs(q) + 0.5)
+                    # decimal avg: at the output's scale, rounded half
+                    # away from zero, in integers as _agg_output's is
+                    # (the chip's float64 quotient lands under a half)
+                    q = _decimal_avg(v, cnt, arg_sf, out_sf)
                 out_cols.append((q.astype(out_dtype), has))
             elif kind == "sum" and out_float:
                 out_cols.append(((v / arg_sf).astype(out_dtype), has))
@@ -1002,12 +1002,12 @@ def _agg_output(spec: AggSpec, state, arg_type: Optional[T.DataType],
             return Column(out_t, jnp.stack([h, lo], axis=-1), has, None)
         return Column(out_t, acc.astype(out_t.dtype), has, None)
     if spec.kind == "avg":
-        q = acc.astype(jnp.float64) / jnp.maximum(cnt, 1)
         if out_t.is_floating:
+            q = acc.astype(jnp.float64) / jnp.maximum(cnt, 1)
             return Column(out_t, (q / arg_sf).astype(out_t.dtype), has, None)
-        # decimal average: rescale to the output scale, round half away
-        q = q * (out_sf / arg_sf)
-        data = (jnp.sign(q) * jnp.floor(jnp.abs(q) + 0.5)).astype(out_t.dtype)
+        # decimal average: at the output scale, half away from zero, in
+        # integers (_decimal_avg: the chip's float64 lands under a half)
+        data = _decimal_avg(acc, cnt, arg_sf, out_sf).astype(out_t.dtype)
         return Column(out_t, data, has, None)
     if spec.kind in ("min", "max", "any"):
         safe = jnp.where(has, acc, jnp.zeros((), dtype=acc.dtype))
@@ -4615,9 +4615,18 @@ class DynamicFilterOperator(Operator):
 
     def __init__(self, bridge: JoinBridge, key_channels: Sequence[int],
                  reverse: bool = False, key_fill: Optional[float] = None,
-                 key_ordered: bool = False):
+                 key_ordered: bool = False, under_aggregate: bool = False):
         self._bridge = bridge
         self._keys = list(key_channels)
+        # not at the end of the filtered side, in front of the probe, but
+        # UNDER the aggregation that side ends in, on its group keys
+        # (`JoinNode.filter_under_aggregate`): the same filters in front
+        # of the aggregation's input, told apart by the stat
+        # `under_aggregate` and counted once an operator; the batches it
+        # hands the aggregation are `agg_filtered_input.batches`
+        self._under_aggregate = under_aggregate
+        if under_aggregate:
+            METRICS.increment("df_under_aggregate")
         # whether the plan found the ONE key to be a column its connector
         # stores in order, scanned with nothing in between that moves
         # rows: the bits of such a batch are looked up a window a block
@@ -4638,6 +4647,8 @@ class DynamicFilterOperator(Operator):
         self._reverse = reverse
         if reverse:
             self.span_stats = {"reverse": 1}
+        if under_aggregate:
+            self.span_stats = {**getattr(self, "span_stats", {}), "under_aggregate": 1}
         self._domains = None
         self._key_set = None
         self._bits = None
@@ -4912,6 +4923,8 @@ class DynamicFilterOperator(Operator):
                     slots=self._slots, path=self._path,
                     key_bytes=self._key_bytes, reverse=int(self._reverse),
                 )
+                if self._under_aggregate:
+                    span.set_metadata(under_aggregate=1)
             METRICS.increment("df_rows_in", rows_in)
             METRICS.increment("df_rows_kept", kept)
             if fallbacks is not None:
@@ -4921,6 +4934,8 @@ class DynamicFilterOperator(Operator):
                 METRICS.increment("df_reverse_rows_kept", kept)
 
     def get_output(self) -> Optional[RelBatch]:
+        if self._outs and self._under_aggregate:
+            METRICS.increment("agg_filtered_input.batches")
         return self._outs.pop(0) if self._outs else None
 
     def is_finished(self) -> bool:
@@ -5270,3 +5285,25 @@ class CollectorSink(Operator):
                 for b in host_batches:
                     out.extend(b.to_pylists())
         return out, host_extra
+
+
+def _decimal_avg(acc, cnt, arg_sf: int, out_sf: int):
+    """`acc / cnt` of a decimal sum scaled by `arg_sf`, at the scale
+    `out_sf`, rounded half away from zero, in int64 (Trino's
+    `avg(decimal)`: `(2 * sum + n) // (2 * n)` for a sum that is not
+    negative). The float64 quotient this replaces is exact on a CPU and
+    not on the chip, whose float64 is made of narrower words: of 100,000
+    (sum, count) pairs up to TPC-H Q17's sizes the 2,196 exact halves
+    among them came out one unit low 3 times (PERF.md section 6, PR 48).
+    Exact while `2 * |sum| * (out_sf // g) + n` fits int64: a sum at the
+    output's scale under 2^62, half the headroom the float64 path had
+    (which was exact to 2^53 only); past it the doubling wraps.
+    (It stands at the file's end, far below its caller `_agg_output`,
+    because a Pallas program's cache key carries the line numbers of
+    this file's frames up to `add_input`: a debt, ROADMAP S5.)"""
+    import math
+
+    g = math.gcd(int(out_sf), int(arg_sf))
+    a = acc.astype(jnp.int64) * (int(out_sf) // g)
+    n = jnp.maximum(cnt, 1).astype(jnp.int64) * (int(arg_sf) // g)
+    return jnp.sign(a) * ((2 * jnp.abs(a) + n) // (2 * n))

@@ -751,13 +751,13 @@ class DistributedQueryRunner:
         else:
             from trino_tpu.sql.analyzer import (
                 plan_is_volatile,
-                reset_volatile_plan,
+                reset_plan_marks,
             )
 
             # snapshot BEFORE planning: a catalog change racing the
             # analyze/optimize/fragment work below must void this store
             cache_generation = self._plan_cache.generation
-            reset_volatile_plan()
+            reset_plan_marks()
             output = self._analyze(stmt, query_span=query_span)
             self._check_access(output, identity)
             # adaptive execution: materialize barriers on the

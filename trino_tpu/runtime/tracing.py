@@ -182,7 +182,19 @@ matched). Of the sort path's batches
 (``agg_ingest_path.sort``), ``agg_ordered_input.batches`` counts those
 whose reduce found them in key order and skipped the key sort,
 ``agg_unordered_input.batches`` the others (both as the batch's flag is
-read, one batch late).
+read, one batch late). A join's key filter that the plan put UNDER the
+aggregation of the side it filters (``JoinNode.filter_under_aggregate``,
+PR 48): ``df_under_aggregate``, one increment an operator so placed (as
+it is made); its ``op.DynamicFilterOperator.*`` calls and its
+``sync.join.dynamic_filter_totals`` carry the stat ``under_aggregate``
+(beside ``reverse``, where the keys are a preserved build side's, and
+its rows are then in ``df_reverse_rows_in`` / ``_kept`` too);
+``agg_filtered_input.batches``, the grouped batches of the aggregation
+such a filter feeds, beside ``agg_ingest_batches``; and
+``decorrelated_scalar_aggregates``, the correlated scalar aggregates the
+analysis turned into a grouped subquery LEFT-joined back, found at plan
+time, kept with the cached plan and counted once an execution (as the
+plan is instantiated). ``chipbench/corr_trace.py`` reads them.
 """
 
 from __future__ import annotations

@@ -242,8 +242,22 @@ def set_session_info(catalog: str, schema: str, user: str) -> None:
     _SESSION_INFO.set((catalog, schema, user))
 
 
-def reset_volatile_plan() -> None:
+# correlated scalar aggregates this analysis turned into a grouped
+# subquery LEFT-joined back (`_plan_correlated_scalar`); the engine keeps
+# the number with the plan it caches, and every execution counts it
+# (METRICS `decorrelated_scalar_aggregates`)
+_DECORRELATED = contextvars.ContextVar("trino_tpu_decorrelated", default=0)
+
+
+def reset_plan_marks() -> None:
+    """Before a statement's analysis: what the analysis will note of
+    its plan (volatile, decorrelated aggregates) starts from nothing."""
     _VOLATILE_PLAN.set(False)
+    _DECORRELATED.set(0)
+
+
+def decorrelated_scalar_aggregates() -> int:
+    return _DECORRELATED.get()
 
 
 def mark_volatile_plan() -> None:
@@ -3658,6 +3672,7 @@ class Analyzer:
             "left", builder.node, node, probe_keys, tuple(range(k)), None,
             builder.node.fields + node.fields,
         )
+        _DECORRELATED.set(_DECORRELATED.get() + 1)
         builder.scope = Scope(
             builder.scope.fields
             + [ScopeField(None, None, f.type) for f in node.fields]

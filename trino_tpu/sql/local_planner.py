@@ -78,6 +78,7 @@ class PhysicalPlan:
         chain: List[Factory],
         schema: Schema,
         warmup_entries: Sequence = (),
+        decorrelated_scalar_aggregates: int = 0,
     ):
         self.pipeline_factories = pipelines
         self.chain_factories = chain
@@ -86,6 +87,10 @@ class PhysicalPlan:
         # programs this plan will dispatch, with their census-predicted
         # capacity classes (the AOT warmup input)
         self.warmup_entries = list(warmup_entries)
+        # correlated scalar aggregates the analysis decorrelated into
+        # this plan (`LocalPlanner.plan`); counted once an execution, so
+        # a plan-cache hit counts what its analysis found
+        self.decorrelated_scalar_aggregates = decorrelated_scalar_aggregates
 
     def instantiate(
         self, ctx: Optional[dict] = None
@@ -93,6 +98,13 @@ class PhysicalPlan:
         """`ctx` seeds the per-execution context; the task runtime
         injects "make_remote_source" for RemoteSourceNode leaves."""
         ctx = {} if ctx is None else ctx
+        if self.decorrelated_scalar_aggregates:
+            from trino_tpu.runtime.metrics import METRICS
+
+            METRICS.increment(
+                "decorrelated_scalar_aggregates",
+                self.decorrelated_scalar_aggregates,
+            )
         pipelines = [
             Pipeline([f(ctx) for f in fs]) for fs in self.pipeline_factories
         ]
@@ -213,6 +225,19 @@ class _JoinWarmer:
                 break
 
 
+@dataclasses.dataclass
+class _KeyFilterSink:
+    """A join's dynamic filter on its way under an aggregation
+    (`LocalPlanner._sink_key_filter`): the side of the join it filters,
+    the node whose output it stands on, what appends it to that node's
+    chain, and whether the visit of that side put it there."""
+
+    side: P.PlanNode
+    at: P.PlanNode
+    place: Callable[[List[Factory]], None]
+    placed: bool = False
+
+
 class LocalPlanner:
     def __init__(
         self,
@@ -247,14 +272,22 @@ class LocalPlanner:
         self._warmup_entries: List = []
         self._stats_calc = None
         self._unread: Dict[int, frozenset] = {}
+        # by `id` of a plan node: what appends a join's key filter to
+        # the chain that ends in that node (a filter that goes under an
+        # aggregation: `_visit_side` leaves it, `_visit` takes it)
+        self._key_filters: Dict[int, object] = {}
 
     # -- public --
-    def plan(self, root: P.PlanNode) -> PhysicalPlan:
+    def plan(self, root: P.PlanNode, decorrelated: int = 0) -> PhysicalPlan:
+        """`decorrelated`: the correlated scalar aggregates the analysis
+        of this statement decorrelated (the engine passes what
+        `analyzer.decorrelated_scalar_aggregates()` found)."""
         self._unread = unread_join_outputs(root)
         chain, schema = self._visit(root)
         return PhysicalPlan(
             self.pipelines, chain, schema,
             warmup_entries=self._warmup_entries,
+            decorrelated_scalar_aggregates=decorrelated,
         )
 
     # -- helpers --
@@ -275,7 +308,11 @@ class LocalPlanner:
         m = getattr(self, f"_visit_{type(node).__name__}", None)
         if m is None:
             raise NotImplementedError(f"no physical plan for {type(node).__name__}")
-        return m(node)
+        chain, schema = m(node)
+        place = self._key_filters.pop(id(node), None)
+        if place is not None:
+            place(chain)
+        return chain, schema
 
     def _visit_OutputNode(self, node: P.OutputNode):
         return self._visit(node.child)
@@ -760,8 +797,16 @@ class LocalPlanner:
         return chain, out_schema
 
     def _visit_JoinNode(self, node: P.JoinNode):
-        build_chain, build_schema = self._visit(node.right)
-        probe_chain, probe_schema = self._visit(node.left)
+        # (called when the plan is instantiated: `key` is set by then)
+        def bridge_of(ctx) -> JoinBridge:
+            return ctx.setdefault(key, JoinBridge())
+
+        sink = self._sink_key_filter(node, bridge_of)
+        build_chain, build_schema = self._visit_side(node.right, sink)
+        probe_chain, probe_schema = self._visit_side(node.left, sink)
+        # whether the key filter is in its place under the aggregation
+        # (else it stands where it always stood)
+        sunk = sink is not None and sink.placed
         build_caps = (
             getattr(build_chain[-1], "out_caps", None) if build_chain
             else None
@@ -771,10 +816,6 @@ class LocalPlanner:
             else None
         )
         key = self._key()
-
-        def bridge_of(ctx) -> JoinBridge:
-            return ctx.setdefault(key, JoinBridge())
-
         if node.kind == "cross":
             build_chain.append(
                 lambda ctx: CrossJoinBuildSink(bridge_of(ctx), build_schema)
@@ -785,7 +826,7 @@ class LocalPlanner:
         if node.kind in ("semi", "anti", "left") and node.build_left:
             return self._join_built_left(
                 node, probe_chain, probe_schema, build_chain, build_schema,
-                bridge_of,
+                bridge_of, sunk,
             )
         rkeys = list(node.right_keys)
         self._end_in_build(node, build_chain, rkeys, build_schema, bridge_of)
@@ -796,7 +837,7 @@ class LocalPlanner:
             )
         lkeys = list(node.left_keys)
         kind = node.kind
-        if kind in ("inner", "semi") and self.dynamic_filtering:
+        if kind in ("inner", "semi") and self.dynamic_filtering and not sunk:
             from trino_tpu.exec.operators import DynamicFilterOperator
 
             # connector reuse: when the probe side is a bare scan, feed
@@ -923,8 +964,52 @@ class LocalPlanner:
         )
         return side.columns[ch] in stats.ordered
 
+    def _sink_key_filter(self, node: P.JoinNode, bridge_of) -> Optional[_KeyFilterSink]:
+        """Where the plan sends `node`'s dynamic filter under an
+        aggregation of the side it filters
+        (`JoinNode.filter_under_aggregate`, `plan.key_filter_target`):
+        that side, the node whose output the filter stands on, and what
+        appends it to a chain, for `_visit_side`. The same operator with
+        the same hints as at the side's end: `key_fill` of the side that
+        gives the keys, `key_ordered` of the scan it now stands on."""
+        if not (node.filter_under_aggregate and self.dynamic_filtering):
+            return None
+        target = P.key_filter_target(node)
+        if target is None:  # (the plan changed under the flag: exchanges)
+            return None
+        from trino_tpu.exec.operators import DynamicFilterOperator
+
+        at, channels, _ = target
+        side, _, source, source_keys = P.filter_sides(node)
+        reverse = side is node.right
+        channels = list(channels)
+        key_fill = self._build_key_fill(source, list(source_keys))
+        key_ordered = self._scan_key_ordered(at, channels)
+
+        def place(chain: List[Factory]) -> None:
+            chain.append(
+                lambda ctx: DynamicFilterOperator(
+                    bridge_of(ctx), channels, reverse=reverse,
+                    key_fill=key_fill, key_ordered=key_ordered,
+                    under_aggregate=True,
+                )
+            )
+
+        return _KeyFilterSink(side, at, place)
+
+    def _visit_side(self, side: P.PlanNode, sink: Optional[_KeyFilterSink]):
+        """Visit one side of a join; where it is the side `sink` filters,
+        with the filter left for the visit of the node it stands on
+        (`_visit` takes it from `_key_filters`)."""
+        if sink is None or sink.side is not side:
+            return self._visit(side)
+        self._key_filters[id(sink.at)] = sink.place
+        out = self._visit(side)
+        sink.placed = self._key_filters.pop(id(sink.at), None) is None
+        return out
+
     def _join_built_left(self, node: P.JoinNode, left_chain, left_schema,
-                         right_chain, right_schema, bridge_of):
+                         right_chain, right_schema, bridge_of, sunk=False):
         """A semi-, anti- or LEFT join whose PRESERVED side is the lookup
         (`JoinNode.build_left`): the left's pipeline ends in the build,
         the other side's scan is filtered by the left's keys (a row
@@ -956,7 +1041,7 @@ class LocalPlanner:
         unread = () if kind == "left" else tuple(sorted(
             frozenset(range(len(left_schema) + len(right_schema))) - read
         ))
-        if self.dynamic_filtering:
+        if self.dynamic_filtering and not sunk:
             key_fill = self._build_key_fill(node.left, lkeys)
             key_ordered = self._scan_key_ordered(node.right, rkeys)
             right_chain.append(

@@ -2078,7 +2078,10 @@ def optimize(
         checkpoint(root, "join_reordering")
     root = _with_aggregates_under_left_joins(root, stats)
     checkpoint(root, "aggregates_under_left_joins")
-    return _with_semi_join_sides(_with_group_key_ranges(root, stats), stats)
+    root = _with_semi_join_sides(_with_group_key_ranges(root, stats), stats)
+    if not session.enable_dynamic_filtering:
+        return root
+    return _with_key_filters_under_aggregates(root, stats)
 
 
 # a LEFT join's null-supplying side is aggregated under the join where
@@ -2200,6 +2203,65 @@ def _with_semi_join_sides(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNod
     if build_left == node.build_left:
         return node
     return dataclasses.replace(node, build_left=build_left)
+
+
+# a join's key filter goes under the aggregation of the side it filters
+# where the side that gives the keys is estimated to have at most this
+# share of the aggregation's groups in rows (so in keys): the filter
+# costs every batch under the aggregation one pass (a gather a row for
+# the key bits), the same bargain as DF_BITS_MAX_FILL in exec/operators.py,
+# and buys the aggregation the groups it is spared
+_UNDER_AGGREGATE_MAX_KEY_SHARE = 0.25
+
+
+def _with_key_filters_under_aggregates(
+    node: P.PlanNode, stats: StatsCalculator
+) -> P.PlanNode:
+    """After the join sides, the last pass: a join whose filtered side
+    (`plan.filter_sides`: an inner or semi-join's probe, the other side
+    of a join that builds the side it preserves) ENDS in an aggregation
+    by the join keys sends its dynamic filter under that aggregation
+    (`plan.key_filter_target`, `JoinNode.filter_under_aggregate`), where
+    the join is on ONE integer key and the estimates say the keys (the
+    rows of the side that gives them, or the key's distinct values where
+    the statistics have fewer) are few beside the aggregation's groups.
+    One integer key, because only there is the filter a membership test
+    (`DynamicFilterOperator`'s key set or key bits): on two keys or more
+    it is a range a column, which keeps nearly every row of a key that
+    lies scattered (TPC-H Q20's `ps_partkey`, `ps_suppkey` under its sum
+    of `lineitem`) and would cost every batch a pass for nothing.
+    TPC-H Q17: the decorrelated `avg(l_quantity)` by `l_partkey` is asked
+    for 2,000 of its 2,000,000 groups; filtered above the aggregation, as
+    a probe's filter stands, it averages 60 M rows into 2 M groups and
+    drops 1,998,000 of them. Where the keys are about as many as the
+    groups (Q18's semi-join on the keys of ALL orders, Q13's LEFT join
+    whose preserved side is ALL customers) the filter would pass over
+    every batch and drop nothing: the plan keeps it where it stood, or
+    has none. Decided from the estimates alone."""
+    node = with_children(
+        node, [_with_key_filters_under_aggregates(c, stats) for c in node.children()]
+    )
+    if not isinstance(node, P.JoinNode):
+        return node
+    under = False
+    target = P.key_filter_target(node)
+    if target is not None:
+        _, _, source, source_keys = P.filter_sides(node)
+        (key,) = source_keys if len(source_keys) == 1 else (None,)
+        try:
+            if key is not None and source.fields[key].type.is_integerlike:
+                given = stats.stats(source)
+                keys = float(given.row_count)
+                if given.col(key).ndv:
+                    # (rows that repeat a key give it once)
+                    keys = min(keys, float(given.col(key).ndv))
+                groups = float(stats.stats(target[2]).row_count)
+                under = keys <= groups * _UNDER_AGGREGATE_MAX_KEY_SHARE
+        except Exception:  # (no estimate: the filter keeps its place)
+            pass
+    if under == node.filter_under_aggregate:
+        return node
+    return dataclasses.replace(node, filter_under_aggregate=under)
 
 
 def _with_group_key_ranges(node: P.PlanNode, stats: StatsCalculator) -> P.PlanNode:

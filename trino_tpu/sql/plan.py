@@ -18,7 +18,7 @@ import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 from trino_tpu import types as T
-from trino_tpu.expr.ir import Expr
+from trino_tpu.expr.ir import Expr, InputRef
 from trino_tpu.ops.sort import SortKey
 
 
@@ -129,7 +129,16 @@ class JoinNode(PlanNode):
     anti-join puts out the flagged rows or the others at its input's
     end, a LEFT join its pairs as they come and the rows no pair
     flagged, with NULLs, at the end. Shown in EXPLAIN as ` build=left`,
-    only where set."""
+    only where set.
+
+    `filter_under_aggregate` (the optimizer's last pass): the dynamic
+    filter this join's built side gives its other side does not stand at
+    that side's end, in front of the probe, but UNDER the aggregation
+    that side ends in (`key_filter_target`): every filtered key is a
+    group key of it, so a row the filter drops could only have made a
+    group the join would drop whole. Shown in EXPLAIN as
+    ` filter=under_aggregate` on the join's line and ` key_filter=[...]`
+    on the line of the node whose output is filtered, only where set."""
 
     kind: str
     left: PlanNode
@@ -152,9 +161,64 @@ class JoinNode(PlanNode):
     skew_hot_keys: Tuple = ()
     spill_build: bool = False
     build_left: bool = False
+    filter_under_aggregate: bool = False
 
     def children(self):
         return (self.left, self.right)
+
+
+def filter_sides(join: JoinNode):
+    """(the side of `join` that its built side's keys filter, that side's
+    key channels, the built side, its key channels), or None for a join
+    that filters neither side: an inner or semi-join filters its probe
+    (the left) by its build's keys; a semi-, anti- or LEFT join that
+    builds the side it preserves (`build_left`) filters the other side,
+    the right, by the preserved side's."""
+    if not join.left_keys:
+        return None
+    left = (join.left, tuple(join.left_keys))
+    right = (join.right, tuple(join.right_keys))
+    if join.kind in ("semi", "anti", "left") and join.build_left:
+        return right + left
+    if join.kind in ("inner", "semi"):
+        return left + right
+    return None
+
+
+def key_filter_target(join: JoinNode):
+    """Where `join`'s dynamic filter can stand under an aggregation of
+    the side it filters: (the node whose OUTPUT it filters, the key
+    channels there, the outermost aggregation it went under), or None
+    where that side does not end in such an aggregation. From the side's root down:
+    a projection hands a key on where it is a plain column, a filter
+    hands every column on, and a single-step aggregation hands a key on
+    where it is one of its GROUP keys (never an aggregate's output, never
+    a global aggregation: dropping a key's rows drops exactly that key's
+    group, and a global aggregation has one group for every row). The
+    walk ends at the first node that hands some key on no further; it
+    never ends ON a filter (a filter's child takes it)."""
+    sides = filter_sides(join)
+    if sides is None:
+        return None
+    node, channels = sides[:2]
+    under = None
+    while True:
+        if isinstance(node, FilterNode):
+            node = node.child
+        elif isinstance(node, ProjectNode):
+            exprs = [node.exprs[c] for c in channels]
+            if not all(isinstance(e, InputRef) for e in exprs):
+                break
+            node, channels = node.child, tuple(e.index for e in exprs)
+        elif (isinstance(node, AggregateNode) and node.step == "single"
+              and node.group_channels
+              and all(c < len(node.group_channels) for c in channels)):
+            under = under or node
+            channels = tuple(node.group_channels[c] for c in channels)
+            node = node.child
+        else:
+            break
+    return None if under is None else (node, channels, under)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -340,8 +404,11 @@ class RemoteSourceNode(PlanNode):
     merge_keys: Tuple = ()
 
 
-def explain_text(node: PlanNode, indent: int = 0) -> str:
-    """EXPLAIN rendering (textual plan like Trino's PlanPrinter)."""
+def explain_text(node: PlanNode, indent: int = 0, marks=None) -> str:
+    """EXPLAIN rendering (textual plan like Trino's PlanPrinter).
+    `marks`: what the joins above wrote for the lines of nodes below
+    them (by `id`), appended to the node's line."""
+    marks = {} if marks is None else marks
     pad = "  " * indent
     name = type(node).__name__.replace("Node", "")
     detail = ""
@@ -384,6 +451,10 @@ def explain_text(node: PlanNode, indent: int = 0) -> str:
             + (" +residual" if node.residual is not None else "")
             + (" build=left" if node.build_left else "")
         )
+        target = key_filter_target(node) if node.filter_under_aggregate else None
+        if target is not None:
+            detail += " filter=under_aggregate"
+            marks[id(target[0])] = f" key_filter={list(target[1])}"
         # skew annotations render only when present, so plans with no
         # skew stay byte-identical to the unannotated output
         if node.skew_hot_keys:
@@ -404,7 +475,7 @@ def explain_text(node: PlanNode, indent: int = 0) -> str:
             f" rows={len(node.rows)} spool={node.spool_key}"
             f" [{getattr(node, 'source_desc', '')}]"
         )
-    lines = [f"{pad}{name}{detail}"]
+    lines = [f"{pad}{name}{detail}{marks.get(id(node), '')}"]
     for c in node.children():
-        lines.append(explain_text(c, indent + 1))
+        lines.append(explain_text(c, indent + 1, marks))
     return "\n".join(lines)
